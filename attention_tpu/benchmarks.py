@@ -30,15 +30,23 @@ from attention_tpu.ops.reference import attention_xla
 from attention_tpu.parallel.kv_sharded import kv_sharded_attention
 from attention_tpu.parallel.mesh import default_mesh
 from attention_tpu.parallel.ring import ring_attention
-from attention_tpu.utils.flops import attention_flops, utilization
+from attention_tpu.utils.flops import (
+    UnknownDeviceError,
+    attention_flops,
+    utilization,
+)
 from attention_tpu.utils.profiling import RunRecord
-from attention_tpu.utils.timing import benchmark_attention
+from attention_tpu.utils.timing import benchmark
 
 
 def _record(config, backend, m, n, dk, dv, dtype, timing, *, n_devices=1,
             mesh_axes=None, extra=None) -> RunRecord:
     flops = attention_flops(m, n, dk, dv)
     dev = jax.devices()[0]
+    try:
+        util = utilization(flops, timing.best_s, dev) / n_devices
+    except UnknownDeviceError:
+        util = None  # off-chip (e.g. the CPU test mesh): no peak to share
     return RunRecord(
         config=config,
         backend=backend,
@@ -47,7 +55,7 @@ def _record(config, backend, m, n, dk, dv, dtype, timing, *, n_devices=1,
         best_us=timing.best_us,
         median_us=timing.median_s * 1e6,
         gflops_per_chip=flops / timing.best_s / 1e9 / n_devices,
-        utilization=utilization(flops, timing.best_s, dev) / n_devices,
+        utilization=util,
         device_kind=getattr(dev, "device_kind", "unknown"),
         n_devices=n_devices,
         mesh_axes=dict(mesh_axes) if mesh_axes else None,
@@ -86,17 +94,17 @@ def ablation_table(
     qf, kf, vf = _qkv(m, n, dk, dv, jnp.float32)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (qf, kf, vf))
 
-    t = benchmark_attention(attention_xla, qf, kf, vf, repeats=repeats)
+    t = benchmark(attention_xla, qf, kf, vf, repeats=repeats)
     variants["baseline"] = _record("ablation", "xla-f32", m, n, dk, dv,
                                    "float32", t)
-    t = benchmark_attention(flash_attention, qf, kf, vf, block_sizes=bs, repeats=repeats)
+    t = benchmark(flash_attention, qf, kf, vf, block_sizes=bs, repeats=repeats)
     variants["fused"] = _record("ablation", "flash-f32", m, n, dk, dv,
                                 "float32", t)
-    t = benchmark_attention(attention_xla, qb, kb, vb, repeats=repeats)
+    t = benchmark(attention_xla, qb, kb, vb, repeats=repeats)
     variants["mixed"] = _record("ablation", "xla-bf16", m, n, dk, dv,
                                 "bfloat16", t)
     if mesh is not None:
-        t = benchmark_attention(
+        t = benchmark(
             kv_sharded_attention, qf, kf, vf, mesh=mesh, block_sizes=bs,
             repeats=repeats,
         )
@@ -104,7 +112,7 @@ def ablation_table(
             "ablation", "kv-sharded-f32", m, n, dk, dv, "float32", t,
             n_devices=mesh.devices.size, mesh_axes=mesh.shape,
         )
-        t = benchmark_attention(
+        t = benchmark(
             kv_sharded_attention, qb, kb, vb, mesh=mesh, block_sizes=bs,
             repeats=repeats,
         )
@@ -113,7 +121,7 @@ def ablation_table(
             n_devices=mesh.devices.size, mesh_axes=mesh.shape,
         )
     else:
-        t = benchmark_attention(flash_attention, qb, kb, vb, block_sizes=bs,
+        t = benchmark(flash_attention, qb, kb, vb, block_sizes=bs,
                       repeats=repeats)
         variants["full"] = _record("ablation", "flash-bf16", m, n, dk, dv,
                                    "bfloat16", t)
@@ -145,7 +153,7 @@ def strong_scaling(
             continue
         mesh = default_mesh("kv" if backend == "kv-sharded" else "sp",
                             devices=jax.devices()[:r])
-        t = benchmark_attention(fn, q, k, v, mesh=mesh, block_sizes=bs, repeats=repeats)
+        t = benchmark(fn, q, k, v, mesh=mesh, block_sizes=bs, repeats=repeats)
         out.append(
             _record("strong_scaling", backend, m, n, dk, dv, dtype, t,
                     n_devices=r, mesh_axes=mesh.shape)
@@ -190,7 +198,7 @@ def placement_table(
     out: dict[str, RunRecord] = {}
     for name, order in orders.items():
         mesh = jax.sharding.Mesh(list(order), ("kv",))
-        t = benchmark_attention(kv_sharded_attention, q, k, v, mesh=mesh,
+        t = benchmark(kv_sharded_attention, q, k, v, mesh=mesh,
                                 block_sizes=bs, repeats=repeats)
         out[name] = _record("placement", "kv-sharded", m, n, dk, dv, dtype,
                             t, n_devices=r, mesh_axes=mesh.shape)
@@ -224,7 +232,7 @@ def weak_scaling(
         q, k, v = _qkv(m, n, dk, dv, dtype)
         mesh = default_mesh("kv" if backend == "kv-sharded" else "sp",
                             devices=jax.devices()[:r])
-        t = benchmark_attention(fn, q, k, v, mesh=mesh, block_sizes=bs, repeats=repeats)
+        t = benchmark(fn, q, k, v, mesh=mesh, block_sizes=bs, repeats=repeats)
         out.append(
             _record("weak_scaling", backend, m, n, dk, dv, dtype, t,
                     n_devices=r, mesh_axes=mesh.shape,

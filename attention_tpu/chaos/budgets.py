@@ -3,8 +3,8 @@
 Every numeric claim the fuzzer enforces lives here.  The bf16/fp32
 kernel families are held to the reference's frozen ±0.02 elementwise
 contract (`attention.c:143` — `core.testcase.VERIFY_THRESHOLD`); the
-quantized caches are held to their MEASURED budgets (tests/test_quant.py,
-RESULTS.md round 5): int8 sits comfortably inside the contract, int4 is
+quantized caches are held to their MEASURED budgets
+(tests/test_quant.py): int8 sits comfortably inside the contract, int4 is
 an opt-in bytes/quality trade whose budget is ~4x the contract (and ~2x
 again under a sliding window, where fewer softmax terms average less of
 the nibble noise out).

@@ -3,26 +3,55 @@
 The reference keeps a compiled serial C implementation as its bit-level
 oracle and CPU baseline (`attention.c`); this module provides the same
 natively-compiled role for this framework.  The library is built on first
-use with the system C compiler and cached next to the sources; every
-entry point falls back to the NumPy implementations in
-:mod:`attention_tpu.core` if no compiler is available, so the Python
-framework never hard-depends on the native path.
+use with the system C compiler and cached next to the sources under a
+name keyed by the sources' content and the host CPU (`_artifact_key`):
+the build uses ``-march=native``, and a checkout copied to another
+machine carries its untracked build products along, so an artefact is
+only ever loaded by the kind of host that built it, from the sources it
+sits beside.  Every entry point falls back to the NumPy implementations
+in :mod:`attention_tpu.core` if no compiler is available
+(:func:`oracle_in_use` says which ran, and the fallback is logged), so
+the Python framework never hard-depends on the native path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
 
 import numpy as np
 
+_logger = logging.getLogger("attention_tpu.core.native")
+
 _CSRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc")
-_LIB_NAME = "libattn_serial.so"
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_error: str | None = None
+
+
+def _artifact_key(srcs: list[str]) -> str:
+    """Short digest of the sources' bytes and the host CPU (machine,
+    model, ISA flags) — the part of a build product's file name that
+    ties it to the host kind and sources it was built from."""
+    h = hashlib.sha256(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("model name", "flags", "Features")):
+                    h.update(line.encode())
+                    if line.startswith(("flags", "Features")):
+                        break
+    except OSError:
+        pass
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:12]
 
 
 def _compile(srcs: list[str], out_path: str, *, shared: bool) -> bool:
@@ -59,17 +88,20 @@ def _build_and_load() -> ctypes.CDLL | None:
             return _lib
         csrc = os.path.abspath(_CSRC)
         src = os.path.join(csrc, "attention_serial.c")
-        lib_path = os.path.join(csrc, _LIB_NAME)
         try:
-            if not os.path.exists(lib_path) or os.path.getmtime(
-                lib_path
-            ) < os.path.getmtime(src):
-                if not _compile([src], lib_path, shared=True):
-                    _build_error = "no working C compiler found"
-                    return None
-            lib = ctypes.CDLL(lib_path)
+            lib_path = os.path.join(
+                csrc, f"libattn_serial.{_artifact_key([src])}.so")
+            if not os.path.exists(lib_path) and not _compile(
+                    [src], lib_path, shared=True):
+                _build_error = "no working C compiler found"
+            else:
+                lib = ctypes.CDLL(lib_path)
         except OSError as e:  # load failure / missing sources
             _build_error = str(e)
+        if _build_error is not None:
+            _logger.warning(
+                "native C oracle unavailable (%s); the NumPy oracle "
+                "serves instead", _build_error)
             return None
 
         i64 = ctypes.c_int64
@@ -92,6 +124,16 @@ def _build_and_load() -> ctypes.CDLL | None:
 
 def native_available() -> bool:
     return _build_and_load() is not None
+
+
+def oracle_in_use() -> str:
+    """Which implementation serves `attention_native` in this process:
+    the compiled C library (by file name) or the NumPy fallback (with
+    the reason the build or load failed)."""
+    lib = _build_and_load()
+    if lib is None:
+        return f"numpy fallback ({_build_error})"
+    return f"native C ({os.path.basename(lib._name)})"
 
 
 def attention_native(
@@ -183,11 +225,11 @@ def native_cli_path() -> str | None:
     csrc = os.path.abspath(_CSRC)
     src_main = os.path.join(csrc, "attention_main.c")
     src_lib = os.path.join(csrc, "attention_serial.c")
-    out = os.path.join(csrc, _CLI_NAME)
     try:
-        newest = max(os.path.getmtime(src_main), os.path.getmtime(src_lib))
+        out = os.path.join(
+            csrc, f"{_CLI_NAME}.{_artifact_key([src_main, src_lib])}")
     except OSError:
         return None
-    if os.path.exists(out) and os.path.getmtime(out) >= newest:
+    if os.path.exists(out):
         return out
     return out if _compile([src_main, src_lib], out, shared=False) else None

@@ -250,6 +250,12 @@ class ServingEngine:
             self._pool_sharding = NamedSharding(
                 self.mesh, PartitionSpec(None, "tp", None, None)
             )
+            # place the parameters on the mesh ONCE: left uncommitted on
+            # the default device they would be re-replicated to every
+            # shard by each step's launch
+            self.params = jax.device_put(
+                params, NamedSharding(self.mesh, PartitionSpec())
+            )
         else:
             self.mesh = None
             self._step_model = model

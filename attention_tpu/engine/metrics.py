@@ -260,7 +260,7 @@ class EngineMetrics:
             best_us=round(per_tok_us, 2),
             median_us=round(per_tok_us, 2),
             gflops_per_chip=0.0,
-            utilization=0.0,
+            utilization=None,
             device_kind=device_kind,
             n_devices=n_dev,
             extra={**s, **(extra or {})},

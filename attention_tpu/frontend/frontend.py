@@ -1818,7 +1818,7 @@ class ServingFrontend:
             best_us=0.0,
             median_us=0.0,
             gflops_per_chip=0.0,
-            utilization=0.0,
+            utilization=None,
             device_kind="virtual",
             n_devices=self.config.num_replicas,
             extra={**s, **(extra or {})},

@@ -35,20 +35,8 @@ from jax.sharding import PartitionSpec as P
 
 def _active_mesh_axes() -> tuple | None:
     """Axis names of the mesh context the caller entered (via
-    `attention_tpu.parallel.mesh.mesh_context`), or None when no mesh
-    is active — tolerant of jax API generations:
-    ``jax.sharding.get_abstract_mesh`` where it exists, else the
-    thread-resource env older jax keeps for ``with mesh:`` contexts."""
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        mesh = gam()
-        return None if mesh.empty else tuple(mesh.axis_names)
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001 - private-path drift reads as no mesh
-        return None
+    ``jax.sharding.set_mesh``), or None when no mesh is active."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else tuple(mesh.axis_names)
 
 
@@ -68,8 +56,7 @@ def _maybe_constrain(x, spec: P | None):
         raise ValueError(
             f"ep_axis {missing} not in the current mesh "
             f"(axes {mesh_axes}); enter the mesh with "
-            "attention_tpu.parallel.mesh.mesh_context or fix the "
-            "axis name"
+            "jax.sharding.set_mesh or fix the axis name"
         )
     return jax.lax.with_sharding_constraint(x, spec)
 

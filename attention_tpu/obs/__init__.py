@@ -152,4 +152,5 @@ def record_run(record: Any) -> None:
               "backend": str(d.get("backend", ""))}
     _RUNS.inc(**labels)
     _RUN_US.set(float(d.get("best_us", 0.0)), **labels)
-    _RUN_UTIL.set(float(d.get("utilization", 0.0)), **labels)
+    if d.get("utilization") is not None:
+        _RUN_UTIL.set(float(d["utilization"]), **labels)

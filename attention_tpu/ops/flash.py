@@ -86,35 +86,15 @@ _UNSAFE_SKIP_GUARD = False
 _BOUND_MIN_SCORE_ELEMS = 24 * 2**20
 
 
-def _compiler_params_cls():
-    """The pallas TPU compiler-params class under either of its
-    spellings (``CompilerParams`` in newer pallas, ``TPUCompilerParams``
-    in older), or None when neither exists."""
-    return (getattr(pltpu, "CompilerParams", None)
-            or getattr(pltpu, "TPUCompilerParams", None))
-
-
 def _compiler_params(semantics, vmem_limit_bytes=None):
-    """CompilerParams with dimension semantics, tolerant of API spelling
-    drift across pallas versions — both the CLASS name
-    (CompilerParams/TPUCompilerParams) and its kwargs (shared by the
-    forward and backward kernels).  ``vmem_limit_bytes`` raises
-    Mosaic's scoped-VMEM budget — the fused backward kernel's
-    VMEM-resident (m_pad, d) fp32 dQ block legitimately exceeds the
-    default budget."""
-    cls = _compiler_params_cls()
-    if cls is None:
-        return None
-    kw = {"dimension_semantics": semantics}
-    if vmem_limit_bytes is not None:
-        kw["vmem_limit_bytes"] = vmem_limit_bytes
-    try:
-        return cls(**kw)
-    except TypeError:  # older/newer param spelling
-        try:
-            return cls(dimension_semantics=semantics)
-        except TypeError:
-            return None
+    """`pltpu.CompilerParams` with dimension semantics (shared by the
+    forward, backward, decode and ragged kernels).  ``vmem_limit_bytes``
+    raises Mosaic's scoped-VMEM budget above its ~16 MB default — the
+    big forward tiles, the fused backward's VMEM-resident (m_pad, d)
+    fp32 dQ block and the ragged kernel's resident packed blocks
+    legitimately exceed it."""
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 class BlockSizes(NamedTuple):
@@ -187,12 +167,12 @@ class BlockSizes(NamedTuple):
             if window is not None:
                 return (512, 512)
             if big_tiles is None:
-                big_tiles = _vmem_limit_supported() and _big_tile_device()
+                big_tiles = _big_tile_device()
             if not big_tiles:
-                # without the raised budget (old pallas) or enough
-                # physical VMEM (v2/v3 cores ~16 MB accept the kwarg
-                # but cannot honor it) the big tiles cannot compile:
-                # keep the round-3 defaults that fit ~16 MB
+                # without enough physical VMEM (v2/v3 cores ~16 MB
+                # accept the raised budget but cannot honor it) the big
+                # tiles cannot compile: keep the defaults that fit
+                # ~16 MB
                 return (1024, 1024) if returns_stats else (2048, 1024)
             # padding-aware: _flash_call pads m to a block_q multiple,
             # so a 4096-row tile on e.g. m=10240 would compute +20%
@@ -272,26 +252,12 @@ def _tuned_max_mode(kernel: str, *, dtype=None, default: str = "online",
     return mode if mode in (allowed or MAX_MODES) else default
 
 
-def _vmem_limit_supported() -> bool:
-    """Whether this pallas accepts ``vmem_limit_bytes`` — the big-tile
-    forward default and the fused backward both NEED the raised budget;
-    without support the defaults must stay inside Mosaic's ~16 MB."""
-    cls = _compiler_params_cls()
-    if cls is None:
-        return False
-    try:
-        cls(dimension_semantics=("parallel",), vmem_limit_bytes=2**20)
-        return True
-    except TypeError:
-        return False
-
-
 @functools.cache
 def _big_tile_device() -> bool:
     """Whether the default device's physical VMEM can hold the big-tile
-    defaults (~110 MB scoped budget).  `_vmem_limit_supported` only
-    proves the API accepts the kwarg; a v2/v3 core (~16 MB VMEM) would
-    accept it and then fail to compile, so gate on the generation too.
+    defaults (~110 MB scoped budget).  A v2/v3 core (~16 MB VMEM)
+    accepts ``vmem_limit_bytes`` and then fails to compile, so gate on
+    the generation.
     Non-TPU backends (pallas interpret mode) have no VMEM to exhaust."""
     try:
         dev = jax.devices()[0]

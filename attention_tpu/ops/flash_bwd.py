@@ -60,7 +60,6 @@ from attention_tpu.ops.flash import (
     _big_tile_device,
     _ceil_to,
     _compiler_params,
-    _vmem_limit_supported,
 )
 
 
@@ -468,7 +467,7 @@ def _fused_chunk_choice(m, n, d, dv, block_sizes, dtype, *, window,
     gate chunking: each chunk patches its sink sliver via per-chunk
     q_offset (`_sink_patch`), so they are chunk-compatible by design."""
     if (segmented or block_sizes is not None
-            or not _vmem_limit_supported() or not _big_tile_device()
+            or not _big_tile_device()
             or _fused_plan(m, n, d, dv, None, dtype, window) is not None):
         return None
     return next(
@@ -490,7 +489,7 @@ def fused_backward_applicable(m: int, d: int, *, window, sinks,
     two-kernel path 14·mnd.  ``sinks`` stays in the signature so
     callers describe the full call, but never gates eligibility —
     sinks are chunk-compatible by design (`_fused_chunk_choice`)."""
-    if not _vmem_limit_supported() or not _big_tile_device():
+    if not _big_tile_device():
         return False
     n_eff = n if n is not None else m
     dv_eff = dv if dv is not None else d
@@ -811,8 +810,7 @@ def flash_backward(
         if segmented:
             raise ValueError("sinks do not compose with segment_ids")
     # Backward default pinned independently of the forward's: with the
-    # deterministic device clock (scripts/bwd_sweep.py + the shape grid
-    # in RESULTS.md round 2), 1024x1024 beats the round-1 512x512 by
+    # deterministic device clock (scripts/bwd_sweep.py), 1024x1024 beats the round-1 512x512 by
     # 22-28% on bf16 at every shape that compiles (9.43->7.39 ms at
     # 16q/4kv 8k causal; 8.24->6.41 at 16k; 6.50->5.40 non-causal 8k),
     # where 2048x1024 / 1024x2048 VMEM-OOM on some shapes.  fp32 inputs

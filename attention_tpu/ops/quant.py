@@ -460,7 +460,7 @@ def flash_decode_quantized_chunk(
 
 # ---------------------------------------------------------------------------
 # int4 KV cache (round 5): half the int8 value bytes.  Decode sits at
-# frac 1.00 of the measured HBM streaming ceiling (BENCH_r04), so the
+# frac 1.00 of the measured HBM streaming ceiling (bench.py --all), so the
 # only remaining currency is bytes streamed — int4 cuts the VALUE
 # stream to 0.25x bf16; with the 32B/row replicated fp32 scales the
 # total at d=128 is (64+32)/256 = 0.375x bf16 (0.6x of int8's 0.625x
@@ -547,7 +547,7 @@ def _unpack_int4(packed):
 def quantize_kv_int4(k: jax.Array, v: jax.Array) -> Int4KV:
     """Quantize full (B, Hkv, N, d) K/V caches to the int4 cache format.
 
-    MEASURED error budget (tests/test_quant.py, RESULTS.md round 5):
+    MEASURED error budget (tests/test_quant.py):
     ~4-8e-2 max abs output error on unit-normal inputs at d=64/128
     decode shapes — ~30x int8's ~2e-3, dominated by K's nibble
     granularity (absmax/7 per element) perturbing the logits.  That
@@ -674,7 +674,8 @@ def flash_decode_int4(
 # side).  The feature-dim packing above measured 0.748 ms vs int8's
 # 0.445 at the bench decode shape: its (block_k, d/2=64) value tiles
 # are HALF the native 128-lane width, so the value stream loses the
-# full-width DMA efficiency the int8 kernel rides (RESULTS.md round 5).
+# full-width DMA efficiency the int8 kernel rides
+# (artifacts/int4_pack_exp.json).
 # This layout packs two ADJACENT TOKENS per byte instead — byte row r
 # holds token 2r in its low nibble and token 2r+1 in its high nibble,
 # per feature — so value tiles stay (rows, d=128) full lane width and

@@ -86,6 +86,11 @@ _RAGGED_LOWERED = obs.counter(
     "ops.ragged.lowered",
     "ragged kernel lowerings by requested/resolved max mode")
 
+# Mosaic's default scoped-VMEM budget, and the ceiling a raised budget
+# may ask for (the forward kernel's big-tile figure: v4+ cores hold it).
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+_MAX_SCOPED_VMEM = 110 * 2**20
+
 #: max_mode values the ragged kernel accepts — "bound" is forward-only
 #: (it needs the key-norm prefetch this grid does not carry).
 RAGGED_MAX_MODES = ("online", "flashd", "amla", "auto")
@@ -172,6 +177,18 @@ def tile_tokens(max_q_len: int, group: int) -> int:
     return t
 
 
+def _row_tile(q_tile: int, t_pad: int, group: int) -> int:
+    """Rows of the kernel's per-slot query tile: ``q_tile * group``,
+    plus 8 spare rows when the tile start has to be rounded down to the
+    sublane granule (group not a multiple of 8 — see `_ragged_kernel`).
+    A tile spanning the whole packed axis starts at row 0 and needs
+    none."""
+    q_rows = q_tile * group
+    if group % 8 == 0 or q_tile == t_pad:
+        return q_rows
+    return q_rows + 8
+
+
 def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
                        kv_heads: int | None = None, seq: int = 0,
                        dim: int = 0, batch: int = 1,
@@ -202,7 +219,7 @@ def _ragged_kernel(
     lens_ref, cu_ref, dist_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
     acc_scr, m_scr, l_scr,
     *, s_slots: int, group: int, page: int, q_tile: int, t_pad: int,
-    softcap2, window: int | None, sinks: int | None,
+    tile_rows: int, softcap2, window: int | None, sinks: int | None,
     variant: str = "online",
 ):
     """One (kv-head * slot, logical-page) grid step.
@@ -217,15 +234,22 @@ def _ragged_kernel(
     j = pl.program_id(1)
     num_j = pl.num_programs(1)
     r = jax.lax.rem(rh, s_slots)
-    q_rows = q_tile * group
     raw_len = lens_ref[r]
     kv_len = jnp.maximum(raw_len, 0)  # poisoned slots read nothing
     q_start = cu_ref[r]
     q_len = cu_ref[r + 1] - q_start
     active = jnp.logical_and(r < dist_ref[1], q_len > 0)
-    # tile start: the span head, clamped so the tile stays in-bounds
-    # (q_len <= q_tile by the caller contract, so the span always fits)
-    clamp = jnp.minimum(q_start, t_pad - q_tile)
+    # tile start, in packed ROWS (token * group + head): the span head
+    # rounded down to the 8-row sublane granule, clamped so the tile
+    # stays in-bounds.  Mosaic refuses a dynamic sublane slice of a
+    # 16-bit ref unless it can prove the start 8-aligned, and
+    # ``q_start * group`` is only provably so when group % 8 == 0 — so
+    # the start is aligned here and the tile carries `_row_tile`'s 8
+    # spare rows (q_len <= q_tile by the caller contract, so the span
+    # always fits; rows outside it are masked per row below).
+    tile_start = pl.multiple_of(
+        jnp.minimum(q_start * group // 8 * 8, t_pad * group - tile_rows),
+        8)
     # the band must admit the EARLIEST query row's window; per-row
     # exactness comes from the mask below (the decode kernels' chunk rule)
     w_eff = (window + q_tile - 1) if window is not None else None
@@ -245,7 +269,7 @@ def _ragged_kernel(
 
     @pl.when(live)
     def _tile():
-        qb = q_ref[0, pl.ds(clamp * group, q_rows), :]
+        qb = q_ref[0, pl.ds(tile_start, tile_rows), :]
         s = jax.lax.dot_general(
             qb, k_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -253,7 +277,7 @@ def _ragged_kernel(
         if softcap2 is not None:
             s = softcap2 * jnp.tanh(s / softcap2)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        seg = clamp + row // group - q_start   # span offset per row
+        seg = (tile_start + row) // group - q_start  # span offset per row
         pos = kv_len - q_len + seg             # absolute cache position
         col = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = jnp.logical_and(
@@ -286,10 +310,10 @@ def _ragged_kernel(
         # poisoned slots (bad append, length -1) emit NaN, loudly
         res = jnp.where(raw_len < 0, jnp.nan, res)
         row = jax.lax.broadcasted_iota(jnp.int32, res.shape, 0)
-        seg = clamp + row // group - q_start
+        seg = (tile_start + row) // group - q_start
         mine = jnp.logical_and(seg >= 0, seg < q_len)
-        cur = o_ref[0, pl.ds(clamp * group, q_rows), :]
-        o_ref[0, pl.ds(clamp * group, q_rows), :] = jnp.where(
+        cur = o_ref[0, pl.ds(tile_start, tile_rows), :]
+        o_ref[0, pl.ds(tile_start, tile_rows), :] = jnp.where(
             mine, res, cur.astype(jnp.float32)
         ).astype(o_ref.dtype)
 
@@ -393,13 +417,30 @@ def _ragged_paged_attention_jit(
         jj = banded_block_clamp(j, valid, page, w_eff, sinks)
         return (jnp.maximum(tbl_ref[r, jj], 0), rh // s_slots, 0, 0)
 
-    q_rows = q_tile * group
+    tile_rows = _row_tile(q_tile, t_pad, group)
     kernel = functools.partial(
         _ragged_kernel, s_slots=s_slots, group=group, page=page,
-        q_tile=q_tile, t_pad=t_pad,
+        q_tile=q_tile, t_pad=t_pad, tile_rows=tile_rows,
         softcap2=None if softcap is None else softcap * _LOG2E,
         window=window, sinks=sinks, variant=variant,
     )
+    # Scoped-VMEM demand: the head's whole packed q and out blocks stay
+    # resident (double-buffered by the pipeline), plus the K/V page
+    # buffers, the fp32 scratch, and the tile's (rows, page) score /
+    # probability temporaries.  Past Mosaic's ~16 MB default budget
+    # (packed width >= 2048 at group 8) the budget is raised to what
+    # the call needs, like the forward kernel's big tiles; small steps
+    # keep the default.
+    kv_item = cache.k_pool.dtype.itemsize
+    vmem_need = (
+        2 * t_pad * group * (d * qs.dtype.itemsize + dv * kv_item)
+        + 4 * page * (d + dv) * kv_item
+        + tile_rows * (dv + 2 * _STAT_LANES) * 4
+        + 3 * tile_rows * page * 4
+    )
+    vmem_limit = None
+    if vmem_need > _DEFAULT_SCOPED_VMEM // 2:
+        vmem_limit = min(int(vmem_need * 1.5), _MAX_SCOPED_VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(hkv * s_slots, max_pages),
@@ -416,9 +457,9 @@ def _ragged_paged_attention_jit(
                                                         0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((q_rows, dv), jnp.float32),
-            pltpu.VMEM((q_rows, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((q_rows, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((tile_rows, dv), jnp.float32),
+            pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
         ],
     )
     outs = pl.pallas_call(
@@ -430,13 +471,14 @@ def _ragged_paged_attention_jit(
         ],
         # NOT parallel: every slot of one head accumulates into the
         # same resident output block
-        compiler_params=_compiler_params(("arbitrary", "arbitrary")),
+        compiler_params=_compiler_params(("arbitrary", "arbitrary"),
+                                         vmem_limit_bytes=vmem_limit),
         cost_estimate=pl.CostEstimate(
-            flops=2 * hkv * s_slots * q_rows * max_pages * page
+            flops=2 * hkv * s_slots * tile_rows * max_pages * page
             * (d + dv),
             bytes_accessed=hkv * s_slots * max_pages * page * (d + dv)
             * cache.k_pool.dtype.itemsize + qs.size * qs.dtype.itemsize,
-            transcendentals=hkv * s_slots * q_rows * max_pages * page,
+            transcendentals=hkv * s_slots * tile_rows * max_pages * page,
         ),
         interpret=interpret,
     )(lens, cu, dist, cache.page_table, qs, cache.k_pool, cache.v_pool)

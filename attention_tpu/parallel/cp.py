@@ -38,12 +38,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from attention_tpu.ops.flash import BlockSizes
 from attention_tpu.ops.flash_vjp import flash_attention_diff
-from attention_tpu.parallel.mesh import shard_map
 
 
 def _maybe_axis(mesh: Mesh, axis: str | None, dim: int) -> str | None:

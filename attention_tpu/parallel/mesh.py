@@ -49,44 +49,6 @@ MERGE_ALPHA = 2.0
 KV_REPLICATE_HBM_CAP_BYTES = 4 * 2**30
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across API generations (the compat shim every
-    orchestrator in this package routes through).
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older
-    releases spell it ``check_rep`` and/or keep the function under
-    ``jax.experimental.shard_map``.  One resolution point here beats
-    twelve call sites drifting independently (the
-    ``_compiler_params`` lesson from `ops/flash.py`)."""
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        for check_kw in ("check_vma", "check_rep"):
-            try:
-                if check_vma is None:
-                    return sm(f, **kw)
-                return sm(f, **kw, **{check_kw: check_vma})
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    if check_vma is None:
-        return legacy_sm(f, **kw)
-    return legacy_sm(f, **kw, check_rep=check_vma)
-
-
-def mesh_context(mesh: Mesh):
-    """``with``-able mesh activation across jax API generations (the
-    same one-resolution-point discipline as :func:`shard_map` above).
-
-    Newer jax activates a mesh for PartitionSpec resolution with
-    ``jax.sharding.set_mesh``; older releases don't have it — there the
-    ``Mesh`` object is its own context manager, which is what
-    ``with_sharding_constraint`` reads."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def default_mesh(axis_name: str = "kv", devices=None) -> Mesh:
     """A 1D mesh over all local devices — the `MPI_COMM_WORLD` analog."""
     devices = devices if devices is not None else jax.devices()

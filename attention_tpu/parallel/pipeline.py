@@ -25,10 +25,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from attention_tpu.parallel.mesh import default_mesh, shard_map
+from attention_tpu.parallel.mesh import default_mesh
 
 
 def pipeline_apply(

@@ -29,11 +29,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from attention_tpu.ops.flash import BlockSizes, flash_attention_partials
-from attention_tpu.parallel.mesh import default_mesh, shard_map
+from attention_tpu.parallel.mesh import default_mesh
 
 NEG_INF = float("-inf")
 

@@ -39,13 +39,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from attention_tpu.ops.decode import flash_decode
 from attention_tpu.ops.flash import BlockSizes, flash_attention_partials
 from attention_tpu.parallel.kv_sharded import merge_partials
-from attention_tpu.parallel.mesh import default_mesh, shard_map
+from attention_tpu.parallel.mesh import default_mesh
 
 
 class MeshConfigError(ValueError):

@@ -22,12 +22,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from attention_tpu.ops.flash import BlockSizes
 from attention_tpu.ops.flash_vjp import flash_attention_diff
-from attention_tpu.parallel.mesh import default_mesh, shard_map
+from attention_tpu.parallel.mesh import default_mesh
 
 
 @functools.partial(

@@ -2,9 +2,11 @@
 
 Two tables share one schema:
 
-- the **user cache** (``~/.cache/attention_tpu/tuning_cache.json``,
-  overridable via ``ATTN_TPU_TUNING_CACHE``): written by
-  ``cli tune`` / ``bench.py --autotune`` runs on the machine at hand;
+- the **user cache** (``ATTN_TPU_TUNING_CACHE``, default
+  ``~/.cache/attention_tpu/tuning_cache.json``): written by
+  ``cli tune`` / ``bench.py --autotune`` runs on the machine at hand,
+  and read back by kernel dispatch only when the variable names it
+  (`lookup.tables_in_use`);
 - the **shipped table** (``attention_tpu/tuning/shipped_table.json``,
   committed): seeded from the measured heuristics by
   ``scripts/make_shipped_table.py`` so a fresh host starts from the
@@ -143,9 +145,10 @@ def validate_entry(entry: dict) -> None:
 
 
 def default_cache_path() -> str:
-    """User cache location: ``ATTN_TPU_TUNING_CACHE`` env override, else
-    ``$XDG_CACHE_HOME/attention_tpu/tuning_cache.json`` (XDG default
-    ``~/.cache``)."""
+    """Where ``tune`` WRITES the user cache: ``ATTN_TPU_TUNING_CACHE``,
+    else ``$XDG_CACHE_HOME/attention_tpu/tuning_cache.json`` (XDG
+    default ``~/.cache``).  Dispatch reads it back only through the
+    variable."""
     env = os.environ.get("ATTN_TPU_TUNING_CACHE")
     if env:
         return env

@@ -6,6 +6,12 @@ keeps an empty-cache CPU run byte-for-byte identical to the
 pre-autotuner library (the shipped table only carries ``tpu-*`` device
 keys, and CPU lookups key as ``cpu``).
 
+The user cache is read ONLY when ``ATTN_TPU_TUNING_CACHE`` names it: a
+file outside the checkout must not decide which tiles a run compiles
+unless the operator pointed at it.  Without the variable, tiles come
+from the committed shipped table and the heuristics alone
+(:func:`tables_in_use` says which; the entry points print it).
+
 ``ATTN_TPU_NO_TUNING=1`` disables both tables (heuristics only) — the
 triage switch for suspect cache entries.
 
@@ -21,7 +27,6 @@ import os
 
 from attention_tpu.tuning.cache import (
     bucket_pow2,
-    default_cache_path,
     device_key,
     load_table_cached,
     make_key,
@@ -53,7 +58,7 @@ def key_fields(kernel: str, *, heads=1, kv_heads=None, seq=0, dim=0,
     Field mapping per family: flash forward keys on (heads bucket,
     m=n=seq, d, causal/stats/window-bucket); the backward families are
     head- and causal-generic (measured: the defaults hold across h and
-    the causal band, RESULTS.md r2/r4) and key on (m=n=seq, d,
+    the causal band) and key on (m=n=seq, d,
     window-bucket); decode/paged/ragged key on (GQA group, m=batch
     (ragged: active slots), n=cache capacity, d, sinks/window-bucket).
     """
@@ -71,6 +76,14 @@ def key_fields(kernel: str, *, heads=1, kv_heads=None, seq=0, dim=0,
     raise ValueError(f"unknown kernel family {kernel!r}")
 
 
+def tables_in_use(cache_path: str | None = None) -> list[str]:
+    """The table files a lookup consults, in resolution order."""
+    if os.environ.get("ATTN_TPU_NO_TUNING"):
+        return []
+    user = cache_path or os.environ.get("ATTN_TPU_TUNING_CACHE")
+    return ([user] if user else []) + [shipped_table_path()]
+
+
 def lookup(kernel: str, *, g: int, m: int, n: int, d: int,
            dtype=None, flags: dict | None = None,
            cache_path: str | None = None) -> dict | None:
@@ -81,8 +94,6 @@ def lookup(kernel: str, *, g: int, m: int, n: int, d: int,
     accelerant, not a dependency — any I/O or schema problem reads as a
     miss.
     """
-    if os.environ.get("ATTN_TPU_NO_TUNING"):
-        return None
     try:
         dev = device_key()
         names = [dtype_name(dtype)]
@@ -92,8 +103,7 @@ def lookup(kernel: str, *, g: int, m: int, n: int, d: int,
             make_key(dev, kernel, g=g, m=m, n=n, d=d, dtype=nm, flags=flags)
             for nm in names
         ]
-        for path in (cache_path or default_cache_path(),
-                     shipped_table_path()):
+        for path in tables_in_use(cache_path):
             table = load_table_cached(path)
             for key in keys:
                 entry = table.get(key)

@@ -1,7 +1,7 @@
 """Tunable-parameter spaces per kernel family.
 
 Candidate lists cover every tile regime the measured history has ever
-picked (RESULTS.md rounds 1-5: 256x1024 seed default, 512x512 windowed,
+picked (rounds 1-5: 256x1024 seed default, 512x512 windowed,
 1024x1024 stats-capped, 2048x1024/2048 causal, 4096x2048 VMEM-unlocked)
 plus one step past each boundary so a new device generation can move
 the optimum without a code change.  Candidates that cannot compile on a

@@ -10,9 +10,12 @@ from __future__ import annotations
 
 import jax
 
-# Peak dense matmul TFLOP/s per chip by TPU generation (bf16).
-# v5e (reported as "TPU v5 lite"): 197 TFLOP/s bf16 — 394 is the int8
-# TOPS number, not the bf16 peak.
+# Published peak dense bf16 matmul TFLOP/s per chip, by `device_kind`
+# prefix.  A device that is not in the table is an error, not a default.
+# v5e (JAX reports it as "TPU v5 lite"): 197 TFLOP/s bf16 and 819 GB/s
+# of HBM bandwidth — Google Cloud documentation, "TPU v5e"; 394 is its
+# int8 TOP/s, not the bf16 peak.  The other rows are the same
+# documentation's per-generation system-architecture pages.
 _PEAK_TFLOPS_BF16 = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,
@@ -20,6 +23,11 @@ _PEAK_TFLOPS_BF16 = {
     "TPU v5": 459.0,  # v5p
     "TPU v6 lite": 918.0,
 }
+
+
+class UnknownDeviceError(LookupError):
+    """The device has no entry in the published-peaks table, so no
+    utilization or roofline share can be stated for it."""
 
 
 def attention_flops(m: int, n: int, dk: int, dv: int, *, causal: bool = False,
@@ -34,16 +42,19 @@ def attention_flops(m: int, n: int, dk: int, dv: int, *, causal: bool = False,
 
 
 def peak_flops(device=None) -> float:
-    """Peak bf16 matmul FLOP/s for the given (default: first) device."""
+    """Peak bf16 matmul FLOP/s for the given (default: first) device;
+    `UnknownDeviceError` off the table (e.g. the CPU backend)."""
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "")
     for prefix, tflops in _PEAK_TFLOPS_BF16.items():
         if kind.startswith(prefix):
             return tflops * 1e12
-    # unknown hardware (e.g. CPU test runs): nominal 1 TFLOP to keep
-    # utilization numbers finite but obviously non-physical
-    return 1e12
+    raise UnknownDeviceError(
+        f"no published peak for device kind {kind!r} (platform "
+        f"{getattr(device, 'platform', '?')}); utilization is only "
+        f"defined on {sorted(_PEAK_TFLOPS_BF16)}"
+    )
 
 
 def utilization(flops: int, seconds: float, device=None) -> float:

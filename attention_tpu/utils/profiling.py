@@ -51,7 +51,7 @@ class RunRecord:
     best_us: float
     median_us: float
     gflops_per_chip: float
-    utilization: float
+    utilization: float | None  # None where the device has no published peak
     device_kind: str
     n_devices: int
     mesh_axes: dict[str, int] | None = None
@@ -66,6 +66,11 @@ def append_jsonl(path: str, record: RunRecord) -> None:
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with open(path, "a") as f:
         f.write(record.to_json() + "\n")
+
+
+#: the profiler's per-device lane that carries one event per executed
+#: XLA module (the lane every device-time clock reads)
+DEVICE_LANE = "XLA Modules"
 
 
 def _latest_capture(log_dir: str) -> str | None:
@@ -111,12 +116,12 @@ def device_module_slices(
             for e in data["traceEvents"]
             if (e.get("ph") == "X"
                 and lanes.get((e.get("pid"), e.get("tid")))
-                == "XLA Modules")
+                == DEVICE_LANE)
         ]
     except (ValueError, KeyError, EOFError, OSError):
-        # a truncated/partial capture (interrupted profiler) must read
-        # as "no device lane" so benchmark_auto's slope fallback engages
-        # rather than aborting the whole benchmark
+        # a truncated/partial capture (interrupted profiler) reads as
+        # "no device lane": `cli obs export` stays usable on a damaged
+        # dump, and `benchmark_auto` decides what a missing lane means
         return None
     return slices or None
 

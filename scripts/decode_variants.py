@@ -50,7 +50,7 @@ def main() -> int:
     qkv = quantize_kv(kc, vc)
     # 2048-row pages, scrambled physical order (the ladder-row config;
     # 128-row vLLM-style pages measured 5x slower — grid-step overhead
-    # scales with pages per sequence, see RESULTS.md)
+    # scales with pages per sequence)
     import random
 
     page = 2048

@@ -52,7 +52,7 @@ def bench_mode(seq, dim, causal, max_mode, repeats, n_long, unsafe=False,
     if guard_impl != "cond":
         # the in-kernel dynamic-mode implementation was REVERTED after
         # measuring 359 us vs 214 at 8k (see the decision comment at
-        # the cond dispatch in ops/flash.py and RESULTS.md round 5);
+        # the cond dispatch in ops/flash.py);
         # without it, setting the flag would silently re-measure the
         # cond path under the wrong label.  Probe the SOURCE for the
         # dispatch (a hasattr check is defeated by this script's own

@@ -3,7 +3,7 @@
 Round 5 measured the feature-dim int4 packing at 0.748 ms vs int8's
 0.445 at the bench decode shape — the (block_k, d/2=64) value tiles are
 half the native lane width, so the stream loses full-width DMA
-efficiency and the kernel leaves the DMA-bound regime (RESULTS.md).
+efficiency and the kernel leaves the DMA-bound regime.
 The token-paired layout (`quantize_kv_int4_tok`) keeps d=128-lane value
 tiles by pairing two ADJACENT TOKENS per byte; the unpack splits along
 sublanes instead of lanes.  This measures whether that recovers the

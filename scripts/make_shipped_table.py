@@ -3,7 +3,7 @@
 The shipped table is the middle layer of the tile-resolution order
 (user cache -> shipped table -> heuristic).  It is seeded FROM the
 measured heuristics — the winners of the rounds 1-5 device-clock sweeps
-on the v5e chip (scripts/kernel_sweep.py, bwd_sweep.py, RESULTS.md) —
+on the v5e chip (scripts/kernel_sweep.py, bwd_sweep.py) —
 by calling the heuristic functions themselves, so the committed table
 can never drift from the code it mirrors.  Entries are keyed
 ``tpu-v5e`` (the measured generation); other devices miss and fall to

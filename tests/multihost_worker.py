@@ -35,7 +35,8 @@ def main() -> int:
     import numpy as np
 
     from attention_tpu.parallel.kv_sharded import merge_partials
-    from attention_tpu.parallel.mesh import hybrid_mesh, shard_map
+    from attention_tpu.parallel.mesh import hybrid_mesh
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = hybrid_mesh(inner_axis="kv", outer_axis="dp")

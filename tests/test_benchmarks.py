@@ -9,6 +9,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from attention_tpu.benchmarks import ablation_table, strong_scaling, weak_scaling
 from attention_tpu.ops.flash import BlockSizes
@@ -25,6 +26,7 @@ def test_ablation_table_structure():
         assert rec.best_us > 0
         assert np.isfinite(rec.gflops_per_chip)
         assert rec.extra["speedup_vs_baseline"] > 0
+        assert rec.utilization is None  # the CPU has no published peak
     assert table["baseline"].extra["speedup_vs_baseline"] == 1.0
 
 
@@ -94,8 +96,10 @@ def test_benchmark_amortized_positive():
     assert per > 0
 
 
-def test_bench_cli_smoke():
-    """bench.py end-to-end on tiny shapes (CPU interpret mode)."""
+@pytest.mark.parametrize("arm", ["headline", "engine"])
+def test_bench_refuses_to_run_off_tpu(arm, capsys):
+    """Both bench.py arms measure the chip: on the CPU backend they
+    exit non-zero, name the platform they found, and print no record."""
     import importlib.util
     import os
 
@@ -104,9 +108,16 @@ def test_bench_cli_smoke():
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    rc = mod.main(["--seq", "256", "--dim", "64", "--repeats", "1",
-                   "--serial-seq", "256"])
-    assert rc == 0
+    try:
+        rc = mod.main(["--arm", arm, "--seq", "256", "--dim", "64",
+                       "--repeats", "1", "--serial-seq", "256"])
+    finally:
+        # main() placed the compile cache; keep the test process hermetic
+        jax.config.update("jax_compilation_cache_dir", None)
+    captured = capsys.readouterr()
+    assert rc != 0
+    assert "platform=cpu" in captured.err
+    assert captured.out == ""
 
 
 def test_blocksizes_for_shape_rules():
@@ -130,9 +141,9 @@ def test_blocksizes_for_shape_rules():
     assert BlockSizes.for_shape(4, 4096, 128, window=64) == BlockSizes()
 
 
-def test_benchmark_auto_cpu_fallback():
-    """On CPU (no device trace lane) benchmark_auto must fall back to
-    the slope clock and return a positive per-iteration time."""
+def test_benchmark_auto_names_its_clock():
+    """On CPU (no device trace lane) benchmark_auto uses the slope
+    clock, returns a positive per-iteration time, and says so."""
     import jax.numpy as jnp
 
     from attention_tpu.utils.timing import benchmark_auto
@@ -140,6 +151,17 @@ def test_benchmark_auto_cpu_fallback():
     t = benchmark_auto(lambda x: x * 2.0, jnp.ones((64, 64)),
                        n_short=2, n_long=6, repeats=2)
     assert t > 0
+    assert t.clock == "wall-slope"
+
+
+def test_peak_flops_raises_off_the_table():
+    """No published peak, no utilization: an unknown device is an
+    error, and the benchmark rows report none instead of a made-up
+    one."""
+    from attention_tpu.utils.flops import UnknownDeviceError, peak_flops
+
+    with pytest.raises(UnknownDeviceError, match="cpu"):
+        peak_flops()
 
 
 def test_device_module_seconds_missing_dir(tmp_path):

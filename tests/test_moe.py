@@ -10,7 +10,6 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from attention_tpu.models import MoEMLP, TinyDecoder
-from attention_tpu.parallel.mesh import mesh_context
 from attention_tpu.models.train import (
     init_sharded,
     make_mesh_3d,
@@ -112,7 +111,7 @@ def test_moe_ep_sharded_matches_unsharded(rng):
         kk: jax.device_put(v, NamedSharding(mesh, spec[kk]))
         for kk, v in params.items()
     }
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         got = np.asarray(
             jax.jit(lambda p, xx: ep_mod.apply({"params": p}, xx))(sharded, x)
         )
@@ -152,7 +151,7 @@ def test_moe_train_step_decreases_loss(rng):
                         moe_experts=4, ep_axis="tp")
     batch = max(4, mesh.shape["dp"])
     seq = 32 * mesh.shape["sp"]
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         params, optimizer, opt_state = init_sharded(
             model, mesh, batch=batch, seq=seq
         )
@@ -181,6 +180,6 @@ def test_moe_bad_ep_axis_raises_under_mesh(rng):
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("ep",))
     mod = MoEMLP(num_experts=8, top_k=2, ep_axis="exp", dtype=jnp.float32)
     x = jnp.zeros((1, 8, 16), jnp.float32)
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         with pytest.raises(ValueError, match="not in the current mesh"):
             mod.init(jax.random.PRNGKey(0), x)

@@ -1,0 +1,161 @@
+"""Compile the kernels for a real TPU v5e from the CPU test process.
+
+The CPU suite runs every Pallas kernel interpreted, and
+interpret-green does not imply Mosaic-green.  The installed libtpu can
+describe a compile-only ``v5e:2x2`` topology without a chip, and
+lowering against its devices runs the real Mosaic / XLA:TPU compiler —
+so what the compiler refuses is found here, not on the chip budget.
+Nothing executes: these tests pin "compiles", the chip run pins "is
+right" (`chip_smoke.py`, `scripts/tpu_smoke.py`).
+
+Marked ``slow`` (run it with ``-m slow``; ~30 s): building the topology
+goes through libtpu's multi-process lockfile.  On a TPU host whose chip
+another process holds it fails ("Internal error when accessing libtpu
+multi-process lockfile" — the fixture skips with that reason), and the
+other way round a test process sitting on that lockfile is in a chip
+process's way, so tier-1 does not touch it.  In the CPU sandbox there
+is no chip and nothing to contend for.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from attention_tpu.engine.engine import _ragged_apply
+from attention_tpu.models import TinyDecoder
+from attention_tpu.ops import decode, flash, paged, quant, ragged_paged
+from attention_tpu.ops.flash_vjp import flash_attention_diff
+from attention_tpu.ops.ragged_paged import (
+    RaggedPagedStep,
+    ragged_paged_attention,
+)
+
+pytestmark = pytest.mark.slow
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four compile-only v5e devices, with the kernels' interpret
+    default (`ops.flash._should_interpret`: anything but a TPU default
+    backend interprets) pinned off in every module that imported it."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # noqa: BLE001 - any plugin failure is a skip
+        pytest.skip(f"no compile-only TPU topology here: "
+                    f"{type(e).__name__}: {str(e)[:200]}")
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (flash, decode, paged, quant, ragged_paged):
+            mp.setattr(mod, "_should_interpret", lambda: False)
+        yield list(topo.devices)
+
+
+def _compile(fn, sharding, *args):
+    """Lower ``fn`` for abstract ``args`` (pytrees of ShapeDtypeStructs)
+    placed by ``sharding`` (one sharding, or a pytree prefix of
+    ``args``), and run the TPU compiler."""
+    placed = jax.tree.map(
+        lambda s, sub: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            sub),
+        sharding, args,
+        is_leaf=lambda x: isinstance(x, jax.sharding.Sharding))
+    return jax.jit(fn).lower(*placed).compile()
+
+
+def _a(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _ragged_cache(hkv, width, q_tile, dtype, *, pages=64, slots=10,
+                  max_pages=34, d=128):
+    pool = _a((pages, hkv, 128, d), dtype)
+    return RaggedPagedStep(
+        pool, pool, _a((slots, max_pages), I32), _a((slots,), I32),
+        _a((slots + 1,), I32), _a((2,), I32), _a((width,), I32),
+        _a((width,), I32), _a((q_tile,), I32))
+
+
+def test_ragged_engine_step_at_smoke_width(v5e):
+    """The whole jitted engine step `chip_smoke.py` serves: dim 4096,
+    32q/4kv x 128, depth 4, vocab 32768, bf16, packed width 512."""
+    model = TinyDecoder(vocab=32768, dim=4096, depth=4, num_q_heads=32,
+                        num_kv_heads=4, impl="flash", rope=True)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    caches = tuple(_ragged_cache(4, 512, 256, BF16, pages=2048)
+                   for _ in range(model.depth))
+    compiled = _compile(
+        functools.partial(_ragged_apply, model),
+        jax.sharding.SingleDeviceSharding(v5e[0]),
+        params, _a((1, 512), I32), caches)
+    # no buffer donation yet: every step allocates a second copy of the
+    # pools (PERF.md, limits) — when donation lands this flips
+    assert compiled.memory_analysis().alias_size_in_bytes == 0
+
+
+def test_ragged_engine_step_head_sharded_over_four_devices(v5e):
+    mesh = Mesh(np.asarray(v5e), ("tp",))
+    model = TinyDecoder(vocab=32768, dim=4096, depth=1, num_q_heads=32,
+                        num_kv_heads=4, impl="flash", rope=True,
+                        tp_axis="tp", mesh=mesh)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    rep = NamedSharding(mesh, P())
+    pool = NamedSharding(mesh, P(None, "tp", None, None))
+    cache = _ragged_cache(4, 512, 256, BF16, pages=256)
+    cache_sh = RaggedPagedStep(pool, pool, *[rep] * 7)
+    _compile(functools.partial(_ragged_apply, model),
+             (rep, rep, (cache_sh,)),
+             params, _a((1, 512), I32), (cache,))
+
+
+@pytest.mark.parametrize("hq,hkv,width,q_tile,dtype", [
+    # 16-bit with group < 8: Mosaic refused the unaligned dynamic
+    # sublane slice ("cannot statically prove ... a multiple of 8")
+    (8, 2, 512, 256, BF16),     # group 4
+    (8, 4, 24, 4, BF16),        # group 2, a decode-only step
+    (8, 8, 512, 64, BF16),      # group 1 (MHA)
+    (8, 2, 512, 256, F32),
+    (32, 4, 2048, 1024, BF16),  # 26 MB scoped VMEM > the 16 MB default
+])
+def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype):
+    _compile(ragged_paged_attention,
+             jax.sharding.SingleDeviceSharding(v5e[0]),
+             _a((1, hq, width, 128), dtype),
+             _ragged_cache(hkv, width, q_tile, dtype))
+
+
+def test_ladder_kernels_compile(v5e):
+    """One row each of the old kernel ladder: flash forward in bound
+    mode, the fused backward, bf16 / int8 / paged decode."""
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    seq = _a((8192, 128), BF16)
+    _compile(functools.partial(flash.flash_attention, max_mode="bound"),
+             one, seq, seq, seq)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda *qkv: jnp.sum(flash_attention_diff(
+                *qkv, causal=True).astype(F32)), argnums=(0, 1, 2))(q, k, v)
+
+    _compile(grads, one, seq, seq, seq)
+
+    q = _a((8, 32, 128), BF16)
+    lens = _a((8,), I32)
+    kv = _a((8, 4, 8192, 128), BF16)
+    _compile(decode.flash_decode, one, q, kv, kv, lens)
+    kv8, scale = _a((8, 4, 8192, 128), jnp.int8), _a((8, 4, 8, 8192), F32)
+    _compile(quant.flash_decode_quantized, one, q,
+             quant.QuantizedKV(kv8, scale, kv8, scale), lens)
+    pool = _a((512, 4, 128, 128), BF16)
+    _compile(paged.paged_flash_decode, one, q,
+             paged.PagedKV(pool, pool, _a((8, 64), I32), lens))

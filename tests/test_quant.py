@@ -310,7 +310,7 @@ def test_int4_decode_close_to_fp(rng):
     budget: ~4-8e-2 max abs on unit-normal inputs at d=64/128 (int8 is
     ~2e-3 here), i.e. int4 does NOT meet the ±0.02 harness contract —
     it is the documented opt-in bytes/quality trade (see
-    `quantize_kv_int4` and RESULTS.md round 5)."""
+    `quantize_kv_int4`)."""
     from attention_tpu.ops.quant import flash_decode_int4, quantize_kv_int4
 
     for d in (64, 128):
@@ -347,7 +347,7 @@ def test_int4_decode_windowed_and_empty(rng):
     # reads average over ~window tokens instead of the whole prefix, so
     # the quantization noise averages down LESS than the full-cache
     # case (measured ~0.16 here vs ~0.08 full) — the budget scales with
-    # 1/sqrt(tokens-attended) (module docstrings + RESULTS.md round 5)
+    # 1/sqrt(tokens-attended) (module docstrings)
     assert np.max(np.abs(got.astype(np.float32)
                          - want.astype(np.float32))) < 0.25
     zero = np.asarray(flash_decode_int4(

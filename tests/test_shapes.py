@@ -281,7 +281,7 @@ _SHARD_PRELUDE = textwrap.dedent("""
     import functools
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from attention_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 """)
 
 
