@@ -1,0 +1,3 @@
+"""`kernel.ragged_share_of_step` in the open-loop cell: see `benchmark/reduce/steps.py`."""
+
+from benchmark.reduce.steps import ragged_share_of_step as read  # noqa: F401

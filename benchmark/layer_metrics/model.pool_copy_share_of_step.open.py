@@ -1,0 +1,3 @@
+"""`model.pool_copy_share_of_step` in the open-loop cell: see `benchmark/reduce/steps.py`."""
+
+from benchmark.reduce.steps import pool_copy_share_of_step as read  # noqa: F401
