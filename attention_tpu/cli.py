@@ -47,7 +47,8 @@ Usage:
   python -m attention_tpu.cli obs export --run run_dir
       --format chrome|prom|jsonl [--out timeline.json]
       # unified telemetry (attention_tpu.obs): counters/spans summary,
-      # or export — chrome merges host spans with the XLA device lane
+      # or export — chrome lays out the ring's host spans; host vs
+      # device on one clock is the --obs-profile capture itself
   python -m attention_tpu.cli chaos fuzz --seed 0 --cases 16
       [--families flash,decode,...] [--inject-failure] [--repro-dir DIR]
   python -m attention_tpu.cli chaos replay <repro.json|repro.bin>
@@ -796,9 +797,9 @@ def _add_serve_sim_args(ss) -> None:
                     help="write the telemetry dump (metrics.json + "
                          "events.jsonl) here; implies --obs")
     ss.add_argument("--obs-profile", action="store_true",
-                    help="also capture a jax.profiler device trace "
-                         "under <obs-out>/device for the merged "
-                         "chrome timeline; implies --obs")
+                    help="also capture a jax.profiler trace under "
+                         "<obs-out>/device: the program's spans and "
+                         "the device lanes on one clock; implies --obs")
 
 
 def _cmd_tune(args: argparse.Namespace) -> int:
@@ -1236,14 +1237,14 @@ def _cmd_obs_export(args: argparse.Namespace) -> int:
 
     from attention_tpu import obs
 
-    snapshot, events, device = _obs_load(args)
+    snapshot, events, _device = _obs_load(args)
     if args.format == "prom":
         text = obs.prom_text(snapshot)
     elif args.format == "jsonl":
         text = "\n".join(obs.jsonl_lines(events, snapshot))
         text += "\n" if text else ""
     else:  # chrome
-        text = json.dumps(obs.chrome_trace(events, device_dir=device))
+        text = json.dumps(obs.chrome_trace(events))
     if args.out:
         with open(args.out, "w") as f:
             f.write(text)
@@ -1565,7 +1566,7 @@ def main(argv: list[str] | None = None) -> int:
     ob = sub.add_parser(
         "obs",
         help="unified telemetry (attention_tpu.obs): report / export a "
-             "run's counters, spans, and merged host/device timeline",
+             "run's counters, spans, and host timeline",
     )
     obsub = ob.add_subparsers(dest="obs_cmd", required=True)
     for name, fn in (("report", _cmd_obs_report),

@@ -77,19 +77,13 @@ _TIMED_OUT = obs.counter("engine.requests.timed_out",
 _LAUNCHES = obs.counter("engine.step.launches",
                         "jitted model launches dispatched by the step loop")
 # mesh-serving surface: how many KV-head shards the per-step launches
-# lower onto (1 = single-device), and what the shard fan-in costs.  In
-# the zero-collective head-sharded design the kernels exchange nothing;
-# the only cross-shard cost is reassembling the replicated logits at
-# the step's single host sync, which is exactly what the histogram
-# times.
+# lower onto (1 = single-device).  In the zero-collective head-sharded
+# design the kernels exchange nothing; the only cross-shard cost is
+# reassembling the replicated logits at the step's single host sync,
+# which the ``engine.step.fetch`` span times on every engine.
 _MESH_SHARDS = obs.gauge("engine.mesh.shards",
                          "KV-head shards the engine's jitted launches "
                          "lower onto (1 = single-device)")
-_COLLECTIVE_MS = obs.histogram("engine.step.collective_ms",
-                               "per-step device sync incl. cross-shard "
-                               "logits reassembly on a mesh engine",
-                               buckets=(0.1, 0.5, 1.0, 5.0, 10.0, 50.0,
-                                        100.0, 500.0))
 
 #: consecutive non-finite-logits steps a request is held back before
 #: the finite guard gives up and samples anyway — must exceed any
@@ -354,6 +348,20 @@ class ServingEngine:
             step=self._step, **extra,
         )
 
+    def _note_first_admissions(self, sched: ScheduledStep) -> None:
+        """Stamp the wall clock of each request's FIRST admission (a
+        readmission after preemption is not one) and mark it on the
+        profiler's timeline with the time it waited in the queue."""
+        now = time.perf_counter()
+        for req in sched.admitted:
+            if req.first_scheduled_step != self._step:
+                continue
+            wall = self._wall[req.request_id]
+            wall["scheduled"] = now
+            with obs.span("engine.request.admitted", rid=req.request_id,
+                          queue_wait_ms=(now - wall["added"]) * 1e3):
+                pass
+
     # -- request intake ---------------------------------------------------
 
     @property
@@ -565,25 +573,28 @@ class ServingEngine:
         self._last_fetch_s = 0.0
         pad_tokens = 0
         occupancy = 0.0
-        with obs.span("engine.step"):
-            timed_out = self._expire_deadlines()
-            sched = self.scheduler.schedule(self._step)
-            if _trace.active():
-                # preemptions free the pages the admissions claim, so
-                # they precede admissions in the chain too
-                for req in sched.preempted:
-                    self._trace_event(req, "preempted")
-                for req in sched.admitted:
-                    ev = ("resumed"
-                          if (req.preemptions or req.output_tokens)
-                          else "prefill_start")
-                    self._trace_event(req, ev)
+        with obs.span("engine.step", step=self._step,
+                      queued=len(self.scheduler.waiting),
+                      running=len(self.scheduler.running)):
+            with obs.span("engine.step.schedule"):
+                timed_out = self._expire_deadlines()
+                sched = self.scheduler.schedule(self._step)
+                self._note_first_admissions(sched)
+                if _trace.active():
+                    # preemptions free the pages the admissions claim,
+                    # so they precede admissions in the chain too
+                    for req in sched.preempted:
+                        self._trace_event(req, "preempted")
+                    for req in sched.admitted:
+                        ev = ("resumed"
+                              if (req.preemptions or req.output_tokens)
+                              else "prefill_start")
+                        self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             baseline_pad = self._baseline_pad(sched)
             if self.config.step_mode == "ragged":
                 if not sched.is_empty:
-                    with obs.span("engine.step.ragged"):
-                        width = self._run_ragged(sched)
+                    width = self._run_ragged(sched)
                     pad_tokens = width - total
                     occupancy = total / width
             else:
@@ -596,36 +607,31 @@ class ServingEngine:
                 pad_tokens = baseline_pad
                 if total:
                     occupancy = total / (total + baseline_pad)
-        if self.mesh is not None and obs.is_enabled():
-            # the mesh engine's only cross-shard cost: the step's
-            # single device sync, where the sharded launch's
-            # replicated logits reassemble on host
-            _COLLECTIVE_MS.observe(self._last_fetch_s * 1e3)
-        wall_s = time.perf_counter() - t0
-        m = StepMetrics(
-            step=self._step,
-            wall_s=wall_s,
-            num_decode_reqs=len(sched.decode),
-            num_prefill_reqs=len(sched.prefill),
-            decode_tokens=sched.num_decode_tokens,
-            prefill_tokens=sched.num_prefill_tokens,
-            queue_depth=len(self.scheduler.waiting),
-            running=len(self.scheduler.running),
-            admitted=len(sched.admitted),
-            preempted=len(sched.preempted),
-            finished=self._finished_in_step,
-            timed_out=timed_out,
-            free_pages=self.pool.free_pages,
-            used_pages=self.pool.used_pages,
-            page_utilization=self.pool.used_pages / self.pool.num_pages,
-            prefix_hit_tokens_total=self.allocator.prefix_hit_tokens,
-            preemptions_total=self.scheduler.num_preemptions,
-            pad_tokens=pad_tokens,
-            baseline_pad_tokens=baseline_pad,
-            ragged_occupancy=occupancy,
-            host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
-        )
-        self.metrics.record_step(m)
+            wall_s = time.perf_counter() - t0
+            m = StepMetrics(
+                step=self._step,
+                wall_s=wall_s,
+                num_decode_reqs=len(sched.decode),
+                num_prefill_reqs=len(sched.prefill),
+                decode_tokens=sched.num_decode_tokens,
+                prefill_tokens=sched.num_prefill_tokens,
+                queue_depth=len(self.scheduler.waiting),
+                running=len(self.scheduler.running),
+                admitted=len(sched.admitted),
+                preempted=len(sched.preempted),
+                finished=self._finished_in_step,
+                timed_out=timed_out,
+                free_pages=self.pool.free_pages,
+                used_pages=self.pool.used_pages,
+                page_utilization=self.pool.used_pages / self.pool.num_pages,
+                prefix_hit_tokens_total=self.allocator.prefix_hit_tokens,
+                preemptions_total=self.scheduler.num_preemptions,
+                pad_tokens=pad_tokens,
+                baseline_pad_tokens=baseline_pad,
+                ragged_occupancy=occupancy,
+                host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
+            )
+            self.metrics.record_step(m)
         self._step += 1
         return m
 
@@ -721,9 +727,10 @@ class ServingEngine:
         finish its overlapped staging before the block, (b) per-step
         host overhead is measurable as wall minus time spent here, and
         (c) fault injectors have a single seam to poison."""
-        t0 = time.perf_counter()
-        out = np.asarray(logits_dev, np.float32)
-        self._last_fetch_s += time.perf_counter() - t0
+        with obs.span("engine.step.fetch", bytes=4 * logits_dev.size):
+            t0 = time.perf_counter()
+            out = np.asarray(logits_dev, np.float32)
+            self._last_fetch_s += time.perf_counter() - t0
         return out
 
     def _apply(self, tokens: np.ndarray, tables: np.ndarray,
@@ -736,13 +743,14 @@ class ServingEngine:
         )
         if obs.is_enabled():
             _LAUNCHES.inc(mode="two_call")
-        logits, new_caches = _paged_apply(
-            self._step_model, self.params,
-            jnp.asarray(tokens, jnp.int32), caches
-        )
-        for layer, c in enumerate(new_caches):
-            self._k_pools[layer] = c.k_pool
-            self._v_pools[layer] = c.v_pool
+        with obs.span("engine.step.dispatch"):
+            logits, new_caches = _paged_apply(
+                self._step_model, self.params,
+                jnp.asarray(tokens, jnp.int32), caches
+            )
+            for layer, c in enumerate(new_caches):
+                self._k_pools[layer] = c.k_pool
+                self._v_pools[layer] = c.v_pool
         return self._fetch_logits(logits)
 
     def _run_ragged(self, sched: ScheduledStep) -> int:
@@ -755,57 +763,65 @@ class ServingEngine:
         O(log max_tokens).  With ``async_steps`` the host stages next
         step's page-table rows between dispatch and the logits sync."""
         cfg = self.config
-        slots = cfg.max_decode_batch + cfg.max_prefill_rows
-        group = self.model.num_q_heads // self.model.num_kv_heads
-        head_dim = self.model.dim // self.model.num_q_heads
-        max_q = max((n for _, n in sched.prefill), default=1)
-        q_tile = recommended_q_tile(
-            max_q, group, heads=self.model.num_q_heads,
-            kv_heads=self.model.num_kv_heads, seq=cfg.max_seq_len,
-            dim=head_dim, batch=slots,
-            dtype=cfg.cache_dtype or self.model.dtype,
-        )
-        total = sched.num_decode_tokens + sched.num_prefill_tokens
-        width = packed_bucket(max(total, q_tile))
-        batch = sched.pack(width=width, slots=slots,
-                           table_width=cfg.table_width,
-                           staged_rows=self._staged_rows)
-        self._staged_rows = {}
-        tables = jnp.asarray(batch.tables, jnp.int32)
-        kv_lens = jnp.asarray(batch.kv_lens, jnp.int32)
-        cu = jnp.asarray(batch.cu_q_lens, jnp.int32)
-        dist = jnp.asarray(batch.distribution, jnp.int32)
-        pos = jnp.asarray(batch.token_pos, jnp.int32)
-        slot = jnp.asarray(batch.token_slot, jnp.int32)
-        q_span = np.zeros((q_tile,), np.int32)  # shape carries q_tile
-        caches = tuple(
-            RaggedPagedStep(self._k_pools[layer], self._v_pools[layer],
-                            tables, kv_lens, cu, dist, pos, slot, q_span)
-            for layer in range(self.model.depth)
-        )
+        with obs.span("engine.step.pack"):
+            slots = cfg.max_decode_batch + cfg.max_prefill_rows
+            group = self.model.num_q_heads // self.model.num_kv_heads
+            head_dim = self.model.dim // self.model.num_q_heads
+            max_q = max((n for _, n in sched.prefill), default=1)
+            q_tile = recommended_q_tile(
+                max_q, group, heads=self.model.num_q_heads,
+                kv_heads=self.model.num_kv_heads, seq=cfg.max_seq_len,
+                dim=head_dim, batch=slots,
+                dtype=cfg.cache_dtype or self.model.dtype,
+            )
+            total = sched.num_decode_tokens + sched.num_prefill_tokens
+            width = packed_bucket(max(total, q_tile))
+            batch = sched.pack(width=width, slots=slots,
+                               table_width=cfg.table_width,
+                               staged_rows=self._staged_rows)
+            self._staged_rows = {}
+        with obs.span("engine.step.upload"):
+            tables = jnp.asarray(batch.tables, jnp.int32)
+            kv_lens = jnp.asarray(batch.kv_lens, jnp.int32)
+            cu = jnp.asarray(batch.cu_q_lens, jnp.int32)
+            dist = jnp.asarray(batch.distribution, jnp.int32)
+            pos = jnp.asarray(batch.token_pos, jnp.int32)
+            slot = jnp.asarray(batch.token_slot, jnp.int32)
+            tokens = jnp.asarray(batch.tokens, jnp.int32)
+            q_span = np.zeros((q_tile,), np.int32)  # shape carries q_tile
+            caches = tuple(
+                RaggedPagedStep(self._k_pools[layer], self._v_pools[layer],
+                                tables, kv_lens, cu, dist, pos, slot,
+                                q_span)
+                for layer in range(self.model.depth)
+            )
         if obs.is_enabled():
             _LAUNCHES.inc(mode="ragged")
-        logits_dev, new_caches = _ragged_apply(
-            self._step_model, self.params,
-            jnp.asarray(batch.tokens, jnp.int32), caches,
-        )
-        for layer, c in enumerate(new_caches):
-            self._k_pools[layer] = c.k_pool
-            self._v_pools[layer] = c.v_pool
+        with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
+                      decode_rows=len(sched.decode),
+                      prefill_tokens=sched.num_prefill_tokens):
+            logits_dev, new_caches = _ragged_apply(
+                self._step_model, self.params, tokens, caches,
+            )
+            for layer, c in enumerate(new_caches):
+                self._k_pools[layer] = c.k_pool
+                self._v_pools[layer] = c.v_pool
         if cfg.async_steps:
             # the double-buffer window: the launch is in flight, the
             # sync has not happened — overlap next step's host staging
             with obs.span("engine.step.overlap"):
                 self._stage_next_step()
         logits = self._fetch_logits(logits_dev)
-        cu_h = batch.cu_q_lens
-        num_decode = len(sched.decode)
-        for i, req in enumerate(sched.decode):
-            self._post_decode(req, logits[0, cu_h[i]])
-        for s, (req, real) in enumerate(sched.prefill):
-            self._post_prefill(
-                req, real, logits[0, cu_h[num_decode + s] + real - 1]
-            )
+        with obs.span("engine.step.sample",
+                      rows=len(sched.decode) + len(sched.prefill)):
+            cu_h = batch.cu_q_lens
+            num_decode = len(sched.decode)
+            for i, req in enumerate(sched.decode):
+                self._post_decode(req, logits[0, cu_h[i]])
+            for s, (req, real) in enumerate(sched.prefill):
+                self._post_prefill(
+                    req, real, logits[0, cu_h[num_decode + s] + real - 1]
+                )
         return width
 
     def _stage_next_step(self) -> None:
@@ -842,8 +858,9 @@ class ServingEngine:
             tokens[i, 0] = req.feed_pending()
             tables[i, : len(req.pages)] = req.pages
         logits = self._apply(tokens, tables, lens)
-        for i, req in enumerate(reqs):
-            self._post_decode(req, logits[i, 0])
+        with obs.span("engine.step.sample", rows=len(reqs)):
+            for i, req in enumerate(reqs):
+                self._post_decode(req, logits[i, 0])
 
     def _post_decode(self, req: Request, logits_row: np.ndarray) -> None:
         """Consume one decode request's logits row — the mode-agnostic
@@ -880,8 +897,9 @@ class ServingEngine:
             tables[i, : len(req.pages)] = req.pages
             lens[i] = c
         logits = self._apply(tokens, tables, lens)
-        for i, (req, real) in enumerate(items):
-            self._post_prefill(req, real, logits[i, real - 1])
+        with obs.span("engine.step.sample", rows=len(items)):
+            for i, (req, real) in enumerate(items):
+                self._post_prefill(req, real, logits[i, real - 1])
 
     def _post_prefill(self, req: Request, real: int,
                       last_row: np.ndarray) -> None:
@@ -956,7 +974,13 @@ class ServingEngine:
             self.journal.record_token(req.request_id, token)
         if req.first_token_step < 0:
             req.first_token_step = self._step
-            self._wall[req.request_id]["first_token"] = time.perf_counter()
+            wall = self._wall[req.request_id]
+            wall["first_token"] = now = time.perf_counter()
+            scheduled = wall.get("scheduled", wall["added"])
+            with obs.span("engine.request.first_token", rid=req.request_id,
+                          queue_wait_ms=(scheduled - wall["added"]) * 1e3,
+                          prefill_ms=(now - scheduled) * 1e3):
+                pass
             if _trace.active():
                 self._trace_event(req, "first_token")
         if self.on_token is not None:
@@ -980,6 +1004,9 @@ class ServingEngine:
         self._finished_in_step += 1
         wall = self._wall.pop(req.request_id, {})
         now = time.perf_counter()
+        added = wall.get("added", now)
+        scheduled = wall.get("scheduled", added)
+        first_token = wall.get("first_token", now)
         self.metrics.record_request(RequestMetrics(
             request_id=req.request_id,
             arrival_step=req.arrival,
@@ -990,10 +1017,10 @@ class ServingEngine:
             output_tokens=req.num_output_tokens,
             prefix_cached_tokens=req.prefix_cached_tokens,
             preemptions=req.preemptions,
-            ttft_s=now - wall.get("added", now)
-            if "first_token" not in wall
-            else wall["first_token"] - wall["added"],
-            finish_s=now - wall.get("added", now),
+            ttft_s=first_token - added,
+            finish_s=now - added,
+            queue_wait_s=scheduled - added,
+            prefill_s=first_token - scheduled,
         ))
         if self.on_finish is not None:
             self.on_finish(req)

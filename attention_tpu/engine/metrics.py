@@ -115,6 +115,10 @@ class RequestMetrics:
     preemptions: int
     ttft_s: float
     finish_s: float
+    # ttft_s split at the request's first admission: seconds in the
+    # queue, then seconds from admission to the first token
+    queue_wait_s: float = 0.0
+    prefill_s: float = 0.0
 
     @property
     def ttft_steps(self) -> int:
@@ -192,6 +196,10 @@ class EngineMetrics:
         busy = [s for s in self.steps if s.decode_tokens or s.prefill_tokens]
         mixed = [s for s in busy if s.decode_tokens and s.prefill_tokens]
         ttft_dig, tpot_dig = self.latency_digests()
+        wait_dig, prefill_dig = QuantileDigest(), QuantileDigest()
+        for r in self.requests:
+            wait_dig.add(r.queue_wait_s * 1e3)
+            prefill_dig.add(r.prefill_s * 1e3)
         return {
             "num_requests": len(self.requests),
             "num_steps": len(self.steps),
@@ -209,6 +217,12 @@ class EngineMetrics:
             # fixed Prometheus buckets) — the SLO accounting surface
             "ttft_p50_steps": round(ttft_dig.quantile(0.5), 3),
             "ttft_p99_steps": round(ttft_dig.quantile(0.99), 3),
+            # wall-clock split of TTFT at first admission (the
+            # operator's view with no profiler attached)
+            "queue_wait_p50_ms": round(wait_dig.quantile(0.5), 3),
+            "queue_wait_p90_ms": round(wait_dig.quantile(0.9), 3),
+            "prefill_p50_ms": round(prefill_dig.quantile(0.5), 3),
+            "prefill_p90_ms": round(prefill_dig.quantile(0.9), 3),
             "mean_tpot_steps": round(
                 sum(tpots) / len(tpots), 3) if tpots else 0.0,
             "tpot_p50_steps": round(tpot_dig.quantile(0.5), 3),

@@ -294,8 +294,8 @@ class Scheduler:
                and self.waiting[0].arrival <= step
                and len(sched.prefill) < self.max_prefill_rows
                and budget >= 1):
-            with obs.span("scheduler.admit"):
-                req = self.waiting[0]
+            req = self.waiting[0]
+            with obs.span("scheduler.admit", rid=req.request_id):
                 if req.pages:  # defensive: queued requests hold nothing
                     self.allocator.free(req.pages)
                     req.pages = []

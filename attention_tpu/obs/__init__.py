@@ -7,15 +7,18 @@ process-wide state:
 
 * **Registry** (`obs.registry`) — counters / gauges / fixed-bucket
   histograms with labeled series, ``snapshot()``/``reset()``;
-* **Spans** (`obs.spans`) — ``with obs.span("engine.step"):`` records a
-  host start/duration event into a bounded ring AND enters
-  ``profiling.annotate`` so the same name lands in HLO;
+* **Spans** (`obs.spans`) — ``with obs.span("engine.step", step=n):``
+  is ALWAYS a ``jax.profiler.TraceAnnotation``: under any profiler
+  capture the span is an event on the capture's host plane, on the
+  device trace's clock, with its fields as stats; enabled, it also
+  records a row into a bounded ring;
 * **Exporters** (`obs.export`) — Prometheus text (:func:`prom_text`),
-  JSONL, and a Chrome-trace timeline merging host spans with the XLA
-  device lane (``cli obs export --format chrome|prom|jsonl``).
+  JSONL, and a Chrome-trace timeline of the ring's host spans
+  (``cli obs export --format chrome|prom|jsonl``).
 
-Telemetry is **disabled by default** and the disabled path is a single
-flag check (no allocation, no clock read — asserted by test).  Enable
+The registry and the ring are **disabled by default**: a disabled
+instrument is a single flag check and a disabled span only the inert
+annotation (no clock read, no ring row — asserted by test).  Enable
 with :func:`enable` or ``ATTN_TPU_OBS=1``.  Instrument handles may be
 created at import time regardless of the flag::
 

@@ -1,4 +1,4 @@
-"""Exporters: Prometheus text, JSONL event log, merged Chrome trace.
+"""Exporters: Prometheus text, JSONL event log, Chrome trace.
 
 Three views of the same state:
 
@@ -7,14 +7,13 @@ Three views of the same state:
 * :func:`jsonl_lines` / :func:`write_jsonl` — one JSON object per span
   event plus one per metric series, the archival format
   (`profiling.append_jsonl`'s discipline applied to telemetry);
-* :func:`chrome_trace` — ONE Chrome-trace/Perfetto JSON timeline
-  merging host spans (pid "host") with the device "XLA Modules" lane
-  (pid "device") parsed from a ``profiling.trace`` capture by
-  `profiling.device_module_slices`.  Host and device clocks have no
-  common epoch, so each lane is normalized to its own first event —
-  relative alignment within a lane is exact, cross-lane offset is
-  nominal (good enough to see an engine step next to its two kernel
-  calls; a shared-epoch clock needs device support we don't assume).
+* :func:`chrome_trace` — a Chrome-trace/Perfetto JSON timeline of
+  the ring's host spans (pid "host"), the request journeys and the
+  incident windows.  It has no device lane: the program's spans are
+  profiler annotations (`obs.spans`), so a ``jax.profiler`` capture
+  (``serve-sim --obs-profile`` writes one under ``<run>/device``)
+  already holds them beside the device's lanes on ONE clock — open
+  that capture for host-against-device questions.
 
 :func:`dump` / :func:`load_dump` persist a run's telemetry
 (``metrics.json`` + ``events.jsonl`` [+ ``device/`` profiler capture])
@@ -139,20 +138,17 @@ TICK_US = 1000.0
 
 
 def chrome_trace(span_events: list[dict] | None = None,
-                 device_dir: str | None = None,
                  request_traces: dict[str, list[dict]] | None = None,
                  incidents: list[dict[str, Any]] | None = None,
                  ) -> dict[str, Any]:
-    """The merged host/device timeline as a Chrome-trace dict.
+    """The ring's host spans as a Chrome-trace dict.
 
-    ``device_dir`` is a ``profiling.trace`` log dir; absent/unparsable
-    captures degrade to a host-only timeline (never an error — the CPU
-    CI path has no device lane).  ``request_traces`` (request id ->
-    event chain, default the live trace store) adds one lane per
-    request under a third process: each journey is a span from submit
-    to terminal with an instant mark per trace event.  ``incidents``
-    (loaded postmortem bundles) adds a fourth lane marking each
-    incident's evidence window and trigger tick."""
+    ``request_traces`` (request id -> event chain, default the live
+    trace store) adds one lane per request under a process of its own:
+    each journey is a span from submit to terminal with an instant
+    mark per trace event.  ``incidents`` (loaded postmortem bundles)
+    adds a lane marking each incident's evidence window and trigger
+    tick."""
     evs = spans.events() if span_events is None else span_events
     trace_events: list[dict[str, Any]] = [
         {"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
@@ -166,30 +162,15 @@ def chrome_trace(span_events: list[dict] | None = None,
             {"ph": "M", "pid": 1, "tid": i, "name": "thread_name",
              "args": {"name": f"host spans (thread {t})"}})
     for e in evs:
-        trace_events.append({
+        row = {
             "ph": "X", "pid": 1, "tid": tid_map[e["tid"]],
             "name": e["name"],
             "ts": round(e["ts_us"] - host_t0, 3),
             "dur": round(e["dur_us"], 3),
-        })
-
-    if device_dir is not None:
-        from attention_tpu.utils.profiling import device_module_slices
-
-        slices = device_module_slices(device_dir)
-        if slices:
-            trace_events.append(
-                {"ph": "M", "pid": 2, "tid": 0, "name": "process_name",
-                 "args": {"name": "device"}})
-            trace_events.append(
-                {"ph": "M", "pid": 2, "tid": 1, "name": "thread_name",
-                 "args": {"name": "XLA Modules"}})
-            dev_t0 = min(ts for _, ts, _ in slices)
-            for name, ts, dur in slices:
-                trace_events.append({
-                    "ph": "X", "pid": 2, "tid": 1, "name": name,
-                    "ts": round(ts - dev_t0, 3), "dur": round(dur, 3),
-                })
+        }
+        if e.get("fields"):
+            row["args"] = e["fields"]
+        trace_events.append(row)
 
     chains = (_trace.all_traces() if request_traces is None
               else request_traces)

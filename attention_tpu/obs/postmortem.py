@@ -71,7 +71,7 @@ def _deterministic_snapshot() -> dict[str, Any]:
     """Registry snapshot minus the wall-clock reporting channels.
 
     ``*_ms`` instruments (``engine.step.wall_ms``,
-    ``engine.snapshot.save_ms``, ``engine.step.collective_ms``) time
+    ``engine.snapshot.save_ms``) time
     host/device walls — ATP801's sanctioned reporting channel,
     excluded from every byte-determinism contract in the repo.  An
     incident bundle IS such a contract (same seed must dump

@@ -6,8 +6,10 @@ builds rather than instrumentation (report Q2).  Here:
 
   * :func:`trace` wraps ``jax.profiler.trace`` so any benchmark or test
     can capture an XLA/TPU trace (xplane) for the profiler UI;
-  * :func:`annotate` names a phase so it shows up on the trace timeline
-    (the instrumentation the reference lacked);
+  * phases are named by ``attention_tpu.obs.span``, which enters a
+    ``jax.profiler.TraceAnnotation``: inside a :func:`trace` block the
+    program's spans are events of the capture itself (the
+    instrumentation the reference lacked);
   * :class:`RunRecord` is the structured per-run JSON record
     (config, timing, GFLOPs, utilization, device) that replaces printf.
 """
@@ -30,11 +32,6 @@ def trace(log_dir: str) -> Iterator[None]:
     os.makedirs(log_dir, exist_ok=True)
     with jax.profiler.trace(log_dir):
         yield
-
-
-def annotate(name: str):
-    """Named region on the profiler timeline (and in HLO op names)."""
-    return jax.named_scope(name)
 
 
 @dataclasses.dataclass
@@ -95,8 +92,7 @@ def device_module_slices(
     Parses the newest Chrome-trace export under ``log_dir`` and returns
     every complete event on the device "XLA Modules" lane as
     ``(module_name, ts_us, dur_us)`` tuples (trace-local clock), or
-    None when no trace/device lane exists (e.g. CPU platforms).  The
-    slice-level view feeds `obs.export.chrome_trace`'s merged timeline;
+    None when no trace/device lane exists (e.g. CPU platforms).
     :func:`device_module_seconds` aggregates it.
     """
     import gzip

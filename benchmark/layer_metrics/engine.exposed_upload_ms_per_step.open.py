@@ -1,0 +1,3 @@
+"""`engine.exposed_upload_ms_per_step` in the open-loop cell: see `benchmark/reduce/phases.py`."""
+
+from benchmark.reduce.phases import exposed_upload_ms_per_step as read  # noqa: F401

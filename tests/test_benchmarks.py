@@ -11,10 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from attention_tpu import obs
 from attention_tpu.benchmarks import ablation_table, strong_scaling, weak_scaling
 from attention_tpu.ops.flash import BlockSizes
 from attention_tpu.parallel.mesh import default_mesh
-from attention_tpu.utils.profiling import RunRecord, append_jsonl, annotate, trace
+from attention_tpu.utils.profiling import RunRecord, append_jsonl, trace
 
 BS = BlockSizes(64, 64)
 
@@ -76,10 +77,10 @@ def test_run_record_jsonl(tmp_path):
     assert parsed["backend"] == "b" and parsed["utilization"] == 0.1
 
 
-def test_trace_and_annotate(tmp_path):
+def test_trace_and_span(tmp_path):
     logdir = str(tmp_path / "trace")
     with trace(logdir):
-        with annotate("phase1"):
+        with obs.span("bench.test.phase"):
             jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
     # a trace produces at least one file under the log dir
     found = [f for _, _, fs in os.walk(logdir) for f in fs]

@@ -203,8 +203,9 @@ def _counter_total(snap, name, **labels):
 def test_mesh_exactly_one_launch_per_busy_step(tiny_model):
     """The single-launch property survives sharding: the mesh engine
     still dispatches exactly one jitted ragged launch per non-empty
-    step, and the mesh instruments carry the shard count and the
-    per-step collective (device-sync) time."""
+    step, the mesh gauge carries the shard count, and the step's one
+    device sync (where the shards' replicated logits reassemble) is
+    one `engine.step.fetch` span per busy step."""
     model, params = tiny_model
     trace = _trace(model)
     was = obs.enabled()
@@ -224,9 +225,10 @@ def test_mesh_exactly_one_launch_per_busy_step(tiny_model):
         shards = [g["value"] for g in snap["gauges"]
                   if g["name"] == "engine.mesh.shards"]
         assert shards == [float(SHARDS)]
-        coll = [h for h in snap["histograms"]
-                if h["name"] == "engine.step.collective_ms"]
-        assert coll and coll[0]["count"] == busy
+        fetches = [e for e in obs.events()
+                   if e["name"] == "engine.step.fetch"]
+        assert len(fetches) == busy
+        assert all(e["fields"]["bytes"] > 0 for e in fetches)
     finally:
         obs.reset()
         (obs.enable if was else obs.disable)()

@@ -1,9 +1,11 @@
 """Unified telemetry subsystem tests (attention_tpu/obs/).
 
 Pins the contracts ISSUE 3 promises: typed instruments with labeled
-series and snapshot/reset; the bounded span ring composing with
-`profiling.annotate`; Prometheus text that round-trips through a
-parser; the merged host/device Chrome timeline; the mtime-newest and
+series and snapshot/reset; the bounded span ring; spans as profiler
+annotations on the capture's own clock (ISSUE 24: nested spans, their
+fields, and the engine step's phases read back from a CPU
+`jax.profiler` capture); Prometheus text that round-trips through a
+parser; the mtime-newest and
 truncated-capture behavior of the profiler parser; the
 zero-overhead-when-disabled contract (<5% on a tight loop, byte-
 identical engine AND multi-replica front-end outputs — the router hot
@@ -13,6 +15,7 @@ family.
 All CPU-safe, tiny shapes.
 """
 
+import contextlib
 import gzip
 import json
 import os
@@ -140,8 +143,6 @@ def test_disabled_records_nothing():
     with obs.span("obs.test.offspan"):
         pass
     assert obs.events() == []
-    # the disabled span is the shared no-op instance — no allocation
-    assert obs.span("obs.test.offspan") is obs.span("obs.test.other")
 
 
 # ------------------------------------------------------------ exporters
@@ -457,22 +458,185 @@ def test_truncated_captures_read_as_no_device_lane(tmp_path):
     assert device_module_seconds(str(tmp_path / "noschema")) is None
 
 
-def test_chrome_trace_merges_host_and_device_lanes(obs_state, tmp_path):
-    with obs.span("engine.step"):
+def _host_events(trace_dir, prefixes):
+    """Events of the newest capture's ``/host:CPU`` plane whose names
+    start with one of ``prefixes``: (thread, name, start_ns, end_ns,
+    stats), in start order (a parent before its children)."""
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    found = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefixes):
+                    found.append((line.name, e.name, e.start_ns,
+                                  e.start_ns + e.duration_ns,
+                                  dict(e.stats)))
+    return sorted(found, key=lambda r: (r[2], -r[3]))
+
+
+@contextlib.contextmanager
+def _capture(trace_dir):
+    """A ``jax.profiler`` capture of the enclosed block: host TraceMe
+    events on, the Python tracer off (the benchmark's settings)."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("enabled", [False, True], ids=["obs_off", "obs_on"])
+def test_spans_are_profiler_annotations_on_one_clock(enabled, tmp_path):
+    """Nested `obs.span`s land on the capture's ``/host:CPU`` plane, on
+    one thread, the child inside the parent on the trace's clock, with
+    their fields as the events' stats — telemetry off AND on."""
+    assert not obs.is_enabled()
+    if enabled:
+        obs.enable()
+        obs.reset()
+    try:
+        with _capture(tmp_path):
+            with obs.span("obs.test.outer", step=7, queued=2):
+                time.sleep(0.001)
+                with obs.span("obs.test.inner", rid="req-3",
+                              wait_ms=1.5):
+                    time.sleep(0.001)
+                time.sleep(0.001)
+        ring = obs.events()
+    finally:
+        obs.reset()
+        obs.disable()
+    outer, inner = _host_events(tmp_path, ("obs.test.",))
+    assert (outer[1], inner[1]) == ("obs.test.outer", "obs.test.inner")
+    assert outer[0] == inner[0]                      # one thread
+    assert outer[2] < inner[2] < inner[3] < outer[3]  # nested, one clock
+    assert inner[3] - inner[2] >= 1_000_000          # the 1 ms sleep, ns
+    assert outer[4] == {"step": 7, "queued": 2}
+    assert inner[4] == {"rid": "req-3", "wait_ms": 1.5}
+    # the ring is the enabled path's addition, fields included
+    if enabled:
+        assert [e["name"] for e in ring] == ["obs.test.inner",
+                                             "obs.test.outer"]
+        assert ring[0]["fields"] == {"rid": "req-3", "wait_ms": 1.5}
+    else:
+        assert ring == []
+
+
+_PHASES = ("engine.step.schedule", "engine.step.pack",
+           "engine.step.upload", "engine.step.dispatch",
+           "engine.step.fetch", "engine.step.sample")
+
+
+@pytest.mark.parametrize("async_steps", [False, True],
+                         ids=["sync", "async"])
+def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
+                                            tmp_path):
+    """A tiny engine stepped under a CPU profiler capture: one
+    `engine.step` per step and, inside every busy one, exactly one of
+    each phase span, in order, none overlapping (the async loop's
+    overlap span between dispatch and fetch); fields ride as stats;
+    the capture changes no token, with telemetry off or on; and every
+    request's queue wait and prefill time add up to its TTFT."""
+    from attention_tpu.engine import ServingEngine, replay, synthetic_trace
+
+    model, params = tiny_model
+    trace = synthetic_trace(4, vocab=43, seed=3, prompt_len_min=4,
+                            prompt_len_max=12, max_tokens=3,
+                            shared_prefix_len=129, shared_count=2)
+    assert not obs.is_enabled()
+    plain = _run_engine(tiny_model, async_steps=async_steps)
+
+    engine = ServingEngine(model, params,
+                           _engine_config(async_steps=async_steps))
+    with _capture(tmp_path / "off"):
+        _summary, captured = replay(engine, trace)
+    assert captured == plain
+    obs.enable()
+    obs.reset()
+    try:
+        with _capture(tmp_path / "on"):
+            captured_on = _run_engine(tiny_model, async_steps=async_steps)
+    finally:
+        obs.reset()
+        obs.disable()
+    assert captured_on == plain
+
+    rows = _host_events(tmp_path / "off", ("engine.step",))
+    assert len({r[0] for r in rows}) == 1            # the loop's thread
+    steps = [r for r in rows if r[1] == "engine.step"]
+    assert [r[4]["step"] for r in steps] == list(range(len(steps)))
+    assert len(steps) == len(engine.metrics.steps)
+    busy = [m for m in engine.metrics.steps
+            if m.decode_tokens or m.prefill_tokens]
+    expected = list(_PHASES)
+    if async_steps:
+        expected.insert(4, "engine.step.overlap")
+    seen_busy = 0
+    for step, m in zip(steps, engine.metrics.steps):
+        kids = [r for r in rows if r is not step
+                and step[2] <= r[2] and r[3] <= step[3]]
+        if not (m.decode_tokens or m.prefill_tokens):
+            assert [k[1] for k in kids] == ["engine.step.schedule"]
+            continue
+        seen_busy += 1
+        assert [k[1] for k in kids] == expected
+        for before, after in zip(kids, kids[1:]):
+            assert before[3] <= after[2]             # none overlaps
+        stats = {k[1]: k[4] for k in kids}
+        assert stats["engine.step.dispatch"]["decode_rows"] \
+            == m.num_decode_reqs
+        assert stats["engine.step.dispatch"]["prefill_tokens"] \
+            == m.prefill_tokens
+        assert stats["engine.step.dispatch"]["width"] \
+            == m.decode_tokens + m.prefill_tokens + m.pad_tokens
+        assert stats["engine.step.sample"]["rows"] \
+            == m.num_decode_reqs + m.num_prefill_reqs
+        assert stats["engine.step.fetch"]["bytes"] == 4 * 43 \
+            * stats["engine.step.dispatch"]["width"]
+    assert seen_busy == len(busy) > 0
+
+    # the requests' marks share the request id with scheduler.admit
+    marks = _host_events(tmp_path / "off",
+                         ("engine.request.", "scheduler.admit"))
+    ids = {r["id"] for r in trace}
+    for name in ("engine.request.admitted", "engine.request.first_token",
+                 "scheduler.admit"):
+        assert {m[4]["rid"] for m in marks if m[1] == name} == ids
+    for req in engine.metrics.requests:
+        assert req.queue_wait_s >= 0 and req.prefill_s > 0
+        assert req.queue_wait_s + req.prefill_s \
+            == pytest.approx(req.ttft_s, abs=1e-9)
+    summary = engine.metrics.summary()
+    assert 0 <= summary["queue_wait_p50_ms"] <= summary["queue_wait_p90_ms"]
+    assert 0 < summary["prefill_p50_ms"] <= summary["prefill_p90_ms"]
+
+
+def test_chrome_trace_is_host_spans_with_fields(obs_state):
+    """The chrome export lays out the ring's host spans (fields as
+    args) and nothing of the device: a profiler capture already holds
+    the program's spans beside the device lanes on one clock."""
+    with obs.span("engine.step", step=0):
         pass
-    _write_capture(tmp_path, "run_1", ["jit_paged_apply"])
-    doc = obs.chrome_trace(device_dir=str(tmp_path))
+    doc = obs.chrome_trace()
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    pids = {e["pid"] for e in xs}
-    assert pids == {1, 2}  # host AND device slices in ONE timeline
-    names = {e["name"] for e in xs}
-    assert {"engine.step", "jit_paged_apply"} <= names
+    assert [(e["pid"], e["name"], e["args"]) for e in xs] \
+        == [(1, "engine.step", {"step": 0})]
     lanes = {e["args"]["name"] for e in doc["traceEvents"]
              if e.get("name") == "thread_name"}
-    assert any("XLA Modules" in x for x in lanes)
-    # unparsable device dir degrades to host-only, never raises
-    doc = obs.chrome_trace(device_dir=str(tmp_path / "missing"))
-    assert {e["pid"] for e in doc["traceEvents"] if e["ph"] == "X"} == {1}
+    assert not any("XLA" in x for x in lanes)
 
 
 # -------------------------------------------------- overhead contracts
@@ -698,17 +862,24 @@ def test_cli_serve_sim_obs_dump_report_and_export(tmp_path, capsys):
         parsed = _parse_prom(capsys.readouterr().out)
         assert parsed["engine_steps_total"][()] > 0
 
-        # a device capture inside the dump joins the chrome timeline
+        # a device capture inside the dump feeds the report's device
+        # modules; the chrome timeline is host spans and journeys only
         _write_capture(run / "device", "r", ["jit_paged_apply"])
+        assert main(["obs", "report", "--run", str(run)]) == 0
+        assert "jit_paged_apply" in capsys.readouterr().out
         out_file = tmp_path / "timeline.json"
         assert main(["obs", "export", "--run", str(run), "--format",
                      "chrome", "--out", str(out_file)]) == 0
         doc = json.loads(out_file.read_text())
         xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-        # host spans, the device lane, AND the request-journey lane
-        assert {e["pid"] for e in xs} == {1, 2, 3}
+        # host spans (the step's phases, fields as args) AND the
+        # request-journey lane
+        assert {e["pid"] for e in xs} == {1, 3}
         names = {e["name"] for e in xs}
-        assert "engine.step" in names and "jit_paged_apply" in names
+        assert {"engine.step", "engine.step.fetch",
+                "engine.step.sample"} <= names
+        assert any(e["name"] == "engine.step.dispatch"
+                   and e["args"]["width"] > 0 for e in xs)
         assert "req-0" in names  # each journey is a span in lane 3
 
         assert main(["obs", "export", "--run", str(run), "--format",
