@@ -18,10 +18,13 @@ by ``cu_q_lens`` + a decode/prefill ``distribution`` split
 power-of-two bucketed, so a serving life compiles O(log max_tokens)
 executables and pad waste per step is just the bucket remainder — not
 the ``(max_decode_batch - d) + (max_prefill_rows*chunk - real)``
-poison rows of the legacy path.  ``step_mode="two_call"`` keeps that
-legacy lowering — a ``(max_decode_batch, 1)`` decode call plus a
-``(max_prefill_rows, prefill_chunk)`` prefill call padded with the
-inactive sentinel (empty table, length -1) — as the parity oracle;
+poison rows of the legacy path.  Of that launch the host fetches only
+the logits it can sample — each slot's last packed row, gathered on
+the device ahead of the final norm and the head once the packed axis
+is wider than the slot count (`_ragged_apply`).  ``step_mode="two_call"``
+keeps that legacy lowering — a ``(max_decode_batch, 1)`` decode call
+plus a ``(max_prefill_rows, prefill_chunk)`` prefill call padded with
+the inactive sentinel (empty table, length -1) — as the parity oracle;
 both modes consume logits through the same post-processing helpers,
 so their token streams are identical by construction.
 
@@ -76,6 +79,12 @@ _TIMED_OUT = obs.counter("engine.requests.timed_out",
 # asserted against this; the ops.*.calls counters tick per jit trace)
 _LAUNCHES = obs.counter("engine.step.launches",
                         "jitted model launches dispatched by the step loop")
+# what the step's one device sync moves against what the host reads of
+# it: logits rows fetched and rows sampled, summed over steps (the used
+# share of the fetch is their ratio)
+_LOGIT_ROWS = obs.counter("engine.step.logit_rows",
+                          "logits rows fetched to the host / sampled "
+                          "there, by kind")
 # mesh-serving surface: how many KV-head shards the per-step launches
 # lower onto (1 = single-device).  In the zero-collective head-sharded
 # design the kernels exchange nothing; the only cross-shard cost is
@@ -119,8 +128,41 @@ def _ragged_apply(model, params, tokens, caches):
     single ``(1, width)`` token axis over per-layer `RaggedPagedStep`
     caches — exactly one attention launch per layer per engine step.
     Width and the caches' q_tile marker are pow2-bucketed by the
-    caller, so distinct compiled signatures stay O(log max_tokens)."""
-    return model.apply({"params": params}, tokens, caches)
+    caller, so distinct compiled signatures stay O(log max_tokens).
+
+    Returns the logits of the rows a step can sample, not of every
+    packed position.  When the packed axis is wider than the slot
+    count, each slot's last row (`_slot_last_rows`) is gathered before
+    the final norm and the float32 head, and the result is
+    ``(1, slots, vocab)`` with slot ``s`` at row ``s``; a width within
+    the slot count already returns no more rows than that and stays
+    ``(1, width, vocab)`` (`_sampled_logit_rows` is the host's half of
+    this rule).  Both sizes are input shapes, and the indices come
+    from the ``cu_q_lens`` already on the device: no signature and no
+    upload is added."""
+    cu = caches[0].cu_q_lens
+    rows = None
+    if tokens.shape[1] > cu.shape[0] - 1:
+        rows = _slot_last_rows(cu)
+    return model.apply({"params": params}, tokens, caches,
+                       logit_rows=rows)
+
+
+def _slot_last_rows(cu_q_lens):
+    """The packed row each slot samples: the last of its span
+    ``[cu[s], cu[s + 1])``.  Empty slots repeat the last offset, so
+    the row is clipped at 0.  Takes the host's array or the device's."""
+    return (cu_q_lens[1:] - 1).clip(0)
+
+
+def _sampled_logit_rows(cu_q_lens: np.ndarray, width: int) -> np.ndarray:
+    """Row of `_ragged_apply`'s logits that slot ``s`` samples, for
+    every slot: ``s`` itself where the step gathered (``width`` over
+    the slot count), else the slot's last packed row."""
+    slots = len(cu_q_lens) - 1
+    if width > slots:
+        return np.arange(slots)
+    return _slot_last_rows(cu_q_lens)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -721,20 +763,27 @@ class ServingEngine:
             return arr
         return jax.device_put(arr, self._pool_sharding)
 
-    def _fetch_logits(self, logits_dev) -> np.ndarray:
-        """The step loop's ONLY device sync: materialize the launch's
-        logits on host.  Isolated in one hook so (a) the async loop can
+    def _fetch_logits(self, logits_dev, used: int) -> np.ndarray:
+        """The step loop's ONLY device sync: materialize on host the
+        logits rows the launch returned — in ragged mode the rows that
+        can be sampled (`_ragged_apply`), of which this step samples
+        ``used``.  Isolated in one hook so (a) the async loop can
         finish its overlapped staging before the block, (b) per-step
         host overhead is measurable as wall minus time spent here, and
         (c) fault injectors have a single seam to poison."""
-        with obs.span("engine.step.fetch", bytes=4 * logits_dev.size):
+        rows = logits_dev.size // logits_dev.shape[-1]
+        if obs.is_enabled():
+            _LOGIT_ROWS.inc(rows, kind="fetched")
+            _LOGIT_ROWS.inc(used, kind="used")
+        with obs.span("engine.step.fetch", bytes=4 * logits_dev.size,
+                      rows=rows, used=used):
             t0 = time.perf_counter()
             out = np.asarray(logits_dev, np.float32)
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
     def _apply(self, tokens: np.ndarray, tables: np.ndarray,
-               lens: np.ndarray) -> np.ndarray:
+               lens: np.ndarray, used: int) -> np.ndarray:
         caches = tuple(
             PagedKV(self._k_pools[layer], self._v_pools[layer],
                     jnp.asarray(tables, jnp.int32),
@@ -751,7 +800,7 @@ class ServingEngine:
             for layer, c in enumerate(new_caches):
                 self._k_pools[layer] = c.k_pool
                 self._v_pools[layer] = c.v_pool
-        return self._fetch_logits(logits)
+        return self._fetch_logits(logits, used)
 
     def _run_ragged(self, sched: ScheduledStep) -> int:
         """Lower the WHOLE step onto one jitted packed launch; returns
@@ -811,17 +860,16 @@ class ServingEngine:
             # sync has not happened — overlap next step's host staging
             with obs.span("engine.step.overlap"):
                 self._stage_next_step()
-        logits = self._fetch_logits(logits_dev)
-        with obs.span("engine.step.sample",
-                      rows=len(sched.decode) + len(sched.prefill)):
-            cu_h = batch.cu_q_lens
+        sampled = len(sched.decode) + len(sched.prefill)
+        logits = self._fetch_logits(logits_dev, sampled)
+        with obs.span("engine.step.sample", rows=sampled):
+            row_of = _sampled_logit_rows(batch.cu_q_lens, width)
             num_decode = len(sched.decode)
             for i, req in enumerate(sched.decode):
-                self._post_decode(req, logits[0, cu_h[i]])
+                self._post_decode(req, logits[0, row_of[i]])
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
-                    req, real, logits[0, cu_h[num_decode + s] + real - 1]
-                )
+                    req, real, logits[0, row_of[num_decode + s]])
         return width
 
     def _stage_next_step(self) -> None:
@@ -857,7 +905,7 @@ class ServingEngine:
             lens[i] = req.computed_tokens
             tokens[i, 0] = req.feed_pending()
             tables[i, : len(req.pages)] = req.pages
-        logits = self._apply(tokens, tables, lens)
+        logits = self._apply(tokens, tables, lens, len(reqs))
         with obs.span("engine.step.sample", rows=len(reqs)):
             for i, req in enumerate(reqs):
                 self._post_decode(req, logits[i, 0])
@@ -896,7 +944,7 @@ class ServingEngine:
             tokens[i, :real] = req.tokens[c : c + real]
             tables[i, : len(req.pages)] = req.pages
             lens[i] = c
-        logits = self._apply(tokens, tables, lens)
+        logits = self._apply(tokens, tables, lens, len(items))
         with obs.span("engine.step.sample", rows=len(items)):
             for i, (req, real) in enumerate(items):
                 self._post_prefill(req, real, logits[i, real - 1])

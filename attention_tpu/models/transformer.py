@@ -98,6 +98,12 @@ class TinyDecoder(nn.Module):
     backward pass (`jax.checkpoint` via `nn.remat`) — the HBM-for-FLOPs
     trade that lets long-sequence training fit; ignored on the cached
     decode path (no backward there).
+
+    ``logit_rows`` (int32 indices into the token axis) keeps only those
+    positions ahead of the final norm and the head, which act row by
+    row: the result is ``(B, len(logit_rows), vocab)``, each row the
+    same numbers as in the whole projection.  ``None`` projects every
+    position (training, ``generate*``); the parameters are the same.
     """
 
     vocab: int = 256
@@ -133,7 +139,8 @@ class TinyDecoder(nn.Module):
 
     @nn.compact
     def __call__(self, tokens: jax.Array, caches=None,
-                 return_hidden: bool = False):  # (B, S) int32
+                 return_hidden: bool = False,
+                 logit_rows: jax.Array | None = None):  # (B, S) int32
         head_dim = self.dim // self.num_q_heads
         x = nn.Embed(self.vocab, self.dim, dtype=self.dtype)(tokens)
         new_caches = []
@@ -171,6 +178,8 @@ class TinyDecoder(nn.Module):
             else:
                 x, c = block(x, caches[i])
                 new_caches.append(c)
+        if logit_rows is not None:
+            x = jnp.take(x, logit_rows, axis=1)
         x = nn.RMSNorm(dtype=self.dtype)(x)
         if return_hidden:
             # pre-head activations for memory-bounded losses (chunked

@@ -102,6 +102,32 @@ def test_ragged_engine_step_at_smoke_width(v5e):
     assert compiled.memory_analysis().alias_size_in_bytes == 0
 
 
+@pytest.mark.parametrize("width,q_tile,rows", [
+    (384, 256, 33),   # a 256-token chunk beside decode rows: gathered
+    (32, 8, 32),      # decode-only, within the 33 slots: every row
+])
+def test_ragged_engine_step_projects_the_sampled_rows(v5e, width, q_tile,
+                                                      rows):
+    """The step of the benchmark's StarCoder2 cells (hidden 4608,
+    36q/4kv x 128, vocab 49152, window 4096, 32 + 1 slots; one layer
+    of the eight): over the slot count it gathers the slots' last rows
+    ahead of the final norm and the float32 head and returns
+    ``(1, 33, vocab)``, 6.5 MB where ``(1, 384, vocab)`` was 75 MB."""
+    model = TinyDecoder(vocab=49152, dim=4608, depth=1, num_q_heads=36,
+                        num_kv_heads=4, impl="flash", window=4096,
+                        rope=True, rope_theta=1e6)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    caches = (_ragged_cache(4, width, q_tile, BF16, pages=768, slots=33,
+                            max_pages=32),)
+    compiled = _compile(
+        functools.partial(_ragged_apply, model),
+        jax.sharding.SingleDeviceSharding(v5e[0]),
+        params, _a((1, width), I32), caches)
+    logits = compiled.out_info[0]
+    assert (logits.shape, logits.dtype) == ((1, rows, 49152), F32)
+
+
 def test_ragged_engine_step_head_sharded_over_four_devices(v5e):
     mesh = Mesh(np.asarray(v5e), ("tp",))
     model = TinyDecoder(vocab=32768, dim=4096, depth=1, num_q_heads=32,

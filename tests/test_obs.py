@@ -604,8 +604,11 @@ def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
             == m.decode_tokens + m.prefill_tokens + m.pad_tokens
         assert stats["engine.step.sample"]["rows"] \
             == m.num_decode_reqs + m.num_prefill_reqs
-        assert stats["engine.step.fetch"]["bytes"] == 4 * 43 \
-            * stats["engine.step.dispatch"]["width"]
+        # 4 + 2 slots, every width over them: the step fetches one
+        # logits row a slot, not one a packed position
+        assert stats["engine.step.fetch"] == {
+            "bytes": 4 * 43 * 6, "rows": 6,
+            "used": m.num_decode_reqs + m.num_prefill_reqs}
     assert seen_busy == len(busy) > 0
 
     # the requests' marks share the request id with scheduler.admit

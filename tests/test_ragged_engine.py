@@ -20,7 +20,11 @@ shapes:
   * the single-launch property, asserted against the
     ``engine.step.launches`` telemetry counter (ticks per host
     dispatch; the per-trace ``ops.*.calls`` counters corroborate that
-    no legacy paged kernel is dispatched in ragged mode).
+    no legacy paged kernel is dispatched in ragged mode);
+  * the step returns the logits of the rows it can sample only
+    (``(1, slots, vocab)`` once the packed axis is wider than the
+    slots), bit-identical to those rows of the whole projection, with
+    no compiled signature added, and the fetch span says so.
 """
 
 import dataclasses
@@ -39,6 +43,8 @@ from attention_tpu.engine import (
     ServingEngine,
     synthetic_trace,
 )
+from attention_tpu.engine import engine as engine_mod
+from attention_tpu.engine.engine import _ragged_apply
 from attention_tpu.engine.request import Request
 from attention_tpu.engine.scheduler import ScheduledStep
 from attention_tpu.engine.sim import replay, sampling_of
@@ -379,7 +385,6 @@ def test_exactly_one_launch_per_busy_step(tiny_model):
     try:
         # ops.*.calls tick at jit-TRACE time; drop the cached executable
         # so this replay's traces land in the freshly reset registry
-        from attention_tpu.engine.engine import _ragged_apply
         _ragged_apply.clear_cache()
         eng = ServingEngine(model, params, _cfg())
         replay(eng, trace)
@@ -423,3 +428,125 @@ def test_two_call_mode_counts_two_launches_on_mixed_steps(tiny_model):
     finally:
         obs.reset()
         (obs.enable if was else obs.disable)()
+
+
+# ------------------------------------------- logits of the sampled rows
+
+_SLOTS10 = dict(max_decode_batch=8, max_prefill_rows=2)  # 10 slots
+
+
+@pytest.fixture
+def ragged_calls(monkeypatch):
+    """Every `_ragged_apply` dispatch of the engines run under this
+    fixture, as ``(tokens, caches, logits)``.  The pools are not
+    donated, so a recorded call can be run again."""
+    calls = []
+
+    def spy(model, params, tokens, caches):
+        out = _ragged_apply(model, params, tokens, caches)
+        calls.append((tokens, caches, out[0]))
+        return out
+
+    monkeypatch.setattr(engine_mod, "_ragged_apply", spy)
+    return calls
+
+
+def _mixed_widths_run(tiny_model, **cfg):
+    """Ten slots under prompts of 4-40 tokens: decode-only steps pack
+    to width 8 (within the slots), steps with a chunk to 16-48."""
+    model, params = tiny_model
+    trace = synthetic_trace(8, vocab=model.vocab, seed=3, max_tokens=6,
+                            prompt_len_min=4, prompt_len_max=40)
+    eng = ServingEngine(model, params, _cfg(**_SLOTS10, **cfg))
+    _, out = replay(eng, trace)
+    assert all(out[e["id"]] for e in trace)
+    return eng
+
+
+@pytest.mark.parametrize("wide", [True, False],
+                         ids=["width_over_slots", "width_within_slots"])
+def test_step_returns_the_sampled_rows_bit_identical(tiny_model,
+                                                     ragged_calls, wide):
+    """Over the slot count the step hands back ``(1, slots, vocab)``,
+    row ``s`` = the projection's row ``cu[s + 1] - 1`` (clipped at 0
+    for empty slots) to the bit; within it the program is the whole
+    projection, as it was."""
+    model, params = tiny_model
+    _mixed_widths_run(tiny_model)
+    whole = jax.jit(lambda t, c: model.apply({"params": params}, t, c)[0])
+    seen = mixed = 0
+    for tokens, caches, got in ragged_calls:
+        width, slots = tokens.shape[1], caches[0].cu_q_lens.shape[0] - 1
+        assert slots == 10
+        if (width > slots) != wide:
+            continue
+        seen += 1
+        full = np.asarray(whole(tokens, caches))
+        assert full.shape == (1, width, model.vocab)
+        if not wide:
+            np.testing.assert_array_equal(np.asarray(got), full)
+            continue
+        assert got.shape == (1, slots, model.vocab)
+        cu = np.asarray(caches[0].cu_q_lens)
+        rows = np.maximum(cu[1:] - 1, 0)
+        decoding, active = (int(n) for n in caches[0].distribution)
+        mixed += 0 < decoding < active  # decode rows beside a chunk
+        np.testing.assert_array_equal(np.asarray(got)[0], full[0, rows])
+    assert seen >= 3 and (mixed >= 3 or not wide)
+
+
+def test_sampled_rows_add_no_compiled_signature(tiny_model, ragged_calls):
+    """The gathered row count is a function of the input shapes: one
+    executable per ``(width, q_tile)`` dispatched, as before."""
+    _ragged_apply.clear_cache()
+    _mixed_widths_run(tiny_model)
+    shapes = {(t.shape[1], c[0].q_tile) for t, c, _ in ragged_calls}
+    widths = {w for w, _ in shapes}
+    assert min(widths) <= 10 < max(widths) and len(shapes) >= 3
+    assert _ragged_apply._cache_size() == len(shapes)
+
+
+@pytest.mark.parametrize("step_mode", ["ragged", "two_call"])
+def test_fetch_span_and_counter_report_rows_fetched_and_used(
+        tiny_model, step_mode):
+    """`engine.step.fetch` carries what the sync moved (``bytes``,
+    ``rows``) and what the host reads of it (``used``); the counter
+    ``engine.step.logit_rows`` sums both under `obs.enable`.  A ragged
+    step over the slot count moves ``4 * slots * vocab`` bytes."""
+    model, _ = tiny_model
+    was = obs.enabled()
+    obs.enable()
+    obs.reset()
+    try:
+        eng = _mixed_widths_run(tiny_model, step_mode=step_mode)
+        events = obs.events()
+        snap = obs.REGISTRY.snapshot()
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()
+    by_name = {n: [e.get("fields", {}) for e in events if e["name"] == n]
+               for n in ("engine.step.dispatch", "engine.step.fetch",
+                         "engine.step.sample")}
+    fetches = by_name["engine.step.fetch"]
+    assert len(fetches) == len(by_name["engine.step.sample"]) > 0
+    for fetch, sample in zip(fetches, by_name["engine.step.sample"]):
+        assert fetch["bytes"] == 4 * model.vocab * fetch["rows"]
+        assert fetch["used"] == sample["rows"] <= fetch["rows"]
+    if step_mode == "ragged":
+        busy = [m for m in eng.metrics.steps
+                if m.decode_tokens or m.prefill_tokens]
+        assert len(fetches) == len(busy)
+        wide = 0
+        for fetch, dispatch, m in zip(
+                fetches, by_name["engine.step.dispatch"], busy):
+            assert fetch["rows"] == min(dispatch["width"], 10)
+            assert fetch["used"] == m.num_decode_reqs + m.num_prefill_reqs
+            wide += dispatch["width"] > 10
+        assert 0 < wide < len(fetches)
+    else:
+        # the legacy pair fetches every padded position of both calls
+        assert {f["rows"] for f in fetches} == {8, 2 * 32}
+    assert _counter_total(snap, "engine.step.logit_rows", kind="fetched") \
+        == sum(f["rows"] for f in fetches)
+    assert _counter_total(snap, "engine.step.logit_rows", kind="used") \
+        == sum(f["used"] for f in fetches)
