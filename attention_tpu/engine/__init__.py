@@ -35,6 +35,7 @@ from attention_tpu.engine.errors import (  # noqa: F401
     DeadlineExceededError,
     PrefixLeaseError,
     PrefixStoreCorruptError,
+    RecurrentStateUnsupportedError,
     ReplicaDeadError,
     ReplicaStateError,
     RequestShedError,

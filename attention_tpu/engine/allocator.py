@@ -25,6 +25,13 @@ free (a reserve so already-running requests can keep appending decode
 tokens); decode-path allocations may drain the reserve, then the
 cache, and only then fail — the scheduler turns that failure into
 preemption-by-recompute.
+
+State slots: a model with recurrent layers keeps, beside its pages,
+one fixed-size state per request (``state_slots`` rows of the engine's
+state pools).  A request takes a slot at admission and gives it back
+with its pages (`release`).  Pages do not hold that state, so for such
+a model the prefix cache matches and publishes nothing: a hit would
+skip tokens whose state nobody kept.
 """
 
 from __future__ import annotations
@@ -44,6 +51,8 @@ _PREFIX_HITS = obs.counter("engine.allocator.prefix_hits")
 _PREFIX_MISSES = obs.counter("engine.allocator.prefix_misses")
 _PREFIX_HIT_TOKENS = obs.counter("engine.allocator.prefix_hit_tokens")
 _PREFIX_EVICTIONS = obs.counter("engine.allocator.prefix_evictions")
+_STATE_SLOTS = obs.gauge("engine.state.slots_in_use",
+                         "recurrent-state slots held by running requests")
 
 
 def pages_for_tokens(n_tokens: int, page_size: int) -> int:
@@ -64,7 +73,7 @@ class BlockAllocator:
     """Watermark-guarded page allocation + prefix cache for one pool."""
 
     def __init__(self, pool: PagePool, page_size: int, *,
-                 watermark_pages: int = 0):
+                 watermark_pages: int = 0, state_slots: int = 0):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if not (0 <= watermark_pages < pool.num_pages):
@@ -75,6 +84,9 @@ class BlockAllocator:
         self.pool = pool
         self.page_size = page_size
         self.watermark_pages = watermark_pages
+        # > 0: the model keeps a recurrent state per request
+        self.state_slots = state_slots
+        self._free_state_slots = list(range(state_slots))
         self._prefix: dict[tuple[int, ...], _PrefixEntry] = {}
         # counters the metrics layer reports
         self.prefix_hits = 0
@@ -145,7 +157,50 @@ class BlockAllocator:
         if any, keep prefix pages alive for future hits)."""
         self.pool.free(pages)
 
+    # -- recurrent-state slots --------------------------------------------
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.state_slots - len(self._free_state_slots)
+
+    @property
+    def has_free_state_slot(self) -> bool:
+        """True for a pages-only model, which needs none."""
+        return not self.state_slots or bool(self._free_state_slots)
+
+    def take_state_slot(self) -> int:
+        """The lowest free state slot (-1 for a pages-only model).
+        The slot's rows hold whatever the last owner left: a request
+        that starts at token 0 starts from a zero state in the kernel."""
+        if not self.state_slots:
+            return -1
+        if not self._free_state_slots:
+            raise OutOfPagesError(
+                f"all {self.state_slots} recurrent-state slots are held")
+        slot = self._free_state_slots.pop(0)
+        _STATE_SLOTS.set(float(self.state_slots_in_use))
+        return slot
+
+    def release(self, req) -> None:
+        """Give back everything ``req`` holds: its reference on its
+        pages and its state slot."""
+        if req.pages:
+            self.free(req.pages)
+        req.pages = []
+        if req.state_slot >= 0:
+            self._free_state_slots.append(req.state_slot)
+            self._free_state_slots.sort()
+            req.state_slot = -1
+            _STATE_SLOTS.set(float(self.state_slots_in_use))
+
     # -- prefix cache -----------------------------------------------------
+
+    def _prefix_limit(self, toks: tuple) -> int:
+        """Pages of ``toks`` a prefix match may cover: all but the last
+        token's, and none for a model with recurrent state."""
+        if self.state_slots:
+            return 0
+        return (len(toks) - 1) // self.page_size
 
     def peek_prefix(self, tokens) -> int:
         """Pages of the longest cached page-aligned prefix of
@@ -155,7 +210,7 @@ class BlockAllocator:
         probe of a replica that loses the routing race would still
         refresh its entries)."""
         toks = tuple(tokens)
-        limit = (len(toks) - 1) // self.page_size
+        limit = self._prefix_limit(toks)
         n = 0
         for i in range(1, limit + 1):
             if toks[: i * self.page_size] not in self._prefix:
@@ -171,7 +226,7 @@ class BlockAllocator:
         store-imported pages onto the end of the locally cached chain
         before committing the extended prefix."""
         toks = tuple(tokens)
-        limit = (len(toks) - 1) // self.page_size
+        limit = self._prefix_limit(toks)
         pages: list[int] = []
         for i in range(1, limit + 1):
             entry = self._prefix.get(toks[: i * self.page_size])
@@ -189,7 +244,7 @@ class BlockAllocator:
         first sampled token comes from.
         """
         toks = tuple(tokens)
-        limit = (len(toks) - 1) // self.page_size
+        limit = self._prefix_limit(toks)
         pages: list[int] = []
         for i in range(1, limit + 1):
             entry = self._prefix.get(toks[: i * self.page_size])
@@ -215,6 +270,8 @@ class BlockAllocator:
         touched — a concurrent identical prompt that missed keeps its
         private pages and the first publisher's copy stays canonical
         (content-identical, so reads through either id agree)."""
+        if self.state_slots:
+            return 0  # pages without the state they led to are no prefix
         toks = tuple(tokens)
         if len(pages) < len(toks) // self.page_size:
             raise ValueError(

@@ -55,7 +55,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from attention_tpu import obs
 from attention_tpu.obs import trace as _trace
 from attention_tpu.engine.allocator import BlockAllocator
-from attention_tpu.engine.errors import DeadlineExceededError
+from attention_tpu.engine.errors import (
+    DeadlineExceededError,
+    RecurrentStateUnsupportedError,
+)
 from attention_tpu.engine.metrics import (
     EngineMetrics,
     RequestMetrics,
@@ -63,6 +66,7 @@ from attention_tpu.engine.metrics import (
 )
 from attention_tpu.engine.request import Request, RequestState, SamplingParams
 from attention_tpu.engine.scheduler import ScheduledStep, Scheduler
+from attention_tpu.ops.gated_delta import RaggedStateStep
 from attention_tpu.ops.paged import OutOfPagesError, PagedKV, PagePool
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
@@ -85,6 +89,14 @@ _LAUNCHES = obs.counter("engine.step.launches",
 _LOGIT_ROWS = obs.counter("engine.step.logit_rows",
                           "logits rows fetched to the host / sampled "
                           "there, by kind")
+# what the recurrent layers' kernel is given each step: real tokens on
+# the packed axis, and slots whose state it reads and writes back
+_RECURRENT_TOKENS = obs.counter(
+    "engine.recurrent.tokens",
+    "tokens a step hands the recurrent layers, summed over steps")
+_RECURRENT_SLOT_STEPS = obs.counter(
+    "engine.recurrent.slot_steps",
+    "request slots whose recurrent state a step reads and writes")
 # mesh-serving surface: how many KV-head shards the per-step launches
 # lower onto (1 = single-device).  In the zero-collective head-sharded
 # design the kernels exchange nothing; the only cross-shard cost is
@@ -109,6 +121,16 @@ class StepLimitExceededError(RuntimeError):
     the bare raise this replaces; typed so drivers can distinguish the
     diagnostic guard from a genuine engine failure (the ATP401
     error-taxonomy contract — see attention_tpu/analysis/errors.py)."""
+
+
+def require_pages_only(model, feature: str) -> None:
+    """Refuse ``feature`` for a model with recurrent layers: it carries
+    KV pages only, and pages alone do not restore such a request."""
+    layers = tuple(getattr(model, "recurrent_layers", ()))
+    if layers:
+        raise RecurrentStateUnsupportedError(
+            f"{feature} knows only KV pages, and {type(model).__name__} "
+            f"keeps a recurrent state per request in layers {list(layers)}")
 
 
 @functools.partial(jax.jit, static_argnames=("model",))
@@ -232,7 +254,20 @@ class EngineConfig:
 class ServingEngine:
     """Deterministic continuous-batching engine over a TinyDecoder-
     family model (any ``impl='flash'`` model whose ``apply`` threads
-    per-layer caches, the `generate_paged` contract)."""
+    per-layer caches, the `generate_paged` contract).
+
+    What a request holds is read from the model: K and V pools exist
+    for its attention layers, all on the one page-id space; for its
+    recurrent layers (``model.recurrent_layers``) each running request
+    also holds one STATE SLOT, a row of every such layer's state pool
+    ``(slots + 1, H, dk, dv)`` float32 and convolution-tail pool, taken
+    at admission and given back at finish, cancel, time-out and
+    preemption.  There are ``max_decode_batch + max_prefill_rows``
+    slots, as many as a step has rows; a request that (re)starts at
+    token 0 starts from a zero state inside the kernel, so a
+    preempted request recomputes correctly and no row is ever cleared
+    by hand.  Features that know only pages refuse such a model
+    (`require_pages_only`)."""
 
     def __init__(self, model, params, config: EngineConfig, *,
                  on_token: Callable[[Request, int], None] | None = None,
@@ -249,6 +284,13 @@ class ServingEngine:
         self.on_token = on_token
         self.on_finish = on_finish
         self.on_timeout = on_timeout
+        self._kv_layers = tuple(getattr(
+            model, "attention_layers", range(model.depth)))
+        self._state_layers = tuple(getattr(model, "recurrent_layers", ()))
+        if config.mesh_shards:
+            self.require_pages_only("mesh_shards > 0")
+        if config.step_mode != "ragged":
+            self.require_pages_only(f"step_mode={config.step_mode!r}")
 
         # mesh mode: a 1D "tp" mesh of the first mesh_shards devices;
         # the step launches run the model's head-sharded cached paths
@@ -301,10 +343,24 @@ class ServingEngine:
         dtype = config.cache_dtype or model.dtype
         pool_shape = (config.num_pages, model.num_kv_heads,
                       config.page_size, head_dim)
+        # one pool pair per ATTENTION layer, in layer order
         self._k_pools = [self._place_pool(jnp.zeros(pool_shape, dtype))
-                         for _ in range(model.depth)]
+                         for _ in self._kv_layers]
         self._v_pools = [self._place_pool(jnp.zeros(pool_shape, dtype))
-                         for _ in range(model.depth)]
+                         for _ in self._kv_layers]
+        # one state and one convolution-tail pool per RECURRENT layer;
+        # the last row is nobody's (empty slots of a step land there)
+        state_slots = 0
+        self._state_pools: list[jax.Array] = []
+        self._conv_pools: list[jax.Array] = []
+        if self._state_layers:
+            state_slots = config.max_decode_batch + config.max_prefill_rows
+            state_shape, conv_shape = model.recurrent_state_shapes()
+            for _ in self._state_layers:
+                self._state_pools.append(jnp.zeros(
+                    (state_slots + 1, *state_shape), jnp.float32))
+                self._conv_pools.append(jnp.zeros(
+                    (state_slots + 1, *conv_shape), model.dtype))
         if obs.is_enabled():
             _MESH_SHARDS.set(float(config.mesh_shards or 1))
 
@@ -312,6 +368,7 @@ class ServingEngine:
         self.allocator = BlockAllocator(
             self.pool, config.page_size,
             watermark_pages=config.watermark_pages,
+            state_slots=state_slots,
         )
         self.scheduler = Scheduler(
             self.allocator,
@@ -377,6 +434,12 @@ class ServingEngine:
         self.trace_incarnation: int = 0
         self.trace_start_tick: int = 0
         self.trace_owner: str = "engine"
+
+    # -- recurrent state --------------------------------------------------
+
+    def require_pages_only(self, feature: str) -> None:
+        """`require_pages_only` for this engine's model."""
+        require_pages_only(self.model, feature)
 
     # -- request tracing --------------------------------------------------
 
@@ -445,7 +508,7 @@ class ServingEngine:
         into the allocator so the lookup then hits.  A no-op without
         an attached store; never raises (corruption is counted and
         the request simply cold-prefills)."""
-        if self.prefix_store is None:
+        if self.prefix_store is None or self._state_layers:
             return 0
         from attention_tpu.prefixstore.adapter import import_chain
 
@@ -555,9 +618,7 @@ class ServingEngine:
                 _CANCELLED.inc()
                 if _trace.active() and self.trace_owner == "engine":
                     self._trace_event(req, "cancelled")
-                if req.pages:
-                    self.allocator.free(req.pages)
-                req.pages = []
+                self.allocator.release(req)
                 req.transition(RequestState.CANCELLED)
                 self._rng_keys.pop(req.request_id, None)
                 self._wall.pop(req.request_id, None)
@@ -577,9 +638,7 @@ class ServingEngine:
         _TIMED_OUT.inc()
         if _trace.active() and self.trace_owner == "engine":
             self._trace_event(req, "timed_out")
-        if req.pages:
-            self.allocator.free(req.pages)
-        req.pages = []
+        self.allocator.release(req)
         req.transition(RequestState.TIMED_OUT)
         req.finish_step = self._step
         self._rng_keys.pop(req.request_id, None)
@@ -788,7 +847,7 @@ class ServingEngine:
             PagedKV(self._k_pools[layer], self._v_pools[layer],
                     jnp.asarray(tables, jnp.int32),
                     jnp.asarray(lens, jnp.int32))
-            for layer in range(self.model.depth)
+            for layer in range(len(self._k_pools))
         )
         if obs.is_enabled():
             _LAUNCHES.inc(mode="two_call")
@@ -838,29 +897,44 @@ class ServingEngine:
             slot = jnp.asarray(batch.token_slot, jnp.int32)
             tokens = jnp.asarray(batch.tokens, jnp.int32)
             q_span = np.zeros((q_tile,), np.int32)  # shape carries q_tile
-            caches = tuple(
-                RaggedPagedStep(self._k_pools[layer], self._v_pools[layer],
-                                tables, kv_lens, cu, dist, pos, slot,
-                                q_span)
-                for layer in range(self.model.depth)
-            )
+            caches: list[Any] = [None] * self.model.depth
+            for i, layer in enumerate(self._kv_layers):
+                caches[layer] = RaggedPagedStep(
+                    self._k_pools[i], self._v_pools[i], tables, kv_lens,
+                    cu, dist, pos, slot, q_span)
+            if self._state_layers:
+                state_rows = jnp.asarray(batch.state_rows, jnp.int32)
+                for i, layer in enumerate(self._state_layers):
+                    caches[layer] = RaggedStateStep(
+                        self._state_pools[i], self._conv_pools[i],
+                        state_rows, kv_lens, cu, slot, q_span)
+        sampled = len(sched.decode) + len(sched.prefill)
+        fields = {}
+        if self._state_layers:
+            fields = {"recurrent_tokens": total,
+                      "recurrent_slot_steps": sampled}
         if obs.is_enabled():
             _LAUNCHES.inc(mode="ragged")
+            if self._state_layers:
+                _RECURRENT_TOKENS.inc(total)
+                _RECURRENT_SLOT_STEPS.inc(sampled)
         with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
                       decode_rows=len(sched.decode),
-                      prefill_tokens=sched.num_prefill_tokens):
+                      prefill_tokens=sched.num_prefill_tokens, **fields):
             logits_dev, new_caches = _ragged_apply(
-                self._step_model, self.params, tokens, caches,
+                self._step_model, self.params, tokens, tuple(caches),
             )
-            for layer, c in enumerate(new_caches):
-                self._k_pools[layer] = c.k_pool
-                self._v_pools[layer] = c.v_pool
+            for i, layer in enumerate(self._kv_layers):
+                self._k_pools[i] = new_caches[layer].k_pool
+                self._v_pools[i] = new_caches[layer].v_pool
+            for i, layer in enumerate(self._state_layers):
+                self._state_pools[i] = new_caches[layer].state_pool
+                self._conv_pools[i] = new_caches[layer].conv_pool
         if cfg.async_steps:
             # the double-buffer window: the launch is in flight, the
             # sync has not happened — overlap next step's host staging
             with obs.span("engine.step.overlap"):
                 self._stage_next_step()
-        sampled = len(sched.decode) + len(sched.prefill)
         logits = self._fetch_logits(logits_dev, sampled)
         with obs.span("engine.step.sample", rows=sampled):
             row_of = _sampled_logit_rows(batch.cu_q_lens, width)
@@ -893,7 +967,8 @@ class ServingEngine:
         until the device pools are final.  Snapshot cuts run this first
         so a serialized image never captures a half-staged async step."""
         self._staged_rows = {}
-        for a in (*self._k_pools, *self._v_pools):
+        for a in (*self._k_pools, *self._v_pools, *self._state_pools,
+                  *self._conv_pools):
             jax.block_until_ready(a)
 
     def _run_decode(self, reqs: list[Request]) -> None:
@@ -918,7 +993,8 @@ class ServingEngine:
             # poisoned logits must never reach sampling: a garbage
             # token would break parity with the fault-free run.
             # Un-feed the pending token (its KV slot is simply
-            # overwritten on retry) so the request makes no
+            # overwritten on retry; a recurrent state is recomputed,
+            # `_recompute_state`) so the request makes no
             # progress this step, and count the event — the
             # replica supervisor's NaN signal.  Bounded: see
             # _NONFINITE_SKIP_LIMIT.
@@ -927,11 +1003,22 @@ class ServingEngine:
             self._nonfinite_skips[req.request_id] = skips
             if skips <= _NONFINITE_SKIP_LIMIT:
                 req.pending_token = req.tokens.pop()
+                self._recompute_state(req)
                 return
         else:
             self._nonfinite_skips.pop(req.request_id, None)
         req.computed_tokens = len(req.tokens)
         self._emit(req, self._sample(req, logits_row))
+
+    def _recompute_state(self, req: Request) -> None:
+        """The retry after a non-finite row, for a model with recurrent
+        layers.  A KV row is overwritten in place by the retry; a
+        recurrent state was already advanced by the skipped token or
+        chunk and would take it a second time.  Nobody kept the state
+        before the step, so the request goes back to the queue and is
+        recomputed from token 0, as after a preemption."""
+        if self._state_layers:
+            self.scheduler.requeue_for_recompute(req)
 
     def _run_prefill(self, items: list[tuple[Request, int]]) -> None:
         p = self.config.max_prefill_rows
@@ -959,12 +1046,14 @@ class ServingEngine:
                 and not np.isfinite(last_row).all()):
             # the final chunk samples the first token; with
             # non-finite logits, skip the whole chunk (the KV it
-            # wrote is recomputed in place next step) rather than
-            # emit garbage.  Bounded: see _NONFINITE_SKIP_LIMIT.
+            # wrote is recomputed in place next step, a recurrent
+            # state from token 0) rather than emit garbage.  Bounded:
+            # see _NONFINITE_SKIP_LIMIT.
             self.nonfinite_events += 1
             skips = self._nonfinite_skips.get(req.request_id, 0) + 1
             self._nonfinite_skips[req.request_id] = skips
             if skips <= _NONFINITE_SKIP_LIMIT:
+                self._recompute_state(req)
                 return
         req.computed_tokens += real
         if req.computed_tokens < len(req.tokens):
@@ -980,7 +1069,7 @@ class ServingEngine:
 
     def _commit_prefix(self, req: Request) -> None:
         full = req.num_prompt_tokens // self.config.page_size
-        if full:
+        if full and not self._state_layers:
             self.allocator.commit_prefix(
                 req.prompt, req.pages[:full], now=self._step
             )
@@ -1044,9 +1133,7 @@ class ServingEngine:
         self._nonfinite_skips.pop(req.request_id, None)
         if self.journal is not None:
             self.journal.record_finish(req.request_id)
-        if req.pages:
-            self.allocator.free(req.pages)
-        req.pages = []
+        self.allocator.release(req)
         self.scheduler.remove_finished(req)
         self._rng_keys.pop(req.request_id, None)
         self._finished_in_step += 1

@@ -54,6 +54,15 @@ the fleet-reuse half:
   lease).  Lease *expiry* is not an error — it is the deterministic
   tick-driven escape hatch when a lease holder dies mid-prefill.
 
+Recurrent layers (ISSUE 27) add the reach half:
+
+* :class:`RecurrentStateUnsupportedError` — a feature that knows only
+  KV pages (snapshots, the prefix store, the fleet hand-off, mesh
+  serving, the two-call step) was asked to serve a model whose layers
+  also keep a recurrent state per request.  Pages alone do not restore
+  such a request, so the feature refuses instead of serving wrong
+  tokens.
+
 All subclass RuntimeError, the `OutOfPagesError` lineage — the
 ATP401 contract (attention_tpu/analysis/errors.py) extends over
 ``frontend/`` and ``prefixstore/`` so generic raises cannot creep
@@ -165,3 +174,15 @@ class HandoffCorruptError(PrefixStoreCorruptError):
     ``handoff_fallback``, and re-admits the request WITHOUT the pages
     — the destination re-prefills, token parity holds, and the
     corruption costs compute, never a wrong token."""
+
+
+class RecurrentStateUnsupportedError(RuntimeError):
+    """A pages-only feature met a model with recurrent layers.
+
+    Such a model keeps, beside its KV pages, one state per request and
+    recurrent layer that cannot be re-derived page by page.  Snapshot
+    save / restore, prefix-store export and import, the fleet's KV
+    hand-off, ``mesh_shards > 0`` and ``step_mode="two_call"`` carry
+    pages only; each raises this for such a model
+    (`ServingEngine.require_pages_only`).  State checkpoints at page
+    boundaries would lift it (ROADMAP R2)."""

@@ -154,6 +154,9 @@ class Request:
     pages: list[int] = dataclasses.field(default_factory=list)
     prefix_cached_tokens: int = 0
     preemptions: int = 0
+    # row of the engine's recurrent-state pools while the request runs
+    # (models with recurrent layers only; -1 = none held)
+    state_slot: int = -1
 
     # metrics timestamps (engine steps; -1 = not yet)
     first_scheduled_step: int = -1

@@ -39,6 +39,10 @@ _PREEMPTED = obs.counter("engine.scheduler.preemptions",
 _ADMIT_WAITS = obs.counter(
     "engine.scheduler.admit_waits",
     "admissions deferred by the allocator watermark")
+_STATE_RESETS = obs.counter(
+    "engine.state.resets",
+    "recurrent states started from zero, by reason (admit: a new "
+    "request; preempt: a preempted one readmitted)")
 
 
 @dataclasses.dataclass
@@ -53,7 +57,9 @@ class PackedBatch:
     (slots, table_width) / ``distribution`` (2,) are the kernel's
     scalar-prefetch operands.  Decode slots come first (the
     ``distribution`` contract); ``num_real`` real tokens occupy the
-    packed prefix, the remaining ``width - num_real`` are pad."""
+    packed prefix, the remaining ``width - num_real`` are pad.
+    ``state_rows`` (slots,) is each slot's row of the recurrent-state
+    pools (-1: an empty slot, or a model without such state)."""
 
     tokens: np.ndarray
     token_slot: np.ndarray
@@ -62,6 +68,7 @@ class PackedBatch:
     cu_q_lens: np.ndarray
     tables: np.ndarray
     distribution: np.ndarray
+    state_rows: np.ndarray
     width: int
     num_real: int
 
@@ -123,6 +130,7 @@ class ScheduledStep:
         kv_lens = np.zeros((slots,), np.int32)
         cu = np.zeros((slots + 1,), np.int32)
         tables = np.full((slots, table_width), -1, np.int32)
+        state_rows = np.full((slots,), -1, np.int32)
         num_decode = len(self.decode)
         off = 0
         for s, (req, n) in enumerate(items):
@@ -134,6 +142,7 @@ class ScheduledStep:
             token_slot[off:off + n] = s
             token_pos[off:off + n] = np.arange(c, c + n)
             kv_lens[s] = c
+            state_rows[s] = req.state_slot
             staged = (staged_rows or {}).get(req.request_id)
             if staged is not None and staged[0] == len(req.pages):
                 tables[s] = staged[1]
@@ -146,7 +155,7 @@ class ScheduledStep:
             tokens=tokens, token_slot=token_slot, token_pos=token_pos,
             kv_lens=kv_lens, cu_q_lens=cu, tables=tables,
             distribution=np.asarray([num_decode, len(items)], np.int32),
-            width=width, num_real=total,
+            state_rows=state_rows, width=width, num_real=total,
         )
 
 
@@ -186,27 +195,32 @@ class Scheduler:
     def remove_finished(self, req: Request) -> None:
         self.running.remove(req)
 
+    def requeue_for_recompute(self, req: Request) -> None:
+        """Preemption-by-recompute of one running request: release
+        every page and the state slot, forget computed KV, requeue at
+        the request's original FCFS position.  Emitted tokens and the
+        pending token survive — readmission re-prefills ``req.tokens``
+        from token 0 (so a recurrent state starts from zero again) and
+        resumes decoding without resampling."""
+        self.running.remove(req)
+        self.allocator.release(req)
+        req.computed_tokens = 0
+        req.prefix_cached_tokens = 0
+        req.preemptions += 1
+        req.transition(RequestState.PREEMPTED)
+        self.num_preemptions += 1
+        _PREEMPTED.inc()
+        self.waiting.append(req)
+        self.waiting.sort(key=self._fcfs)
+
     def _preempt(self, victim: Request, sched: ScheduledStep) -> None:
-        """Preemption-by-recompute: release every page, forget computed
-        KV, requeue at the victim's original FCFS position.  Emitted
-        tokens and the pending token survive — readmission re-prefills
-        ``victim.tokens`` and resumes decoding without resampling."""
-        self.running.remove(victim)
+        """`requeue_for_recompute` during step composition: the victim
+        also leaves the step being composed."""
         if victim in sched.decode:
             sched.decode.remove(victim)
         sched.prefill = [(r, n) for r, n in sched.prefill if r is not victim]
-        if victim.pages:
-            self.allocator.free(victim.pages)
-        victim.pages = []
-        victim.computed_tokens = 0
-        victim.prefix_cached_tokens = 0
-        victim.preemptions += 1
-        victim.transition(RequestState.PREEMPTED)
-        self.num_preemptions += 1
-        _PREEMPTED.inc()
+        self.requeue_for_recompute(victim)
         sched.preempted.append(victim)
-        self.waiting.append(victim)
-        self.waiting.sort(key=self._fcfs)
 
     def _preempt_for(self, req: Request, sched: ScheduledStep) -> bool:
         """Free pages for ``req``'s decode append by preempting the
@@ -295,10 +309,13 @@ class Scheduler:
                and len(sched.prefill) < self.max_prefill_rows
                and budget >= 1):
             req = self.waiting[0]
+            if not self.allocator.has_free_state_slot:
+                # every recurrent-state slot is held: wait, like a
+                # watermark refusal, for a running request to finish
+                _ADMIT_WAITS.inc()
+                break
             with obs.span("scheduler.admit", rid=req.request_id):
-                if req.pages:  # defensive: queued requests hold nothing
-                    self.allocator.free(req.pages)
-                    req.pages = []
+                self.allocator.release(req)  # defensive: queued hold nothing
                 pages = (self.allocator.lookup_prefix(req.tokens,
                                                       now=step)
                          if self.prefix_admission else [])
@@ -327,6 +344,10 @@ class Scheduler:
                     req.prefix_cached_tokens = 0
                     _ADMIT_WAITS.inc()
                     break
+                req.state_slot = self.allocator.take_state_slot()
+                if req.state_slot >= 0:
+                    _STATE_RESETS.inc(
+                        reason="preempt" if req.preemptions else "admit")
                 self.waiting.pop(0)
                 self.running.append(req)
                 req.transition(RequestState.PREFILLING)

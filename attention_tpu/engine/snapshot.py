@@ -68,7 +68,11 @@ import numpy as np
 from attention_tpu import obs
 from attention_tpu.obs import trace as _trace
 from attention_tpu.engine.allocator import _PrefixEntry
-from attention_tpu.engine.engine import EngineConfig, ServingEngine
+from attention_tpu.engine.engine import (
+    EngineConfig,
+    ServingEngine,
+    require_pages_only,
+)
 from attention_tpu.engine.errors import SnapshotCorruptError, SnapshotError
 from attention_tpu.engine.journal import (
     Journal,
@@ -230,6 +234,9 @@ def _request_from_dict(d: dict) -> Request:
 
 
 def _serialize_sections(engine: ServingEngine) -> list[tuple[str, bytes]]:
+    # a snapshot holds pages and requests; a recurrent layer's state per
+    # request is in neither, so such an engine is refused, not half-saved
+    engine.require_pages_only("a snapshot")
     # a snapshot cut must not capture a half-staged async step: settle
     # the double buffer (drop staged page-table rows, block until the
     # device pools are final) before reading any bytes out
@@ -485,7 +492,10 @@ def restore(path: str, model, params, *,
             on_timeout=None) -> ServingEngine:
     """Reconstruct an engine whose subsequent outputs are byte-identical
     to the snapshotted one's.  Raises `SnapshotCorruptError` on any
-    validation failure (the caller's cue to fall back cold)."""
+    validation failure (the caller's cue to fall back cold), and the
+    engine's `RecurrentStateUnsupportedError` for a model with
+    recurrent layers (no snapshot of one can exist)."""
+    require_pages_only(model, "a snapshot")
     manifest, sections = _read_sections(path)
     try:
         meta = json.loads(sections["meta"])
@@ -693,6 +703,7 @@ class SnapshotManager:
                 f"SnapshotManager needs every>=1, keep>=1 "
                 f"(got every={every}, keep={keep})"
             )
+        engine.require_pages_only("SnapshotManager")
         os.makedirs(directory, exist_ok=True)
         self.engine = engine
         self.directory = directory

@@ -291,7 +291,10 @@ def export_handoff(engine, req, request_record: dict) -> bytes | None:
 
     ``request_record`` is the caller's `_request_to_dict` dict — the
     cut serializes the request exactly once and ships the same record
-    in the blob the chaos checkers later audit."""
+    in the blob the chaos checkers later audit.  Refuses an engine
+    whose model keeps recurrent state: the destination could not
+    continue from pages alone."""
+    engine.require_pages_only("the fleet's KV hand-off")
     ps = engine.config.page_size
     toks = tuple(int(t) for t in req.prompt)
     full = min(len(toks) // ps, len(req.pages))
@@ -322,6 +325,7 @@ def import_handoff(engine, blob: bytes, *, now: int) -> int:
     mismatch (another fleet's pages: a miss), an already-cached chain,
     or allocator pressure (`for_decode=False`: a busy decode replica
     refuses the import before it refuses decode appends)."""
+    engine.require_pages_only("the fleet's KV hand-off")
     rec = decode_handoff(blob)
     if (rec.fingerprint != fleet_fingerprint(engine)
             or rec.geometry != engine_geometry(engine)):

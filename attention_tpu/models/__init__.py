@@ -18,7 +18,9 @@ from attention_tpu.models.seq2seq import (  # noqa: F401
     seq2seq_loss,
 )
 from attention_tpu.models.speculative import generate_speculative  # noqa: F401
+from attention_tpu.models.linear_attention import GatedDeltaNet  # noqa: F401
 from attention_tpu.models.transformer import TransformerBlock, TinyDecoder  # noqa: F401
+from attention_tpu.models.config import decoder_from_config  # noqa: F401
 from attention_tpu.models.decode import (  # noqa: F401
     decode_step,
     generate,

@@ -250,6 +250,9 @@ class GQASelfAttention(nn.Module):
     rope: bool = False  # rotary position embeddings on Q/K
     rope_theta: float = 10000.0
     softcap: float | None = None  # logit soft-capping (Gemma-2 style)
+    # RMSNorm over the WHOLE q and k projections (all heads together),
+    # ahead of the rotation: the OLMo 2 convention
+    qk_norm: bool = False
     # Context parallelism: when set (training under a mesh whose
     # ``cp_axis`` shards the sequence), batch attention runs a
     # differentiable CP composition — the Pallas flash custom VJP under
@@ -323,6 +326,13 @@ class GQASelfAttention(nn.Module):
         q = dense("q_proj", self.num_q_heads)(x)  # (B, S, Hq, dh)
         k = dense("k_proj", self.num_kv_heads)(x)
         v = dense("v_proj", self.num_kv_heads)(x)
+        if self.qk_norm:
+            def whole(t, name):
+                flat = t.reshape(t.shape[:2] + (-1,))
+                return nn.RMSNorm(dtype=self.dtype, name=name)(
+                    flat).reshape(t.shape)
+
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # (B, H, S, dh)
         if self.rope:
             # rotate BEFORE caching: keys are stored already-rotated at

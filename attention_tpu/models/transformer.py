@@ -17,7 +17,15 @@ from attention_tpu.models.attention_layer import (
     KVCache,
     RollingKVCache,
 )
+from attention_tpu.models.linear_attention import GatedDeltaNet
 from attention_tpu.models.moe import MoEMLP
+
+#: the kinds of mixer a decoder layer can have (``layer_types``)
+FULL_ATTENTION = "full_attention"
+LINEAR_ATTENTION = "linear_attention"
+LAYER_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION)
+
+_ACTIVATIONS = {"gelu": nn.gelu, "silu": nn.silu}
 
 
 class MLP(nn.Module):
@@ -30,6 +38,25 @@ class MLP(nn.Module):
         h = nn.Dense(d * self.hidden_mult, use_bias=False, dtype=self.dtype)(x)
         h = nn.gelu(h)
         return nn.Dense(d, use_bias=False, dtype=self.dtype)(h)
+
+
+class GatedMLP(nn.Module):
+    """``down(act(gate x) * up x)`` at a free width (SwiGLU with
+    ``act="silu"``)."""
+
+    hidden: int
+    act: str = "silu"
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(name, features):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            name=name)
+
+        h = (_ACTIVATIONS[self.act](dense("gate_proj", self.hidden)(x))
+             * dense("up_proj", self.hidden)(x))
+        return dense("down_proj", x.shape[-1])(h)
 
 
 class TransformerBlock(nn.Module):
@@ -52,11 +79,29 @@ class TransformerBlock(nn.Module):
     cp_impl: str = "allgather"  # "ring"/"zigzag" (O(n/R) KV) or "ulysses"
     tp_axis: str | None = None  # head-sharded serving on cached paths
     mesh: "jax.sharding.Mesh | None" = None
+    # what follows is off in the plain block; a decoder sets it per layer
+    kind: str = FULL_ATTENTION  # the mixer: attention or GatedDeltaNet
+    qk_norm: bool = False     # RMSNorm over the whole q / k projections
+    post_norm: bool = False   # x + norm(f(x)) in place of x + f(norm(x))
+    mlp_hidden: int | None = None  # gated MLP of this width (None: MLP)
+    mlp_act: str = "silu"
+    linear_heads: int = 0     # GatedDeltaNet's heads and head sizes
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    linear_neg_eigval: bool = True
 
-    @nn.compact
-    def __call__(self, x, cache=None):
-        y = nn.RMSNorm(dtype=self.dtype)(x)
-        attn_out = GQASelfAttention(
+    def _mixer(self):
+        if self.kind == LINEAR_ATTENTION:
+            return GatedDeltaNet(
+                num_heads=self.linear_heads, key_dim=self.linear_key_dim,
+                value_dim=self.linear_value_dim,
+                conv_width=self.linear_conv,
+                neg_eigval=self.linear_neg_eigval, dtype=self.dtype)
+        if self.kind != FULL_ATTENTION:
+            raise ValueError(
+                f"unknown layer kind {self.kind!r}; one of {LAYER_KINDS}")
+        return GQASelfAttention(
             num_q_heads=self.num_q_heads,
             num_kv_heads=self.num_kv_heads,
             head_dim=self.head_dim,
@@ -68,16 +113,27 @@ class TransformerBlock(nn.Module):
             rope=self.rope,
             rope_theta=self.rope_theta,
             softcap=self.softcap,
+            qk_norm=self.qk_norm,
             cp_axis=self.cp_axis,
             cp_impl=self.cp_impl,
             tp_axis=self.tp_axis,
             mesh=self.mesh,
-        )(y, cache)
+        )
+
+    @nn.compact
+    def __call__(self, x, cache=None):
+        def norm(t):
+            return nn.RMSNorm(dtype=self.dtype)(t)
+
+        attn_out = self._mixer()(x if self.post_norm else norm(x), cache)
         if cache is not None:
             attn_out, cache = attn_out
-        x = x + attn_out
-        y = nn.RMSNorm(dtype=self.dtype)(x)
-        if self.moe_experts:
+        x = x + (norm(attn_out) if self.post_norm else attn_out)
+        y = x if self.post_norm else norm(x)
+        if self.mlp_hidden is not None:
+            mlp_out = GatedMLP(hidden=self.mlp_hidden, act=self.mlp_act,
+                               dtype=self.dtype)(y)
+        elif self.moe_experts:
             mlp_out = MoEMLP(
                 num_experts=self.moe_experts,
                 top_k=self.moe_top_k,
@@ -87,7 +143,7 @@ class TransformerBlock(nn.Module):
             )(y)
         else:
             mlp_out = MLP(dtype=self.dtype)(y)
-        x = x + mlp_out
+        x = x + (norm(mlp_out) if self.post_norm else mlp_out)
         return x if cache is None else (x, cache)
 
 
@@ -136,6 +192,52 @@ class TinyDecoder(nn.Module):
     # tensor-parallel with the framework's own kernels.
     tp_axis: str | None = None
     mesh: "jax.sharding.Mesh | None" = None
+    # One mixer kind per layer (`LAYER_KINDS`); None: attention in every
+    # block.  A linear-attention layer is a `GatedDeltaNet` of
+    # ``linear_heads`` heads, keys of ``linear_key_dim`` and values of
+    # ``linear_value_dim``; it keeps a recurrent state per request in
+    # place of K and V rows (`recurrent_state_shapes`).
+    layer_types: tuple[str, ...] | None = None
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    linear_neg_eigval: bool = True
+    qk_norm: bool = False     # RMSNorm on the whole q / k projections
+    post_norm: bool = False   # x + norm(f(x)): the OLMo 2 block
+    mlp_hidden: int | None = None  # gated MLP of this width (None: 4x gelu)
+    mlp_act: str = "silu"
+
+    @property
+    def kinds(self) -> tuple[str, ...]:
+        """The mixer kind of each layer."""
+        if self.layer_types is None:
+            return (FULL_ATTENTION,) * self.depth
+        if len(self.layer_types) != self.depth:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"depth is {self.depth}")
+        return tuple(self.layer_types)
+
+    @property
+    def attention_layers(self) -> tuple[int, ...]:
+        """Layers that keep K and V rows (paged KV pools)."""
+        return tuple(i for i, kind in enumerate(self.kinds)
+                     if kind == FULL_ATTENTION)
+
+    @property
+    def recurrent_layers(self) -> tuple[int, ...]:
+        """Layers that keep one state per request instead."""
+        return tuple(i for i, kind in enumerate(self.kinds)
+                     if kind == LINEAR_ATTENTION)
+
+    def recurrent_state_shapes(self) -> tuple[tuple, tuple]:
+        """Per request and recurrent layer: the float32 state
+        ``(heads, key_dim, value_dim)`` and the convolution's tail
+        ``(conv - 1, channels)`` in the model's dtype."""
+        h, dk, dv = (self.linear_heads, self.linear_key_dim,
+                     self.linear_value_dim)
+        return (h, dk, dv), (self.linear_conv - 1, h * (2 * dk + dv))
 
     @nn.compact
     def __call__(self, tokens: jax.Array, caches=None,
@@ -149,7 +251,7 @@ class TinyDecoder(nn.Module):
             if self.remat and caches is None
             else TransformerBlock
         )
-        for i in range(self.depth):
+        for i, kind in enumerate(self.kinds):
             # explicit name: keeps the param tree identical whether or
             # not the block class is wrapped in nn.remat
             block = block_cls(
@@ -171,6 +273,16 @@ class TinyDecoder(nn.Module):
                 cp_impl=self.cp_impl,
                 tp_axis=self.tp_axis,
                 mesh=self.mesh,
+                kind=kind,
+                qk_norm=self.qk_norm,
+                post_norm=self.post_norm,
+                mlp_hidden=self.mlp_hidden,
+                mlp_act=self.mlp_act,
+                linear_heads=self.linear_heads,
+                linear_key_dim=self.linear_key_dim,
+                linear_value_dim=self.linear_value_dim,
+                linear_conv=self.linear_conv,
+                linear_neg_eigval=self.linear_neg_eigval,
                 name=f"TransformerBlock_{i}",
             )
             if caches is None:
@@ -199,6 +311,10 @@ class TinyDecoder(nn.Module):
         ``rolling=True`` (windowed models only) returns ring-buffer
         caches whose memory is bounded by the window, not by
         ``capacity``/sequence length."""
+        if self.recurrent_layers:
+            raise ValueError(
+                "a model with recurrent layers serves through the "
+                "engine's packed step; it has no dense per-layer caches")
         head_dim = self.dim // self.num_q_heads
         if rolling:
             if self.window is None:

@@ -90,7 +90,10 @@ def export_chain(engine, tokens, pages, *, now: int) -> int:
     """Publish the committed chain ``pages`` (covering the full pages
     of ``tokens``) into the engine's store; returns records newly
     stored.  Safe to call with any committed prefix — existing records
-    are touched, not rewritten."""
+    are touched, not rewritten.  Refuses an engine whose model keeps
+    recurrent state (`ServingEngine.require_pages_only`): its pages are
+    no prefix without the state they led to."""
+    engine.require_pages_only("the prefix store's export")
     store = engine.prefix_store
     if store is None:
         return 0
@@ -125,7 +128,10 @@ def import_chain(engine, tokens, *, now: int) -> int:
 
     Never raises: corruption is counted + dropped (the caller's later
     cold prefill is the recovery), and an allocator refusal under the
-    watermark simply aborts the import."""
+    watermark simply aborts the import.  (An engine whose model keeps
+    recurrent state never calls this, `ServingEngine._import_prefix`;
+    called directly for one it refuses like `export_chain`.)"""
+    engine.require_pages_only("the prefix store's import")
     store = engine.prefix_store
     if store is None:
         return 0

@@ -27,7 +27,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from attention_tpu.engine.engine import _ragged_apply
 from attention_tpu.models import TinyDecoder
-from attention_tpu.ops import decode, flash, paged, quant, ragged_paged
+from attention_tpu.ops import (
+    decode,
+    flash,
+    gated_delta,
+    paged,
+    quant,
+    ragged_paged,
+)
 from attention_tpu.ops.flash_vjp import flash_attention_diff
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
@@ -53,7 +60,7 @@ def v5e():
         pytest.skip(f"no compile-only TPU topology here: "
                     f"{type(e).__name__}: {str(e)[:200]}")
     with pytest.MonkeyPatch.context() as mp:
-        for mod in (flash, decode, paged, quant, ragged_paged):
+        for mod in (flash, decode, paged, quant, ragged_paged, gated_delta):
             mp.setattr(mod, "_should_interpret", lambda: False)
         yield list(topo.devices)
 
@@ -158,6 +165,26 @@ def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype):
              jax.sharding.SingleDeviceSharding(v5e[0]),
              _a((1, hq, width, 128), dtype),
              _ragged_cache(hkv, width, q_tile, dtype))
+
+
+@pytest.mark.parametrize("width, q_tile", [
+    (8, 8), (48, 24), (128, 96), (384, 256)])
+def test_gated_delta_kernel_compiles_at_the_published_widths(
+        v5e, width, q_tile):
+    """The recurrent layers' kernel at Olmo-Hybrid-7B's head sizes (30
+    heads, keys of 96, values of 192), 9 slots: a decode-only step,
+    chunks of 8, 32 and 64."""
+    heads, dk, dv, slots = 30, 96, 192, 9
+    step = gated_delta.RaggedStateStep(
+        _a((slots + 1, heads, dk, dv), F32),
+        _a((slots + 1, 3, heads * (2 * dk + dv)), BF16),
+        _a((slots,), I32), _a((slots,), I32), _a((slots + 1,), I32),
+        _a((width,), I32), _a((q_tile,), I32))
+    _compile(gated_delta.ragged_gated_delta,
+             jax.sharding.SingleDeviceSharding(v5e[0]),
+             _a((width, heads, dk), F32), _a((width, heads, dk), F32),
+             _a((width, heads, dv), BF16), _a((width, heads), F32),
+             _a((width, heads), F32), step)
 
 
 def test_ladder_kernels_compile(v5e):
