@@ -45,7 +45,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -144,30 +144,72 @@ def _paged_apply(model, params, tokens, caches):
     return model.apply({"params": params}, tokens, caches)
 
 
-@functools.partial(jax.jit, static_argnames=("model",))
-def _ragged_apply(model, params, tokens, caches):
+class RaggedStepIndex(NamedTuple):
+    """What one packed step tells every layer, uploaded once: the index
+    fields of `RaggedPagedStep` (and of `RaggedStateStep`, which reads
+    ``state_rows`` with four of them; None for a model with no
+    recurrent layer).  Kept apart from the pools because the step
+    donates those, and one buffer that every layer reads cannot be
+    given away."""
+
+    page_table: jax.Array
+    kv_lens: jax.Array
+    cu_q_lens: jax.Array
+    distribution: jax.Array
+    token_pos: jax.Array
+    token_slot: jax.Array
+    q_span: jax.Array
+    state_rows: jax.Array | None = None
+
+
+def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
+    """Each layer's cache for a packed step: its pool pair (K and V,
+    or recurrent state and convolution tail) with the shared index."""
+    recurrent = set(getattr(model, "recurrent_layers", ()))
+    return tuple(
+        RaggedStateStep(*pair, index.state_rows, index.kv_lens,
+                        index.cu_q_lens, index.token_slot, index.q_span)
+        if layer in recurrent
+        else RaggedPagedStep(*pair, *index[:-1])  # all but state_rows
+        for layer, pair in enumerate(pools))
+
+
+@functools.partial(jax.jit, static_argnames=("model",),
+                   donate_argnames=("pools",))
+def _ragged_apply(model, params, tokens, pools, index):
     """One PACKED model step: the whole mixed decode/prefill batch as a
     single ``(1, width)`` token axis over per-layer `RaggedPagedStep`
     caches — exactly one attention launch per layer per engine step.
-    Width and the caches' q_tile marker are pow2-bucketed by the
+    Width and the index's q_tile marker are pow2-bucketed by the
     caller, so distinct compiled signatures stay O(log max_tokens).
 
-    Returns the logits of the rows a step can sample, not of every
-    packed position.  When the packed axis is wider than the slot
-    count, each slot's last row (`_slot_last_rows`) is gathered before
-    the final norm and the float32 head, and the result is
-    ``(1, slots, vocab)`` with slot ``s`` at row ``s``; a width within
-    the slot count already returns no more rows than that and stays
-    ``(1, width, vocab)`` (`_sampled_logit_rows` is the host's half of
-    this rule).  Both sizes are input shapes, and the indices come
-    from the ``cu_q_lens`` already on the device: no signature and no
-    upload is added."""
-    cu = caches[0].cu_q_lens
+    ``pools`` holds one pair of arrays a layer, in layer order, and is
+    DONATED: the step writes its rows into them in place
+    (`ragged_paged_append`, the recurrent layers' kernel) and hands
+    the same buffers back, so the caller's arrays are gone after the
+    call and it rebinds from the result.  ``index``
+    (`RaggedStepIndex`) is shared by every layer and stays the
+    caller's.
+
+    Returns ``(logits, pools)``, the logits of the rows a step can
+    sample, not of every packed position.  When the packed axis is
+    wider than the slot count, each slot's last row
+    (`_slot_last_rows`) is gathered before the final norm and the
+    float32 head, and the result is ``(1, slots, vocab)`` with slot
+    ``s`` at row ``s``; a width within the slot count already returns
+    no more rows than that and stays ``(1, width, vocab)``
+    (`_sampled_logit_rows` is the host's half of this rule).  Both
+    sizes are input shapes, and the indices come from the
+    ``cu_q_lens`` already on the device: no signature and no upload is
+    added."""
+    cu = index.cu_q_lens
     rows = None
     if tokens.shape[1] > cu.shape[0] - 1:
         rows = _slot_last_rows(cu)
-    return model.apply({"params": params}, tokens, caches,
-                       logit_rows=rows)
+    logits, steps = model.apply({"params": params}, tokens,
+                                _layer_steps(model, pools, index),
+                                logit_rows=rows)
+    return logits, tuple(step[:2] for step in steps)
 
 
 def _slot_last_rows(cu_q_lens):
@@ -822,6 +864,22 @@ class ServingEngine:
             return arr
         return jax.device_put(arr, self._pool_sharding)
 
+    def _layer_pools(self) -> tuple:
+        """The pools as `_ragged_apply` takes them: a pair a layer, in
+        layer order.  The call consumes these arrays."""
+        pairs: list[Any] = [None] * self.model.depth
+        for i, layer in enumerate(self._kv_layers):
+            pairs[layer] = (self._k_pools[i], self._v_pools[i])
+        for i, layer in enumerate(self._state_layers):
+            pairs[layer] = (self._state_pools[i], self._conv_pools[i])
+        return tuple(pairs)
+
+    def _rebind_pools(self, pairs) -> None:
+        for i, layer in enumerate(self._kv_layers):
+            self._k_pools[i], self._v_pools[i] = pairs[layer]
+        for i, layer in enumerate(self._state_layers):
+            self._state_pools[i], self._conv_pools[i] = pairs[layer]
+
     def _fetch_logits(self, logits_dev, used: int) -> np.ndarray:
         """The step loop's ONLY device sync: materialize on host the
         logits rows the launch returned — in ragged mode the rows that
@@ -896,18 +954,13 @@ class ServingEngine:
             pos = jnp.asarray(batch.token_pos, jnp.int32)
             slot = jnp.asarray(batch.token_slot, jnp.int32)
             tokens = jnp.asarray(batch.tokens, jnp.int32)
-            q_span = np.zeros((q_tile,), np.int32)  # shape carries q_tile
-            caches: list[Any] = [None] * self.model.depth
-            for i, layer in enumerate(self._kv_layers):
-                caches[layer] = RaggedPagedStep(
-                    self._k_pools[i], self._v_pools[i], tables, kv_lens,
-                    cu, dist, pos, slot, q_span)
+            state_rows = None
             if self._state_layers:
                 state_rows = jnp.asarray(batch.state_rows, jnp.int32)
-                for i, layer in enumerate(self._state_layers):
-                    caches[layer] = RaggedStateStep(
-                        self._state_pools[i], self._conv_pools[i],
-                        state_rows, kv_lens, cu, slot, q_span)
+            index = RaggedStepIndex(
+                tables, kv_lens, cu, dist, pos, slot,
+                np.zeros((q_tile,), np.int32),  # shape carries q_tile
+                state_rows)
         sampled = len(sched.decode) + len(sched.prefill)
         fields = {}
         if self._state_layers:
@@ -921,15 +974,10 @@ class ServingEngine:
         with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
                       decode_rows=len(sched.decode),
                       prefill_tokens=sched.num_prefill_tokens, **fields):
-            logits_dev, new_caches = _ragged_apply(
-                self._step_model, self.params, tokens, tuple(caches),
-            )
-            for i, layer in enumerate(self._kv_layers):
-                self._k_pools[i] = new_caches[layer].k_pool
-                self._v_pools[i] = new_caches[layer].v_pool
-            for i, layer in enumerate(self._state_layers):
-                self._state_pools[i] = new_caches[layer].state_pool
-                self._conv_pools[i] = new_caches[layer].conv_pool
+            logits_dev, new_pools = _ragged_apply(
+                self._step_model, self.params, tokens,
+                self._layer_pools(), index)
+            self._rebind_pools(new_pools)
         if cfg.async_steps:
             # the double-buffer window: the launch is in flight, the
             # sync has not happened — overlap next step's host staging

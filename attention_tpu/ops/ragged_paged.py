@@ -95,6 +95,10 @@ _MAX_SCOPED_VMEM = 110 * 2**20
 #: (it needs the key-norm prefetch this grid does not carry).
 RAGGED_MAX_MODES = ("online", "flashd", "amla", "auto")
 
+#: rows of a page that the append kernel reads, merges and writes
+#: back together: whole memory tiles of 32-, 16- and 8-bit pools
+_APPEND_ROWS = 32
+
 
 class RaggedPagedStep(NamedTuple):
     """One packed engine step over the shared page pool.
@@ -108,7 +112,7 @@ class RaggedPagedStep(NamedTuple):
     packed tokens ``[cu[s], cu[s+1])``.  ``distribution``: (2,) int32
     (num_decode_slots, num_active_slots); decode slots come first.
     ``token_pos``: (T,) int32 absolute cache position of each packed
-    token (drives RoPE and the append scatter).  ``token_slot``: (T,)
+    token (drives RoPE and the append).  ``token_slot``: (T,)
     int32 owning slot per token, -1 for pad tokens.  ``q_span``: a
     (q_tile,) int32 zeros marker whose SHAPE carries the static
     per-request query-tile width (values unused).
@@ -498,17 +502,111 @@ def ragged_paged_attention(q: jax.Array, cache: RaggedPagedStep,
     return _ragged_paged_attention_jit(q, cache, **kwargs)
 
 
+def _row_append_kernel(page_ref, block_ref, first_ref, count_ref,
+                       k_new_ref, v_new_ref, k_in_ref, v_in_ref,
+                       k_out_ref, v_out_ref):
+    """One run a grid step: ``count`` new rows from row ``first`` of
+    one `_APPEND_ROWS`-row block of both pools, the block's other rows
+    as they were.  Steps past the last run stay on its block and write
+    nothing, so nothing moves for them."""
+    j = pl.program_id(0)
+    first, count = first_ref[j], count_ref[j]
+
+    @pl.when((j == 0) | (count > 0))
+    def _():
+        for new_ref, in_ref, out_ref in ((k_new_ref, k_in_ref, k_out_ref),
+                                         (v_new_ref, v_in_ref, v_out_ref)):
+            old = in_ref[0]                          # (Hkv, rows, d)
+            row = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1)
+            new = (row >= first) & (row < first + count)
+            out_ref[0] = jnp.where(new, new_ref[...], old)
+
+
+@functools.partial(jax.jit, static_argnames=("max_runs", "interpret"))
+def _append_rows(k_pool, v_pool, k_rows, v_rows, tgt, pos, *, max_runs,
+                 interpret):
+    """``pool[tgt[t], :, pos[t] % page] = rows[:, t]`` for both pools,
+    in place: the pools are aliased to the results, and the kernel
+    moves only the blocks of `_APPEND_ROWS` rows that hold a new row.
+    ``tgt`` equal to the pool's page count writes nothing.
+
+    Neighbours on the packed axis that write into one block form a
+    RUN, one grid step: a slot's tokens follow each other at rising
+    positions, so a step has at most ``max_runs`` of them."""
+    pages, hkv, page, _ = k_pool.shape
+    t = k_rows.shape[1]
+    rows = _APPEND_ROWS
+    if page % rows:
+        raise ValueError(f"page size {page} is not a multiple of the "
+                         f"{rows} rows one append moves")
+    writes = tgt < pages
+    off = pos % page
+    key = jnp.where(writes, tgt * (page // rows) + off // rows, -1)
+    lead = writes & (key != jnp.concatenate([key[:1] - 1, key[:-1]]))
+    run = jnp.cumsum(lead) - 1                       # of each writing token
+    lead_tok = jnp.nonzero(lead, size=max_runs, fill_value=0)[0]
+    count = jnp.zeros((max_runs,), jnp.int32).at[
+        jnp.where(writes, run, max_runs)].add(1, mode="drop")
+    first = off[lead_tok] % rows
+    # steps past the last run repeat its block; with no run at all,
+    # step 0 rewrites block 0 of page 0 as it is
+    at = lead_tok[jnp.minimum(jnp.arange(max_runs),
+                              jnp.maximum(run[-1], 0))]
+    page_of = jnp.where(writes[at], tgt[at], 0)
+    block_of = jnp.where(writes[at], off[at] // rows, 0)
+    # each run's rows where they go in its block (the rest is not read)
+    src = jnp.clip(lead_tok[:, None] - first[:, None]
+                   + jnp.arange(rows)[None, :], 0, t - 1).reshape(-1)
+
+    def new_spec(width):
+        return pl.BlockSpec((hkv, rows, width),
+                            lambda j, pg, bl, fi, co: (0, j, 0))
+
+    def pool_spec(width):
+        return pl.BlockSpec((1, hkv, rows, width),
+                            lambda j, pg, bl, fi, co: (pg[j], 0, bl[j], 0))
+
+    d, dv = k_pool.shape[3], v_pool.shape[3]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(max_runs,),
+        in_specs=[new_spec(d), new_spec(dv), pool_spec(d), pool_spec(dv)],
+        out_specs=[pool_spec(d), pool_spec(dv)],
+    )
+    return pl.pallas_call(
+        _row_append_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # every element no token writes stays as it is: the new pools
+        # ARE the old ones, written in place
+        input_output_aliases={4 + 2: 0, 4 + 3: 1},
+        compiler_params=_compiler_params(("arbitrary",)),
+        name="kv_row_append",
+        interpret=interpret,
+    )(page_of.astype(jnp.int32), block_of.astype(jnp.int32),
+      first.astype(jnp.int32), count,
+      k_rows[:, src], v_rows[:, src], k_pool, v_pool)
+
+
 def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
                         v_new: jax.Array) -> RaggedPagedStep:
     """Write every packed token's K/V row (k/v (1, Hkv, T, d)) at its
     slot's next positions; returns the cache with post-append lengths.
 
-    One vectorized drop-mode scatter over the token axis — the packed
-    analog of `ops.paged.paged_append`, with the same poison contract:
-    a token targeting an unclaimed (-1) table entry or past the table's
-    capacity writes NOTHING and marks its whole SLOT's length -1
-    (sticky; the attention kernel then emits NaN for that slot's
-    tokens).  Pad tokens (slot -1) always drop, silently."""
+    The packed analog of `ops.paged.paged_append`, with the same poison
+    contract: a token targeting an unclaimed (-1) table entry or past
+    the table's capacity writes NOTHING and marks its whole SLOT's
+    length -1 (sticky; the attention kernel then emits NaN for that
+    slot's tokens).  Pad tokens (slot -1) always drop, silently.
+
+    The rows are written IN PLACE by one small kernel
+    (`_append_rows`): under a jit that donates the pools the step
+    holds no second copy of them and moves no more of a pool than the
+    32-row blocks its new rows sit in.  A slot's tokens follow each
+    other on the packed axis at rising positions (``cu_q_lens``), so
+    neighbours share a block's one trip, and a step of ``T`` tokens
+    over ``S`` slots makes at most ``T // 32 + 2 * S`` such trips."""
     page = cache.page_size
     t = k_new.shape[2]
     if (k_new.ndim != 4 or v_new.ndim != 4
@@ -530,13 +628,14 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
            | (logical >= max_pages)
            | (cache.kv_lens[safe_slot] < 0))
     drop = jnp.logical_or(bad, slot < 0)
-    # drop-mode scatter: dropped tokens target one-past-the-end (a
-    # positive sentinel — negative indices would WRAP before the check)
+    # dropped tokens target one-past-the-end
     tgt = jnp.where(drop, cache.k_pool.shape[0], phys)
-    k_rows = k_new[0].transpose(1, 0, 2).astype(cache.k_pool.dtype)
-    v_rows = v_new[0].transpose(1, 0, 2).astype(cache.v_pool.dtype)
-    k_pool = cache.k_pool.at[tgt, :, pos % page].set(k_rows, mode="drop")
-    v_pool = cache.v_pool.at[tgt, :, pos % page].set(v_rows, mode="drop")
+    k_pool, v_pool = _append_rows(
+        cache.k_pool, cache.v_pool,
+        k_new[0].astype(cache.k_pool.dtype),
+        v_new[0].astype(cache.v_pool.dtype), tgt, pos,
+        max_runs=min(t, t // _APPEND_ROWS + 2 * s_slots),
+        interpret=_should_interpret())
     # per-slot sticky poison: any bad REAL token condemns its slot
     bad_slot = jnp.zeros((s_slots + 1,), jnp.bool_).at[
         jnp.where(slot < 0, s_slots, slot)

@@ -287,7 +287,7 @@ def head_sharded_ragged_step(
     lowering made tensor-parallel.
 
     Both halves of the step run INSIDE one shard_map so the pool
-    scatter and the attention read stay a single per-shard program:
+    append and the attention read stay a single per-shard program:
     the physical pools (P, Hkv, page_size, d) and this step's new K/V
     rows shard along their KV-head dim, while every host-packed index
     array — page tables, ``kv_lens``, ``cu_q_lens``, the decode/

@@ -16,7 +16,9 @@ from attention_tpu.engine import (
     ServingEngine,
     SnapshotManager,
     snapshot,
+    synthetic_trace,
 )
+from attention_tpu.engine.sim import replay
 from attention_tpu.models import decoder_from_config
 from benchmark import harness
 
@@ -153,6 +155,41 @@ def test_a_retry_after_nan_logits_recomputes_the_state(hybrid):
                                  req.output_tokens)
         np.testing.assert_allclose(got, want, atol=TOL)
     assert eng.allocator.state_slots_in_use == 0 == eng.pool.used_pages
+
+
+#: what the parent of the in-place append (b9da22c) served for
+#: `test_replay_gives_the_parents_recorded_tokens`
+_PARENT_TOKENS = {
+    "req-0": [87, 71, 69, 52, 12, 43], "req-1": [12, 90, 7, 77, 22, 5],
+    "req-2": [2, 2, 49, 87, 60, 78], "req-3": [89, 60, 12, 71, 4, 28],
+    "req-4": [43, 39, 7, 25, 94, 68],
+}
+
+
+def test_replay_gives_the_parents_recorded_tokens(hybrid):
+    """The step donates the K / V, state and convolution-tail pools
+    and writes them in place: the same numbers at the same addresses,
+    so the same tokens as before, and no pool of the step before
+    survives it."""
+    model, params, _ = hybrid
+    trace = synthetic_trace(5, vocab=VOCAB, seed=13, max_tokens=6,
+                            prompt_len_min=4, prompt_len_max=80)
+    eng = ServingEngine(model, params, EngineConfig(**ENGINE))
+    pools = []
+    step = eng.step
+
+    def watched():
+        held = [*eng._k_pools, *eng._v_pools, *eng._state_pools,
+                *eng._conv_pools]
+        out = step()
+        pools.append((held, out))
+        return out
+
+    eng.step = watched
+    _, out = replay(eng, trace)
+    assert out == _PARENT_TOKENS
+    busy = [held for held, m in pools if m.decode_tokens or m.prefill_tokens]
+    assert busy and all(a.is_deleted() for held in busy for a in held)
 
 
 def test_a_repeated_prompt_reports_no_prefix_cached_tokens(hybrid):

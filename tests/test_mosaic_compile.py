@@ -18,6 +18,9 @@ is no chip and nothing to contend for.
 """
 
 import functools
+import json
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +28,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from attention_tpu.engine.engine import _ragged_apply
-from attention_tpu.models import TinyDecoder
+from attention_tpu.engine.engine import RaggedStepIndex, _ragged_apply
+from attention_tpu.models import TinyDecoder, decoder_from_config
 from attention_tpu.ops import (
     decode,
     flash,
@@ -65,30 +68,76 @@ def v5e():
         yield list(topo.devices)
 
 
-def _compile(fn, sharding, *args):
-    """Lower ``fn`` for abstract ``args`` (pytrees of ShapeDtypeStructs)
-    placed by ``sharding`` (one sharding, or a pytree prefix of
-    ``args``), and run the TPU compiler."""
-    placed = jax.tree.map(
+def _placed(sharding, args):
+    """Abstract ``args`` (pytrees of ShapeDtypeStructs) placed by
+    ``sharding`` (one sharding, or a pytree prefix of ``args``)."""
+    return jax.tree.map(
         lambda s, sub: jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
             sub),
         sharding, args,
         is_leaf=lambda x: isinstance(x, jax.sharding.Sharding))
-    return jax.jit(fn).lower(*placed).compile()
+
+
+def _compile(fn, sharding, *args):
+    """Lower ``fn`` for the placed ``args`` and run the TPU compiler."""
+    return jax.jit(fn).lower(*_placed(sharding, args)).compile()
+
+
+def _compile_step(model, sharding, *args):
+    """The engine's own jitted step, donation and all (a jit around it
+    would keep the caller's pools alive)."""
+    return _ragged_apply.lower(model, *_placed(sharding, args)).compile()
 
 
 def _a(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _ragged_cache(hkv, width, q_tile, dtype, *, pages=64, slots=10,
-                  max_pages=34, d=128):
-    pool = _a((pages, hkv, 128, d), dtype)
-    return RaggedPagedStep(
-        pool, pool, _a((slots, max_pages), I32), _a((slots,), I32),
+def _ragged_index(width, q_tile, *, slots=10, max_pages=34,
+                  recurrent=False):
+    return RaggedStepIndex(
+        _a((slots, max_pages), I32), _a((slots,), I32),
         _a((slots + 1,), I32), _a((2,), I32), _a((width,), I32),
-        _a((width,), I32), _a((q_tile,), I32))
+        _a((width,), I32), _a((q_tile,), I32),
+        _a((slots,), I32) if recurrent else None)
+
+
+def _ragged_cache(hkv, width, q_tile, dtype, *, pages=64, d=128, **index):
+    pool = _a((pages, hkv, 128, d), dtype)
+    return RaggedPagedStep(pool, pool,
+                           *_ragged_index(width, q_tile, **index)[:-1])
+
+
+def _device_bytes(sharding, pools):
+    """What ``pools`` take on one device, tile padding and all: the
+    arguments of a program that takes nothing else."""
+    compiled = _compile(lambda tree: jax.tree.map(lambda a: a + 1, tree),
+                        sharding, pools)
+    return compiled.memory_analysis().argument_size_in_bytes
+
+
+def _pool_shaped(compiled, pools, ops):
+    """Lines of the compiled step in which one of ``ops`` makes an
+    array of the shape of one of ``pools``."""
+    shapes = {"[" + ",".join(map(str, a.shape)) + "]"
+              for a in jax.tree.leaves(pools)}
+    return [line.strip()[:200] for line in compiled.as_text().splitlines()
+            if re.search(rf"= \S+ ({ops})\(", line)
+            and any(s in line for s in shapes)]
+
+
+def _assert_in_place(compiled, model, pools, sharding):
+    """Every donated pool is aliased to the step's result, none is
+    copied or transposed (the out-of-place update, the relayouts around
+    XLA's scatter), and no K / V pool goes through a scatter at all.
+    (The convolution tails, ten rows of 69 KB, are an XLA scatter on
+    their leading axis, in place.)"""
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            == _device_bytes(sharding, pools))
+    assert _pool_shaped(compiled, pools, "copy|transpose") == []
+    kv_pools = [pools[layer] for layer in model.attention_layers]
+    assert _pool_shaped(compiled, kv_pools, "scatter") == []
 
 
 def test_ragged_engine_step_at_smoke_width(v5e):
@@ -98,15 +147,63 @@ def test_ragged_engine_step_at_smoke_width(v5e):
                         num_kv_heads=4, impl="flash", rope=True)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), I32))["params"]
-    caches = tuple(_ragged_cache(4, 512, 256, BF16, pages=2048)
-                   for _ in range(model.depth))
-    compiled = _compile(
-        functools.partial(_ragged_apply, model),
-        jax.sharding.SingleDeviceSharding(v5e[0]),
-        params, _a((1, 512), I32), caches)
-    # no buffer donation yet: every step allocates a second copy of the
-    # pools (PERF.md, limits) — when donation lands this flips
-    assert compiled.memory_analysis().alias_size_in_bytes == 0
+    pool = _a((2048, 4, 128, 128), BF16)
+    pools = ((pool, pool),) * model.depth
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = _compile_step(
+        model, one,
+        params, _a((1, 512), I32), pools, _ragged_index(512, 256))
+    # the pools are donated and written in place: the step holds them
+    # once, and every byte of them is the caller's buffer
+    _assert_in_place(compiled, model, pools, one)
+    assert _device_bytes(one, pools) == 2 * 4 * 2048 * 4 * 128 * 128 * 2
+
+
+def _starcoder2_cell():
+    """One layer of the benchmark's StarCoder2 cells: 4 KV heads, 768
+    pages, 32 + 1 slots."""
+    model = TinyDecoder(vocab=49152, dim=4608, depth=1, num_q_heads=36,
+                        num_kv_heads=4, impl="flash", window=4096,
+                        rope=True, rope_theta=1e6)
+    pool = _a((768, 4, 128, 128), BF16)
+    return model, ((pool, pool),), dict(slots=33, max_pages=34)
+
+
+def _olmo_hybrid_cell():
+    """One period (three recurrent layers, one attention layer) of the
+    benchmark's Olmo-Hybrid cell: 30 KV heads, 416 pages, 8 + 1 slots
+    with a state row each and the spare."""
+    path = (pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
+            / "olmo-hybrid-7b.json")
+    config = dict(json.loads(path.read_text()), num_hidden_layers=4)
+    model = decoder_from_config(config)
+    state, conv = model.recurrent_state_shapes()
+    pool = _a((416, 30, 128, 128), BF16)
+    pair = (_a((10, *state), F32), _a((10, *conv), BF16))
+    pools = tuple(pair if layer in model.recurrent_layers else (pool, pool)
+                  for layer in range(model.depth))
+    return model, pools, dict(slots=9, max_pages=52, recurrent=True)
+
+
+@pytest.mark.parametrize("width,q_tile", [(384, 256), (8, 8)],
+                         ids=["chunk_step", "decode_only"])
+@pytest.mark.parametrize("cell", [_starcoder2_cell, _olmo_hybrid_cell],
+                         ids=["starcoder2_4kv_768pages",
+                              "olmo_hybrid_30kv_416pages"])
+def test_ragged_engine_step_updates_the_cells_pools_in_place(
+        v5e, cell, width, q_tile):
+    """At both served configurations' pool shapes the compiled step
+    aliases every donated pool (K, V, recurrent state, convolution
+    tail) to its result and holds no copy, transpose or scatter of a
+    pool's shape."""
+    model, pools, index = cell()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = _compile_step(
+        model, one, params, _a((1, width), I32), pools,
+        _ragged_index(width, q_tile, **index))
+    _assert_in_place(compiled, model, pools, one)
 
 
 @pytest.mark.parametrize("width,q_tile,rows", [
@@ -125,12 +222,11 @@ def test_ragged_engine_step_projects_the_sampled_rows(v5e, width, q_tile,
                         rope=True, rope_theta=1e6)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), I32))["params"]
-    caches = (_ragged_cache(4, width, q_tile, BF16, pages=768, slots=33,
-                            max_pages=32),)
-    compiled = _compile(
-        functools.partial(_ragged_apply, model),
-        jax.sharding.SingleDeviceSharding(v5e[0]),
-        params, _a((1, width), I32), caches)
+    pool = _a((768, 4, 128, 128), BF16)
+    compiled = _compile_step(
+        model, jax.sharding.SingleDeviceSharding(v5e[0]),
+        params, _a((1, width), I32), ((pool, pool),),
+        _ragged_index(width, q_tile, slots=33, max_pages=32))
     logits = compiled.out_info[0]
     assert (logits.shape, logits.dtype) == ((1, rows, 49152), F32)
 
@@ -143,12 +239,14 @@ def test_ragged_engine_step_head_sharded_over_four_devices(v5e):
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), I32))["params"]
     rep = NamedSharding(mesh, P())
-    pool = NamedSharding(mesh, P(None, "tp", None, None))
-    cache = _ragged_cache(4, 512, 256, BF16, pages=256)
-    cache_sh = RaggedPagedStep(pool, pool, *[rep] * 7)
-    _compile(functools.partial(_ragged_apply, model),
-             (rep, rep, (cache_sh,)),
-             params, _a((1, 512), I32), (cache,))
+    by_head = NamedSharding(mesh, P(None, "tp", None, None))
+    pool = _a((256, 4, 128, 128), BF16)
+    compiled = _compile_step(model, (rep, rep, by_head, rep),
+                        params, _a((1, 512), I32), ((pool, pool),),
+                        _ragged_index(512, 256))
+    # each device's quarter of the pools, in place there too
+    _assert_in_place(compiled, model, ((pool, pool),), by_head)
+    assert _device_bytes(by_head, (pool, pool)) == 2 * 256 * 128 * 128 * 2
 
 
 @pytest.mark.parametrize("hq,hkv,width,q_tile,dtype", [

@@ -159,6 +159,233 @@ def test_kernel_matches_fp64_reference(specs, kw):
         [kv + q for kv, q in specs]
 
 
+# ------------------------------------------------- the append, in place
+
+
+def _append_case(hkv, dtype, width, seed=0):
+    """A packed step that holds every clause of the append's contract,
+    and what a NumPy loop makes of it.  Slots: two decode rows (one on
+    a page's first row), two requests behind ONE shared prefix page (a
+    decode row and a chunk that runs over page boundaries), a row at an
+    unclaimed (-1) entry, a row past the table, a row whose slot was
+    poisoned before, and pad tokens up to ``width``."""
+    r = np.random.default_rng(seed)
+    d, slots, max_pages, num_pool = 16, 8, 4, 14
+    chunk = {8: 2, 32: 20, 384: 300}[width]
+    shared = 9
+    table = np.full((slots, max_pages), -1, np.int32)
+    table[0, :1] = [0]
+    table[1, :2] = [1, 2]
+    table[2, :2] = [shared, 3]
+    table[3, :4] = [shared, 4, 5, 6]
+    table[4, :1] = [7]              # its token is for entry 1: unclaimed
+    table[5, :4] = [8, 10, 11, 12]  # its token is for entry 4: none
+    table[6, :1] = [13]
+    kv_lens = np.array([37, 128, 130, 128, 128, 4 * _PAGE, -1, 0], np.int32)
+    q_lens = [1, 1, 1, chunk, 1, 1, 1, 0]
+    cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+    pos = np.zeros((width,), np.int32)
+    slot = np.full((width,), -1, np.int32)
+    for s, n in enumerate(q_lens):
+        slot[cu[s]:cu[s + 1]] = s
+        pos[cu[s]:cu[s + 1]] = max(kv_lens[s], 0) + np.arange(n)
+    pools = [jnp.asarray(r.standard_normal((num_pool, hkv, _PAGE, d)),
+                         dtype) for _ in range(2)]
+    rows = [jnp.asarray(r.standard_normal((1, hkv, width, d)), dtype)
+            for _ in range(2)]
+    cache = RaggedPagedStep(
+        *pools, jnp.asarray(table), jnp.asarray(kv_lens), jnp.asarray(cu),
+        jnp.asarray([3, 7], jnp.int32), jnp.asarray(pos), jnp.asarray(slot),
+        np.zeros((8,), np.int32))
+    want = [np.array(p) for p in pools]
+    want_lens = kv_lens + np.asarray(q_lens, np.int32)
+    for t in range(int(cu[-1])):
+        s, page = slot[t], pos[t] // _PAGE
+        if kv_lens[s] < 0 or page >= max_pages or table[s, page] < 0:
+            want_lens[s] = -1
+            continue
+        for pool, new in zip(want, rows):
+            pool[table[s, page], :, pos[t] % _PAGE] = np.asarray(new)[0, :, t]
+    return cache, rows, want, want_lens, shared
+
+
+@pytest.mark.parametrize("width", [8, 32, 384])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("hkv", [1, 4, 30])
+def test_append_writes_the_new_rows_and_nothing_else(hkv, dtype, width):
+    """Against a NumPy loop, to the bit: the new rows land, every other
+    element of both pools is as it was, pad tokens drop, a token at a
+    -1 entry or past the table writes nothing and its slot's length
+    reads -1, a poisoned slot stays poisoned, the shared prefix page is
+    untouched."""
+    cache, rows, want, want_lens, shared = _append_case(hkv, dtype, width)
+    before = [np.array(cache.k_pool), np.array(cache.v_pool)]
+    out = ragged_paged_append(cache, *rows)
+    assert np.asarray(out.kv_lens).tolist() == want_lens.tolist()
+    assert want_lens[4:7].tolist() == [-1, -1, -1]
+    for got, pool, old in zip((out.k_pool, out.v_pool), want, before):
+        assert got.dtype == dtype
+        got = np.asarray(got)
+        assert got.tobytes() == pool.tobytes()
+        assert got[shared].tobytes() == old[shared].tobytes()
+        changed = (got != old).any(axis=(1, 3))      # (pages, rows)
+        assert changed.sum() == int(np.asarray(cache.cu_q_lens)[-1]) - 3
+
+
+#: what the parent of the in-place append (b9da22c) sampled for
+#: `_starcoder2_like_replay`: the same rows at the same addresses give
+#: the same tokens, sampled ones included
+_PARENT_TOKENS = {
+    "req-0": [12, 54, 27, 42, 31, 46], "req-1": [35, 4, 48, 48, 47, 16],
+    "req-2": [55, 12, 30, 1, 12, 49], "req-3": [27, 37, 6, 12, 44, 49],
+    "req-4": [46, 57, 6, 27, 44, 6], "req-5": [49, 22, 11, 41, 12, 24],
+}
+
+
+def _starcoder2_like():
+    """StarCoder2's mixer at toy size: GQA 6 / 2, rope at theta 1e6, a
+    sliding window shorter than the prompts."""
+    model = TinyDecoder(vocab=61, dim=48, depth=2, num_q_heads=6,
+                        num_kv_heads=2, impl="flash", dtype=jnp.float32,
+                        window=96, rope=True, rope_theta=1e6)
+    params = model.init(jax.random.PRNGKey(5),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    trace = synthetic_trace(6, vocab=61, seed=11, max_tokens=6,
+                            prompt_len_min=4, prompt_len_max=150,
+                            shared_prefix_len=129, shared_count=2,
+                            temperature=0.8)
+    return model, params, trace
+
+
+def test_replay_gives_the_parents_recorded_tokens():
+    model, params, trace = _starcoder2_like()
+    _, out = replay(ServingEngine(model, params, _cfg()), trace)
+    assert out == _PARENT_TOKENS
+
+
+def test_a_step_consumes_the_pools_it_was_given(tiny_model):
+    """The step donates its pools: where the backend honours donation
+    (this one does) the arrays bound before a step are deleted after
+    it, and the engine holds the step's results."""
+    model, params = tiny_model
+    eng = ServingEngine(model, params, _cfg())
+    eng.add_request(list(range(1, 20)), SamplingParams(max_tokens=4))
+    before = [*eng._k_pools, *eng._v_pools]
+    eng.step()
+    after = [*eng._k_pools, *eng._v_pools]
+    assert all(a.is_deleted() for a in before)
+    assert not any(a.is_deleted() for a in after)
+    assert float(jnp.abs(after[0]).sum()) > 0.0     # rows were written
+    eng.run()
+
+
+def _add(eng, entry, **kw):
+    eng.add_request(entry["prompt"], sampling_of(entry),
+                    request_id=entry["id"], **kw)
+
+
+def _stepped_engine(model, params, trace, steps):
+    """An engine that has taken ``trace`` and made ``steps`` steps."""
+    eng = ServingEngine(model, params, _cfg())
+    for entry in trace:
+        _add(eng, entry, arrival=entry["arrival"])
+    for _ in range(steps):
+        eng.step()
+    return eng
+
+
+def _drain(eng):
+    """Run ``eng`` dry; the tokens it hands out from here on."""
+    out = {}
+    eng.on_token = lambda req, tok: out.setdefault(
+        req.request_id, []).append(int(tok))
+    eng.run()
+    return out
+
+
+def _snapshot_then_restore(model, params, trace, tmp_path):
+    """Save after three steps, step on, restore the image: the restored
+    engine finishes as the one that never stopped."""
+    eng = _stepped_engine(model, params, trace, 3)
+    path = str(tmp_path / "cut.snap")
+    save(eng, path)
+    want = state_fingerprint(eng)
+    for _ in range(2):
+        eng.step()
+    back = restore(path, model, params)
+    assert state_fingerprint(back) == want
+    return _drain(back), _drain(_stepped_engine(model, params, trace, 3))
+
+
+def _page_poison(model, params, trace, tmp_path):
+    """The chaos injector poisons a private page of a running request
+    in the pools as they are after a step; the engine steps on, and the
+    requests it did not touch finish as in a clean run."""
+    from attention_tpu.chaos.faults import FaultInjector, FaultPlan
+
+    eng = _stepped_engine(model, params, trace, 4)
+    target = next(r.request_id for r in eng.scheduler.running if r.pages)
+    assert FaultInjector(eng, FaultPlan(0, ()))._corrupt(target)
+    assert bool(jnp.isnan(eng._k_pools[0]).any())
+    got = _drain(eng)
+    clean = _drain(_stepped_engine(model, params, trace, 4))
+    got.pop(target, None), clean.pop(target, None)
+    return got, clean
+
+
+def _prefix_store_import(model, params, trace, tmp_path):
+    """One engine publishes a prompt's full page to a store; a second,
+    which has stepped on other work, imports it into its pools and
+    serves the prompt as a cold engine does."""
+    from attention_tpu.prefixstore import PrefixStore
+    from attention_tpu.prefixstore.adapter import import_chain
+
+    shared = trace[0]
+    store = PrefixStore()
+    src = ServingEngine(model, params, _cfg())
+    src.prefix_store = store
+    _add(src, shared)
+    _drain(src)                      # commits, hence exports, the page
+    dest = _stepped_engine(model, params, trace[2:], 3)
+    dest.prefix_store = store
+    assert import_chain(dest, shared["prompt"], now=3) == 128
+    _add(dest, shared)
+    cold = _stepped_engine(model, params, trace[2:], 3)
+    _add(cold, shared)
+    return _drain(dest), _drain(cold)
+
+
+def _handoff_import(model, params, trace, tmp_path):
+    """A request with a committed page leaves one stepped engine as a
+    hand-off blob and enters another stepped engine's pools."""
+    from attention_tpu.engine.snapshot import _request_to_dict
+    from attention_tpu.fleet.handoff import export_handoff, import_handoff
+
+    shared = trace[0]
+    src = _stepped_engine(model, params, [shared], 8)
+    req = next(r for r in src.scheduler.running if r.output_tokens)
+    blob = export_handoff(src, req, _request_to_dict(req, "running"))
+    dest = _stepped_engine(model, params, trace[2:], 3)
+    assert import_handoff(dest, blob, now=3) == 128
+    _add(dest, shared)
+    cold = _stepped_engine(model, params, trace[2:], 3)
+    _add(cold, shared)
+    return _drain(dest), _drain(cold)
+
+
+@pytest.mark.parametrize("holder", [
+    _snapshot_then_restore, _page_poison, _prefix_store_import,
+    _handoff_import])
+def test_pool_holders_work_on_an_engine_that_has_stepped(holder, tmp_path):
+    """Everything that reads or rewrites the pools from outside the
+    step takes the engine's current arrays and rebinds: none keeps an
+    array that a later step has consumed."""
+    model, params, trace = _starcoder2_like()
+    got, want = holder(model, params, trace, tmp_path)
+    assert got == want and all(want.values())
+
+
 # ----------------------------------------------------------------- pack
 
 
@@ -438,13 +665,16 @@ _SLOTS10 = dict(max_decode_batch=8, max_prefill_rows=2)  # 10 slots
 @pytest.fixture
 def ragged_calls(monkeypatch):
     """Every `_ragged_apply` dispatch of the engines run under this
-    fixture, as ``(tokens, caches, logits)``.  The pools are not
-    donated, so a recorded call can be run again."""
+    fixture, as ``(tokens, caches, logits)``.  The step consumes its
+    pools, so ``caches`` holds copies of them as they were before it:
+    a recorded call can be run again."""
     calls = []
 
-    def spy(model, params, tokens, caches):
-        out = _ragged_apply(model, params, tokens, caches)
-        calls.append((tokens, caches, out[0]))
+    def spy(model, params, tokens, pools, index):
+        before = jax.tree.map(jnp.copy, pools)
+        out = _ragged_apply(model, params, tokens, pools, index)
+        calls.append((tokens, engine_mod._layer_steps(model, before, index),
+                      out[0]))
         return out
 
     monkeypatch.setattr(engine_mod, "_ragged_apply", spy)
