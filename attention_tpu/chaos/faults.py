@@ -824,10 +824,8 @@ class FrontendFaultInjector:
         eng = handle.engine
         state = {"left": count}
         if kind == "nan":
-            # wrap the logits device sync — the ONE seam both step
-            # modes (ragged single-launch and legacy two-call) fetch
-            # through, so the injector composes with either loop and
-            # with async staging unchanged
+            # wrap the logits device sync, the one seam every step
+            # fetches through
             orig_fetch = eng._fetch_logits
 
             def poisoned(*args, **kwargs):

@@ -228,37 +228,6 @@ def token_parity_violations(
     return _report("token_parity", problems)
 
 
-def async_parity_violations(
-    sync_outputs: Mapping[str, list[int]],
-    async_outputs: Mapping[str, list[int]],
-    *,
-    exclude: Iterable[str] = (),
-) -> list[str]:
-    """The double-buffered step loop (`EngineConfig.async_steps`) must
-    be token-identical to the synchronous loop on the same seed/trace —
-    staging is pure pre-rendering, so ANY divergence means the overlap
-    leaked into scheduling, sampling, or the page tables.  Checked in
-    both directions: a request that exists in one run but not the other
-    is a violation too."""
-    excluded = set(exclude)
-    problems = []
-    for rid, want in sync_outputs.items():
-        if rid in excluded:
-            continue
-        got = async_outputs.get(rid)
-        if got != want:
-            problems.append(
-                f"request {rid}: async loop diverged from the sync "
-                f"loop (got {got}, want {want})"
-            )
-    for rid in async_outputs:
-        if rid not in sync_outputs and rid not in excluded:
-            problems.append(
-                f"request {rid}: emitted by the async loop only"
-            )
-    return _report("async_parity", problems)
-
-
 def termination_violations(finished: bool, error: BaseException | None,
                            *, max_steps: int) -> list[str]:
     """The run must drain (or fail TYPED) within the step bound."""

@@ -1,14 +1,14 @@
 """Continuous-batching serving engine over the paged KV kernels.
 
 The bridge from "fast kernel" to "high-throughput server": many
-concurrent requests in, batched `paged_append(_chunk)` +
-`paged_flash_decode` steps out.
+concurrent requests in, one packed `ragged_paged_append` +
+`ragged_paged_attention` launch a step out.
 
     requests ──> Scheduler ────────> ServingEngine.step()
-                   │  FCFS admission,     │  fixed-shape decode +
-                   │  chunked prefill ⊕   │  chunked-prefill calls
+                   │  FCFS admission,     │  one packed launch:
+                   │  chunked prefill ⊕   │  decode rows + chunks
                    │  decode batching,    ▼
-                   │  preemption      paged kernels (ops.paged)
+                   │  preemption      ops.ragged_paged
                    ▼                      │
                BlockAllocator <───────────┘
                    watermark-guarded pages + hash-keyed

@@ -4,37 +4,23 @@
 steps.  Memory is ONE page-id space across all model layers (per-layer
 physical pools share the geometry, so a single `PagePool`/
 `BlockAllocator` and one table row per request drive the whole stack);
-compute is the model's own paged cache paths — `paged_append` +
-`paged_flash_decode` for decode rows, `paged_append_chunk` + the
-chunk-mode kernel for prefill slices — exactly the kernels
-`generate_paged` steps, which is what makes the engine's output
-token-for-token comparable to per-request sequential generation.
+compute is the model's packed cache path — `ragged_paged_append` +
+`ragged_paged_attention` over the same pools and page tables that
+`generate_paged` steps one request at a time, which is what the
+engine's output is held to, token for token.
 
-Shape discipline (the TPU way): in the default ``step_mode="ragged"``
-every step lowers onto exactly ONE jitted call over a PACKED token
-axis — decode tokens and prefill chunks ride the same axis, delimited
-by ``cu_q_lens`` + a decode/prefill ``distribution`` split
-(`ops.ragged_paged`).  The packed width and per-request query tile are
-power-of-two bucketed, so a serving life compiles O(log max_tokens)
-executables and pad waste per step is just the bucket remainder — not
-the ``(max_decode_batch - d) + (max_prefill_rows*chunk - real)``
-poison rows of the legacy path.  Of that launch the host fetches only
-the logits it can sample — each slot's last packed row, gathered on
-the device ahead of the final norm and the head once the packed axis
-is wider than the slot count (`_ragged_apply`).  ``step_mode="two_call"``
-keeps that legacy lowering — a ``(max_decode_batch, 1)`` decode call
-plus a ``(max_prefill_rows, prefill_chunk)`` prefill call padded with
-the inactive sentinel (empty table, length -1) — as the parity oracle;
-both modes consume logits through the same post-processing helpers,
-so their token streams are identical by construction.
-
-``async_steps=True`` double-buffers the loop: after the launch is
-dispatched, next step's page-table rows are staged on host
-(``engine.step.overlap`` span) BEFORE `jax.block_until_ready` forces
-the logits sync — host staging hides behind device compute, the source
-paper's ping-pong trick.  Staging is pure pre-rendering (no
-allocation, no RNG), so the async loop is token-identical to the sync
-loop; snapshot cuts call `quiesce` to settle it.
+Shape discipline (the TPU way): every step lowers onto exactly ONE
+jitted call over a PACKED token axis — decode tokens and prefill
+chunks ride the same axis, delimited by ``cu_q_lens`` + a
+decode/prefill ``distribution`` split (`ops.ragged_paged`).  The
+packed width and per-request query tile are power-of-two bucketed, so
+a serving life compiles O(log max_tokens) executables and pad waste
+per step is just the bucket remainder.  Of that launch the host
+fetches only the logits it can sample — each slot's last packed row,
+gathered on the device ahead of the final norm and the head once the
+packed axis is wider than the slot count (`_ragged_apply`).  A step is
+schedule, pack, upload, dispatch, fetch, sample, in that order; no
+option selects another.
 
 Tokens stream out through callbacks (``on_token``/``on_finish``) the
 moment they are sampled — iteration-level, not request-level, latency.
@@ -67,7 +53,7 @@ from attention_tpu.engine.metrics import (
 from attention_tpu.engine.request import Request, RequestState, SamplingParams
 from attention_tpu.engine.scheduler import ScheduledStep, Scheduler
 from attention_tpu.ops.gated_delta import RaggedStateStep
-from attention_tpu.ops.paged import OutOfPagesError, PagedKV, PagePool
+from attention_tpu.ops.paged import OutOfPagesError, PagePool
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
     packed_bucket,
@@ -78,9 +64,9 @@ _CANCELLED = obs.counter("engine.requests.cancelled",
                          "requests cancelled mid-flight")
 _TIMED_OUT = obs.counter("engine.requests.timed_out",
                          "requests expired by the deadline sweep")
-# host-side dispatches of jitted attention work, labelled by step mode:
-# ticks once per LAUNCH (the ragged loop's single-launch property is
-# asserted against this; the ops.*.calls counters tick per jit trace)
+# host-side dispatches of the jitted step: ticks once per LAUNCH (the
+# loop's single-launch property is asserted against this; the
+# ops.*.calls counters tick per jit trace)
 _LAUNCHES = obs.counter("engine.step.launches",
                         "jitted model launches dispatched by the step loop")
 # what the step's one device sync moves against what the host reads of
@@ -131,17 +117,6 @@ def require_pages_only(model, feature: str) -> None:
         raise RecurrentStateUnsupportedError(
             f"{feature} knows only KV pages, and {type(model).__name__} "
             f"keeps a recurrent state per request in layers {list(layers)}")
-
-
-@functools.partial(jax.jit, static_argnames=("model",))
-def _paged_apply(model, params, tokens, caches):
-    """One batched model step over paged caches.  Module-level with a
-    static ``model`` (flax Modules hash by config, the `generate_paged`
-    discipline) so every engine instance serving the same model at the
-    same batch shapes shares ONE compiled executable per shape — two
-    total: ``(max_decode_batch, 1)`` and ``(max_prefill_rows,
-    prefill_chunk)``."""
-    return model.apply({"params": params}, tokens, caches)
 
 
 class RaggedStepIndex(NamedTuple):
@@ -237,23 +212,20 @@ class EngineConfig:
     num_pages: int = 64
     page_size: int = 128           # paged-kernel granule: 128-multiple
     max_seq_len: int = 1024        # per-request prompt + generated cap
-    max_decode_batch: int = 8      # decode rows per step (fixed shape)
-    max_prefill_rows: int = 2      # prefill rows per step (fixed shape)
-    prefill_chunk: int = 64        # tokens per prefill slice (padded to)
+    max_decode_batch: int = 8      # decode requests per step, at most
+    max_prefill_rows: int = 2      # prefill chunks per step, at most
+    prefill_chunk: int = 64        # tokens per prefill slice, at most
     token_budget: int = 128        # real tokens scheduled per step
     watermark_pages: int = 1       # admission must leave this reserve
     cache_dtype: Any = None        # None -> model dtype
-    # "ragged": ONE packed jitted launch per step (ops/ragged_paged);
-    # "two_call": the legacy fixed-shape decode+prefill pair, kept as
-    # the parity oracle
+    # one value left, and it selects nothing: the benchmark's config
+    # files pass "step_mode": "ragged" into this constructor, so the
+    # field stays until they drop the key (PERF.md §7)
     step_mode: str = "ragged"
-    # double-buffer: stage next step's page-table rows on host while
-    # the current launch runs on device (ragged mode only)
-    async_steps: bool = False
     # 0 = single-device (default).  N >= 1 serves every per-step jitted
-    # launch — both step modes — through the KV-head-sharded kernels on
-    # a 1D "tp" mesh of the first N devices: one pool slice per head
-    # shard, page tables replicated, host-side packing unchanged.
+    # launch through the KV-head-sharded kernels on a 1D "tp" mesh of
+    # the first N devices: one pool slice per head shard, page tables
+    # replicated, host-side packing unchanged.
     # Requires num_kv_heads % N == 0 and N available devices (typed
     # MeshConfigError otherwise, raised at engine construction).
     mesh_shards: int = 0
@@ -264,10 +236,11 @@ class EngineConfig:
                 f"page_size {self.page_size} must be a 128-multiple "
                 "(paged kernel granule)"
             )
-        if self.step_mode not in ("ragged", "two_call"):
+        if self.step_mode != "ragged":
             raise ValueError(
-                f"step_mode {self.step_mode!r} not in "
-                "['ragged', 'two_call']"
+                f"step_mode {self.step_mode!r}: the packed step "
+                "(\"ragged\") is the only lowering, the two-call pair "
+                "is gone"
             )
         if min(self.num_pages, self.max_seq_len, self.max_decode_batch,
                self.max_prefill_rows, self.prefill_chunk,
@@ -286,9 +259,9 @@ class EngineConfig:
 
     @property
     def table_width(self) -> int:
-        """Page-table row width: covers max_seq_len PLUS one padded
-        prefill chunk, so pad rows of a final partial chunk always land
-        on claimable pages instead of NaN-poisoning the row."""
+        """Page-table row width: the pages of max_seq_len plus one
+        prefill chunk.  A compiled shape: the packed kernel's grid
+        has this many page steps."""
         return -(-(self.max_seq_len + self.prefill_chunk)
                  // self.page_size)
 
@@ -331,8 +304,6 @@ class ServingEngine:
         self._state_layers = tuple(getattr(model, "recurrent_layers", ()))
         if config.mesh_shards:
             self.require_pages_only("mesh_shards > 0")
-        if config.step_mode != "ragged":
-            self.require_pages_only(f"step_mode={config.step_mode!r}")
 
         # mesh mode: a 1D "tp" mesh of the first mesh_shards devices;
         # the step launches run the model's head-sharded cached paths
@@ -451,11 +422,6 @@ class ServingEngine:
         # documented garbage-but-terminating contract (the checkers
         # exclude corrupted targets from parity)
         self._nonfinite_skips: dict[str, int] = {}
-        # async double-buffer state: page-table rows pre-rendered for
-        # next step while the current launch runs on device, keyed by
-        # request id as (num_pages, row) — `pack` only consumes a row
-        # whose page count is still current
-        self._staged_rows: dict[str, tuple[int, np.ndarray]] = {}
         # seconds this step spent blocked in the logits device sync
         # (host overhead = step wall minus this)
         self._last_fetch_s = 0.0
@@ -708,8 +674,7 @@ class ServingEngine:
 
     def step(self) -> StepMetrics:
         """Run one scheduler iteration: compose a batch, lower it onto
-        ONE ragged launch (or the legacy two-call pair), stream out
-        sampled tokens."""
+        ONE packed launch, stream out sampled tokens."""
         t0 = time.perf_counter()
         self._finished_in_step = 0
         self.last_step_virtual_cost = self.step_cost_multiplier
@@ -734,22 +699,10 @@ class ServingEngine:
                               else "prefill_start")
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
-            baseline_pad = self._baseline_pad(sched)
-            if self.config.step_mode == "ragged":
-                if not sched.is_empty:
-                    width = self._run_ragged(sched)
-                    pad_tokens = width - total
-                    occupancy = total / width
-            else:
-                if sched.decode:
-                    with obs.span("engine.step.decode"):
-                        self._run_decode(sched.decode)
-                if sched.prefill:
-                    with obs.span("engine.step.prefill"):
-                        self._run_prefill(sched.prefill)
-                pad_tokens = baseline_pad
-                if total:
-                    occupancy = total / (total + baseline_pad)
+            if not sched.is_empty:
+                width = self._run_ragged(sched)
+                pad_tokens = width - total
+                occupancy = total / width
             wall_s = time.perf_counter() - t0
             m = StepMetrics(
                 step=self._step,
@@ -770,7 +723,6 @@ class ServingEngine:
                 prefix_hit_tokens_total=self.allocator.prefix_hit_tokens,
                 preemptions_total=self.scheduler.num_preemptions,
                 pad_tokens=pad_tokens,
-                baseline_pad_tokens=baseline_pad,
                 ragged_occupancy=occupancy,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
             )
@@ -835,25 +787,6 @@ class ServingEngine:
 
     # -- batch lowering ---------------------------------------------------
 
-    def _baseline_pad(self, sched: ScheduledStep) -> int:
-        """Pad tokens the legacy two-call lowering dispatches for this
-        step's composition — the yardstick ragged occupancy is measured
-        against."""
-        pad = 0
-        if sched.decode:
-            pad += self.config.max_decode_batch - len(sched.decode)
-        if sched.prefill:
-            pad += (self.config.max_prefill_rows
-                    * self.config.prefill_chunk
-                    - sched.num_prefill_tokens)
-        return pad
-
-    def _table_rows(self, reqs: list[Request]) -> np.ndarray:
-        rows = np.full((len(reqs), self.config.table_width), -1, np.int64)
-        for i, req in enumerate(reqs):
-            rows[i, : len(req.pages)] = req.pages
-        return rows
-
     def _place_pool(self, arr):
         """Device placement for one per-layer pool: one KV-head slice
         per shard on a mesh engine, plain single-device otherwise.
@@ -882,12 +815,11 @@ class ServingEngine:
 
     def _fetch_logits(self, logits_dev, used: int) -> np.ndarray:
         """The step loop's ONLY device sync: materialize on host the
-        logits rows the launch returned — in ragged mode the rows that
-        can be sampled (`_ragged_apply`), of which this step samples
-        ``used``.  Isolated in one hook so (a) the async loop can
-        finish its overlapped staging before the block, (b) per-step
-        host overhead is measurable as wall minus time spent here, and
-        (c) fault injectors have a single seam to poison."""
+        logits rows the launch returned — the rows that can be sampled
+        (`_ragged_apply`), of which this step samples ``used``.
+        Isolated in one hook so (a) per-step host overhead is
+        measurable as wall minus time spent here, and (b) fault
+        injectors have a single seam to poison."""
         rows = logits_dev.size // logits_dev.shape[-1]
         if obs.is_enabled():
             _LOGIT_ROWS.inc(rows, kind="fetched")
@@ -899,26 +831,6 @@ class ServingEngine:
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
-    def _apply(self, tokens: np.ndarray, tables: np.ndarray,
-               lens: np.ndarray, used: int) -> np.ndarray:
-        caches = tuple(
-            PagedKV(self._k_pools[layer], self._v_pools[layer],
-                    jnp.asarray(tables, jnp.int32),
-                    jnp.asarray(lens, jnp.int32))
-            for layer in range(len(self._k_pools))
-        )
-        if obs.is_enabled():
-            _LAUNCHES.inc(mode="two_call")
-        with obs.span("engine.step.dispatch"):
-            logits, new_caches = _paged_apply(
-                self._step_model, self.params,
-                jnp.asarray(tokens, jnp.int32), caches
-            )
-            for layer, c in enumerate(new_caches):
-                self._k_pools[layer] = c.k_pool
-                self._v_pools[layer] = c.v_pool
-        return self._fetch_logits(logits, used)
-
     def _run_ragged(self, sched: ScheduledStep) -> int:
         """Lower the WHOLE step onto one jitted packed launch; returns
         the packed width dispatched.
@@ -926,8 +838,7 @@ class ServingEngine:
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
         occupancy stays high while compiled signatures stay
-        O(log max_tokens).  With ``async_steps`` the host stages next
-        step's page-table rows between dispatch and the logits sync."""
+        O(log max_tokens)."""
         cfg = self.config
         with obs.span("engine.step.pack"):
             slots = cfg.max_decode_batch + cfg.max_prefill_rows
@@ -943,9 +854,7 @@ class ServingEngine:
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             width = packed_bucket(max(total, q_tile))
             batch = sched.pack(width=width, slots=slots,
-                               table_width=cfg.table_width,
-                               staged_rows=self._staged_rows)
-            self._staged_rows = {}
+                               table_width=cfg.table_width)
         with obs.span("engine.step.upload"):
             tables = jnp.asarray(batch.tables, jnp.int32)
             kv_lens = jnp.asarray(batch.kv_lens, jnp.int32)
@@ -967,7 +876,7 @@ class ServingEngine:
             fields = {"recurrent_tokens": total,
                       "recurrent_slot_steps": sampled}
         if obs.is_enabled():
-            _LAUNCHES.inc(mode="ragged")
+            _LAUNCHES.inc()
             if self._state_layers:
                 _RECURRENT_TOKENS.inc(total)
                 _RECURRENT_SLOT_STEPS.inc(sampled)
@@ -978,11 +887,6 @@ class ServingEngine:
                 self._step_model, self.params, tokens,
                 self._layer_pools(), index)
             self._rebind_pools(new_pools)
-        if cfg.async_steps:
-            # the double-buffer window: the launch is in flight, the
-            # sync has not happened — overlap next step's host staging
-            with obs.span("engine.step.overlap"):
-                self._stage_next_step()
         logits = self._fetch_logits(logits_dev, sampled)
         with obs.span("engine.step.sample", rows=sampled):
             row_of = _sampled_logit_rows(batch.cu_q_lens, width)
@@ -994,49 +898,16 @@ class ServingEngine:
                     req, real, logits[0, row_of[num_decode + s]])
         return width
 
-    def _stage_next_step(self) -> None:
-        """Host half of the double buffer: pre-render page-table rows
-        for every request that will decode next step, while the device
-        is still busy.  Pure staging — no page allocation, no pool
-        mutation, no RNG consumption — so the async loop's tokens are
-        identical to the sync loop's by construction; `pack` discards
-        any staged row whose page count went stale."""
-        staged: dict[str, tuple[int, np.ndarray]] = {}
-        tw = self.config.table_width
-        for req in self.scheduler.running:
-            if req.state is RequestState.DECODING and req.pages:
-                row = np.full((tw,), -1, np.int32)
-                row[: len(req.pages)] = req.pages
-                staged[req.request_id] = (len(req.pages), row)
-        self._staged_rows = staged
-
     def quiesce(self) -> None:
-        """Settle the staged/in-flight step: drop staged rows and block
-        until the device pools are final.  Snapshot cuts run this first
-        so a serialized image never captures a half-staged async step."""
-        self._staged_rows = {}
+        """Block until the device pools are final.  A snapshot cut
+        runs this before it reads them."""
         for a in (*self._k_pools, *self._v_pools, *self._state_pools,
                   *self._conv_pools):
             jax.block_until_ready(a)
 
-    def _run_decode(self, reqs: list[Request]) -> None:
-        d = self.config.max_decode_batch
-        tokens = np.zeros((d, 1), np.int32)
-        tables = np.full((d, self.config.table_width), -1, np.int64)
-        lens = np.full((d,), -1, np.int32)  # -1 = inactive pad row
-        for i, req in enumerate(reqs):
-            lens[i] = req.computed_tokens
-            tokens[i, 0] = req.feed_pending()
-            tables[i, : len(req.pages)] = req.pages
-        logits = self._apply(tokens, tables, lens, len(reqs))
-        with obs.span("engine.step.sample", rows=len(reqs)):
-            for i, req in enumerate(reqs):
-                self._post_decode(req, logits[i, 0])
-
     def _post_decode(self, req: Request, logits_row: np.ndarray) -> None:
-        """Consume one decode request's logits row — the mode-agnostic
-        half of a decode step (both lowerings call this, which is what
-        makes their token streams identical by construction)."""
+        """Consume one decode request's logits row: guard it, sample
+        from it, emit."""
         if not np.isfinite(logits_row).all():
             # poisoned logits must never reach sampling: a garbage
             # token would break parity with the fault-free run.
@@ -1068,27 +939,10 @@ class ServingEngine:
         if self._state_layers:
             self.scheduler.requeue_for_recompute(req)
 
-    def _run_prefill(self, items: list[tuple[Request, int]]) -> None:
-        p = self.config.max_prefill_rows
-        s = self.config.prefill_chunk
-        tokens = np.zeros((p, s), np.int32)
-        tables = np.full((p, self.config.table_width), -1, np.int64)
-        lens = np.full((p,), -1, np.int32)
-        for i, (req, real) in enumerate(items):
-            c = req.computed_tokens
-            tokens[i, :real] = req.tokens[c : c + real]
-            tables[i, : len(req.pages)] = req.pages
-            lens[i] = c
-        logits = self._apply(tokens, tables, lens, len(items))
-        with obs.span("engine.step.sample", rows=len(items)):
-            for i, (req, real) in enumerate(items):
-                self._post_prefill(req, real, logits[i, real - 1])
-
     def _post_prefill(self, req: Request, real: int,
                       last_row: np.ndarray) -> None:
-        """Consume one prefill chunk's last logits row — the
-        mode-agnostic half of a prefill step (both lowerings call
-        this)."""
+        """Consume one prefill chunk's last logits row: advance the
+        request, and on its final chunk sample the first token."""
         if (req.computed_tokens + real >= len(req.tokens)
                 and not req.output_tokens
                 and not np.isfinite(last_row).all()):

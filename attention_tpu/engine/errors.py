@@ -182,7 +182,7 @@ class RecurrentStateUnsupportedError(RuntimeError):
     Such a model keeps, beside its KV pages, one state per request and
     recurrent layer that cannot be re-derived page by page.  Snapshot
     save / restore, prefix-store export and import, the fleet's KV
-    hand-off, ``mesh_shards > 0`` and ``step_mode="two_call"`` carry
-    pages only; each raises this for such a model
+    hand-off and ``mesh_shards > 0`` carry pages only; each raises
+    this for such a model
     (`ServingEngine.require_pages_only`).  State checkpoints at page
     boundaries would lift it (ROADMAP R2)."""

@@ -87,7 +87,6 @@ class StepMetrics:
     prefix_hit_tokens_total: int = 0  # cumulative
     preemptions_total: int = 0        # cumulative
     pad_tokens: int = 0              # pads dispatched this step
-    baseline_pad_tokens: int = 0     # what the two-call lowering pads
     ragged_occupancy: float = 0.0    # real / dispatched width
     host_overhead_s: float = 0.0     # wall minus the logits device sync
 
@@ -237,8 +236,6 @@ class EngineMetrics:
             "preemptions": self.steps[-1].preemptions_total
             if self.steps else 0,
             "pad_tokens_total": sum(s.pad_tokens for s in self.steps),
-            "baseline_pad_tokens_total": sum(
-                s.baseline_pad_tokens for s in self.steps),
             "mean_ragged_occupancy": round(
                 sum(s.ragged_occupancy for s in busy) / len(busy), 4)
             if busy else 0.0,

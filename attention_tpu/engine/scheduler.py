@@ -80,9 +80,8 @@ class ScheduledStep:
 
     step: int
     decode: list[Request] = dataclasses.field(default_factory=list)
-    # (request, real tokens of this chunk) — the two-call engine pads
-    # every chunk to the configured prefill_chunk for shape stability;
-    # the ragged engine packs the real tokens via `pack`
+    # (request, real tokens of this chunk); `pack` lays out the real
+    # tokens only
     prefill: list[tuple[Request, int]] = dataclasses.field(
         default_factory=list
     )
@@ -101,19 +100,13 @@ class ScheduledStep:
     def is_empty(self) -> bool:
         return not self.decode and not self.prefill
 
-    def pack(self, *, width: int, slots: int, table_width: int,
-             staged_rows: dict | None = None) -> PackedBatch:
+    def pack(self, *, width: int, slots: int,
+             table_width: int) -> PackedBatch:
         """Flatten this step onto one padded token axis, decode slots
         first then prefill chunks, each request's tokens contiguous.
 
         CONSUMES pending decode tokens (`Request.feed_pending`) — call
-        at most once per step, from the engine's dispatch path.
-
-        ``staged_rows`` (optional ``{request_id: (num_pages, row)}``)
-        reuses page-table rows staged by the async loop while the
-        previous step ran on device; a row is taken only when the
-        request's page count is unchanged, so the packed operands are
-        bit-identical to a cold rebuild."""
+        at most once per step, from the engine's dispatch path."""
         items = [(r, 1) for r in self.decode] + list(self.prefill)
         total = self.num_decode_tokens + self.num_prefill_tokens
         if len(items) > slots:
@@ -143,11 +136,7 @@ class ScheduledStep:
             token_pos[off:off + n] = np.arange(c, c + n)
             kv_lens[s] = c
             state_rows[s] = req.state_slot
-            staged = (staged_rows or {}).get(req.request_id)
-            if staged is not None and staged[0] == len(req.pages):
-                tables[s] = staged[1]
-            else:
-                tables[s, :len(req.pages)] = req.pages
+            tables[s, :len(req.pages)] = req.pages
             off += n
             cu[s + 1] = off
         cu[len(items) + 1:] = off

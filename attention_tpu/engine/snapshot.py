@@ -84,7 +84,7 @@ from attention_tpu.engine.request import Request, RequestState, SamplingParams
 from attention_tpu.parallel.serving import MeshConfigError
 
 SNAPSHOT_MAGIC = "atp-snapshot"
-SNAPSHOT_VERSION = 1
+SNAPSHOT_VERSION = 2
 SNAPSHOT_SUFFIX = ".atpsnap"
 
 #: manifest section order for a single-device snapshot.  A mesh
@@ -237,9 +237,7 @@ def _serialize_sections(engine: ServingEngine) -> list[tuple[str, bytes]]:
     # a snapshot holds pages and requests; a recurrent layer's state per
     # request is in neither, so such an engine is refused, not half-saved
     engine.require_pages_only("a snapshot")
-    # a snapshot cut must not capture a half-staged async step: settle
-    # the double buffer (drop staged page-table rows, block until the
-    # device pools are final) before reading any bytes out
+    # block until the device pools are final before reading bytes out
     engine.quiesce()
     cfg = dataclasses.asdict(engine.config)
     if cfg["cache_dtype"] is not None:

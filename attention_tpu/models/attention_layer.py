@@ -674,8 +674,8 @@ class GQASelfAttention(nn.Module):
             raise ValueError(
                 "rope+sinks needs the per-sequence rotated sink read "
                 "copy (paged_sink_decode), which the packed step does "
-                "not carry; serve such models with "
-                "step_mode='two_call'"
+                "not carry; generate_paged runs such a model one "
+                "request at a time"
             )
         if self.tp_axis is not None:
             # head-sharded single-launch step: append + ragged

@@ -855,12 +855,9 @@ def _bench_engine(args, device: dict) -> dict:
             "engine_wall_s": round(engine_s, 3),
             "sequential_wall_s": round(sequential_s, 3),
             "output_tokens": out_tokens,
-            # ragged single-launch packing economics: pads actually
-            # dispatched vs what the two-call lowering would have padded
-            # on the identical schedule, plus the host-side staging cost
+            # packing economics of the single-launch step: pads
+            # dispatched, occupancy, and the host-side cost of a step
             "pad_tokens_total": summary.get("pad_tokens_total", 0),
-            "baseline_pad_tokens_total": summary.get(
-                "baseline_pad_tokens_total", 0),
             "mean_ragged_occupancy": summary.get(
                 "mean_ragged_occupancy", 0.0),
             "mean_host_overhead_ms": summary.get(

@@ -1078,21 +1078,34 @@ def test_tree_wide_analysis_is_clean_modulo_baseline():
     assert r.stdout == "analysis OK\n"
 
 
-def test_tree_wide_run_fits_the_time_budget():
-    """ISSUE 13's perf contract: the whole tree — index build plus
-    every pass, the symbolic shapes/sharding interpreters included —
-    analyzes in <= 5 s."""
+def _tree_wide_timings():
+    """``check_all.py --timings`` on the tree: its stderr and the one
+    ``total`` line's milliseconds."""
     r = _run(["scripts/check_all.py", "--timings"])
     assert r.returncode == 0, r.stdout + r.stderr
     total_lines = [ln for ln in r.stderr.splitlines()
                    if ln.strip().endswith("ms  total")]
     assert len(total_lines) == 1, r.stderr
-    total_ms = float(total_lines[0].strip().split()[0])
+    return r.stderr, float(total_lines[0].strip().split()[0])
+
+
+def test_tree_wide_run_itemizes_its_timings():
+    """The whole tree — index build plus every pass — analyzes clean
+    and says where the time went: one total, and a line each for the
+    interprocedural index, the determinism pass and the two symbolic
+    interpreters."""
+    stderr, _ = _tree_wide_timings()
+    assert "<index>" in stderr and "determinism" in stderr
+    assert "shapes" in stderr and "sharding" in stderr
+
+
+@pytest.mark.slow
+def test_tree_wide_run_fits_the_time_budget():
+    """ISSUE 13's perf contract: the whole tree analyzes in <= 5 s.
+    A wall-clock limit means something only on an idle machine, so it
+    stays out of tier-1, which runs beside five busy workers."""
+    _, total_ms = _tree_wide_timings()
     assert total_ms <= 5000.0, f"tree-wide analysis took {total_ms} ms"
-    # the interprocedural machinery is itemized, not hidden
-    assert "<index>" in r.stderr and "determinism" in r.stderr
-    # ... and so are the two symbolic passes under the same pin
-    assert "shapes" in r.stderr and "sharding" in r.stderr
 
 
 def test_cli_analyze_changed_exits_clean():

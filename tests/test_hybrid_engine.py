@@ -273,12 +273,10 @@ def test_pages_only_features_refuse_a_model_with_recurrent_state(
         feature(eng, tmp_path)
 
 
-@pytest.mark.parametrize("config", [
-    {"mesh_shards": 2}, {"step_mode": "two_call"}])
-def test_pages_only_engine_modes_refuse_at_construction(hybrid, config):
+def test_mesh_engine_refuses_at_construction(hybrid):
     model, params, _ = hybrid
     with pytest.raises(RecurrentStateUnsupportedError):
-        ServingEngine(model, params, EngineConfig(**dict(ENGINE, **config)))
+        ServingEngine(model, params, EngineConfig(**ENGINE, mesh_shards=2))
     with pytest.raises(RecurrentStateUnsupportedError):
         snapshot.restore("nowhere", model, params)
 

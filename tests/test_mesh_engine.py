@@ -4,7 +4,7 @@ The tentpole contract, pinned on the 8-device simulated CPU mesh from
 conftest: an engine whose jitted launches lower onto KV-head-sharded
 paged kernels (`parallel.serving.head_sharded_ragged_step`) is
 TOKEN-FOR-TOKEN identical to the single-device engine — greedy and
-sampled, both step modes, through preemption, warm restart from a
+sampled, through preemption, warm restart from a
 per-shard snapshot, and a kill+migrate chaos storm — while still
 making exactly one launch per busy step.  Geometry that cannot split
 is a typed `MeshConfigError` at call/construct time, and damage to
@@ -100,18 +100,6 @@ def test_mesh_token_parity_ragged(tiny_model, tkw):
     assert mesh == single
     assert single  # non-vacuous: every request finished with tokens
     assert all(single.values())
-
-
-def test_mesh_token_parity_two_call(tiny_model):
-    """The legacy two-call lowering shards through the same mesh mode
-    (parity oracle stays a parity oracle on a mesh)."""
-    model, params = tiny_model
-    trace = _trace(model)
-    _, single = _serve(model, params, _cfg(step_mode="two_call"), trace)
-    _, mesh = _serve(
-        model, params, _cfg(step_mode="two_call", mesh_shards=SHARDS),
-        trace)
-    assert mesh == single and single
 
 
 def test_mesh_preemption_parity(tiny_model):
@@ -218,10 +206,7 @@ def test_mesh_exactly_one_launch_per_busy_step(tiny_model):
         busy = sum(1 for m in eng.metrics.steps
                    if m.decode_tokens or m.prefill_tokens)
         assert busy > 0
-        assert _counter_total(
-            snap, "engine.step.launches", mode="ragged") == busy
-        assert _counter_total(
-            snap, "engine.step.launches", mode="two_call") == 0
+        assert _counter_total(snap, "engine.step.launches") == busy
         shards = [g["value"] for g in snap["gauges"]
                   if g["name"] == "engine.mesh.shards"]
         assert shards == [float(SHARDS)]

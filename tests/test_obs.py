@@ -55,25 +55,23 @@ def tiny_model():
     return model, params
 
 
-def _engine_config(**overrides):
+def _engine_config():
     from attention_tpu.engine import EngineConfig
 
-    kw = dict(num_pages=32, page_size=128, max_seq_len=256,
-              max_decode_batch=4, max_prefill_rows=2,
-              prefill_chunk=32, token_budget=64,
-              watermark_pages=1)
-    kw.update(overrides)
-    return EngineConfig(**kw)
+    return EngineConfig(num_pages=32, page_size=128, max_seq_len=256,
+                        max_decode_batch=4, max_prefill_rows=2,
+                        prefill_chunk=32, token_budget=64,
+                        watermark_pages=1)
 
 
-def _run_engine(tiny_model, **cfg_overrides):
+def _run_engine(tiny_model):
     from attention_tpu.engine import ServingEngine, replay, synthetic_trace
 
     model, params = tiny_model
     trace = synthetic_trace(4, vocab=43, seed=3, prompt_len_min=4,
                             prompt_len_max=12, max_tokens=3,
                             shared_prefix_len=129, shared_count=2)
-    engine = ServingEngine(model, params, _engine_config(**cfg_overrides))
+    engine = ServingEngine(model, params, _engine_config())
     _summary, outputs = replay(engine, trace)
     return outputs
 
@@ -540,14 +538,10 @@ _PHASES = ("engine.step.schedule", "engine.step.pack",
            "engine.step.fetch", "engine.step.sample")
 
 
-@pytest.mark.parametrize("async_steps", [False, True],
-                         ids=["sync", "async"])
-def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
-                                            tmp_path):
+def test_engine_phase_spans_under_a_capture(tiny_model, tmp_path):
     """A tiny engine stepped under a CPU profiler capture: one
     `engine.step` per step and, inside every busy one, exactly one of
-    each phase span, in order, none overlapping (the async loop's
-    overlap span between dispatch and fetch); fields ride as stats;
+    each phase span, in order, none overlapping; fields ride as stats;
     the capture changes no token, with telemetry off or on; and every
     request's queue wait and prefill time add up to its TTFT."""
     from attention_tpu.engine import ServingEngine, replay, synthetic_trace
@@ -557,10 +551,9 @@ def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
                             prompt_len_max=12, max_tokens=3,
                             shared_prefix_len=129, shared_count=2)
     assert not obs.is_enabled()
-    plain = _run_engine(tiny_model, async_steps=async_steps)
+    plain = _run_engine(tiny_model)
 
-    engine = ServingEngine(model, params,
-                           _engine_config(async_steps=async_steps))
+    engine = ServingEngine(model, params, _engine_config())
     with _capture(tmp_path / "off"):
         _summary, captured = replay(engine, trace)
     assert captured == plain
@@ -568,7 +561,7 @@ def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
     obs.reset()
     try:
         with _capture(tmp_path / "on"):
-            captured_on = _run_engine(tiny_model, async_steps=async_steps)
+            captured_on = _run_engine(tiny_model)
     finally:
         obs.reset()
         obs.disable()
@@ -581,9 +574,6 @@ def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
     assert len(steps) == len(engine.metrics.steps)
     busy = [m for m in engine.metrics.steps
             if m.decode_tokens or m.prefill_tokens]
-    expected = list(_PHASES)
-    if async_steps:
-        expected.insert(4, "engine.step.overlap")
     seen_busy = 0
     for step, m in zip(steps, engine.metrics.steps):
         kids = [r for r in rows if r is not step
@@ -592,7 +582,7 @@ def test_engine_phase_spans_under_a_capture(tiny_model, async_steps,
             assert [k[1] for k in kids] == ["engine.step.schedule"]
             continue
         seen_busy += 1
-        assert [k[1] for k in kids] == expected
+        assert [k[1] for k in kids] == list(_PHASES)
         for before, after in zip(kids, kids[1:]):
             assert before[3] <= after[2]             # none overlaps
         stats = {k[1]: k[4] for k in kids}
@@ -716,22 +706,20 @@ def test_engine_outputs_byte_identical_with_obs_on(tiny_model):
     assert out_on == out_off
 
 
-def test_ragged_async_outputs_byte_identical_with_obs_on(tiny_model):
-    """The zero-overhead contract over the PR 11 serving path: the
-    ragged single-launch step with the async double-buffered host loop
-    must stream byte-identical tokens with telemetry off vs on, and
-    the launch/occupancy counters must land when it is on."""
+def test_ragged_outputs_byte_identical_with_obs_on(tiny_model):
+    """The zero-overhead contract over the serving path: the
+    single-launch step must stream byte-identical tokens with
+    telemetry off vs on, and the launch/occupancy counters must land
+    when it is on."""
     import jax
 
     assert not obs.is_enabled()
-    out_off = _run_engine(tiny_model, step_mode="ragged",
-                          async_steps=True)
+    out_off = _run_engine(tiny_model)
     obs.enable()
     obs.reset()
     try:
         jax.clear_caches()
-        out_on = _run_engine(tiny_model, step_mode="ragged",
-                             async_steps=True)
+        out_on = _run_engine(tiny_model)
         snap = obs.REGISTRY.snapshot()
         counters = {s["name"] for s in snap["counters"]}
         assert "engine.step.launches" in counters

@@ -1,26 +1,22 @@
 """Ragged single-launch serving engine tests (PR: one launch per step).
 
-The engine's default ``step_mode="ragged"`` lowers a whole mixed
-decode/prefill scheduler step onto ONE jitted attention launch over a
-packed token axis (`ops/ragged_paged`).  Pinned here, on tiny CPU
-shapes:
+The engine lowers a whole mixed decode/prefill scheduler step onto ONE
+jitted attention launch over a packed token axis (`ops/ragged_paged`).
+Pinned here, on tiny CPU shapes:
 
   * the kernel itself against the fp64 packed reference
     (`ops.reference.ragged_paged_reference`), mixed and windowed;
   * `ScheduledStep.pack` — the host-side flattening the launch
-    consumes — layout, decode-first ordering, staged-row reuse;
-  * token parity: ragged == two_call on the same trace, greedy and
-    sampled — the two lowerings share the post-processing helpers, so
-    this pins the packed math end to end;
-  * the async double-buffered loop (``async_steps=True``) is
-    token-identical to the sync loop, fault-free and under a chaos
-    fault plan (`chaos.invariants.async_parity_violations`);
-  * snapshot/warm-restart parity with the async loop live (the save
-    path's `quiesce` settles the staged step);
+    consumes — layout, decode-first ordering;
+  * token parity with what the deleted two-call lowering (a fixed-shape
+    decode call plus a fixed-shape prefill call) sampled on the same
+    trace at its last commit, greedy and sampled;
+  * a preempt / watermark fault plan drains with no invariant fired
+    and the fault-free run's tokens;
   * the single-launch property, asserted against the
     ``engine.step.launches`` telemetry counter (ticks per host
     dispatch; the per-trace ``ops.*.calls`` counters corroborate that
-    no legacy paged kernel is dispatched in ragged mode);
+    no paged kernel of `generate_paged` is dispatched);
   * the step returns the logits of the rows it can sample only
     (``(1, slots, vocab)`` once the packed axis is wider than the
     slots), bit-identical to those rows of the whole projection, with
@@ -36,7 +32,7 @@ import pytest
 
 from attention_tpu import obs
 from attention_tpu.chaos.faults import FaultEvent, FaultPlan, run_plan
-from attention_tpu.chaos.invariants import async_parity_violations
+from attention_tpu.chaos.invariants import token_parity_violations
 from attention_tpu.engine import (
     EngineConfig,
     SamplingParams,
@@ -428,26 +424,6 @@ def test_pack_layout_decode_first():
     assert (batch.tables[2:] == -1).all()
 
 
-def test_pack_staged_row_reuse_and_staleness():
-    fresh = _decode_req("d0", (1, 2), 3, [6, 7])
-    staged_row = np.full((3,), -1, np.int32)
-    staged_row[:2] = [6, 7]
-    batch = ScheduledStep(step=0, decode=[fresh]).pack(
-        width=8, slots=2, table_width=3,
-        staged_rows={"d0": (2, staged_row)})
-    assert batch.tables[0].tolist() == [6, 7, -1]
-
-    # a staged row whose page count went stale is discarded: the row is
-    # rebuilt from the request's CURRENT pages
-    stale = _decode_req("d1", (1, 2), 3, [6, 7, 8])
-    old_row = np.full((3,), -1, np.int32)
-    old_row[:2] = [6, 7]
-    batch = ScheduledStep(step=0, decode=[stale]).pack(
-        width=8, slots=2, table_width=3,
-        staged_rows={"d1": (2, old_row)})
-    assert batch.tables[0].tolist() == [6, 7, 8]
-
-
 def test_pack_rejects_overflow():
     reqs = [_decode_req(f"d{i}", (1,), 2, [i]) for i in range(3)]
     with pytest.raises(ValueError, match="slots"):
@@ -462,69 +438,65 @@ def test_pack_rejects_overflow():
 # ----------------------------------------------------- engine token parity
 
 
+#: what the two-call lowering (`step_mode="two_call"`, deleted with
+#: PR 29) sampled at ba2a1ab, its last commit, for the trace of
+#: `test_ragged_matches_two_call_token_parity`, by temperature
+_TWO_CALL_TOKENS = {
+    0.0: {
+        "req-0": [29, 32, 8, 29, 32, 8], "req-1": [26, 34, 36, 29, 11, 30],
+        "req-2": [24, 26, 34, 36, 29, 11], "req-3": [32, 36, 29, 4, 29, 4],
+        "req-4": [40, 30, 40, 33, 29, 30], "req-5": [14, 24, 24, 24, 24, 24],
+        "req-6": [30, 38, 10, 36, 29, 10], "req-7": [9, 28, 29, 32, 8, 29],
+    },
+    0.7: {
+        "req-0": [29, 11, 31, 4, 8, 19], "req-1": [34, 14, 40, 15, 40, 7],
+        "req-2": [24, 33, 29, 41, 24, 9], "req-3": [29, 22, 10, 17, 13, 29],
+        "req-4": [8, 29, 16, 15, 40, 14], "req-5": [27, 36, 12, 28, 11, 14],
+        "req-6": [7, 29, 15, 40, 37, 25], "req-7": [9, 14, 8, 29, 11, 34],
+    },
+}
+
+
 @pytest.mark.parametrize("temperature", [0.0, 0.7])
 def test_ragged_matches_two_call_token_parity(tiny_model, temperature):
-    """The acceptance gate: the packed single-launch step produces,
-    request for request, EXACTLY the tokens of the two-call lowering —
-    mixed prefill/decode steps, prefix-cache hits, greedy and sampled."""
+    """The packed single-launch step produces, request for request,
+    EXACTLY the tokens the two-call lowering sampled — mixed
+    prefill/decode steps, prefix-cache hits, greedy and sampled."""
     model, params = tiny_model
     trace = synthetic_trace(8, vocab=model.vocab, seed=3, max_tokens=6,
                             prompt_len_min=4, prompt_len_max=40,
                             shared_prefix_len=129, shared_count=3,
                             temperature=temperature)
-    _, ragged = replay(
-        ServingEngine(model, params, _cfg(step_mode="ragged")), trace)
-    _, two_call = replay(
-        ServingEngine(model, params, _cfg(step_mode="two_call")), trace)
-    assert ragged == two_call
-    assert all(ragged[e["id"]] for e in trace)
+    _, ragged = replay(ServingEngine(model, params, _cfg()), trace)
+    assert ragged == _TWO_CALL_TOKENS[temperature]
 
 
-def test_ragged_pad_strictly_below_two_call_baseline(tiny_model):
+def test_pad_and_occupancy_account_for_the_packed_width(tiny_model):
     model, params = tiny_model
     trace = synthetic_trace(6, vocab=model.vocab, seed=5, max_tokens=5)
     eng = ServingEngine(model, params, _cfg())
     summary, _ = replay(eng, trace)
-    assert summary["pad_tokens_total"] \
-        < summary["baseline_pad_tokens_total"]
     assert 0.0 < summary["mean_ragged_occupancy"] <= 1.0
-    # every busy step actually measured the launch width
+    padded = 0
     for m in eng.metrics.steps:
-        if m.decode_tokens or m.prefill_tokens:
-            total = m.decode_tokens + m.prefill_tokens
-            width = total + m.pad_tokens
-            assert width == packed_bucket(max(width, 1))  # pow2 bucket
-            assert m.ragged_occupancy == pytest.approx(total / width)
+        total = m.decode_tokens + m.prefill_tokens
+        if not total:
+            # an idle step dispatches nothing and pads nothing
+            assert m.pad_tokens == 0 and m.ragged_occupancy == 0.0
+            continue
+        # a busy step measured the launch width: a pow2 bucket that
+        # holds the real tokens, the remainder counted as pad
+        width = total + m.pad_tokens
+        assert width == packed_bucket(width) and m.pad_tokens < width
+        assert m.ragged_occupancy == pytest.approx(total / width)
+        padded += m.pad_tokens
+    assert summary["pad_tokens_total"] == padded
 
 
-# ---------------------------------------------------------- async parity
-
-
-def test_async_steps_token_identical_to_sync(tiny_model):
-    model, params = tiny_model
-    trace = synthetic_trace(7, vocab=model.vocab, seed=9, max_tokens=6,
-                            temperature=0.6)
-    _, sync_out = replay(
-        ServingEngine(model, params, _cfg(async_steps=False)), trace)
-    async_eng = ServingEngine(model, params, _cfg(async_steps=True))
-    _, async_out = replay(async_eng, trace)
-    assert async_parity_violations(sync_out, async_out) == []
-    # the overlap actually staged rows at some point (decode happened)
-    assert any(m.decode_tokens for m in async_eng.metrics.steps)
-
-
-def test_async_parity_detects_divergence():
-    assert async_parity_violations({"a": [1, 2]}, {"a": [1, 3]})
-    assert async_parity_violations({"a": [1]}, {"a": [1], "b": [2]})
-    assert async_parity_violations(
-        {"a": [1, 2]}, {"a": [9]}, exclude=("a",)) == []
-
-
-def test_async_parity_under_chaos_plan(tiny_model):
-    """Fault injectors compose with the double buffer: the same
-    deterministic preempt/watermark plan replayed sync and async stays
-    token-identical (staging is pure pre-rendering; `pack` drops rows
-    a preemption invalidated)."""
+def test_preempt_watermark_plan_keeps_token_parity(tiny_model):
+    """A deterministic preempt / watermark plan on the step loop: it
+    drains, no invariant fires, and every request's tokens are the
+    fault-free replay's."""
     model, params = tiny_model
     trace = synthetic_trace(6, vocab=model.vocab, seed=13, max_tokens=5)
     plan = FaultPlan(seed=0, events=(
@@ -532,58 +504,27 @@ def test_async_parity_under_chaos_plan(tiny_model):
         FaultEvent(step=4, kind="watermark", arg=2),
         FaultEvent(step=6, kind="preempt", arg=1),
     ))
-    sync_r = run_plan(model, params, _cfg(async_steps=False), trace, plan)
-    async_r = run_plan(model, params, _cfg(async_steps=True), trace, plan)
-    assert sync_r.drained and async_r.drained
-    assert sync_r.violations == [] and async_r.violations == []
-    assert async_parity_violations(sync_r.outputs, async_r.outputs) == []
+    _, clean = replay(ServingEngine(model, params, _cfg()), trace)
+    r = run_plan(model, params, _cfg(), trace, plan)
+    assert r.drained and r.preemptions >= 1
+    assert r.violations == []
+    assert token_parity_violations(clean, r.outputs) == []
 
 
-# ------------------------------------------------- snapshot + warm restart
-
-
-def test_snapshot_restart_parity_with_async_steps(tiny_model, tmp_path):
-    """A snapshot cut mid-flight of the ASYNC loop (quiesce drops the
-    staged step) restores to a sync-identical continuation."""
-    model, params = tiny_model
-    trace = synthetic_trace(5, vocab=model.vocab, seed=11, max_tokens=6,
-                            temperature=0.7)
-    _, baseline = replay(
-        ServingEngine(model, params, _cfg(async_steps=True)), trace)
-
-    outs1: dict[str, list[int]] = {}
-    eng1 = ServingEngine(
-        model, params, _cfg(async_steps=True),
-        on_finish=lambda r: outs1.__setitem__(
-            r.request_id, list(r.output_tokens)))
-    for e in trace:
-        eng1.add_request(e["prompt"], sampling_of(e),
-                         request_id=e["id"], arrival=e["arrival"])
-    for _ in range(4):
-        eng1.step()
-    assert eng1._staged_rows  # the cut lands on a live staged step
-
-    path = str(tmp_path / "snap-async.atpsnap")
-    save(eng1, path)
-
-    outs2: dict[str, list[int]] = {}
-    eng2 = restore(path, model, params,
-                   on_finish=lambda r: outs2.__setitem__(
-                       r.request_id, list(r.output_tokens)))
-    assert eng2.config.async_steps and eng2.config.step_mode == "ragged"
-    assert state_fingerprint(eng2) == state_fingerprint(eng1)
-
-    for eng in (eng1, eng2):
-        steps = 0
-        while eng.scheduler.has_work():
-            eng.step()
-            steps += 1
-            assert steps < 200
-    assert outs2
-    for rid, toks in outs2.items():
-        assert toks == baseline[rid], rid
-    for rid, toks in outs1.items():
-        assert toks == baseline[rid], rid
+def test_rope_sinks_window_model_is_refused_at_its_first_step():
+    """The packed step carries no rotated sink read copy: a rope +
+    sink-token + window model constructs an engine and is refused,
+    typed, by the first step that would serve it."""
+    model = TinyDecoder(vocab=43, dim=32, depth=1, num_q_heads=4,
+                        num_kv_heads=2, impl="flash", dtype=jnp.float32,
+                        rope=True, attn_sinks=4, window=64)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ServingEngine(model, params, _cfg())
+    eng.add_request([1, 2, 3], SamplingParams(max_tokens=2))
+    with pytest.raises(ValueError, match="packed step does not carry.*"
+                                         "generate_paged"):
+        eng.step()
 
 
 # ------------------------------------------------------- launch counters
@@ -600,9 +541,9 @@ def _counter_total(snap, name, **labels):
 
 
 def test_exactly_one_launch_per_busy_step(tiny_model):
-    """The single-launch property, from telemetry: in ragged mode the
-    step loop dispatches EXACTLY one jitted launch per non-empty step
-    and never touches the legacy paged kernels."""
+    """The single-launch property, from telemetry: the step loop
+    dispatches EXACTLY one jitted launch per non-empty step and never
+    touches the paged kernels of `generate_paged`."""
     model, params = tiny_model
     trace = synthetic_trace(6, vocab=model.vocab, seed=7, max_tokens=5,
                             shared_prefix_len=129, shared_count=2)
@@ -619,39 +560,14 @@ def test_exactly_one_launch_per_busy_step(tiny_model):
         busy = sum(1 for m in eng.metrics.steps
                    if m.decode_tokens or m.prefill_tokens)
         assert busy > 0
-        assert _counter_total(
-            snap, "engine.step.launches", mode="ragged") == busy
-        assert _counter_total(
-            snap, "engine.step.launches", mode="two_call") == 0
+        assert _counter_total(snap, "engine.step.launches") == busy
         # the ragged op traced (>= once; ticks per jit trace, not per
-        # execution) and no legacy paged attention was dispatched
+        # execution) and no paged attention was dispatched
         assert _counter_total(snap, "ops.ragged.calls") >= 1
         assert _counter_total(snap, "ops.paged.calls") == 0
         # pad accounting reached the registry
         padded = sum(m.pad_tokens for m in eng.metrics.steps)
         assert _counter_total(snap, "engine.step.pad_tokens") == padded
-    finally:
-        obs.reset()
-        (obs.enable if was else obs.disable)()
-
-
-def test_two_call_mode_counts_two_launches_on_mixed_steps(tiny_model):
-    model, params = tiny_model
-    trace = synthetic_trace(6, vocab=model.vocab, seed=7, max_tokens=5)
-    was = obs.enabled()
-    obs.enable()
-    obs.reset()
-    try:
-        eng = ServingEngine(model, params, _cfg(step_mode="two_call"))
-        replay(eng, trace)
-        snap = obs.REGISTRY.snapshot()
-        launches = sum(
-            (1 if m.decode_tokens else 0) + (1 if m.prefill_tokens else 0)
-            for m in eng.metrics.steps)
-        assert _counter_total(
-            snap, "engine.step.launches", mode="two_call") == launches
-        assert _counter_total(
-            snap, "engine.step.launches", mode="ragged") == 0
     finally:
         obs.reset()
         (obs.enable if was else obs.disable)()
@@ -681,13 +597,13 @@ def ragged_calls(monkeypatch):
     return calls
 
 
-def _mixed_widths_run(tiny_model, **cfg):
+def _mixed_widths_run(tiny_model):
     """Ten slots under prompts of 4-40 tokens: decode-only steps pack
     to width 8 (within the slots), steps with a chunk to 16-48."""
     model, params = tiny_model
     trace = synthetic_trace(8, vocab=model.vocab, seed=3, max_tokens=6,
                             prompt_len_min=4, prompt_len_max=40)
-    eng = ServingEngine(model, params, _cfg(**_SLOTS10, **cfg))
+    eng = ServingEngine(model, params, _cfg(**_SLOTS10))
     _, out = replay(eng, trace)
     assert all(out[e["id"]] for e in trace)
     return eng
@@ -736,19 +652,17 @@ def test_sampled_rows_add_no_compiled_signature(tiny_model, ragged_calls):
     assert _ragged_apply._cache_size() == len(shapes)
 
 
-@pytest.mark.parametrize("step_mode", ["ragged", "two_call"])
-def test_fetch_span_and_counter_report_rows_fetched_and_used(
-        tiny_model, step_mode):
+def test_fetch_span_and_counter_report_rows_fetched_and_used(tiny_model):
     """`engine.step.fetch` carries what the sync moved (``bytes``,
     ``rows``) and what the host reads of it (``used``); the counter
-    ``engine.step.logit_rows`` sums both under `obs.enable`.  A ragged
-    step over the slot count moves ``4 * slots * vocab`` bytes."""
+    ``engine.step.logit_rows`` sums both under `obs.enable`.  A step
+    over the slot count moves ``4 * slots * vocab`` bytes."""
     model, _ = tiny_model
     was = obs.enabled()
     obs.enable()
     obs.reset()
     try:
-        eng = _mixed_widths_run(tiny_model, step_mode=step_mode)
+        eng = _mixed_widths_run(tiny_model)
         events = obs.events()
         snap = obs.REGISTRY.snapshot()
     finally:
@@ -762,20 +676,16 @@ def test_fetch_span_and_counter_report_rows_fetched_and_used(
     for fetch, sample in zip(fetches, by_name["engine.step.sample"]):
         assert fetch["bytes"] == 4 * model.vocab * fetch["rows"]
         assert fetch["used"] == sample["rows"] <= fetch["rows"]
-    if step_mode == "ragged":
-        busy = [m for m in eng.metrics.steps
-                if m.decode_tokens or m.prefill_tokens]
-        assert len(fetches) == len(busy)
-        wide = 0
-        for fetch, dispatch, m in zip(
-                fetches, by_name["engine.step.dispatch"], busy):
-            assert fetch["rows"] == min(dispatch["width"], 10)
-            assert fetch["used"] == m.num_decode_reqs + m.num_prefill_reqs
-            wide += dispatch["width"] > 10
-        assert 0 < wide < len(fetches)
-    else:
-        # the legacy pair fetches every padded position of both calls
-        assert {f["rows"] for f in fetches} == {8, 2 * 32}
+    busy = [m for m in eng.metrics.steps
+            if m.decode_tokens or m.prefill_tokens]
+    assert len(fetches) == len(busy)
+    wide = 0
+    for fetch, dispatch, m in zip(
+            fetches, by_name["engine.step.dispatch"], busy):
+        assert fetch["rows"] == min(dispatch["width"], 10)
+        assert fetch["used"] == m.num_decode_reqs + m.num_prefill_reqs
+        wide += dispatch["width"] > 10
+    assert 0 < wide < len(fetches)
     assert _counter_total(snap, "engine.step.logit_rows", kind="fetched") \
         == sum(f["rows"] for f in fetches)
     assert _counter_total(snap, "engine.step.logit_rows", kind="used") \

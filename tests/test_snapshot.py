@@ -196,9 +196,10 @@ def _corrupt_blob(blob: bytes, mode: str) -> bytes:
         return blob[:-7]
     if mode == "trailing_garbage":
         return blob + b"\x00cruft"
-    if mode == "stale_version":
+    if mode in ("stale_version", "older_version"):
         manifest = json.loads(blob[:nl])
-        manifest["version"] = SNAPSHOT_VERSION + 1
+        manifest["version"] = (SNAPSHOT_VERSION + 1
+                               if mode == "stale_version" else 1)
         return (json.dumps(manifest, sort_keys=True,
                            separators=(",", ":")).encode()
                 + blob[nl:])
@@ -214,12 +215,14 @@ def _corrupt_blob(blob: bytes, mode: str) -> bytes:
 @pytest.mark.parametrize("mode", [
     "bitflip_meta", "bitflip_pools", "bitflip_state",
     "bitflip_requests", "truncate_mid", "truncate_tail",
-    "trailing_garbage", "stale_version", "bad_magic",
+    "trailing_garbage", "stale_version", "older_version", "bad_magic",
 ])
 def test_corruption_is_typed_refusal(tiny_model, tmp_path, mode):
     """Every damage class — per-section bit flip, truncation, trailing
-    bytes, version skew, foreign magic — reads as a non-empty
-    `verify()` report and a `SnapshotCorruptError` from `restore()`."""
+    bytes, version skew either way, foreign magic — reads as a
+    non-empty `verify()` report and a `SnapshotCorruptError` from
+    `restore()`; a sound image of the older format is named as that,
+    not as damage."""
     model, params = tiny_model
     eng = ServingEngine(model, params, _cfg())
     _admit_all(eng, synthetic_trace(3, vocab=model.vocab, seed=5,
@@ -235,7 +238,8 @@ def test_corruption_is_typed_refusal(tiny_model, tmp_path, mode):
         f.write(_corrupt_blob(blob, mode))
     assert verify(bad), mode
     assert not inspect(bad)["valid"]
-    with pytest.raises(SnapshotCorruptError):
+    refused_as = {"older_version": "unsupported snapshot version 1"}
+    with pytest.raises(SnapshotCorruptError, match=refused_as.get(mode)):
         restore(bad, model, params)
     # the pristine file still round-trips (corruption helper sanity)
     assert verify(good) == []
