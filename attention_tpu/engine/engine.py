@@ -56,6 +56,7 @@ from attention_tpu.ops.gated_delta import RaggedStateStep
 from attention_tpu.ops.paged import OutOfPagesError, PagePool
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
+    live_pages,
     packed_bucket,
     recommended_q_tile,
 )
@@ -390,7 +391,9 @@ class ServingEngine:
             prefill_chunk=config.prefill_chunk,
             token_budget=config.token_budget,
         )
-        self.metrics = EngineMetrics()
+        self.metrics = EngineMetrics(
+            table_entries=(config.max_decode_batch
+                           + config.max_prefill_rows) * config.table_width)
         self._step = 0
         # plain int (not itertools.count) so snapshots can persist the
         # position: auto request-ids and FCFS tiebreaks survive restore
@@ -679,7 +682,7 @@ class ServingEngine:
         self._finished_in_step = 0
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
-        pad_tokens = 0
+        pad_tokens = kv_pages = 0
         occupancy = 0.0
         with obs.span("engine.step", step=self._step,
                       queued=len(self.scheduler.waiting),
@@ -700,7 +703,7 @@ class ServingEngine:
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             if not sched.is_empty:
-                width = self._run_ragged(sched)
+                width, kv_pages = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
             wall_s = time.perf_counter() - t0
@@ -724,6 +727,7 @@ class ServingEngine:
                 preemptions_total=self.scheduler.num_preemptions,
                 pad_tokens=pad_tokens,
                 ragged_occupancy=occupancy,
+                kv_pages=kv_pages,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
             )
             self.metrics.record_step(m)
@@ -831,9 +835,10 @@ class ServingEngine:
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
-    def _run_ragged(self, sched: ScheduledStep) -> int:
+    def _run_ragged(self, sched: ScheduledStep) -> tuple[int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
-        the packed width dispatched.
+        the packed width dispatched and the (slot, page) pairs the
+        attention kernel's grid walks for it.
 
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
@@ -855,6 +860,15 @@ class ServingEngine:
             width = packed_bucket(max(total, q_tile))
             batch = sched.pack(width=width, slots=slots,
                                table_width=cfg.table_width)
+            # the kernel's grid bound for this step, counted here by
+            # the rule the device builds it from (a slot the append
+            # poisons there reads 1 on the device, its pages here)
+            kv_pages = int(live_pages(
+                batch.kv_lens + np.diff(batch.cu_q_lens), batch.cu_q_lens,
+                batch.distribution, max_pages=cfg.table_width,
+                page=cfg.page_size, q_tile=q_tile,
+                window=self.model.window,
+                sinks=self.model.attn_sinks or None, xp=np).sum())
         with obs.span("engine.step.upload"):
             tables = jnp.asarray(batch.tables, jnp.int32)
             kv_lens = jnp.asarray(batch.kv_lens, jnp.int32)
@@ -882,7 +896,8 @@ class ServingEngine:
                 _RECURRENT_SLOT_STEPS.inc(sampled)
         with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
                       decode_rows=len(sched.decode),
-                      prefill_tokens=sched.num_prefill_tokens, **fields):
+                      prefill_tokens=sched.num_prefill_tokens,
+                      kv_pages=kv_pages, **fields):
             logits_dev, new_pools = _ragged_apply(
                 self._step_model, self.params, tokens,
                 self._layer_pools(), index)
@@ -896,7 +911,7 @@ class ServingEngine:
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
-        return width
+        return width, kv_pages
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut

@@ -88,6 +88,7 @@ class StepMetrics:
     preemptions_total: int = 0        # cumulative
     pad_tokens: int = 0              # pads dispatched this step
     ragged_occupancy: float = 0.0    # real / dispatched width
+    kv_pages: int = 0                # (slot, page) pairs the kernel walked
     host_overhead_s: float = 0.0     # wall minus the logits device sync
 
     def to_dict(self) -> dict[str, Any]:
@@ -141,7 +142,9 @@ class RequestMetrics:
 class EngineMetrics:
     """Collects step and request rows over an engine's lifetime."""
 
-    def __init__(self):
+    def __init__(self, *, table_entries: int = 0):
+        # slots x table width: what `StepMetrics.kv_pages` is a share of
+        self.table_entries = table_entries
         self.steps: list[StepMetrics] = []
         self.requests: list[RequestMetrics] = []
         self._t0 = time.perf_counter()
@@ -239,6 +242,12 @@ class EngineMetrics:
             "mean_ragged_occupancy": round(
                 sum(s.ragged_occupancy for s in busy) / len(busy), 4)
             if busy else 0.0,
+            # share of the page-table entries the attention kernel's
+            # grid walked: 1.0 is every slot full to the table's end
+            "mean_kv_page_share": round(
+                sum(s.kv_pages for s in busy)
+                / (len(busy) * self.table_entries), 4)
+            if busy and self.table_entries else 0.0,
             "mean_host_overhead_ms": round(
                 sum(s.host_overhead_s for s in busy) * 1e3 / len(busy),
                 3) if busy else 0.0,

@@ -182,13 +182,16 @@ def banded_live(j, valid, block_k: int, window, sinks):
     """Compute-guard predicate paired with :func:`banded_block_clamp`:
     True for blocks holding valid rows inside the window band or pinned
     sink rows.  The two MUST stay mirrored — a block the clamp remaps
-    must never compute, and a live block must keep its identity index."""
+    must never compute, and a live block must keep its identity index.
+    Written in operators alone, so it takes a kernel's scalars, a
+    traced array and the host's NumPy arrays alike (``j >= 0``)."""
     live = j * block_k < valid
     if window is not None:
-        above_min = (j + 1) * block_k > jnp.maximum(valid - window, 0)
+        # a block ends above 0, so the window's start needs no clamp
+        above_min = (j + 1) * block_k > valid - window
         if sinks:
-            above_min = jnp.logical_or(above_min, j * block_k < sinks)
-        live = jnp.logical_and(live, above_min)
+            above_min = above_min | (j * block_k < sinks)
+        live = live & above_min
     return live
 
 

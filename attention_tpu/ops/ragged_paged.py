@@ -15,15 +15,21 @@ a PACKED token axis (the tpu_commons ``ragged_paged_attention`` shape):
     the request: the token at span offset ``s`` attends cache positions
     ``<= kv_len - q_len + s``.
 
-The grid is ``(Hkv * S, max_pages)`` — request-slot minor, kv-head
-major — so one head's packed output block stays VMEM-resident while
-every slot accumulates into its own row span (slots never overlap rows,
-so the read-modify-write at finalize composes).  Per-slot KV pages
-translate through the scalar-prefetched page table exactly like
-`ops.paged`; clamped indices make Pallas elide the DMAs of inactive
-slots and past-the-prefix pages, so pad SLOTS cost nothing — the pad
-waste of a step is just ``T - total_real`` bucketed tokens, not
-``(D - d) + (P*S - real)`` poison rows.
+The grid is ``(Hkv, n)``: for each kv head, the step's ``n`` WORK ITEMS
+— the (slot, logical page) pairs that hold something to attend, slot
+major and page minor (`work_items`).  The list is built on the device
+from the lengths the step already carries, and ``n`` is a traced
+scalar, a grid bound that is a VALUE: a decode-only step of five short
+requests on a 33 x 34 table walks ~35 items a head, a full table all
+1,122, and both run the one compiled kernel.  A slot the step does not
+use, and a table entry past a request's prefix or below its window
+band, is no grid step at all.  One head's packed output block stays
+VMEM-resident while every slot accumulates into its own row span
+(slots never overlap rows, so the read-modify-write at finalize
+composes), and each item's page translates through the
+scalar-prefetched page table like `ops.paged` — so the pad waste of a
+step is just ``T - total_real`` bucketed tokens, not ``(D - d) +
+(P*S - real)`` poison rows.
 
 Static tile discipline: the per-request query tile is ``q_tile`` tokens
 (>= the longest span; the engine buckets it to a power of two), and
@@ -58,11 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from attention_tpu import obs
-from attention_tpu.ops.decode import (
-    banded_block_clamp,
-    banded_live,
-    check_band,
-)
+from attention_tpu.ops.decode import banded_live, check_band
 from attention_tpu.ops.flash import (
     _LOG2E,
     _STAT_LANES,
@@ -219,25 +221,91 @@ def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
     return tile_tokens(t, group)
 
 
+def _band_window(window: int | None, q_tile: int) -> int | None:
+    """The window a slot's PAGES are banded by: it must admit the
+    earliest query row of the tile; per-row exactness comes from the
+    kernel's mask (the decode kernels' chunk rule)."""
+    return None if window is None else window + q_tile - 1
+
+
+def live_pages(kv_lens, cu_q_lens, distribution, *, max_pages: int,
+               page: int, q_tile: int, window: int | None,
+               sinks: int | None, xp=jnp):
+    """The step's work, as an ``(S, max_pages)`` mask: True where slot
+    ``s`` attends logical page ``j``.  ``kv_lens`` are POST-append (-1
+    = poisoned).  A slot counts when it is active (below
+    ``distribution[1]``, with a token in the step), a page of it by the
+    kernel's own compute guard (`ops.decode.banded_live`: inside the
+    prefix, and inside the window band or the sinks).
+
+    Two entries hold no page to attend and are kept all the same, at
+    page 0: an active slot with no live page (poisoned) is still
+    FINALISED, which is what makes its rows NaN; and a step with no
+    active slot still visits slot 0 once, which is what zeroes the
+    output block.
+
+    ``xp`` is the array namespace: the device builds the kernel's grid
+    from this mask (`work_items`), and the engine counts it on the host
+    (``xp=numpy``) for the ``kv_pages`` field of its step span."""
+    s_slots = kv_lens.shape[0]
+    slot = xp.arange(s_slots)
+    active = (slot < distribution[1]) & (cu_q_lens[1:] > cu_q_lens[:-1])
+    j = xp.arange(max_pages)[None, :]
+    live = active[:, None] & banded_live(
+        j, xp.maximum(kv_lens, 0)[:, None], page,
+        _band_window(window, q_tile), sinks)
+    bare = (active & ~live.any(axis=1)) | ((slot == 0) & ~active.any())
+    return live | (bare[:, None] & (j == 0))
+
+
+def work_items(mask):
+    """`live_pages` as the kernel's grid walks it: ``(items, n)``, the
+    first ``n`` of ``items`` being the True entries' flat indices
+    ``s * max_pages + j`` in rising order, every later one the
+    sentinel ``S * max_pages`` (slot ``S``: no slot's neighbour).  One
+    entry longer than the mask, so that the item after the last is
+    always there to read."""
+    flat = mask.reshape(-1)
+    filled = jnp.cumsum(flat.astype(jnp.int32))
+    # item i is where the running count first reaches i + 1: one
+    # compare-and-count fusion (`jnp.nonzero(size=)` would count
+    # through a scatter)
+    items = jnp.searchsorted(
+        filled, jnp.arange(1, flat.shape[0] + 2, dtype=jnp.int32),
+        side="left", method="compare_all")
+    return items.astype(jnp.int32), filled[-1]
+
+
+def _slot_and_page(item, max_pages: int):
+    """A work item's slot and logical page (`work_items`): one scalar
+    divide and one remainder (items are never negative)."""
+    width = jnp.int32(max_pages)
+    return jax.lax.div(item, width), jax.lax.rem(item, width)
+
+
 def _ragged_kernel(
-    lens_ref, cu_ref, dist_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-    acc_scr, m_scr, l_scr,
-    *, s_slots: int, group: int, page: int, q_tile: int, t_pad: int,
+    lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, q_ref, k_ref, v_ref,
+    o_ref, acc_scr, m_scr, l_scr,
+    *, max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
     tile_rows: int, softcap2, window: int | None, sinks: int | None,
     variant: str = "online",
 ):
-    """One (kv-head * slot, logical-page) grid step.
+    """One (kv-head, work item) grid step: item ``i`` is page ``j`` of
+    slot ``r`` (`work_items`).
 
     The output block is the head's FULL packed row axis, index-mapped
-    constant over (slot, page), so it stays VMEM-resident while every
+    constant over the items, so it stays VMEM-resident while every
     slot finalizes its own row span into it — the single-launch analog
-    of one out-block per decode row.  Slot spans never overlap, and the
-    grid is sequential over slots ("arbitrary" semantics), so the
-    masked read-modify-write at finalize is race-free."""
-    rh = pl.program_id(0)
-    j = pl.program_id(1)
-    num_j = pl.num_programs(1)
-    r = jax.lax.rem(rh, s_slots)
+    of one out-block per decode row.  Slot spans never overlap, a
+    slot's items follow each other in page order, and the grid is
+    sequential ("arbitrary" semantics), so the masked
+    read-modify-write at finalize is race-free."""
+    i = pl.program_id(1)
+    r, j = _slot_and_page(items_ref[i], max_pages)
+    first = jnp.logical_or(
+        i == 0,
+        _slot_and_page(items_ref[jnp.maximum(i - 1, 0)], max_pages)[0] != r)
+    last = _slot_and_page(items_ref[i + 1], max_pages)[0] != r
     raw_len = lens_ref[r]
     kv_len = jnp.maximum(raw_len, 0)  # poisoned slots read nothing
     q_start = cu_ref[r]
@@ -254,22 +322,22 @@ def _ragged_kernel(
     tile_start = pl.multiple_of(
         jnp.minimum(q_start * group // 8 * 8, t_pad * group - tile_rows),
         8)
-    # the band must admit the EARLIEST query row's window; per-row
-    # exactness comes from the mask below (the decode kernels' chunk rule)
-    w_eff = (window + q_tile - 1) if window is not None else None
 
-    @pl.when(jnp.logical_and(r == 0, j == 0))
+    @pl.when(i == 0)
     def _zero_out():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    live = jnp.logical_and(active,
-                           banded_live(j, kv_len, page, w_eff, sinks))
+    # false only for the two kept entries that hold no page
+    # (`live_pages`)
+    live = jnp.logical_and(
+        active, banded_live(j, kv_len, page, _band_window(window, q_tile),
+                            sinks))
 
     @pl.when(live)
     def _tile():
@@ -301,7 +369,7 @@ def _ragged_kernel(
         )
         acc_scr[...] = update_acc(acc_scr[...], pv)
 
-    @pl.when(jnp.logical_and(j == num_j - 1, active))
+    @pl.when(jnp.logical_and(last, active))
     def _finalize():
         if variant == "flashd":
             # the accumulator is already normalized (flashd's hidden
@@ -343,12 +411,12 @@ def _ragged_paged_attention_jit(
 
     ``kv_lens`` must be POST-append (run `ragged_paged_append` first);
     pad tokens return zeros, poisoned slots NaN.  ``window``/``sinks``
-    are the decode kernels' per-request logical band, applied before
-    page translation so out-of-window pages never DMA.  ``max_mode``
-    picks the rescaling math ("online"/"flashd"/"amla" — the per-slot
-    masked read-modify-write finalize is exactly the epilogue flashd
-    and amla cheapen); "auto" consults the tuning tables (ragged
-    family) and falls back to "online"."""
+    are the decode kernels' per-request logical band, applied to the
+    work list (`live_pages`), so a page outside the band is never
+    visited.  ``max_mode`` picks the rescaling math ("online"/"flashd"/
+    "amla" — the per-slot masked read-modify-write finalize is exactly
+    the epilogue flashd and amla cheapen); "auto" consults the tuning
+    tables (ragged family) and falls back to "online"."""
     check_softcap(softcap)
     check_band(window, sinks)
     if q.ndim != 4 or q.shape[0] != 1:
@@ -409,21 +477,24 @@ def _ragged_paged_attention_jit(
     qs = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
     qs = qs[0].reshape(hkv, group, t_pad, d).transpose(0, 2, 1, 3)
     qs = qs.reshape(hkv, t_pad * group, d)
-    w_eff = (window + q_tile - 1) if window is not None else None
+    items, n_items = work_items(live_pages(
+        lens, cu, dist, max_pages=max_pages, page=page, q_tile=q_tile,
+        window=window, sinks=sinks))
 
-    def kv_index(rh, j, lens_ref, cu_ref, dist_ref, tbl_ref):
-        # LOGICAL-page clamp (past-the-prefix, and below-the-band with
-        # a window), THEN page translation, all on prefetched scalars:
-        # repeated physical indices make Pallas elide the DMA — pad
-        # slots (length 0) pin to one page and never re-fetch.
-        r = jax.lax.rem(rh, s_slots)
-        valid = jnp.maximum(lens_ref[r], 0)
-        jj = banded_block_clamp(j, valid, page, w_eff, sinks)
-        return (jnp.maximum(tbl_ref[r, jj], 0), rh // s_slots, 0, 0)
+    def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref):
+        # the item's table entry, read on prefetched scalars.  An item
+        # that holds a page to attend has its entry claimed; the kept
+        # entries that hold none (`live_pages`) may read -1, and fetch
+        # page 0 for nobody.
+        r, j = _slot_and_page(items_ref[i], max_pages)
+        return (jnp.maximum(tbl_ref[r, j], 0), hd, 0, 0)
+
+    def head_index(hd, i, *_):
+        return (hd, 0, 0)
 
     tile_rows = _row_tile(q_tile, t_pad, group)
     kernel = functools.partial(
-        _ragged_kernel, s_slots=s_slots, group=group, page=page,
+        _ragged_kernel, max_pages=max_pages, group=group, page=page,
         q_tile=q_tile, t_pad=t_pad, tile_rows=tile_rows,
         softcap2=None if softcap is None else softcap * _LOG2E,
         window=window, sinks=sinks, variant=variant,
@@ -446,19 +517,17 @@ def _ragged_paged_attention_jit(
     if vmem_need > _DEFAULT_SCOPED_VMEM // 2:
         vmem_limit = min(int(vmem_need * 1.5), _MAX_SCOPED_VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(hkv * s_slots, max_pages),
+        num_scalar_prefetch=5,
+        # the second bound is the step's own count of work items, a
+        # traced scalar: one executable whatever the step holds
+        grid=(hkv, n_items),
         in_specs=[
-            pl.BlockSpec((1, t_pad * group, d),
-                         lambda rh, j, lr, cr, dr, tr: (rh // s_slots,
-                                                        0, 0)),
+            pl.BlockSpec((1, t_pad * group, d), head_index),
             pl.BlockSpec((1, 1, page, d), kv_index),
             pl.BlockSpec((1, 1, page, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, t_pad * group, dv),
-                         lambda rh, j, lr, cr, dr, tr: (rh // s_slots,
-                                                        0, 0)),
+            pl.BlockSpec((1, t_pad * group, dv), head_index),
         ],
         scratch_shapes=[
             pltpu.VMEM((tile_rows, dv), jnp.float32),
@@ -466,6 +535,9 @@ def _ragged_paged_attention_jit(
             pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
         ],
     )
+    # the estimate has to be a number, so it is the longest list's: a
+    # table with every entry live
+    full = hkv * s_slots * max_pages
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -478,14 +550,14 @@ def _ragged_paged_attention_jit(
         compiler_params=_compiler_params(("arbitrary", "arbitrary"),
                                          vmem_limit_bytes=vmem_limit),
         cost_estimate=pl.CostEstimate(
-            flops=2 * hkv * s_slots * tile_rows * max_pages * page
-            * (d + dv),
-            bytes_accessed=hkv * s_slots * max_pages * page * (d + dv)
+            flops=2 * full * tile_rows * page * (d + dv),
+            bytes_accessed=full * page * (d + dv)
             * cache.k_pool.dtype.itemsize + qs.size * qs.dtype.itemsize,
-            transcendentals=hkv * s_slots * tile_rows * max_pages * page,
+            transcendentals=full * tile_rows * page,
         ),
         interpret=interpret,
-    )(lens, cu, dist, cache.page_table, qs, cache.k_pool, cache.v_pool)
+    )(lens, cu, dist, cache.page_table, items, qs, cache.k_pool,
+      cache.v_pool)
     out = outs[0] if isinstance(outs, (list, tuple)) else outs
     out = out.reshape(hkv, t_pad, group, dv).transpose(0, 2, 1, 3)
     return out.reshape(1, h, t_pad, dv)

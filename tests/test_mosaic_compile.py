@@ -140,6 +140,30 @@ def _assert_in_place(compiled, model, pools, sharding):
     assert _pool_shaped(compiled, kv_pools, "scatter") == []
 
 
+def _ragged_kernel_calls(compiled):
+    """The compiled step's attention kernels, as (the Mosaic calls, the
+    arrays they take their work lists from): `_ragged_paged_attention_jit`
+    names the custom call, and its sixth operand, after the grid's
+    bound and ``lens`` / ``cu`` / ``dist`` / the page table, is the
+    list."""
+    calls = [line for line in compiled.as_text().splitlines()
+             if re.match(r"\s*%_ragged_paged_attention_jit\S* = ", line)
+             and 'custom_call_target="tpu_custom_call"' in line]
+    lists = {re.search(r"/\*index=5\*/(%[\w.\-]+)", line).group(1)
+             for line in calls}
+    return calls, lists
+
+
+def _assert_one_kernel_a_layer(compiled, model):
+    """One Mosaic call an attention layer whatever the shape, each
+    with a traced grid bound (its first operand, a scalar), and ONE
+    work list a step: the layers' identical copies are merged."""
+    calls, lists = _ragged_kernel_calls(compiled)
+    assert len(calls) == len(model.attention_layers)
+    assert all("operand_layout_constraints={s32[]," in c for c in calls)
+    assert len(lists) == 1
+
+
 def test_ragged_engine_step_at_smoke_width(v5e):
     """The whole jitted engine step `chip_smoke.py` serves: dim 4096,
     32q/4kv x 128, depth 4, vocab 32768, bf16, packed width 512."""
@@ -157,6 +181,7 @@ def test_ragged_engine_step_at_smoke_width(v5e):
     # once, and every byte of them is the caller's buffer
     _assert_in_place(compiled, model, pools, one)
     assert _device_bytes(one, pools) == 2 * 4 * 2048 * 4 * 128 * 128 * 2
+    _assert_one_kernel_a_layer(compiled, model)
 
 
 def _starcoder2_cell():
@@ -195,7 +220,9 @@ def test_ragged_engine_step_updates_the_cells_pools_in_place(
     """At both served configurations' pool shapes the compiled step
     aliases every donated pool (K, V, recurrent state, convolution
     tail) to its result and holds no copy, transpose or scatter of a
-    pool's shape."""
+    pool's shape; and the decode-only step, like the chunk step, holds
+    one attention kernel a layer over a list of the step's live
+    (slot, page) pairs (33 x 34 at 4 KV heads; 9 x 52 at 30)."""
     model, pools, index = cell()
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), I32))["params"]
@@ -204,6 +231,7 @@ def test_ragged_engine_step_updates_the_cells_pools_in_place(
         model, one, params, _a((1, width), I32), pools,
         _ragged_index(width, q_tile, **index))
     _assert_in_place(compiled, model, pools, one)
+    _assert_one_kernel_a_layer(compiled, model)
 
 
 @pytest.mark.parametrize("width,q_tile,rows", [
@@ -247,22 +275,35 @@ def test_ragged_engine_step_head_sharded_over_four_devices(v5e):
     # each device's quarter of the pools, in place there too
     _assert_in_place(compiled, model, ((pool, pool),), by_head)
     assert _device_bytes(by_head, (pool, pool)) == 2 * 256 * 128 * 128 * 2
+    _assert_one_kernel_a_layer(compiled, model)
 
 
-@pytest.mark.parametrize("hq,hkv,width,q_tile,dtype", [
+_CELL_TABLES = (dict(slots=33, max_pages=34), dict(slots=9, max_pages=52))
+
+
+@pytest.mark.parametrize("hq,hkv,width,q_tile,dtype,table", [
     # 16-bit with group < 8: Mosaic refused the unaligned dynamic
     # sublane slice ("cannot statically prove ... a multiple of 8")
-    (8, 2, 512, 256, BF16),     # group 4
-    (8, 4, 24, 4, BF16),        # group 2, a decode-only step
-    (8, 8, 512, 64, BF16),      # group 1 (MHA)
-    (8, 2, 512, 256, F32),
-    (32, 4, 2048, 1024, BF16),  # 26 MB scoped VMEM > the 16 MB default
+    (8, 2, 512, 256, BF16, {}),     # group 4
+    (8, 4, 24, 4, BF16, {}),        # group 2, a decode-only step
+    (8, 8, 512, 64, BF16, {}),      # group 1 (MHA)
+    (8, 2, 512, 256, F32, {}),
+    (32, 4, 2048, 1024, BF16, {}),  # 26 MB scoped VMEM > 16 MB default
+    # the benchmark's cells, decode-only and with a chunk: a grid of
+    # (4, n <= 33 x 34) at a group of 9, of (30, n <= 9 x 52) at 1
+    (36, 4, 8, 8, BF16, _CELL_TABLES[0]),
+    (36, 4, 384, 256, BF16, _CELL_TABLES[0]),
+    (30, 30, 8, 8, BF16, _CELL_TABLES[1]),
+    (30, 30, 384, 256, BF16, _CELL_TABLES[1]),
 ])
-def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype):
-    _compile(ragged_paged_attention,
-             jax.sharding.SingleDeviceSharding(v5e[0]),
-             _a((1, hq, width, 128), dtype),
-             _ragged_cache(hkv, width, q_tile, dtype))
+def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype, table):
+    compiled = _compile(
+        ragged_paged_attention,
+        jax.sharding.SingleDeviceSharding(v5e[0]),
+        _a((1, hq, width, 128), dtype),
+        _ragged_cache(hkv, width, q_tile, dtype, **table))
+    calls, _ = _ragged_kernel_calls(compiled)
+    assert len(calls) == 1
 
 
 @pytest.mark.parametrize("width, q_tile", [

@@ -48,10 +48,12 @@ from attention_tpu.engine.snapshot import restore, save, state_fingerprint
 from attention_tpu.models import TinyDecoder
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
+    live_pages,
     packed_bucket,
     ragged_paged_append,
     ragged_paged_attention,
     tile_tokens,
+    work_items,
 )
 from attention_tpu.ops.reference import ragged_paged_reference
 
@@ -690,3 +692,37 @@ def test_fetch_span_and_counter_report_rows_fetched_and_used(tiny_model):
         == sum(f["rows"] for f in fetches)
     assert _counter_total(snap, "engine.step.logit_rows", kind="used") \
         == sum(f["used"] for f in fetches)
+
+
+def test_dispatch_span_and_metrics_carry_the_kernels_grid_bound(
+        tiny_model, ragged_calls):
+    """``kv_pages``, counted on the host in the pack phase, is the
+    count of work items the device builds for the same step; it rides
+    the `engine.step.dispatch` span and `StepMetrics`, and the summary
+    gives its mean as a share of the 10 x 2 page table."""
+    was = obs.enabled()
+    obs.enable()
+    obs.reset()
+    try:
+        eng = _mixed_widths_run(tiny_model)
+        spans = [e["fields"] for e in obs.events()
+                 if e["name"] == "engine.step.dispatch"]
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()
+    busy = [m for m in eng.metrics.steps
+            if m.decode_tokens or m.prefill_tokens]
+    assert len(spans) == len(busy) == len(ragged_calls) > 0
+    table = 10 * eng.config.table_width
+    for span, m, (_, caches, _) in zip(spans, busy, ragged_calls):
+        c = caches[0]  # as the step was handed it: lengths pre-append
+        _, n = work_items(live_pages(
+            c.kv_lens + jnp.diff(c.cu_q_lens), c.cu_q_lens, c.distribution,
+            max_pages=eng.config.table_width, page=c.page_size,
+            q_tile=c.q_tile, window=None, sinks=None))
+        assert span["kv_pages"] == m.kv_pages == int(n)
+        assert m.num_decode_reqs + m.num_prefill_reqs <= m.kv_pages < table
+    idle = [m for m in eng.metrics.steps if m not in busy]
+    assert all(m.kv_pages == 0 for m in idle)
+    assert eng.metrics.summary()["mean_kv_page_share"] == round(
+        sum(m.kv_pages for m in busy) / (len(busy) * table), 4)
