@@ -52,6 +52,7 @@ from attention_tpu.engine.metrics import (
 )
 from attention_tpu.engine.request import Request, RequestState, SamplingParams
 from attention_tpu.engine.scheduler import ScheduledStep, Scheduler
+from attention_tpu.models.moe import PackedTokens
 from attention_tpu.ops.gated_delta import RaggedStateStep
 from attention_tpu.ops.paged import OutOfPagesError, PagePool
 from attention_tpu.ops.ragged_paged import (
@@ -84,6 +85,12 @@ _RECURRENT_TOKENS = obs.counter(
 _RECURRENT_SLOT_STEPS = obs.counter(
     "engine.recurrent.slot_steps",
     "request slots whose recurrent state a step reads and writes")
+# what the expert layers' routing gave this chip: token-expert pairs of
+# experts held here and of experts held elsewhere, summed over the
+# expert layers and the steps
+_EXPERT_PAIRS = obs.counter(
+    "engine.experts.pairs",
+    "token-expert pairs a step routed, by where the expert is held")
 # mesh-serving surface: how many KV-head shards the per-step launches
 # lower onto (1 = single-device).  In the zero-collective head-sharded
 # design the kernels exchange nothing; the only cross-shard cost is
@@ -140,14 +147,21 @@ class RaggedStepIndex(NamedTuple):
 
 def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
     """Each layer's cache for a packed step: its pool pair (K and V,
-    or recurrent state and convolution tail) with the shared index."""
+    or recurrent state and convolution tail) with the shared index; a
+    layer that keeps nothing (``pools`` holds None for it) is told
+    which tokens are pads."""
     recurrent = set(getattr(model, "recurrent_layers", ()))
-    return tuple(
-        RaggedStateStep(*pair, index.state_rows, index.kv_lens,
-                        index.cu_q_lens, index.token_slot, index.q_span)
-        if layer in recurrent
-        else RaggedPagedStep(*pair, *index[:-1])  # all but state_rows
-        for layer, pair in enumerate(pools))
+
+    def cache(layer, pair):
+        if pair is None:
+            return PackedTokens(index.token_slot)
+        if layer in recurrent:
+            return RaggedStateStep(*pair, index.state_rows, index.kv_lens,
+                                   index.cu_q_lens, index.token_slot,
+                                   index.q_span)
+        return RaggedPagedStep(*pair, *index[:-1])  # all but state_rows
+
+    return tuple(cache(layer, pair) for layer, pair in enumerate(pools))
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -167,8 +181,12 @@ def _ragged_apply(model, params, tokens, pools, index):
     (`RaggedStepIndex`) is shared by every layer and stays the
     caller's.
 
-    Returns ``(logits, pools)``, the logits of the rows a step can
-    sample, not of every packed position.  When the packed axis is
+    Returns ``(logits, pools, expert_pairs)``, the logits of the rows
+    a step can sample, not of every packed position, and for a model
+    with expert layers the sum over them of what each sowed
+    (`models.moe.LatentExperts`: pairs per held expert, pairs of
+    experts held elsewhere, held experts that received a pair), else
+    None.  When the packed axis is
     wider than the slot count, each slot's last row
     (`_slot_last_rows`) is gathered before the final norm and the
     float32 head, and the result is ``(1, slots, vocab)`` with slot
@@ -182,10 +200,18 @@ def _ragged_apply(model, params, tokens, pools, index):
     rows = None
     if tokens.shape[1] > cu.shape[0] - 1:
         rows = _slot_last_rows(cu)
-    logits, steps = model.apply({"params": params}, tokens,
-                                _layer_steps(model, pools, index),
-                                logit_rows=rows)
-    return logits, tuple(step[:2] for step in steps)
+    counted = bool(getattr(model, "expert_layers", ()))
+    out = model.apply({"params": params}, tokens,
+                      _layer_steps(model, pools, index), logit_rows=rows,
+                      mutable=["expert_stats"] if counted else False)
+    pairs = None
+    if counted:
+        out, sown = out
+        pairs = sum(jax.tree_util.tree_leaves(sown))
+    logits, steps = out
+    return logits, tuple(
+        None if pair is None else step[:2]
+        for pair, step in zip(pools, steps)), pairs
 
 
 def _slot_last_rows(cu_q_lens):
@@ -303,6 +329,7 @@ class ServingEngine:
         self._kv_layers = tuple(getattr(
             model, "attention_layers", range(model.depth)))
         self._state_layers = tuple(getattr(model, "recurrent_layers", ()))
+        self._expert_layers = tuple(getattr(model, "expert_layers", ()))
         if config.mesh_shards:
             self.require_pages_only("mesh_shards > 0")
 
@@ -393,7 +420,10 @@ class ServingEngine:
         )
         self.metrics = EngineMetrics(
             table_entries=(config.max_decode_batch
-                           + config.max_prefill_rows) * config.table_width)
+                           + config.max_prefill_rows) * config.table_width,
+            held_experts=getattr(model, "held_experts", 0))
+        # the last step's expert pairs, fetched with its logits
+        self._expert_pairs: np.ndarray | None = None
         self._step = 0
         # plain int (not itertools.count) so snapshots can persist the
         # position: auto request-ids and FCFS tiebreaks survive restore
@@ -684,6 +714,7 @@ class ServingEngine:
         self._last_fetch_s = 0.0
         pad_tokens = kv_pages = 0
         occupancy = 0.0
+        self._expert_pairs = None
         with obs.span("engine.step", step=self._step,
                       queued=len(self.scheduler.waiting),
                       running=len(self.scheduler.running)):
@@ -729,10 +760,26 @@ class ServingEngine:
                 ragged_occupancy=occupancy,
                 kv_pages=kv_pages,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
+                **self._expert_fields(),
             )
             self.metrics.record_step(m)
         self._step += 1
         return m
+
+    def _expert_fields(self) -> dict[str, int]:
+        """The step's expert pairs as `StepMetrics` has them."""
+        pairs = self._expert_pairs
+        if pairs is None:
+            return {}
+        held, absent = pairs[:-2], int(pairs[-2])
+        local = int(held.sum())
+        if obs.is_enabled():
+            _EXPERT_PAIRS.inc(local, where="local")
+            _EXPERT_PAIRS.inc(absent, where="absent")
+        return {"expert_pairs_local": local,
+                "expert_pairs_absent": absent,
+                "expert_load_max": int(held.max()),
+                "experts_reached": int(pairs[-1])}
 
     def run(self, *, max_steps: int | None = None) -> dict[str, Any]:
         """Step until every request finishes; returns the metrics
@@ -804,6 +851,7 @@ class ServingEngine:
     def _layer_pools(self) -> tuple:
         """The pools as `_ragged_apply` takes them: a pair a layer, in
         layer order.  The call consumes these arrays."""
+        # None stays for a layer that keeps nothing (sparse experts)
         pairs: list[Any] = [None] * self.model.depth
         for i, layer in enumerate(self._kv_layers):
             pairs[layer] = (self._k_pools[i], self._v_pools[i])
@@ -817,10 +865,13 @@ class ServingEngine:
         for i, layer in enumerate(self._state_layers):
             self._state_pools[i], self._conv_pools[i] = pairs[layer]
 
-    def _fetch_logits(self, logits_dev, used: int) -> np.ndarray:
+    def _fetch_logits(self, logits_dev, used: int,
+                      pairs_dev=None) -> np.ndarray:
         """The step loop's ONLY device sync: materialize on host the
         logits rows the launch returned — the rows that can be sampled
-        (`_ragged_apply`), of which this step samples ``used``.
+        (`_ragged_apply`), of which this step samples ``used`` — and
+        with them, where the model has expert layers, the step's count
+        of expert pairs (``pairs_dev``, a few hundred bytes).
         Isolated in one hook so (a) per-step host overhead is
         measurable as wall minus time spent here, and (b) fault
         injectors have a single seam to poison."""
@@ -832,6 +883,8 @@ class ServingEngine:
                       rows=rows, used=used):
             t0 = time.perf_counter()
             out = np.asarray(logits_dev, np.float32)
+            if pairs_dev is not None:
+                self._expert_pairs = np.asarray(pairs_dev)
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
@@ -888,7 +941,10 @@ class ServingEngine:
         fields = {}
         if self._state_layers:
             fields = {"recurrent_tokens": total,
-                      "recurrent_slot_steps": sampled}
+                      "recurrent_slot_steps": sampled,
+                      "state_layers": len(self._state_layers)}
+        if self._expert_layers:
+            fields["expert_layers"] = len(self._expert_layers)
         if obs.is_enabled():
             _LAUNCHES.inc()
             if self._state_layers:
@@ -898,11 +954,11 @@ class ServingEngine:
                       decode_rows=len(sched.decode),
                       prefill_tokens=sched.num_prefill_tokens,
                       kv_pages=kv_pages, **fields):
-            logits_dev, new_pools = _ragged_apply(
+            logits_dev, new_pools, pairs_dev = _ragged_apply(
                 self._step_model, self.params, tokens,
                 self._layer_pools(), index)
             self._rebind_pools(new_pools)
-        logits = self._fetch_logits(logits_dev, sampled)
+        logits = self._fetch_logits(logits_dev, sampled, pairs_dev)
         with obs.span("engine.step.sample", rows=sampled):
             row_of = _sampled_logit_rows(batch.cu_q_lens, width)
             num_decode = len(sched.decode)

@@ -90,6 +90,14 @@ class StepMetrics:
     ragged_occupancy: float = 0.0    # real / dispatched width
     kv_pages: int = 0                # (slot, page) pairs the kernel walked
     host_overhead_s: float = 0.0     # wall minus the logits device sync
+    # a model with expert layers (`models.moe.LatentExperts`), summed
+    # over them: token-expert pairs of the experts held here, pairs of
+    # experts held elsewhere, the most pairs one held expert took, and
+    # the (layer, held expert) that took any
+    expert_pairs_local: int = 0
+    expert_pairs_absent: int = 0
+    expert_load_max: int = 0
+    experts_reached: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
@@ -142,9 +150,11 @@ class RequestMetrics:
 class EngineMetrics:
     """Collects step and request rows over an engine's lifetime."""
 
-    def __init__(self, *, table_entries: int = 0):
+    def __init__(self, *, table_entries: int = 0, held_experts: int = 0):
         # slots x table width: what `StepMetrics.kv_pages` is a share of
         self.table_entries = table_entries
+        # experts an expert layer holds: the mean load's denominator
+        self.held_experts = held_experts
         self.steps: list[StepMetrics] = []
         self.requests: list[RequestMetrics] = []
         self._t0 = time.perf_counter()
@@ -198,6 +208,9 @@ class EngineMetrics:
         busy = [s for s in self.steps if s.decode_tokens or s.prefill_tokens]
         mixed = [s for s in busy if s.decode_tokens and s.prefill_tokens]
         ttft_dig, tpot_dig = self.latency_digests()
+        pairs_local = sum(s.expert_pairs_local for s in self.steps)
+        pairs_all = pairs_local + sum(s.expert_pairs_absent
+                                      for s in self.steps)
         wait_dig, prefill_dig = QuantileDigest(), QuantileDigest()
         for r in self.requests:
             wait_dig.add(r.queue_wait_s * 1e3)
@@ -248,6 +261,15 @@ class EngineMetrics:
                 sum(s.kv_pages for s in busy)
                 / (len(busy) * self.table_entries), 4)
             if busy and self.table_entries else 0.0,
+            # expert layers: the share of routed pairs whose expert is
+            # held here (1 / shares at an even router), and the fullest
+            # held expert's pairs over the mean's, both over all steps
+            "local_pair_share": round(
+                pairs_local / pairs_all, 4) if pairs_all else 0.0,
+            "expert_load_max_over_mean": round(
+                sum(s.expert_load_max for s in self.steps)
+                * self.held_experts / pairs_local, 4)
+            if pairs_local and self.held_experts else 0.0,
             "mean_host_overhead_ms": round(
                 sum(s.host_overhead_s for s in busy) * 1e3 / len(busy),
                 3) if busy else 0.0,

@@ -6,7 +6,8 @@ from attention_tpu.models.attention_layer import (  # noqa: F401
     RollingKVCache,
 )
 from attention_tpu.models.cross_attention import GQACrossAttention  # noqa: F401
-from attention_tpu.models.moe import MoEMLP  # noqa: F401
+from attention_tpu.models.moe import LatentExperts, MoEMLP  # noqa: F401
+from attention_tpu.models.mamba import Mamba2Mixer  # noqa: F401
 from attention_tpu.models.pipeline import (  # noqa: F401
     make_pipelined_train_step,
     pipelined_forward,

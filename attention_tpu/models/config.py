@@ -8,6 +8,24 @@ a key of any published configuration, so the file states it beside its
 2 / 3) and ``qk_norm`` (RMSNorm on the q and k projections), both
 false where absent, which is the pre-norm block of StarCoder2.
 Nothing is decided from a model's name.
+
+A configuration with ``hybrid_override_pattern`` is a stack of blocks
+of ONE sublayer each, a letter a layer (`PATTERN_KINDS`): ``M`` a
+Mamba-2 state-space mixer (``mamba_*``, ``ssm_state_size``,
+``n_groups``, ``conv_kernel``, ``use_conv_bias``), ``E`` latent sparse
+experts (``n_routed_experts``, ``num_experts_per_tok``,
+``moe_intermediate_size``, ``moe_latent_size``,
+``moe_shared_expert_intermediate_size``, ``routed_scaling_factor``,
+``norm_topk_prob``), ``*`` attention.  What no configuration has asked
+for yet is refused by the key's name: the letter ``-`` (a dense
+feed-forward layer), a convolution without bias, router weights that
+are not normalised, a group-limited router, a sliding window.  THE SHARE such a file states:
+``n_routed_experts`` is the number of experts HELD HERE and
+``expert_share`` = ``{"index": k, "of": n}`` says that they are share
+``k`` of ``n`` equal shares, so the router is ``n_routed_experts * n``
+wide; ``vocab_size`` is the slice of the vocabulary held here.  Absent,
+the layer holds every expert.  ``attention_rotary`` (true where
+absent) says whether the attention layers rotate q and k.
 """
 
 from __future__ import annotations
@@ -15,30 +33,112 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from attention_tpu.models.transformer import (
-    LAYER_KINDS,
+    ATTENTION,
+    FULL_ATTENTION,
     LINEAR_ATTENTION,
+    SPARSE_EXPERTS,
+    STATE_SPACE,
     TinyDecoder,
 )
 
 _GELU = ("gelu", "gelu_new", "gelu_pytorch_tanh")
 
+#: the layer a letter of ``hybrid_override_pattern`` names
+PATTERN_KINDS = {"M": STATE_SPACE, "E": SPARSE_EXPERTS, "*": ATTENTION}
 
-def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
-    """The program's decoder at the configuration's sizes."""
+
+def _common(config: dict, *, impl: str) -> dict:
+    """The fields every decoder has, and the check of the head size."""
     dim = int(config["hidden_size"])
     heads = int(config["num_attention_heads"])
     head_dim = config.get("head_dim")
     if head_dim is not None and dim // heads != int(head_dim):
         raise ValueError("hidden_size / num_attention_heads != head_dim")
-    depth = int(config["num_hidden_layers"])
+    theta = config.get("rope_theta")
+    if theta is None:
+        theta = (config.get("rope_parameters") or {}).get("rope_theta")
+    rope = theta is not None and bool(config.get("attention_rotary", True))
+    return dict(
+        vocab=int(config["vocab_size"]), dim=dim,
+        depth=int(config["num_hidden_layers"]), num_q_heads=heads,
+        num_kv_heads=int(config.get("num_key_value_heads", heads)),
+        impl=impl, dtype=jnp.dtype(config.get("torch_dtype", "bfloat16")),
+        rope=rope, rope_theta=float(theta) if rope else 10000.0)
+
+
+def _sublayer_decoder(config: dict, *, impl: str) -> TinyDecoder:
+    """The decoder of a ``hybrid_override_pattern``: see the module's
+    docstring for the keys."""
+    common = _common(config, impl=impl)
+    pattern = config["hybrid_override_pattern"][:common["depth"]]
+    unknown = sorted(set(pattern) - set(PATTERN_KINDS))
+    if unknown or len(pattern) != common["depth"]:
+        raise ValueError(
+            f"hybrid_override_pattern must name {common['depth']} layers "
+            f"by the letters {sorted(PATTERN_KINDS)}; it has "
+            f"{len(pattern)} and cannot build {unknown}")
+    kinds = tuple(PATTERN_KINDS[letter] for letter in pattern)
+    if config.get("sliding_window") is not None:
+        raise ValueError("sliding_window: the single-sublayer attention "
+                         "layer is full attention")
+    fields = {}
+    if STATE_SPACE in kinds:
+        if config.get("mamba_hidden_act", "silu") != "silu":
+            raise ValueError("mamba_hidden_act: the state-space mixer "
+                             "gates with silu")
+        if not config["use_conv_bias"]:
+            raise ValueError("use_conv_bias: the state-space mixer's "
+                             "convolution carries a bias")
+        fields.update(
+            ssm_heads=int(config["mamba_num_heads"]),
+            ssm_head_dim=int(config["mamba_head_dim"]),
+            ssm_state=int(config["ssm_state_size"]),
+            ssm_groups=int(config["n_groups"]),
+            ssm_conv=int(config["conv_kernel"]))
+    if SPARSE_EXPERTS in kinds:
+        if config.get("mlp_hidden_act") != "relu2":
+            raise ValueError(
+                f"mlp_hidden_act {config.get('mlp_hidden_act')!r}: the "
+                "experts are relu2")
+        if not config["norm_topk_prob"]:
+            raise ValueError("norm_topk_prob: the router normalises the "
+                             "chosen experts' weights")
+        if (int(config.get("n_group", 1)), int(config.get("topk_group", 1))
+                ) != (1, 1):
+            raise ValueError("n_group / topk_group: the router has no "
+                             "group limit")
+        share = config.get("expert_share") or {"index": 0, "of": 1}
+        held = int(config["n_routed_experts"])
+        fields.update(
+            experts=held * int(share["of"]), experts_held=held,
+            experts_share=int(share["index"]),
+            experts_top_k=int(config["num_experts_per_tok"]),
+            experts_latent=int(config["moe_latent_size"]),
+            experts_hidden=int(config["moe_intermediate_size"]),
+            experts_shared_hidden=int(
+                config.get("n_shared_experts", 0)
+                and config["moe_shared_expert_intermediate_size"]),
+            experts_scale=float(config["routed_scaling_factor"]))
+    return TinyDecoder(
+        **common, layer_types=kinds, sublayer=tuple(sorted(fields.items())),
+        norm_eps=float(config.get("norm_eps", 1e-6)))
+
+
+def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
+    """The program's decoder at the configuration's sizes."""
+    if "hybrid_override_pattern" in config:
+        return _sublayer_decoder(config, impl=impl)
+    common = _common(config, impl=impl)
+    dim, depth = common["dim"], common["depth"]
+    two_sublayers = (FULL_ATTENTION, LINEAR_ATTENTION)
     kinds = config.get("layer_types")
     if kinds is not None:
         # a configuration cut in depth keeps the published list whole
         # and serves its first layers (whole periods of the pattern)
         kinds = tuple(kinds[:depth])
-        if len(kinds) != depth or set(kinds) - set(LAYER_KINDS):
+        if len(kinds) != depth or set(kinds) - set(two_sublayers):
             raise ValueError(
-                f"layer_types must name {depth} layers of {LAYER_KINDS}")
+                f"layer_types must name {depth} layers of {two_sublayers}")
     act = config.get("hidden_act", "gelu")
     hidden = int(config["intermediate_size"])
     if act in _GELU:
@@ -49,9 +149,6 @@ def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
         mlp = {"mlp_hidden": hidden, "mlp_act": "silu"}
     else:
         raise ValueError(f"no MLP for hidden_act {act!r}")
-    theta = config.get("rope_theta")
-    if theta is None:
-        theta = (config.get("rope_parameters") or {}).get("rope_theta")
     linear = {}
     if kinds is not None and LINEAR_ATTENTION in kinds:
         if (config["linear_num_key_heads"]
@@ -67,13 +164,7 @@ def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
         }
     window = config.get("sliding_window")
     return TinyDecoder(
-        vocab=int(config["vocab_size"]), dim=dim, depth=depth,
-        num_q_heads=heads,
-        num_kv_heads=int(config.get("num_key_value_heads", heads)),
-        impl=impl, dtype=jnp.dtype(config.get("torch_dtype", "bfloat16")),
-        window=None if window is None else int(window),
-        rope=theta is not None,
-        rope_theta=10000.0 if theta is None else float(theta),
+        **common, window=None if window is None else int(window),
         layer_types=kinds, **linear, **mlp,
         post_norm=bool(config.get("post_norm", False)),
         qk_norm=bool(config.get("qk_norm", False)))

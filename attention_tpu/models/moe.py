@@ -27,10 +27,18 @@ into the ``losses`` collection; `train.loss_fn` picks it up.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from attention_tpu.ops.experts import (
+    expert_layout,
+    grouped_experts,
+    row_tile,
+)
 
 
 def _active_mesh_axes() -> tuple | None:
@@ -157,3 +165,128 @@ class MoEMLP(nn.Module):
                  reduce_fn=lambda a, b_: a + b_, init_fn=lambda: 0.0)
 
         return y.reshape(b, s, d).astype(x.dtype)
+
+
+# -- the served expert layer ---------------------------------------------------
+
+class PackedTokens(NamedTuple):
+    """What a packed engine step tells a layer that keeps no cache:
+    ``token_slot`` (T,) int32, -1 for the pad tokens of the step."""
+
+    token_slot: jax.Array
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+class ReluSquaredMLP(nn.Module):
+    """``down(relu(up x)^2)`` at a free width: the shared expert of
+    `LatentExperts`."""
+
+    hidden: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.Dense(self.hidden, use_bias=False, dtype=self.dtype,
+                     name="up_proj")(x)
+        return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                        name="down_proj")(_relu2(h))
+
+
+def sigmoid_top_k(x, router, bias, *, top_k: int, scale: float):
+    """The router of `LatentExperts`, in float32 whatever ``x`` is:
+    scores ``sigmoid(x W_r)``, the ``top_k`` experts by ``score +
+    bias``, weighted by their scores normalised over the chosen, times
+    ``scale``.  Returns ``(chosen (T, k) int32, weight (T, k)
+    float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    weight = jnp.take_along_axis(scores, chosen, axis=-1)
+    weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weight * scale
+
+
+class LatentExperts(nn.Module):
+    """Sparse experts in a latent space, as ONE CHIP'S SHARE of an
+    expert-parallel deployment: (B, S, D) -> (B, S, D).
+
+        s = sigmoid(W_r x)                    float32, all ``num_experts``
+        chosen = top_k of (s + bias)          the bias selects, s weighs
+        g_i = scale * s_i / sum_chosen s
+        u = W_dn x                            D -> latent
+        r = sum_{i chosen, HELD HERE} g_i W2_i relu(W1_i u)^2
+        out = W_up r + shared(x)              the shared expert at D
+
+    The layer holds experts ``[held * share, held * (share + 1))`` of
+    ``num_experts``: it routes over all of them and computes its own
+    experts' part of ``r``; what the experts held elsewhere would add
+    is left out (the exchange that brings it belongs to the
+    deployment, not to this chip).  Pairs are counted by held expert
+    and run through one grouped product (`ops.experts`): no token is
+    dropped whatever the imbalance, and no shape depends on the
+    routing.  ``PackedTokens`` marks a step's pad tokens, which take no
+    expert.
+
+    Sows into the ``expert_stats`` collection ``pairs`` (held + 2,)
+    int32: the pairs of each held expert, the pairs of experts held
+    elsewhere, and the held experts that received a pair."""
+
+    num_experts: int
+    held: int
+    share: int = 0
+    top_k: int = 2
+    latent: int = 64
+    hidden: int = 128
+    shared_hidden: int = 0
+    scale: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array, cache: PackedTokens | None = None):
+        if not (0 < self.held and self.held * (self.share + 1)
+                <= self.num_experts and 1 <= self.top_k <= self.num_experts):
+            raise ValueError(
+                f"share {self.share} of {self.held} experts, top "
+                f"{self.top_k}, does not fit {self.num_experts} experts")
+        batch, seq, dim = x.shape
+        tokens = batch * seq
+        xt = x.reshape(tokens, dim)
+        valid = (jnp.ones((tokens,), bool) if cache is None
+                 else jnp.asarray(cache.token_slot).reshape(tokens) >= 0)
+        chosen, weight = sigmoid_top_k(
+            xt, self.param("router", nn.initializers.lecun_normal(),
+                           (dim, self.num_experts), jnp.float32),
+            self.param("router_bias", nn.initializers.zeros,
+                       (self.num_experts,), jnp.float32),
+            top_k=self.top_k, scale=self.scale)
+        w1 = self.param("experts_up", nn.initializers.lecun_normal(),
+                        (self.held, self.latent, self.hidden), jnp.float32)
+        w2 = self.param("experts_down", nn.initializers.lecun_normal(),
+                        (self.held, self.hidden, self.latent), jnp.float32)
+        u = nn.Dense(self.latent, use_bias=False, dtype=self.dtype,
+                     name="latent_down")(xt)
+
+        tile = row_tile(tokens)
+        layout = expert_layout(chosen - self.held * self.share, valid,
+                               held=self.held, tile=tile)
+        y = grouped_experts(u[layout.row_token], w1, w2, layout, tile=tile)
+        here = layout.dest < y.shape[0]
+        # rows that hold no pair were never written: select, not scale
+        picked = jnp.where(here[..., None],
+                           y[jnp.minimum(layout.dest, y.shape[0] - 1)], 0.0)
+        r = jnp.sum(picked * weight[..., None], axis=1)
+        out = nn.Dense(dim, use_bias=False, dtype=self.dtype,
+                       name="latent_up")(r.astype(self.dtype))
+        if self.shared_hidden:
+            out = out + ReluSquaredMLP(self.shared_hidden, dtype=self.dtype,
+                                       name="shared_expert")(xt)
+        local = jnp.sum(layout.counts)
+        absent = jnp.sum(valid) * self.top_k - local
+        self.sow("expert_stats", "pairs", jnp.concatenate(
+            [layout.counts, jnp.stack([absent, jnp.sum(layout.counts > 0)])
+             ]).astype(jnp.int32))
+        return out.reshape(batch, seq, dim)

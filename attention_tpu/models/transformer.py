@@ -8,6 +8,8 @@ shardings, and (c) serve as the `__graft_entry__` forward step.
 
 from __future__ import annotations
 
+from typing import Any
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -18,12 +20,22 @@ from attention_tpu.models.attention_layer import (
     RollingKVCache,
 )
 from attention_tpu.models.linear_attention import GatedDeltaNet
-from attention_tpu.models.moe import MoEMLP
+from attention_tpu.models.mamba import Mamba2Mixer
+from attention_tpu.models.moe import LatentExperts, MoEMLP
 
-#: the kinds of mixer a decoder layer can have (``layer_types``)
+#: the kinds of layer a decoder can have (``layer_types``).  The first
+#: two are blocks of TWO sublayers, a mixer (attention or Gated
+#: DeltaNet) and an MLP, each under its own residual
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
-LAYER_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION)
+#: the rest are blocks of ONE sublayer, ``x + f(norm(x))``: attention
+#: alone, a Mamba-2 state-space mixer, or latent sparse experts
+#: (`LatentExperts`)
+ATTENTION = "attention"
+STATE_SPACE = "state_space"
+SPARSE_EXPERTS = "sparse_experts"
+SUBLAYER_KINDS = (ATTENTION, STATE_SPACE, SPARSE_EXPERTS)
+LAYER_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION) + SUBLAYER_KINDS
 
 _ACTIVATIONS = {"gelu": nn.gelu, "silu": nn.silu}
 
@@ -147,6 +159,65 @@ class TransformerBlock(nn.Module):
         return x if cache is None else (x, cache)
 
 
+class SublayerBlock(nn.Module):
+    """A block of one sublayer under one residual, ``x + f(norm(x))``,
+    ``f`` by ``kind`` (`SUBLAYER_KINDS`).  With a cache (a packed
+    engine step) it returns ``(x, cache)``: K / V pools for attention,
+    state pools for the state-space mixer, and for the experts, which
+    keep nothing, the cache as it came."""
+
+    kind: str
+    num_q_heads: int
+    num_kv_heads: int
+    head_dim: int
+    impl: str = "flash"
+    dtype: jnp.dtype = jnp.bfloat16
+    rope: bool = False
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    ssm_heads: int = 0        # the state-space mixer's sizes
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    experts: int = 0          # the sparse layer: routed over, held, share
+    experts_held: int = 0
+    experts_share: int = 0
+    experts_top_k: int = 1
+    experts_latent: int = 0
+    experts_hidden: int = 0
+    experts_shared_hidden: int = 0
+    experts_scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, x, cache=None):
+        y = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype)(x)
+        if self.kind == ATTENTION:
+            out = GQASelfAttention(
+                num_q_heads=self.num_q_heads, num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim, impl=self.impl, dtype=self.dtype,
+                rope=self.rope, rope_theta=self.rope_theta)(y, cache)
+        elif self.kind == STATE_SPACE:
+            out = Mamba2Mixer(
+                num_heads=self.ssm_heads, head_dim=self.ssm_head_dim,
+                state_dim=self.ssm_state, num_groups=self.ssm_groups,
+                conv_width=self.ssm_conv,
+                norm_eps=self.norm_eps, dtype=self.dtype)(y, cache)
+        elif self.kind == SPARSE_EXPERTS:
+            out = LatentExperts(
+                num_experts=self.experts, held=self.experts_held,
+                share=self.experts_share, top_k=self.experts_top_k,
+                latent=self.experts_latent, hidden=self.experts_hidden,
+                shared_hidden=self.experts_shared_hidden,
+                scale=self.experts_scale, dtype=self.dtype)(y, cache)
+        else:
+            raise ValueError(f"unknown sublayer kind {self.kind!r}; one of "
+                             f"{SUBLAYER_KINDS}")
+        if cache is not None and self.kind in (ATTENTION, STATE_SPACE):
+            out, cache = out
+        return x + out if cache is None else (x + out, cache)
+
+
 class TinyDecoder(nn.Module):
     """Decoder-only LM: embed -> N blocks -> norm -> logits.
 
@@ -192,12 +263,16 @@ class TinyDecoder(nn.Module):
     # tensor-parallel with the framework's own kernels.
     tp_axis: str | None = None
     mesh: "jax.sharding.Mesh | None" = None
-    # One mixer kind per layer (`LAYER_KINDS`); None: attention in every
+    # One kind per layer (`LAYER_KINDS`); None: attention in every
     # block.  A linear-attention layer is a `GatedDeltaNet` of
     # ``linear_heads`` heads, keys of ``linear_key_dim`` and values of
     # ``linear_value_dim``; it keeps a recurrent state per request in
-    # place of K and V rows (`recurrent_state_shapes`).
+    # place of K and V rows (`recurrent_state_shapes`), and so does a
+    # state-space layer (`SublayerBlock`, which reads ``sublayer``: its
+    # fields by name, as a tuple of pairs so that the module hashes).
     layer_types: tuple[str, ...] | None = None
+    sublayer: tuple[tuple[str, Any], ...] = ()
+    norm_eps: float = 1e-6    # the final norm's, and a SublayerBlock's
     linear_heads: int = 0
     linear_key_dim: int = 0
     linear_value_dim: int = 0
@@ -219,22 +294,45 @@ class TinyDecoder(nn.Module):
                 f"depth is {self.depth}")
         return tuple(self.layer_types)
 
+    def _layers_of(self, *kinds: str) -> tuple[int, ...]:
+        return tuple(i for i, kind in enumerate(self.kinds) if kind in kinds)
+
     @property
     def attention_layers(self) -> tuple[int, ...]:
         """Layers that keep K and V rows (paged KV pools)."""
-        return tuple(i for i, kind in enumerate(self.kinds)
-                     if kind == FULL_ATTENTION)
+        return self._layers_of(FULL_ATTENTION, ATTENTION)
 
     @property
     def recurrent_layers(self) -> tuple[int, ...]:
         """Layers that keep one state per request instead."""
-        return tuple(i for i, kind in enumerate(self.kinds)
-                     if kind == LINEAR_ATTENTION)
+        return self._layers_of(LINEAR_ATTENTION, STATE_SPACE)
+
+    @property
+    def expert_layers(self) -> tuple[int, ...]:
+        """Layers of sparse experts: they keep nothing per request, and
+        report their pairs (`LatentExperts`)."""
+        return self._layers_of(SPARSE_EXPERTS)
+
+    @property
+    def held_experts(self) -> int:
+        """Experts each expert layer holds here (its share of those it
+        routes over); 0 for a model without expert layers."""
+        return dict(self.sublayer).get("experts_held", 0)
 
     def recurrent_state_shapes(self) -> tuple[tuple, tuple]:
-        """Per request and recurrent layer: the float32 state
-        ``(heads, key_dim, value_dim)`` and the convolution's tail
-        ``(conv - 1, channels)`` in the model's dtype."""
+        """Per request and recurrent layer: the float32 state (Gated
+        DeltaNet: ``(heads, key_dim, value_dim)``; state-space:
+        ``(heads, head_dim, state)``) and the convolution's tail
+        ``(conv - 1, channels)`` in the model's dtype.  One pool shape
+        serves every recurrent layer, so a model has one kind of them."""
+        if self._layers_of(STATE_SPACE):
+            if self._layers_of(LINEAR_ATTENTION):
+                raise ValueError("state-space and linear-attention layers "
+                                 "in one model would need two pool shapes")
+            f = dict(self.sublayer)
+            h, p, n = f["ssm_heads"], f["ssm_head_dim"], f["ssm_state"]
+            return (h, p, n), (f.get("ssm_conv", 4) - 1,
+                               h * p + 2 * f.get("ssm_groups", 1) * n)
         h, dk, dv = (self.linear_heads, self.linear_key_dim,
                      self.linear_value_dim)
         return (h, dk, dv), (self.linear_conv - 1, h * (2 * dk + dv))
@@ -252,6 +350,19 @@ class TinyDecoder(nn.Module):
             else TransformerBlock
         )
         for i, kind in enumerate(self.kinds):
+            if kind in SUBLAYER_KINDS:
+                block = SublayerBlock(
+                    kind=kind, num_q_heads=self.num_q_heads,
+                    num_kv_heads=self.num_kv_heads, head_dim=head_dim,
+                    impl=self.impl, dtype=self.dtype, rope=self.rope,
+                    rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                    **dict(self.sublayer), name=f"SublayerBlock_{i}")
+                if caches is None:
+                    x = block(x)
+                else:
+                    x, c = block(x, caches[i])
+                    new_caches.append(c)
+                continue
             # explicit name: keeps the param tree identical whether or
             # not the block class is wrapped in nn.remat
             block = block_cls(
@@ -292,7 +403,7 @@ class TinyDecoder(nn.Module):
                 new_caches.append(c)
         if logit_rows is not None:
             x = jnp.take(x, logit_rows, axis=1)
-        x = nn.RMSNorm(dtype=self.dtype)(x)
+        x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype)(x)
         if return_hidden:
             # pre-head activations for memory-bounded losses (chunked
             # cross-entropy re-projects per chunk instead of

@@ -320,10 +320,11 @@ def ragged_gated_delta(q, k, v, log_a, beta, step: RaggedStateStep, *,
                                    interpret=interpret)
 
 
-def ragged_causal_conv(x, weight, step: RaggedStateStep):
+def ragged_causal_conv(x, weight, step: RaggedStateStep, bias=None):
     """Depthwise causal convolution over a packed step.  ``x``: (T,
     channels) the layer's inputs on the packed axis, ``weight``: (K,
-    channels) with the newest tap last.  A token at offset ``j`` of its
+    channels) with the newest tap last, ``bias``: (channels,) added to
+    every output, or None.  A token at offset ``j`` of its
     span reads ``x`` at offsets ``j - K + 1 .. j``; offsets before the
     span come from the slot's row of ``conv_pool`` (zeros when the slot
     starts a request).  Returns ``(y (T, channels), conv_pool after
@@ -347,6 +348,8 @@ def ragged_causal_conv(x, weight, step: RaggedStateStep):
         before = tails[slot, jnp.clip(taps - 1 + src, 0, taps - 2)]
         y = y + (jnp.where((src >= 0)[:, None], here, before)
                  .astype(jnp.float32) * weight[taps - 1 - back])
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     # the new tails: the last K - 1 of (old tail ; span)
     q_lens = cu[1:] - cu[:-1]
     src = q_lens[:, None] - (taps - 1) + jnp.arange(taps - 1)[None, :]

@@ -202,7 +202,15 @@ def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
     """Static query-tile width (tokens) for a step whose longest span
     is ``max_q_len``: pow2-bucketed for jit-signature reuse, sublane-
     aligned, optionally widened toward the tuned ``ragged`` family
-    ``block_q`` row count when the measured-dispatch tables ship one."""
+    ``block_q`` row count when the measured-dispatch tables ship one.
+
+    A step that holds a prefill chunk (``max_q_len`` > 1) never gets a
+    tile under 8 tokens.  A group that is no multiple of 8 is held to
+    that by the sublane rule anyway (`tile_tokens`); a group that is
+    one (16 query heads a KV head) would otherwise get tiles of 1, 2
+    and 4 for the short remainders of a prompt, each a compiled
+    program at every packed width.  A decode-only step keeps the tile
+    `tile_tokens` gives one token."""
     t = packed_bucket(max_q_len, minimum=1)
     try:
         from attention_tpu.tuning.lookup import key_fields, lookup
@@ -218,6 +226,8 @@ def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
                 t = min(t, cap)
     except Exception:  # noqa: BLE001 - tuning must never break dispatch
         pass
+    if max_q_len > 1:
+        t = max(t, 8)
     return tile_tokens(t, group)
 
 

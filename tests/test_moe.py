@@ -183,3 +183,152 @@ def test_moe_bad_ep_axis_raises_under_mesh(rng):
     with jax.sharding.set_mesh(mesh):
         with pytest.raises(ValueError, match="not in the current mesh"):
             mod.init(jax.random.PRNGKey(0), x)
+
+
+# -- the served expert layer (`LatentExperts`, `ops.experts`) ------------------
+
+E, HELD, TOP_K, DIM, LATENT, HIDDEN = 16, 4, 4, 64, 32, 48
+
+
+def _served_layer(share, held=HELD):
+    from attention_tpu.models.moe import LatentExperts
+
+    return LatentExperts(num_experts=E, held=held, share=share, top_k=TOP_K,
+                         latent=LATENT, hidden=HIDDEN, shared_hidden=96,
+                         scale=5.0, dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def whole_layer():
+    """The uncut layer: every expert held (one share of one), weights
+    from the benchmark reference's initialiser, a selection bias that
+    is not zero."""
+    from benchmark import harness
+
+    reference = harness.load_module("configs",
+                                    "nemotron-3-super-120b_reference")
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 24, DIM), jnp.float32)
+    layer = _served_layer(0, held=E)
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(1), x)["params"]
+    params = reference.init_params(shapes, jax.random.PRNGKey(2))
+    params["router_bias"] = 0.3 * jax.random.normal(jax.random.PRNGKey(3),
+                                                    (E,))
+    return reference, x, params
+
+
+def _dense(reference, params, x, share=0):
+    with jax.default_matmul_precision("highest"):
+        return reference._experts(params, x[0], share=share, top_k=TOP_K,
+                                  scale=5.0, quant=lambda t: t)[0]
+
+
+def _share_of(params, share):
+    cut = slice(share * HELD, (share + 1) * HELD)
+    return dict(params, experts_up=params["experts_up"][cut],
+                experts_down=params["experts_down"][cut])
+
+
+def test_the_shares_add_up_to_the_uncut_layer(whole_layer):
+    """THE SHARE TEST: the routed parts of all 4 shares of 4 experts,
+    with what every chip computes alike (the shared expert; ``W_up`` is
+    linear) counted once, are the uncut reference's layer."""
+    reference, x, params = whole_layer
+    with jax.default_matmul_precision("highest"):
+        whole = _dense(reference, params, x)
+        shared = reference._feed_forward(params["shared_expert"], x[0],
+                                         quant=lambda t: t)
+        total, counted = 0.0, 0
+        for share in range(E // HELD):
+            out, sown = _served_layer(share).apply(
+                {"params": _share_of(params, share)}, x,
+                mutable=["expert_stats"])
+            # each share against the reference given the same share
+            np.testing.assert_allclose(
+                out[0], _dense(reference, _share_of(params, share), x,
+                               share), atol=2e-5)
+            total = total + (out[0] - shared)
+            (pairs,) = sown["expert_stats"]["pairs"]
+            assert int(pairs[:HELD].sum() + pairs[HELD]) == 24 * TOP_K
+            counted += int(pairs[:HELD].sum())
+        assert counted == 24 * TOP_K     # every pair is some share's
+        np.testing.assert_allclose(total + shared, whole, atol=5e-5)
+        # the whole layer through the program, all 16 held
+        out = _served_layer(0, held=E).apply({"params": params}, x)
+        np.testing.assert_allclose(out[0], whole, atol=2e-5)
+
+
+def test_a_skewed_router_drops_nothing(whole_layer):
+    """One held expert takes a pair of EVERY token (its selection bias
+    lifts it over all others): 24 rows in tiles of 8 where an even
+    load gives 6; nothing is dropped and the counts add up."""
+    from attention_tpu.models.moe import PackedTokens
+
+    reference, x, params = whole_layer
+    skew = dict(params, router_bias=params["router_bias"].at[5].set(9.0))
+    layer = _served_layer(1)                     # holds experts 4-7
+    with jax.default_matmul_precision("highest"):
+        out, sown = layer.apply({"params": _share_of(skew, 1)}, x,
+                                mutable=["expert_stats"])
+        np.testing.assert_allclose(
+            out[0], _dense(reference, _share_of(skew, 1), x, 1), atol=2e-5)
+        (pairs,) = sown["expert_stats"]["pairs"]
+        assert int(pairs[1]) == 24 == int(pairs[:HELD].max())
+        assert int(pairs[:HELD].sum() + pairs[HELD]) == 24 * TOP_K
+        assert int(pairs[HELD + 1]) == int((pairs[:HELD] > 0).sum())
+        # pad tokens of a packed step take no expert and move no row
+        slot = jnp.where(jnp.arange(24) < 20, 0, -1)
+        padded, sown = layer.apply({"params": _share_of(skew, 1)}, x,
+                                   PackedTokens(slot),
+                                   mutable=["expert_stats"])
+        (pairs,) = sown["expert_stats"]["pairs"]
+        assert int(pairs[1]) == 20
+        assert int(pairs[:HELD].sum() + pairs[HELD]) == 20 * TOP_K
+        np.testing.assert_allclose(padded[0, :20], out[0, :20], atol=1e-6)
+
+
+def test_the_router_decides_in_float32(whole_layer):
+    """bf16 activations feed a float32 router: the scores are those of
+    the float32 product, not of a bf16 one."""
+    from attention_tpu.models.moe import sigmoid_top_k
+
+    _, x, params = whole_layer
+    xb = x.astype(jnp.bfloat16)
+    chosen, weight = sigmoid_top_k(
+        xb[0], params["router"], params["router_bias"], top_k=TOP_K,
+        scale=5.0)
+    scores = jax.nn.sigmoid(jnp.dot(xb[0].astype(jnp.float32),
+                                    params["router"], precision="highest"))
+    want = jax.lax.top_k(scores + params["router_bias"], TOP_K)[1]
+    assert weight.dtype == jnp.float32
+    assert np.array_equal(np.sort(chosen, -1), np.sort(want, -1))
+    np.testing.assert_allclose(weight.sum(-1), 5.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("tokens, top_k, held, tile", [
+    (24, 4, 4, 8), (7, 3, 2, 8), (40, 22, 64, 8), (200, 4, 4, 32)])
+def test_the_layout_puts_every_held_pair_on_a_row_of_its_expert(
+        tokens, top_k, held, tile):
+    from attention_tpu.ops.experts import (
+        expert_layout, layout_rows, row_tile)
+
+    rng = np.random.default_rng(tokens)
+    local = np.stack([rng.permutation(3 * held)[:top_k] - held
+                      for _ in range(tokens)]).astype(np.int32)
+    valid = rng.random(tokens) < 0.8
+    lay = expert_layout(jnp.asarray(local), jnp.asarray(valid), held=held,
+                        tile=tile)
+    rows = layout_rows(tokens, top_k, held, tile)
+    here = (local >= 0) & (local < held) & valid[:, None]
+    dest = np.asarray(lay.dest)
+    assert (dest[~here] == rows).all() and (dest[here] < rows).all()
+    assert len(set(dest[here])) == here.sum()           # a row a pair
+    assert np.array_equal(np.asarray(lay.counts),
+                          np.bincount(local[here], minlength=held))
+    tile_expert = np.asarray(lay.tile_expert)
+    assert (tile_expert[dest[here] // tile] == local[here]).all()
+    token = np.broadcast_to(np.arange(tokens)[:, None], local.shape)
+    assert (np.asarray(lay.row_token)[dest[here]] == token[here]).all()
+    assert int(lay.num_tiles) == sum(-(-c // tile)
+                                     for c in np.asarray(lay.counts))
+    assert int(lay.num_tiles) * tile <= rows
+    assert row_tile(8) == 8 and row_tile(64) == 8 and row_tile(384) == 32

@@ -32,11 +32,13 @@ from attention_tpu.engine.engine import RaggedStepIndex, _ragged_apply
 from attention_tpu.models import TinyDecoder, decoder_from_config
 from attention_tpu.ops import (
     decode,
+    experts,
     flash,
     gated_delta,
     paged,
     quant,
     ragged_paged,
+    ssm,
 )
 from attention_tpu.ops.flash_vjp import flash_attention_diff
 from attention_tpu.ops.ragged_paged import (
@@ -63,7 +65,8 @@ def v5e():
         pytest.skip(f"no compile-only TPU topology here: "
                     f"{type(e).__name__}: {str(e)[:200]}")
     with pytest.MonkeyPatch.context() as mp:
-        for mod in (flash, decode, paged, quant, ragged_paged, gated_delta):
+        for mod in (flash, decode, paged, quant, ragged_paged, gated_delta,
+                    ssm, experts):
             mp.setattr(mod, "_should_interpret", lambda: False)
         yield list(topo.devices)
 
@@ -324,6 +327,81 @@ def test_gated_delta_kernel_compiles_at_the_published_widths(
              _a((width, heads, dk), F32), _a((width, heads, dk), F32),
              _a((width, heads, dv), BF16), _a((width, heads), F32),
              _a((width, heads), F32), step)
+
+
+@pytest.mark.parametrize("width, q_tile", [
+    (64, 1), (96, 8), (48, 24), (256, 192), (384, 256)])
+def test_ssm_scan_kernel_compiles_at_the_published_widths(v5e, width,
+                                                          q_tile):
+    """The state-space layers' kernel at Nemotron-3-Super's sizes (128
+    heads of 64, state 128, 8 groups), 65 slots: decode-only steps at
+    a tile of one token, chunks of 8, 64 and 128 (the cell's widest
+    step: a chunk of 256 beside 64 decode rows)."""
+    heads, p, n, groups, slots = 128, 64, 128, 8, 65
+    step = gated_delta.RaggedStateStep(
+        _a((slots + 1, heads, p, n), F32),
+        _a((slots + 1, 3, heads * p + 2 * groups * n), BF16),
+        _a((slots,), I32), _a((slots,), I32), _a((slots + 1,), I32),
+        _a((width,), I32), _a((q_tile,), I32))
+    _compile(ssm.ragged_ssm_scan, jax.sharding.SingleDeviceSharding(v5e[0]),
+             _a((width, heads, p), BF16), _a((width, heads), F32),
+             _a((width, heads), F32), _a((width, groups, n), BF16),
+             _a((width, groups, n), BF16), step)
+
+
+@pytest.mark.parametrize("tokens", [8, 64, 192, 768])
+def test_grouped_experts_kernel_compiles_at_the_published_widths(v5e,
+                                                                 tokens):
+    """The routed experts' grouped product at Nemotron-3-Super's sizes
+    (64 held experts of 1024 x 2688, float32 as stored, top-22): row
+    tiles of 8, 16 and 32."""
+    tile = experts.row_tile(tokens)
+    rows = experts.layout_rows(tokens, 22, 64, tile)
+    layout = experts.ExpertLayout(
+        _a((tokens, 22), I32), _a((rows,), I32), _a((rows // tile,), I32),
+        _a((), I32), _a((64,), I32))
+    _compile(functools.partial(experts.grouped_experts, tile=tile),
+             jax.sharding.SingleDeviceSharding(v5e[0]),
+             _a((rows, 1024), BF16), _a((64, 1024, 2688), F32),
+             _a((64, 2688, 1024), F32), layout)
+
+
+def _nemotron_cell():
+    """The benchmark's Nemotron-3-Super cell whole: 11 one-sublayer
+    blocks (5 state-space, 5 sparse-expert, 1 attention), 64 + 1 slots
+    with a state row each and the spare, 1048 pages at 2 KV heads."""
+    path = (pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
+            / "nemotron-3-super-120b.json")
+    model = decoder_from_config(json.loads(path.read_text()))
+    state, conv = model.recurrent_state_shapes()
+    pool = _a((1048, 2, 128, 128), BF16)
+    pair = (_a((66, *state), F32), _a((66, *conv), BF16))
+    pools = tuple(pair if layer in model.recurrent_layers
+                  else (pool, pool) if layer in model.attention_layers
+                  else None for layer in range(model.depth))
+    return model, pools, dict(slots=65, max_pages=18, recurrent=True)
+
+
+@pytest.mark.parametrize("width,q_tile", [(384, 256), (64, 1)],
+                         ids=["chunk_step", "decode_only"])
+def test_the_nemotron_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
+    """The whole served step of the Nemotron cell compiles for one v5e
+    chip: every donated pool aliased to its result, and arguments +
+    temporaries inside the chip's 16 GB."""
+    model, pools, index = _nemotron_cell()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = _compile_step(
+        model, one, params, _a((1, width), I32), pools,
+        _ragged_index(width, q_tile, **index))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.0e9, total     # 12.55 GB of arguments + 0.23 GB
+    pooled = sum(np.prod(a.shape) * a.dtype.itemsize
+                 for a in jax.tree.leaves(pools))
+    assert mem.alias_size_in_bytes >= pooled
 
 
 def test_ladder_kernels_compile(v5e):
