@@ -342,7 +342,9 @@ class TinyDecoder(nn.Module):
                  return_hidden: bool = False,
                  logit_rows: jax.Array | None = None):  # (B, S) int32
         head_dim = self.dim // self.num_q_heads
-        x = nn.Embed(self.vocab, self.dim, dtype=self.dtype)(tokens)
+        # rows first, then the cast: `Embed(dtype=...)` would cast the
+        # whole float32 table in every call and only then take the rows
+        x = nn.Embed(self.vocab, self.dim)(tokens).astype(self.dtype)
         new_caches = []
         block_cls = (
             nn.remat(TransformerBlock)
