@@ -202,11 +202,10 @@ class FaultInjector:
                      and engine.pool.refcount(p) == 1), None)
         if page is None:
             return False
-        for layer in range(len(engine._k_pools)):
-            engine._k_pools[layer] = \
-                engine._k_pools[layer].at[page].set(jnp.nan)
-            engine._v_pools[layer] = \
-                engine._v_pools[layer].at[page].set(jnp.nan)
+        # a latent-attention model keeps no V pools
+        for pools in (engine._k_pools, engine._v_pools):
+            for layer, pool in enumerate(pools):
+                pools[layer] = pool.at[page].set(jnp.nan)
         return True
 
 

@@ -43,6 +43,7 @@ from attention_tpu.obs import trace as _trace
 from attention_tpu.engine.allocator import BlockAllocator
 from attention_tpu.engine.errors import (
     DeadlineExceededError,
+    LatentCacheUnsupportedError,
     RecurrentStateUnsupportedError,
 )
 from attention_tpu.engine.metrics import (
@@ -119,12 +120,20 @@ class StepLimitExceededError(RuntimeError):
 
 def require_pages_only(model, feature: str) -> None:
     """Refuse ``feature`` for a model with recurrent layers: it carries
-    KV pages only, and pages alone do not restore such a request."""
+    KV pages only, and pages alone do not restore such a request.  And
+    for a model with latent-attention layers: it carries K / V pool
+    pairs, one a layer, and such a model keeps one pool a sublayer."""
     layers = tuple(getattr(model, "recurrent_layers", ()))
     if layers:
         raise RecurrentStateUnsupportedError(
             f"{feature} knows only KV pages, and {type(model).__name__} "
             f"keeps a recurrent state per request in layers {list(layers)}")
+    layers = tuple(getattr(model, "latent_layers", ()))
+    if layers:
+        raise LatentCacheUnsupportedError(
+            f"{feature} carries a K and a V pool a layer, and "
+            f"{type(model).__name__} keeps ONE latent pool for each "
+            f"attention sublayer of layers {list(layers)}")
 
 
 class RaggedStepIndex(NamedTuple):
@@ -149,8 +158,11 @@ def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
     """Each layer's cache for a packed step: its pool pair (K and V,
     or recurrent state and convolution tail) with the shared index; a
     layer that keeps nothing (``pools`` holds None for it) is told
-    which tokens are pads."""
+    which tokens are pads; a double layer (``pools`` holds the latent
+    pool of each of its attention sublayers) gets a step a sublayer,
+    each of ONE pool."""
     recurrent = set(getattr(model, "recurrent_layers", ()))
+    latent = set(getattr(model, "latent_layers", ()))
 
     def cache(layer, pair):
         if pair is None:
@@ -159,9 +171,28 @@ def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
             return RaggedStateStep(*pair, index.state_rows, index.kv_lens,
                                    index.cu_q_lens, index.token_slot,
                                    index.q_span)
+        if layer in latent:
+            return tuple(RaggedPagedStep(pool, None, *index[:-1])
+                         for pool in pair)
         return RaggedPagedStep(*pair, *index[:-1])  # all but state_rows
 
     return tuple(cache(layer, pair) for layer, pair in enumerate(pools))
+
+
+def _step_pools(model, pools, steps) -> tuple:
+    """What `_ragged_apply` hands back of the layers' ``steps``: the
+    arrays ``pools`` held, in their places."""
+    latent = set(getattr(model, "latent_layers", ()))
+
+    def kept(layer, pair, step):
+        if pair is None:
+            return None
+        if layer in latent:
+            return tuple(sub.k_pool for sub in step)
+        return step[:2]
+
+    return tuple(kept(layer, pair, step)
+                 for layer, (pair, step) in enumerate(zip(pools, steps)))
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -173,8 +204,9 @@ def _ragged_apply(model, params, tokens, pools, index):
     Width and the index's q_tile marker are pow2-bucketed by the
     caller, so distinct compiled signatures stay O(log max_tokens).
 
-    ``pools`` holds one pair of arrays a layer, in layer order, and is
-    DONATED: the step writes its rows into them in place
+    ``pools`` holds one pair of arrays a layer, in layer order (for a
+    double layer the two latent pools of its attention sublayers), and
+    is DONATED: the step writes its rows into them in place
     (`ragged_paged_append`, the recurrent layers' kernel) and hands
     the same buffers back, so the caller's arrays are gone after the
     call and it rebinds from the result.  ``index``
@@ -209,9 +241,21 @@ def _ragged_apply(model, params, tokens, pools, index):
         out, sown = out
         pairs = sum(jax.tree_util.tree_leaves(sown))
     logits, steps = out
-    return logits, tuple(
-        None if pair is None else step[:2]
-        for pair, step in zip(pools, steps)), pairs
+    return logits, _step_pools(model, pools, steps), pairs
+
+
+def _qk_pairs(kv_before: np.ndarray, q_lens: np.ndarray,
+              window: int | None) -> int:
+    """(query token, key) pairs an attention sublayer attends in a
+    step: slot by slot, query token ``t`` of ``q`` new ones on ``kv``
+    cached reaches ``kv + t + 1`` keys, ``window`` at most."""
+    kv, q = kv_before.astype(np.int64), q_lens.astype(np.int64)
+    # the first ``free`` of a slot's query tokens reach every key
+    free = q if window is None else np.clip(window - kv, 0, q)
+    pairs = free * kv + free * (free + 1) // 2
+    if window is not None:
+        pairs = pairs + (q - free) * window
+    return int(pairs.sum())
 
 
 def _slot_last_rows(cu_q_lens):
@@ -326,11 +370,21 @@ class ServingEngine:
         self.on_token = on_token
         self.on_finish = on_finish
         self.on_timeout = on_timeout
+        # the layer of every attention SUBLAYER (a double layer has
+        # two), and the layers whose sublayers keep one latent pool
         self._kv_layers = tuple(getattr(
-            model, "attention_layers", range(model.depth)))
+            model, "attention_sublayers", range(model.depth)))
+        self._latent_layers = tuple(getattr(model, "latent_layers", ()))
         self._state_layers = tuple(getattr(model, "recurrent_layers", ()))
         self._expert_layers = tuple(getattr(model, "expert_layers", ()))
         if config.mesh_shards:
+            if self._latent_layers:
+                from attention_tpu.parallel.serving import MeshConfigError
+
+                raise MeshConfigError(
+                    f"mesh_shards {config.mesh_shards}: the mesh engine "
+                    "shards KV HEADS, and a latent-attention sublayer has "
+                    "one; its four-chip layout shards pages, not heads")
             self.require_pages_only("mesh_shards > 0")
 
         # mesh mode: a 1D "tp" mesh of the first mesh_shards devices;
@@ -380,15 +434,19 @@ class ServingEngine:
             self._step_model = model
             self._pool_sharding = None
 
-        head_dim = model.dim // model.num_q_heads
         dtype = config.cache_dtype or model.dtype
-        pool_shape = (config.num_pages, model.num_kv_heads,
-                      config.page_size, head_dim)
-        # one pool pair per ATTENTION layer, in layer order
-        self._k_pools = [self._place_pool(jnp.zeros(pool_shape, dtype))
-                         for _ in self._kv_layers]
-        self._v_pools = [self._place_pool(jnp.zeros(pool_shape, dtype))
-                         for _ in self._kv_layers]
+        # what an attention sublayer keeps, by the model's own word: K
+        # and V pools of the head size, or ONE latent pool and no V
+        kv_heads, widths = model.kv_pool_widths()
+
+        def pools(width):
+            shape = (config.num_pages, kv_heads, config.page_size, width)
+            return [self._place_pool(jnp.zeros(shape, dtype))
+                    for _ in self._kv_layers]
+
+        # one pool (pair) per attention SUBLAYER, in layer order
+        self._k_pools = pools(widths[0])
+        self._v_pools = pools(widths[1]) if len(widths) > 1 else []
         # one state and one convolution-tail pool per RECURRENT layer;
         # the last row is nobody's (empty slots of a step land there)
         state_slots = 0
@@ -712,7 +770,7 @@ class ServingEngine:
         self._finished_in_step = 0
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
-        pad_tokens = kv_pages = 0
+        pad_tokens = kv_pages = qk_pairs = 0
         occupancy = 0.0
         self._expert_pairs = None
         with obs.span("engine.step", step=self._step,
@@ -734,7 +792,7 @@ class ServingEngine:
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             if not sched.is_empty:
-                width, kv_pages = self._run_ragged(sched)
+                width, kv_pages, qk_pairs = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
             wall_s = time.perf_counter() - t0
@@ -759,6 +817,7 @@ class ServingEngine:
                 pad_tokens=pad_tokens,
                 ragged_occupancy=occupancy,
                 kv_pages=kv_pages,
+                attn_qk_pairs=qk_pairs,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
                 **self._expert_fields(),
             )
@@ -771,15 +830,22 @@ class ServingEngine:
         pairs = self._expert_pairs
         if pairs is None:
             return {}
-        held, absent = pairs[:-2], int(pairs[-2])
-        local = int(held.sum())
+        # (`models.moe.pair_counts`) the held experts' pairs, then the
+        # absent pairs, the experts reached and, where the layers have
+        # zero-compute experts, the pairs that went to those
+        n = self.metrics.held_experts
+        held, (absent, reached, *more) = pairs[:n], pairs[n:].tolist()
+        local, zero = int(held.sum()), sum(more)
         if obs.is_enabled():
             _EXPERT_PAIRS.inc(local, where="local")
             _EXPERT_PAIRS.inc(absent, where="absent")
+            if more:
+                _EXPERT_PAIRS.inc(zero, where="zero")
         return {"expert_pairs_local": local,
                 "expert_pairs_absent": absent,
                 "expert_load_max": int(held.max()),
-                "experts_reached": int(pairs[-1])}
+                "experts_reached": reached,
+                "expert_pairs_zero": zero}
 
     def run(self, *, max_steps: int | None = None) -> dict[str, Any]:
         """Step until every request finishes; returns the metrics
@@ -854,14 +920,22 @@ class ServingEngine:
         # None stays for a layer that keeps nothing (sparse experts)
         pairs: list[Any] = [None] * self.model.depth
         for i, layer in enumerate(self._kv_layers):
-            pairs[layer] = (self._k_pools[i], self._v_pools[i])
+            # K and V, or a latent sublayer's ONE pool; a double
+            # layer's two sublayers follow each other into its tuple
+            pairs[layer] = (pairs[layer] or ()) + tuple(
+                pools[i] for pools in (self._k_pools, self._v_pools)
+                if pools)
         for i, layer in enumerate(self._state_layers):
             pairs[layer] = (self._state_pools[i], self._conv_pools[i])
         return tuple(pairs)
 
     def _rebind_pools(self, pairs) -> None:
-        for i, layer in enumerate(self._kv_layers):
-            self._k_pools[i], self._v_pools[i] = pairs[layer]
+        kept = (pool for layer in dict.fromkeys(self._kv_layers)
+                for pool in pairs[layer])
+        for i in range(len(self._kv_layers)):     # `_layer_pools`' order
+            self._k_pools[i] = next(kept)
+            if self._v_pools:
+                self._v_pools[i] = next(kept)
         for i, layer in enumerate(self._state_layers):
             self._state_pools[i], self._conv_pools[i] = pairs[layer]
 
@@ -888,10 +962,11 @@ class ServingEngine:
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
-    def _run_ragged(self, sched: ScheduledStep) -> tuple[int, int]:
+    def _run_ragged(self, sched: ScheduledStep) -> tuple[int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
-        the packed width dispatched and the (slot, page) pairs the
-        attention kernel's grid walks for it.
+        the packed width dispatched, the (slot, page) pairs the
+        attention kernel's grid walks for it, and the (query token,
+        key) pairs one attention sublayer attends.
 
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
@@ -922,6 +997,8 @@ class ServingEngine:
                 page=cfg.page_size, q_tile=q_tile,
                 window=self.model.window,
                 sinks=self.model.attn_sinks or None, xp=np).sum())
+            qk_pairs = _qk_pairs(batch.kv_lens, np.diff(batch.cu_q_lens),
+                                 self.model.window)
         with obs.span("engine.step.upload"):
             tables = jnp.asarray(batch.tables, jnp.int32)
             kv_lens = jnp.asarray(batch.kv_lens, jnp.int32)
@@ -943,8 +1020,14 @@ class ServingEngine:
             fields = {"recurrent_tokens": total,
                       "recurrent_slot_steps": sampled,
                       "state_layers": len(self._state_layers)}
+        if self._latent_layers:
+            fields["latent_layers"] = len(self._kv_layers)
         if self._expert_layers:
             fields["expert_layers"] = len(self._expert_layers)
+            if getattr(self.model, "zero_experts", 0):
+                # the pairs they take are known at the fetch
+                # (`StepMetrics.expert_pairs_zero`)
+                fields["zero_experts"] = self.model.zero_experts
         if obs.is_enabled():
             _LAUNCHES.inc()
             if self._state_layers:
@@ -967,7 +1050,7 @@ class ServingEngine:
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
-        return width, kv_pages
+        return width, kv_pages, qk_pairs
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut

@@ -186,3 +186,15 @@ class RecurrentStateUnsupportedError(RuntimeError):
     this for such a model
     (`ServingEngine.require_pages_only`).  State checkpoints at page
     boundaries would lift it (ROADMAP R2)."""
+
+
+class LatentCacheUnsupportedError(RuntimeError):
+    """A feature that carries a K pool and a V pool a layer met a
+    model whose attention sublayers keep ONE latent pool each.
+
+    Snapshot save / restore, prefix-store export and import and the
+    fleet's KV hand-off read and write K / V pool pairs, one a layer;
+    a latent-attention model keeps one pool a SUBLAYER and no V pool,
+    and each of them raises this for it
+    (`ServingEngine.require_pages_only`).  The local prefix cache is
+    not among them: page ids are head- and pool-agnostic."""

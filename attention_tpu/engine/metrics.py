@@ -89,15 +89,20 @@ class StepMetrics:
     pad_tokens: int = 0              # pads dispatched this step
     ragged_occupancy: float = 0.0    # real / dispatched width
     kv_pages: int = 0                # (slot, page) pairs the kernel walked
+    # (query token, key) pairs one attention sublayer attends: over the
+    # step's slots, each query token times the keys it reaches
+    attn_qk_pairs: int = 0
     host_overhead_s: float = 0.0     # wall minus the logits device sync
-    # a model with expert layers (`models.moe.LatentExperts`), summed
-    # over them: token-expert pairs of the experts held here, pairs of
-    # experts held elsewhere, the most pairs one held expert took, and
-    # the (layer, held expert) that took any
+    # a model with expert layers (`models.moe.LatentExperts`,
+    # `GatedExperts`), summed over them: token-expert pairs of the
+    # experts held here, pairs of experts held elsewhere, the most
+    # pairs one held expert took, the (layer, held expert) that took
+    # any, and the pairs that went to zero-compute experts
     expert_pairs_local: int = 0
     expert_pairs_absent: int = 0
     expert_load_max: int = 0
     experts_reached: int = 0
+    expert_pairs_zero: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
@@ -209,8 +214,9 @@ class EngineMetrics:
         mixed = [s for s in busy if s.decode_tokens and s.prefill_tokens]
         ttft_dig, tpot_dig = self.latency_digests()
         pairs_local = sum(s.expert_pairs_local for s in self.steps)
-        pairs_all = pairs_local + sum(s.expert_pairs_absent
-                                      for s in self.steps)
+        pairs_zero = sum(s.expert_pairs_zero for s in self.steps)
+        pairs_all = pairs_local + pairs_zero + sum(
+            s.expert_pairs_absent for s in self.steps)
         wait_dig, prefill_dig = QuantileDigest(), QuantileDigest()
         for r in self.requests:
             wait_dig.add(r.queue_wait_s * 1e3)
@@ -261,11 +267,20 @@ class EngineMetrics:
                 sum(s.kv_pages for s in busy)
                 / (len(busy) * self.table_entries), 4)
             if busy and self.table_entries else 0.0,
+            # (query token, key) pairs an attention sublayer attends a
+            # busy step
+            "mean_attn_qk_pairs": round(
+                sum(s.attn_qk_pairs for s in busy) / len(busy), 1)
+            if busy else 0.0,
             # expert layers: the share of routed pairs whose expert is
             # held here (1 / shares at an even router), and the fullest
             # held expert's pairs over the mean's, both over all steps
             "local_pair_share": round(
                 pairs_local / pairs_all, 4) if pairs_all else 0.0,
+            # the share that went to zero-compute experts, which cost
+            # nothing wherever the token is
+            "zero_pair_share": round(
+                pairs_zero / pairs_all, 4) if pairs_all else 0.0,
             "expert_load_max_over_mean": round(
                 sum(s.expert_load_max for s in self.steps)
                 * self.held_experts / pairs_local, 4)

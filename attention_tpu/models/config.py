@@ -26,6 +26,24 @@ are not normalised, a group-limited router, a sliding window.  THE SHARE such a 
 wide; ``vocab_size`` is the slice of the vocabulary held here.  Absent,
 the layer holds every expert.  ``attention_rotary`` (true where
 absent) says whether the attention layers rotate q and k.
+
+A configuration with ``attention_method`` is a stack of ``num_layers``
+DOUBLE layers (`transformer.ShortcutExpertsBlock`): two latent
+attention sublayers (``"MLA"``: ``q_lora_rank``, ``kv_lora_rank``,
+``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+``mla_scale_q_lora`` / ``mla_scale_kv_lora``, ``rope_theta``), two
+dense gated feed-forwards (``ffn_hidden_size``) and ONE branch of
+gated experts (``n_routed_experts`` and ``expert_share`` as above,
+``expert_ffn_hidden_size``, ``moe_topk``, ``routed_scaling_factor``)
+behind a softmax router that also routes over ``zero_expert_num``
+zero-compute experts (``zero_expert_type``); the norms read
+``rms_norm_eps``.  The decoder has ONE KV head, the latent, and every
+query head is its group.  Refused by the key's name: an
+``attention_method`` other than ``"MLA"``, ``mla_scale_*`` false or
+absent, a ``zero_expert_type`` other than ``"identity"``,
+``rope_scaling``, ``sliding_window``, ``norm_topk_prob`` true (the
+softmax router's weights are not normalised), a group-limited router,
+``n_shared_experts``.
 """
 
 from __future__ import annotations
@@ -36,6 +54,7 @@ from attention_tpu.models.transformer import (
     ATTENTION,
     FULL_ATTENTION,
     LINEAR_ATTENTION,
+    SHORTCUT_EXPERTS,
     SPARSE_EXPERTS,
     STATE_SPACE,
     TinyDecoder,
@@ -64,6 +83,13 @@ def _common(config: dict, *, impl: str) -> dict:
         num_kv_heads=int(config.get("num_key_value_heads", heads)),
         impl=impl, dtype=jnp.dtype(config.get("torch_dtype", "bfloat16")),
         rope=rope, rope_theta=float(theta) if rope else 10000.0)
+
+
+def _no_group_limit(config: dict) -> None:
+    if (int(config.get("n_group", 1)), int(config.get("topk_group", 1))
+            ) != (1, 1):
+        raise ValueError("n_group / topk_group: the router has no "
+                         "group limit")
 
 
 def _sublayer_decoder(config: dict, *, impl: str) -> TinyDecoder:
@@ -103,10 +129,7 @@ def _sublayer_decoder(config: dict, *, impl: str) -> TinyDecoder:
         if not config["norm_topk_prob"]:
             raise ValueError("norm_topk_prob: the router normalises the "
                              "chosen experts' weights")
-        if (int(config.get("n_group", 1)), int(config.get("topk_group", 1))
-                ) != (1, 1):
-            raise ValueError("n_group / topk_group: the router has no "
-                             "group limit")
+        _no_group_limit(config)
         share = config.get("expert_share") or {"index": 0, "of": 1}
         held = int(config["n_routed_experts"])
         fields.update(
@@ -124,8 +147,64 @@ def _sublayer_decoder(config: dict, *, impl: str) -> TinyDecoder:
         norm_eps=float(config.get("norm_eps", 1e-6)))
 
 
+def _latent_decoder(config: dict, *, impl: str) -> TinyDecoder:
+    """The decoder of an ``attention_method``: double layers of latent
+    attention with a shortcut-connected expert branch; see the
+    module's docstring for the keys."""
+    method = config["attention_method"]
+    if method != "MLA":
+        raise ValueError(f"attention_method {method!r}: the builder knows "
+                         "\"MLA\"")
+    for key in ("mla_scale_q_lora", "mla_scale_kv_lora"):
+        if not config.get(key, False):
+            raise ValueError(f"{key}: the latent attention layer scales "
+                             "both normalised latents")
+    zero = int(config.get("zero_expert_num", 0))
+    if zero and config.get("zero_expert_type") != "identity":
+        raise ValueError(
+            f"zero_expert_type {config.get('zero_expert_type')!r}: a "
+            "zero-compute expert returns its input (\"identity\")")
+    for key in ("rope_scaling", "sliding_window"):
+        if config.get(key) is not None:
+            raise ValueError(f"{key}: the latent attention layer has none")
+    if config.get("norm_topk_prob"):
+        raise ValueError("norm_topk_prob: the softmax router's weights "
+                         "are not normalised over the chosen experts")
+    _no_group_limit(config)
+    if config.get("n_shared_experts"):
+        raise ValueError("n_shared_experts: the double layer's dense "
+                         "feed-forwards are its shared path")
+    share = config.get("expert_share") or {"index": 0, "of": 1}
+    held = int(config["n_routed_experts"])
+    depth = int(config["num_layers"])
+    fields = dict(
+        q_lora_rank=int(config["q_lora_rank"]),
+        kv_lora_rank=int(config["kv_lora_rank"]),
+        nope_dim=int(config["qk_nope_head_dim"]),
+        rope_dim=int(config["qk_rope_head_dim"]),
+        v_dim=int(config["v_head_dim"]),
+        mlp_hidden=int(config["ffn_hidden_size"]),
+        experts=held * int(share["of"]), experts_held=held,
+        experts_share=int(share["index"]), experts_zero=zero,
+        experts_top_k=int(config["moe_topk"]),
+        experts_hidden=int(config["expert_ffn_hidden_size"]),
+        experts_scale=float(config.get("routed_scaling_factor", 1.0)))
+    # one latent KV head a sublayer: every query head is its group
+    return TinyDecoder(
+        vocab=int(config["vocab_size"]), dim=int(config["hidden_size"]),
+        depth=depth, num_q_heads=int(config["num_attention_heads"]),
+        num_kv_heads=1, impl=impl,
+        dtype=jnp.dtype(config.get("torch_dtype", "bfloat16")),
+        rope=True, rope_theta=float(config["rope_theta"]),
+        layer_types=(SHORTCUT_EXPERTS,) * depth,
+        sublayer=tuple(sorted(fields.items())),
+        norm_eps=float(config.get("rms_norm_eps", 1e-6)))
+
+
 def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
     """The program's decoder at the configuration's sizes."""
+    if "attention_method" in config:
+        return _latent_decoder(config, impl=impl)
     if "hybrid_override_pattern" in config:
         return _sublayer_decoder(config, impl=impl)
     common = _common(config, impl=impl)

@@ -35,8 +35,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from attention_tpu.ops.experts import (
+    ExpertLayout,
     expert_layout,
     grouped_experts,
+    grouped_gated_experts,
     row_tile,
 )
 
@@ -210,6 +212,34 @@ def sigmoid_top_k(x, router, bias, *, top_k: int, scale: float):
     return chosen.astype(jnp.int32), weight * scale
 
 
+def packed_rows(x, cache: PackedTokens | None):
+    """``x`` (B, S, D) as rows (T, D), and which of them are real
+    tokens: all without a cache, a packed step's non-pad tokens with
+    one."""
+    tokens = x.shape[0] * x.shape[1]
+    valid = (jnp.ones((tokens,), bool) if cache is None
+             else jnp.asarray(cache.token_slot).reshape(tokens) >= 0)
+    return x.reshape(tokens, x.shape[-1]), valid
+
+
+def weighted_pairs(y, layout: ExpertLayout, weight):
+    """Each token's weighted sum over its pairs held here: ``y`` is the
+    grouped product's (R, width) result, ``weight`` (T, k).  Rows that
+    hold no pair were never written: select, not scale."""
+    here = layout.dest < y.shape[0]
+    picked = jnp.where(here[..., None],
+                       y[jnp.minimum(layout.dest, y.shape[0] - 1)], 0.0)
+    return jnp.sum(picked * weight[..., None], axis=1)
+
+
+def pair_counts(layout: ExpertLayout, absent, *more):
+    """What an expert layer sows: the pairs of each held expert, the
+    pairs of experts held elsewhere, the held experts that received a
+    pair, then ``more``."""
+    return jnp.concatenate([layout.counts, jnp.stack(
+        [absent, jnp.sum(layout.counts > 0), *more])]).astype(jnp.int32)
+
+
 class LatentExperts(nn.Module):
     """Sparse experts in a latent space, as ONE CHIP'S SHARE of an
     expert-parallel deployment: (B, S, D) -> (B, S, D).
@@ -254,9 +284,7 @@ class LatentExperts(nn.Module):
                 f"{self.top_k}, does not fit {self.num_experts} experts")
         batch, seq, dim = x.shape
         tokens = batch * seq
-        xt = x.reshape(tokens, dim)
-        valid = (jnp.ones((tokens,), bool) if cache is None
-                 else jnp.asarray(cache.token_slot).reshape(tokens) >= 0)
+        xt, valid = packed_rows(x, cache)
         chosen, weight = sigmoid_top_k(
             xt, self.param("router", nn.initializers.lecun_normal(),
                            (dim, self.num_experts), jnp.float32),
@@ -274,19 +302,104 @@ class LatentExperts(nn.Module):
         layout = expert_layout(chosen - self.held * self.share, valid,
                                held=self.held, tile=tile)
         y = grouped_experts(u[layout.row_token], w1, w2, layout, tile=tile)
-        here = layout.dest < y.shape[0]
-        # rows that hold no pair were never written: select, not scale
-        picked = jnp.where(here[..., None],
-                           y[jnp.minimum(layout.dest, y.shape[0] - 1)], 0.0)
-        r = jnp.sum(picked * weight[..., None], axis=1)
+        r = weighted_pairs(y, layout, weight)
         out = nn.Dense(dim, use_bias=False, dtype=self.dtype,
                        name="latent_up")(r.astype(self.dtype))
         if self.shared_hidden:
             out = out + ReluSquaredMLP(self.shared_hidden, dtype=self.dtype,
                                        name="shared_expert")(xt)
-        local = jnp.sum(layout.counts)
-        absent = jnp.sum(valid) * self.top_k - local
-        self.sow("expert_stats", "pairs", jnp.concatenate(
-            [layout.counts, jnp.stack([absent, jnp.sum(layout.counts > 0)])
-             ]).astype(jnp.int32))
+        absent = jnp.sum(valid) * self.top_k - jnp.sum(layout.counts)
+        self.sow("expert_stats", "pairs", pair_counts(layout, absent))
         return out.reshape(batch, seq, dim)
+
+
+def softmax_top_k(x, router, bias, *, top_k: int, scale: float):
+    """The router of `GatedExperts`, in float32 whatever ``x`` is:
+    scores ``softmax(x W_r)`` over every column, the ``top_k`` by
+    ``score + bias``, weighted by ``scale`` times their scores as they
+    are, NOT normalised over the chosen.  Returns ``(chosen (T, k)
+    int32, weight (T, k) float32)``."""
+    scores = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    weight = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen.astype(jnp.int32), weight * scale
+
+
+class GatedExperts(nn.Module):
+    """Sparse gated (SwiGLU) experts beside ZERO-COMPUTE experts, as
+    ONE CHIP'S SHARE of an expert-parallel deployment: (B, S, D) ->
+    (B, S, D).
+
+        s = softmax(W_r y)                  float32, ``num_experts + zero_experts`` columns, real first
+        chosen = top_k of (s + bias)        the bias selects, s weighs
+        g_i = scale * s_i                   not normalised over the chosen
+        m = sum_{i chosen, real, HELD HERE} g_i Wd_i (silu(Wg_i y) * (Wu_i y))
+          + (sum_{i chosen, zero} g_i) * y  a zero expert returns its input
+
+    The share is `LatentExperts`'s: the layer holds real experts
+    ``[held * share, held * (share + 1))``, routes over every column,
+    computes its own experts' part through one grouped product
+    (`ops.experts.grouped_gated_experts`: no token dropped, no shape
+    from the routing) and leaves out what the real experts held
+    elsewhere would add.  The zero experts' part is computed where the
+    token is, on every chip alike, so it is whole here.  A token takes
+    0 to ``top_k`` real experts.  Pad tokens of a packed step
+    (`PackedTokens`) take none.
+
+    Sows into ``expert_stats`` ``pairs`` (held + 3,) int32: the pairs
+    of each held expert, the pairs of real experts held elsewhere, the
+    held experts that received a pair, and the pairs that went to zero
+    experts."""
+
+    num_experts: int
+    held: int
+    share: int = 0
+    zero_experts: int = 0
+    top_k: int = 2
+    hidden: int = 128
+    scale: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array, cache: PackedTokens | None = None):
+        columns = self.num_experts + self.zero_experts
+        if not (0 < self.held and self.held * (self.share + 1)
+                <= self.num_experts and 1 <= self.top_k <= columns):
+            raise ValueError(
+                f"share {self.share} of {self.held} experts, top "
+                f"{self.top_k}, does not fit {self.num_experts} experts "
+                f"and {self.zero_experts} zero experts")
+        batch, seq, dim = x.shape
+        tokens = batch * seq
+        xt, valid = packed_rows(x, cache)
+        chosen, weight = softmax_top_k(
+            xt, self.param("router", nn.initializers.lecun_normal(),
+                           (dim, columns), jnp.float32),
+            self.param("router_bias", nn.initializers.zeros,
+                       (columns,), jnp.float32),
+            top_k=self.top_k, scale=self.scale)
+
+        def experts(name, shape):
+            return self.param(name, nn.initializers.lecun_normal(
+                in_axis=-2, out_axis=-1, batch_axis=(0,)), shape,
+                jnp.float32)
+
+        wg = experts("experts_gate", (self.held, dim, self.hidden))
+        wu = experts("experts_up", (self.held, dim, self.hidden))
+        wd = experts("experts_down", (self.held, self.hidden, dim))
+        tile = row_tile(tokens)
+        layout = expert_layout(chosen - self.held * self.share, valid,
+                               held=self.held, tile=tile)
+        y = grouped_gated_experts(xt.astype(self.dtype)[layout.row_token],
+                                  wg, wu, wd, layout, tile=tile)
+        to_zero = chosen >= self.num_experts
+        out = (weighted_pairs(y, layout, weight)
+               + jnp.sum(jnp.where(to_zero, weight, 0.0), axis=1,
+                         keepdims=True) * xt.astype(jnp.float32))
+        zero = jnp.sum(to_zero & valid[:, None])
+        absent = (jnp.sum(valid) * self.top_k - jnp.sum(layout.counts)
+                  - zero)
+        self.sow("expert_stats", "pairs", pair_counts(layout, absent, zero))
+        return out.astype(self.dtype).reshape(batch, seq, dim)

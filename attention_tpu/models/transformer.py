@@ -19,9 +19,18 @@ from attention_tpu.models.attention_layer import (
     KVCache,
     RollingKVCache,
 )
+from attention_tpu.models.latent_attention import (
+    LatentAttention,
+    latent_row_width,
+)
 from attention_tpu.models.linear_attention import GatedDeltaNet
 from attention_tpu.models.mamba import Mamba2Mixer
-from attention_tpu.models.moe import LatentExperts, MoEMLP
+from attention_tpu.models.moe import (
+    GatedExperts,
+    LatentExperts,
+    MoEMLP,
+    PackedTokens,
+)
 
 #: the kinds of layer a decoder can have (``layer_types``).  The first
 #: two are blocks of TWO sublayers, a mixer (attention or Gated
@@ -35,7 +44,12 @@ ATTENTION = "attention"
 STATE_SPACE = "state_space"
 SPARSE_EXPERTS = "sparse_experts"
 SUBLAYER_KINDS = (ATTENTION, STATE_SPACE, SPARSE_EXPERTS)
-LAYER_KINDS = (FULL_ATTENTION, LINEAR_ATTENTION) + SUBLAYER_KINDS
+#: a DOUBLE layer (`ShortcutExpertsBlock`): two latent-attention and
+#: two dense feed-forward sublayers, and one expert branch that reads
+#: the first half and lands after the second
+SHORTCUT_EXPERTS = "shortcut_experts"
+LAYER_KINDS = ((FULL_ATTENTION, LINEAR_ATTENTION) + SUBLAYER_KINDS
+               + (SHORTCUT_EXPERTS,))
 
 _ACTIVATIONS = {"gelu": nn.gelu, "silu": nn.silu}
 
@@ -218,6 +232,77 @@ class SublayerBlock(nn.Module):
         return x + out if cache is None else (x + out, cache)
 
 
+class ShortcutExpertsBlock(nn.Module):
+    """A double layer with a shortcut-connected expert branch:
+
+        for i in (0, 1):
+            x = x + LatentAttention_i(norm(x))
+            y = norm(x)
+            if i == 0: m = GatedExperts(y)       # reads the FIRST half
+            x = x + GatedMLP_i(y)
+        x = x + m                                # lands after the SECOND
+
+    Four norms, separate parameters.  In a deployment the experts'
+    exchange runs beside the second attention and feed-forward; here
+    the branch is computed where it is read.  With a cache (a packed
+    engine step) ``cache`` is the two attention sublayers' latent
+    pools' steps, and the block returns ``(x, (step_0, step_1))``."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    mlp_hidden: int
+    experts: int              # real experts routed over, held, share
+    experts_held: int
+    experts_share: int = 0
+    experts_zero: int = 0
+    experts_top_k: int = 1
+    experts_hidden: int = 0
+    experts_scale: float = 1.0
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, cache=None):
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name=name)
+
+        steps = []
+        for i in (0, 1):
+            attn = LatentAttention(
+                num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+                kv_lora_rank=self.kv_lora_rank, nope_dim=self.nope_dim,
+                rope_dim=self.rope_dim, v_dim=self.v_dim,
+                rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                dtype=self.dtype, name=f"attn_{i}")(
+                    norm(f"attn_norm_{i}")(x),
+                    None if cache is None else cache[i])
+            if cache is not None:
+                attn, step = attn
+                steps.append(step)
+            x = x + attn
+            y = norm(f"mlp_norm_{i}")(x)
+            if i == 0:
+                branch = GatedExperts(
+                    num_experts=self.experts, held=self.experts_held,
+                    share=self.experts_share,
+                    zero_experts=self.experts_zero,
+                    top_k=self.experts_top_k, hidden=self.experts_hidden,
+                    scale=self.experts_scale, dtype=self.dtype,
+                    name="experts")(
+                        y, None if cache is None
+                        else PackedTokens(cache[0].token_slot))
+            x = x + GatedMLP(hidden=self.mlp_hidden, dtype=self.dtype,
+                             name=f"mlp_{i}")(y)
+        x = x + branch
+        return x if cache is None else (x, tuple(steps))
+
+
 class TinyDecoder(nn.Module):
     """Decoder-only LM: embed -> N blocks -> norm -> logits.
 
@@ -269,7 +354,9 @@ class TinyDecoder(nn.Module):
     # ``linear_value_dim``; it keeps a recurrent state per request in
     # place of K and V rows (`recurrent_state_shapes`), and so does a
     # state-space layer (`SublayerBlock`, which reads ``sublayer``: its
-    # fields by name, as a tuple of pairs so that the module hashes).
+    # fields by name, as a tuple of pairs so that the module hashes;
+    # a double layer, `ShortcutExpertsBlock`, reads its own the same
+    # way).
     layer_types: tuple[str, ...] | None = None
     sublayer: tuple[tuple[str, Any], ...] = ()
     norm_eps: float = 1e-6    # the final norm's, and a SublayerBlock's
@@ -308,10 +395,42 @@ class TinyDecoder(nn.Module):
         return self._layers_of(LINEAR_ATTENTION, STATE_SPACE)
 
     @property
+    def latent_layers(self) -> tuple[int, ...]:
+        """Double layers (`ShortcutExpertsBlock`): each keeps ONE
+        latent pool for each of its two attention sublayers."""
+        return self._layers_of(SHORTCUT_EXPERTS)
+
+    @property
+    def attention_sublayers(self) -> tuple[int, ...]:
+        """The layer of every sublayer that keeps pages, in order: a
+        double layer is named twice."""
+        return tuple(sorted(self.attention_layers + 2 * self.latent_layers))
+
+    def kv_pool_widths(self) -> tuple[int, tuple[int, ...]]:
+        """What an attention sublayer keeps a token: the KV heads, and
+        the row width of each pool: K and V of the head size, or the
+        ONE latent pool's ``[c | k_r]`` of ONE head
+        (`latent_attention.latent_row_width`)."""
+        if self.latent_layers:
+            if self.attention_layers:
+                raise ValueError("latent and K / V attention layers in "
+                                 "one model would need two pool shapes")
+            f = dict(self.sublayer)
+            return 1, (latent_row_width(f["kv_lora_rank"], f["rope_dim"]),)
+        return self.num_kv_heads, (self.dim // self.num_q_heads,) * 2
+
+    @property
     def expert_layers(self) -> tuple[int, ...]:
-        """Layers of sparse experts: they keep nothing per request, and
-        report their pairs (`LatentExperts`)."""
-        return self._layers_of(SPARSE_EXPERTS)
+        """Layers with sparse experts: the experts keep nothing per
+        request, and report their pairs (`LatentExperts`,
+        `GatedExperts`)."""
+        return self._layers_of(SPARSE_EXPERTS, SHORTCUT_EXPERTS)
+
+    @property
+    def zero_experts(self) -> int:
+        """Zero-compute experts each expert layer routes over beside
+        the real ones; 0 for a model without them."""
+        return dict(self.sublayer).get("experts_zero", 0)
 
     @property
     def held_experts(self) -> int:
@@ -352,13 +471,20 @@ class TinyDecoder(nn.Module):
             else TransformerBlock
         )
         for i, kind in enumerate(self.kinds):
-            if kind in SUBLAYER_KINDS:
+            if kind == SHORTCUT_EXPERTS:
+                block = ShortcutExpertsBlock(
+                    num_heads=self.num_q_heads, dtype=self.dtype,
+                    rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                    **dict(self.sublayer),
+                    name=f"ShortcutExpertsBlock_{i}")
+            elif kind in SUBLAYER_KINDS:
                 block = SublayerBlock(
                     kind=kind, num_q_heads=self.num_q_heads,
                     num_kv_heads=self.num_kv_heads, head_dim=head_dim,
                     impl=self.impl, dtype=self.dtype, rope=self.rope,
                     rope_theta=self.rope_theta, norm_eps=self.norm_eps,
                     **dict(self.sublayer), name=f"SublayerBlock_{i}")
+            if kind in SUBLAYER_KINDS + (SHORTCUT_EXPERTS,):
                 if caches is None:
                     x = block(x)
                 else:
@@ -424,10 +550,11 @@ class TinyDecoder(nn.Module):
         ``rolling=True`` (windowed models only) returns ring-buffer
         caches whose memory is bounded by the window, not by
         ``capacity``/sequence length."""
-        if self.recurrent_layers:
+        if self.recurrent_layers or self.latent_layers:
             raise ValueError(
-                "a model with recurrent layers serves through the "
-                "engine's packed step; it has no dense per-layer caches")
+                "a model with recurrent or latent-attention layers serves "
+                "through the engine's packed step; it has no dense "
+                "per-layer caches")
         head_dim = self.dim // self.num_q_heads
         if rolling:
             if self.window is None:

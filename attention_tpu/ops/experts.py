@@ -1,7 +1,10 @@
 """Grouped expert feed-forward: ONE kernel launch a layer for the
 token-expert pairs of the experts this chip holds.
 
-An expert ``e`` is ``y = W2_e relu(W1_e u)^2`` on a latent row ``u``.
+An expert ``e`` is ``y = W2_e relu(W1_e u)^2`` on a latent row ``u``
+(`grouped_experts`), or, with a gate branch, ``y = Wd_e (silu(Wg_e x)
+* (Wu_e x))`` (`grouped_gated_experts`: the same layout and grid, three
+weight tiles a step).
 A step's pairs (token, held expert) are laid out expert by expert on a
 row axis of STATIC length, each expert's rows starting at a multiple
 of the row tile (`expert_layout`), so a tile of rows belongs to one
@@ -175,6 +178,101 @@ def _latent_experts_gmm_jit(rows, w1, w2, tile_expert, num_tiles, *,
     return out
 
 
+def _gated_experts_kernel(tile_expert_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                          o_ref, *, dtype):
+    """One (row tile, hidden tile) grid step of the gated expert:
+    ``silu(x Wg) * (x Wu)`` on the hidden tile, then its part of the
+    down projection."""
+    @pl.when(pl.program_id(1) == 0)
+    def _clear():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    x = x_ref[...]
+    gate = jnp.dot(x, wg_ref[0].astype(dtype),
+                   preferred_element_type=jnp.float32)
+    up = jnp.dot(x, wu_ref[0].astype(dtype),
+                 preferred_element_type=jnp.float32)
+    h = (gate * jax.nn.sigmoid(gate) * up).astype(dtype)
+    o_ref[...] += jnp.dot(h, wd_ref[0].astype(dtype),
+                          preferred_element_type=jnp.float32)
+
+
+def gated_hidden_tile(width: int, hidden: int, itemsize: int) -> int:
+    """The hidden width a grid step of the gated product takes: the
+    largest of 512, 256, 128 that divides ``hidden`` and keeps one
+    weight tile at 4 MB or under (three are held, twice each), else
+    all of it."""
+    for t in (512, 256, 128):
+        if hidden % t == 0 and hidden > t and width * t * itemsize <= 4 << 20:
+            return t
+    return hidden
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _gated_experts_gmm_jit(rows, wg, wu, wd, tile_expert, num_tiles, *,
+                           tile: int, interpret: bool | None = None):
+    n_rows, width = rows.shape
+    held, _, hidden = wg.shape
+    if (wg.shape != (held, width, hidden) or wu.shape != wg.shape
+            or wd.shape != (held, hidden, width)):
+        raise ValueError(f"experts disagree: rows{rows.shape} Wg{wg.shape} "
+                         f"Wu{wu.shape} Wd{wd.shape}")
+    if n_rows % tile or tile_expert.shape != (n_rows // tile,):
+        raise ValueError(f"{n_rows} rows in tiles of {tile} need "
+                         f"{n_rows // tile} tile experts, got "
+                         f"{tile_expert.shape}")
+    if interpret is None:
+        interpret = _should_interpret()
+    item = jnp.dtype(wg.dtype).itemsize
+    step = gated_hidden_tile(width, hidden, item)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        # at least one step, so that the output is something (a row
+        # tile of nobody's)
+        grid=(jnp.maximum(num_tiles, 1), hidden // step),
+        in_specs=[
+            pl.BlockSpec((tile, width), lambda i, j, e: (i, 0)),
+            pl.BlockSpec((1, width, step), lambda i, j, e: (e[i], 0, j)),
+            pl.BlockSpec((1, width, step), lambda i, j, e: (e[i], 0, j)),
+            pl.BlockSpec((1, step, width), lambda i, j, e: (e[i], j, 0)),
+        ],
+        out_specs=[pl.BlockSpec((tile, width), lambda i, j, e: (i, 0))],
+    )
+    (out,) = pl.pallas_call(
+        functools.partial(_gated_experts_kernel, dtype=rows.dtype),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((n_rows, width), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("arbitrary", "arbitrary"),
+            # three weight tiles, held twice, and their casts
+            vmem_limit_bytes=max(
+                32 << 20, int(3 * width * step * (2 * item + 2) * 1.5)
+                + (8 << 20))),
+        # the estimate has to be a number: every held expert once
+        cost_estimate=pl.CostEstimate(
+            flops=6 * n_rows * width * hidden,
+            bytes_accessed=3 * held * width * hidden * item
+            + n_rows * width * 6,
+            transcendentals=n_rows * hidden),
+        name="gated_experts_gmm",
+        interpret=interpret,
+    )(tile_expert, rows, wg, wu, wd)
+    return out
+
+
+def grouped_gated_experts(rows, wg, wu, wd, layout: ExpertLayout, *,
+                          tile: int, interpret: bool | None = None):
+    """``Wd_e (silu(Wg_e x) * (Wu_e x))`` for every row ``x`` of
+    ``rows`` (R, width) with its tile's expert ``e``: `grouped_experts`
+    for experts with a gate branch, three weight tiles a grid step.
+    ``wg``, ``wu``: (held, width, hidden), ``wd``: (held, hidden,
+    width), in their stored dtype.  Returns (R, width) float32; rows of
+    tiles past ``layout.num_tiles`` are NOT written."""
+    return _gated_experts_gmm_jit(rows, wg, wu, wd, layout.tile_expert,
+                                  layout.num_tiles, tile=tile,
+                                  interpret=interpret)
+
+
 def grouped_experts(rows, w1, w2, layout: ExpertLayout, *, tile: int,
                     interpret: bool | None = None):
     """``W2_e relu(W1_e u)^2`` for every row ``u`` of ``rows`` (R,
@@ -190,4 +288,5 @@ def grouped_experts(rows, w1, w2, layout: ExpertLayout, *, tile: int,
 
 
 __all__ = ["ExpertLayout", "expert_layout", "grouped_experts",
-           "layout_rows", "row_tile", "hidden_tile"]
+           "grouped_gated_experts", "layout_rows", "row_tile", "hidden_tile",
+           "gated_hidden_tile"]

@@ -118,10 +118,14 @@ class RaggedPagedStep(NamedTuple):
     int32 owning slot per token, -1 for pad tokens.  ``q_span``: a
     (q_tile,) int32 zeros marker whose SHAPE carries the static
     per-request query-tile width (values unused).
+
+    A LATENT cache is ONE pool: ``v_pool`` is None and a token's row in
+    ``k_pool`` (P, 1, page_size, d) is both its key and, in its first
+    lanes, its value (`ragged_paged_attention` with ``value_dim``).
     """
 
     k_pool: jax.Array
-    v_pool: jax.Array
+    v_pool: jax.Array | None
     page_table: jax.Array
     kv_lens: jax.Array
     cu_q_lens: jax.Array
@@ -293,12 +297,63 @@ def _slot_and_page(item, max_pages: int):
     return jax.lax.div(item, width), jax.lax.rem(item, width)
 
 
+def row_block_items(kv_lens, cu_q_lens, distribution, *, max_pages: int,
+                    page: int, block_tokens: int, blocks: int, width: int):
+    """The work list of the ROW-BLOCKED form (`_ragged_kernel` with
+    ``blocks``): ``(items, n)`` as `work_items` gives them, an item
+    being ``(slot * blocks + b) * max_pages + j``: page ``j`` for the
+    ``b``-th block of ``block_tokens`` tokens of the slot's span.  Slot
+    major, then block, then page, so that a block's pages follow each
+    other.  A block's pages are those its LAST row reaches (causal, no
+    window), which is a prefix of the table row, so the list is built
+    from counts: which slot a block belongs to, then which block an
+    item belongs to, two small searches where the mask form would
+    search ``slots * blocks * max_pages`` entries.
+
+    The two entries `live_pages` keeps are kept here too: an active
+    slot whose length is poisoned still has one item a block (it is
+    finalised, as NaN), and a step with no active slot visits slot 0
+    once.  The list's static length is ``(slots + width //
+    block_tokens) * max_pages + 1``: a slot has one block more than its
+    whole ones."""
+    s_slots = kv_lens.shape[0]
+    i32 = jnp.int32
+    slot = jnp.arange(s_slots, dtype=i32)
+    q_lens = (cu_q_lens[1:] - cu_q_lens[:-1]).astype(i32)
+    active = (slot < distribution[1]) & (q_lens > 0)
+    bt, pg = i32(block_tokens), i32(page)
+    nb = jnp.where(active, jax.lax.div(q_lens + bt - 1, bt), 0)
+    nb = jnp.where((slot == 0) & ~active.any(), 1, nb)
+    nb = jnp.minimum(nb, blocks)
+    slot_end = jnp.cumsum(nb)
+    groups = s_slots + width // block_tokens
+    g = jnp.arange(groups, dtype=i32)
+    g_slot = jnp.minimum(jnp.searchsorted(
+        slot_end, g, side="right", method="compare_all").astype(i32),
+        s_slots - 1)
+    g_block = g - (slot_end - nb)[g_slot]
+    q_len = q_lens[g_slot]
+    reach = (jnp.maximum(kv_lens, 0)[g_slot] - q_len
+             + jnp.minimum((g_block + 1) * bt, q_len))
+    pages = jnp.clip(jax.lax.div(reach + pg - 1, pg), 1, max_pages)
+    pages = jnp.where(g < slot_end[-1], pages, 0)
+    group_end = jnp.cumsum(pages)
+    n = group_end[-1]
+    i = jnp.arange(groups * max_pages + 1, dtype=i32)
+    of = jnp.minimum(jnp.searchsorted(
+        group_end, i, side="right", method="compare_all").astype(i32),
+        groups - 1)
+    j = i - (group_end - pages)[of]
+    items = (g_slot[of] * blocks + g_block[of]) * max_pages + j
+    return jnp.where(i < n, items, s_slots * blocks * max_pages), n
+
+
 def _ragged_kernel(
-    lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, q_ref, k_ref, v_ref,
-    o_ref, acc_scr, m_scr, l_scr,
-    *, max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
+    lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, q_ref, k_ref, *rest,
+    max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
     tile_rows: int, softcap2, window: int | None, sinks: int | None,
-    variant: str = "online",
+    variant: str = "online", dv: int = 0, shared_kv: bool = False,
+    blocks: int = 0, block_tokens: int = 0,
 ):
     """One (kv-head, work item) grid step: item ``i`` is page ``j`` of
     slot ``r`` (`work_items`).
@@ -309,51 +364,82 @@ def _ragged_kernel(
     of one out-block per decode row.  Slot spans never overlap, a
     slot's items follow each other in page order, and the grid is
     sequential ("arbitrary" semantics), so the masked
-    read-modify-write at finalize is race-free."""
+    read-modify-write at finalize is race-free.
+
+    ``shared_kv``: the values are the first ``dv`` lanes of the key
+    block (a latent cache: one pool, no ``v_ref``).
+
+    ``blocks`` > 0 is the ROW-BLOCKED form, for a head whose packed
+    rows do not fit VMEM (one latent KV head is the group of EVERY
+    query head).  The packed query and result stay in HBM; an item is
+    a page of one BLOCK of ``block_tokens`` tokens of a slot's span
+    (`row_block_items`), whose rows are copied in at the block's first
+    item and out at its last.  A span of one token (a decode row)
+    takes a tile of that token's rows alone, whatever the step's
+    ``q_tile``: a page is read once for all of a decode row's heads,
+    and a chunk in the same step does not widen it.  The tile
+    arithmetic is the resident form's."""
+    if shared_kv:
+        v_ref = None
+    else:
+        v_ref, *rest = rest
+    if blocks:
+        _, o_ref, acc_scr, m_scr, l_scr, q_scr, o_scr, sem = rest
+    else:
+        o_ref, acc_scr, m_scr, l_scr = rest
+    hd = pl.program_id(0)
     i = pl.program_id(1)
     r, j = _slot_and_page(items_ref[i], max_pages)
     first = jnp.logical_or(
         i == 0,
         _slot_and_page(items_ref[jnp.maximum(i - 1, 0)], max_pages)[0] != r)
     last = _slot_and_page(items_ref[i + 1], max_pages)[0] != r
+    block = 0
+    if blocks:  # the item's first field is (slot, block of its span)
+        r, block = (jax.lax.div(r, jnp.int32(blocks)),
+                    jax.lax.rem(r, jnp.int32(blocks)))
     raw_len = lens_ref[r]
     kv_len = jnp.maximum(raw_len, 0)  # poisoned slots read nothing
     q_start = cu_ref[r]
     q_len = cu_ref[r + 1] - q_start
     active = jnp.logical_and(r < dist_ref[1], q_len > 0)
-    # tile start, in packed ROWS (token * group + head): the span head
-    # rounded down to the 8-row sublane granule, clamped so the tile
-    # stays in-bounds.  Mosaic refuses a dynamic sublane slice of a
-    # 16-bit ref unless it can prove the start 8-aligned, and
-    # ``q_start * group`` is only provably so when group % 8 == 0 — so
-    # the start is aligned here and the tile carries `_row_tile`'s 8
-    # spare rows (q_len <= q_tile by the caller contract, so the span
-    # always fits; rows outside it are masked per row below).
-    tile_start = pl.multiple_of(
-        jnp.minimum(q_start * group // 8 * 8, t_pad * group - tile_rows),
-        8)
+    if blocks:
+        # a block starts at a token, and ``group`` is a multiple of 8
+        tile_start = pl.multiple_of(
+            (q_start + block * block_tokens) * group, 8)
+        # what the block's last row reaches: later pages are no items
+        live = jnp.logical_and(active, j * page < kv_len - q_len
+                               + jnp.minimum((block + 1) * block_tokens,
+                                             q_len))
+    else:
+        # tile start, in packed ROWS (token * group + head): the span
+        # head rounded down to the 8-row sublane granule, clamped so
+        # the tile stays in-bounds.  Mosaic refuses a dynamic sublane
+        # slice of a 16-bit ref unless it can prove the start
+        # 8-aligned, and ``q_start * group`` is only provably so when
+        # group % 8 == 0 — so the start is aligned here and the tile
+        # carries `_row_tile`'s 8 spare rows (q_len <= q_tile by the
+        # caller contract, so the span always fits; rows outside it
+        # are masked per row below).
+        tile_start = pl.multiple_of(
+            jnp.minimum(q_start * group // 8 * 8,
+                        t_pad * group - tile_rows),
+            8)
+        # false only for the two kept entries that hold no page
+        # (`live_pages`)
+        live = jnp.logical_and(
+            active, banded_live(j, kv_len, page,
+                                _band_window(window, q_tile), sinks))
 
-    @pl.when(i == 0)
-    def _zero_out():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def init(m, l, acc):
+        m[...] = jnp.full_like(m, NEG_INF)
+        l[...] = jnp.zeros_like(l)
+        acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(first)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # false only for the two kept entries that hold no page
-    # (`live_pages`)
-    live = jnp.logical_and(
-        active, banded_live(j, kv_len, page, _band_window(window, q_tile),
-                            sinks))
-
-    @pl.when(live)
-    def _tile():
-        qb = q_ref[0, pl.ds(tile_start, tile_rows), :]
+    def attend(qb, m, l, acc):
+        keys = k_ref[0, 0]
         s = jax.lax.dot_general(
-            qb, k_ref[0, 0], (((1,), (1,)), ((), ())),
+            qb, keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (q_rows, page), log2-domain (q pre-scaled by scale*log2e)
         if softcap2 is not None:
@@ -372,38 +458,103 @@ def _ragged_kernel(
             mask = jnp.logical_and(mask, win)
         s = jnp.where(mask, s, NEG_INF)
         p, update_acc = _softmax_variant_update(
-            s, m_scr, l_scr, variant=variant, masked=True)
+            s, m, l, variant=variant, masked=True)
+        values = keys[:, :dv] if shared_kv else v_ref[0, 0]
         pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_scr[...] = update_acc(acc_scr[...], pv)
+        acc[...] = update_acc(acc[...], pv)
 
-    @pl.when(jnp.logical_and(last, active))
-    def _finalize():
+    def result(l, acc):
+        """The tile's rows, and which of them are this span's."""
         if variant == "flashd":
             # the accumulator is already normalized (flashd's hidden
             # division) — the per-slot epilogue loses its divide
-            res = acc_scr[...]
+            res = acc[...]
         else:
-            l = jnp.max(l_scr[...], axis=-1, keepdims=True)
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            res = acc_scr[...] / l_safe
+            l_max = jnp.max(l[...], axis=-1, keepdims=True)
+            res = acc[...] / jnp.where(l_max == 0.0, 1.0, l_max)
         # poisoned slots (bad append, length -1) emit NaN, loudly
         res = jnp.where(raw_len < 0, jnp.nan, res)
         row = jax.lax.broadcasted_iota(jnp.int32, res.shape, 0)
         seg = (tile_start + row) // group - q_start
-        mine = jnp.logical_and(seg >= 0, seg < q_len)
-        cur = o_ref[0, pl.ds(tile_start, tile_rows), :]
-        o_ref[0, pl.ds(tile_start, tile_rows), :] = jnp.where(
-            mine, res, cur.astype(jnp.float32)
-        ).astype(o_ref.dtype)
+        return res, jnp.logical_and(seg >= 0, seg < q_len)
+
+    if not blocks:
+        @pl.when(i == 0)
+        def _zero_out():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(first)
+        def _init():
+            init(m_scr, l_scr, acc_scr)
+
+        @pl.when(live)
+        def _tile():
+            attend(q_ref[0, pl.ds(tile_start, tile_rows), :], m_scr, l_scr,
+                   acc_scr)
+
+        @pl.when(jnp.logical_and(last, active))
+        def _finalize():
+            res, mine = result(l_scr, acc_scr)
+            cur = o_ref[0, pl.ds(tile_start, tile_rows), :]
+            o_ref[0, pl.ds(tile_start, tile_rows), :] = jnp.where(
+                mine, res, cur.astype(jnp.float32)
+            ).astype(o_ref.dtype)
+        return
+
+    def block_of(rows: int, mine_too):
+        """The three phases of a block at a tile of ``rows`` rows, the
+        head of each scratch.  The result's rows past the span are
+        zeros: they are pad tokens' rows, or rows of a later block or
+        slot, which writes them after this one."""
+        def head(ref):
+            return ref.at[pl.ds(0, rows)]
+
+        m, l, acc = head(m_scr), head(l_scr), head(acc_scr)
+
+        @pl.when(jnp.logical_and(mine_too, jnp.logical_and(first, active)))
+        def _load():
+            rows_in = pltpu.make_async_copy(
+                q_ref.at[hd, pl.ds(tile_start, rows)], head(q_scr),
+                sem.at[0])
+            rows_in.start()
+            init(m, l, acc)
+            rows_in.wait()
+
+        @pl.when(jnp.logical_and(mine_too, live))
+        def _tile():
+            attend(q_scr[pl.ds(0, rows), :], m, l, acc)
+
+        @pl.when(jnp.logical_and(mine_too, jnp.logical_and(last, active)))
+        def _store():
+            res, mine = result(l, acc)
+            o_scr[pl.ds(0, rows), :] = jnp.where(mine, res, 0.0).astype(
+                o_scr.dtype)
+            rows_out = pltpu.make_async_copy(
+                head(o_scr), o_ref.at[hd, pl.ds(tile_start, rows)],
+                sem.at[1])
+            rows_out.start()
+            rows_out.wait()
+
+    one_token = tile_tokens(1, group) * group
+    if block_tokens * group == one_token:
+        block_of(one_token, True)
+    else:
+        block_of(one_token, q_len <= 1)
+        block_of(block_tokens * group, q_len > 1)
+
+
+#: rows of a block of the row-blocked form (`_ragged_kernel`): what a
+#: block keeps in VMEM is about 7 KB a row at keys of 576
+_BLOCK_ROWS = 1024
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "interpret", "softcap", "window", "sinks",
-                     "max_mode"),
+                     "max_mode", "value_dim"),
 )
 def _ragged_paged_attention_jit(
     q: jax.Array,            # (1, Hq, T, d) packed token axis
@@ -415,6 +566,7 @@ def _ragged_paged_attention_jit(
     window: int | None = None,
     sinks: int | None = None,
     max_mode: str = "online",
+    value_dim: int | None = None,
 ) -> jax.Array:
     """softmax(q K^T * scale) V for every packed token through its
     slot's page table, causal within each request — (1, Hq, T, dv).
@@ -426,7 +578,15 @@ def _ragged_paged_attention_jit(
     visited.  ``max_mode`` picks the rescaling math ("online"/"flashd"/
     "amla" — the per-slot masked read-modify-write finalize is exactly
     the epilogue flashd and amla cheapen); "auto" consults the tuning
-    tables (ragged family) and falls back to "online"."""
+    tables (ragged family) and falls back to "online".
+
+    A cache with ONE pool (``v_pool`` None: a latent cache) gives keys
+    and values from the same page block, the values its first
+    ``value_dim`` lanes, and runs the kernel's row-blocked form: its
+    one KV head is the group of every query head, so a token's rows
+    alone are a tile, and the whole packed rows of that head (89 MB at
+    a width of 320, 64 heads and 576 / 512 lanes) are not held in
+    VMEM.  No window there."""
     check_softcap(softcap)
     check_band(window, sinks)
     if q.ndim != 4 or q.shape[0] != 1:
@@ -435,12 +595,27 @@ def _ragged_paged_attention_jit(
         )
     _, h, t_pad, d = q.shape
     p_, hkv, page, dk = cache.k_pool.shape
-    dv = cache.v_pool.shape[-1]
+    shared_kv = cache.v_pool is None
+    if shared_kv:
+        if value_dim is None or not 0 < value_dim <= dk:
+            raise ValueError(
+                f"a cache of one pool needs value_dim in (0, {dk}], the "
+                f"lanes of a key that are its value; got {value_dim}")
+        if window is not None:
+            raise ValueError("window: the row-blocked form a cache of "
+                             "one pool takes has no band")
+        dv, out_dtype = value_dim, cache.k_pool.dtype
+    else:
+        if value_dim is not None:
+            raise ValueError("value_dim is for a cache of one pool")
+        dv, out_dtype = cache.v_pool.shape[-1], cache.v_pool.dtype
     s_slots, max_pages = cache.page_table.shape
-    if dk != d or cache.v_pool.shape[:3] != (p_, hkv, page):
+    if dk != d or (not shared_kv
+                   and cache.v_pool.shape[:3] != (p_, hkv, page)):
         raise ValueError(
             f"ragged cache shapes inconsistent: Q{q.shape} "
-            f"K{cache.k_pool.shape} V{cache.v_pool.shape}"
+            f"K{cache.k_pool.shape} "
+            f"V{None if shared_kv else cache.v_pool.shape}"
         )
     if cache.cu_q_lens.shape != (s_slots + 1,):
         raise ValueError(
@@ -461,6 +636,9 @@ def _ragged_paged_attention_jit(
         )
     if q_tile > t_pad:
         raise ValueError(f"q_tile {q_tile} > packed width {t_pad}")
+    if shared_kv and group % 8:
+        raise ValueError(f"a cache of one pool needs a group that is a "
+                         f"multiple of 8, got {group}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
@@ -487,9 +665,24 @@ def _ragged_paged_attention_jit(
     qs = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
     qs = qs[0].reshape(hkv, group, t_pad, d).transpose(0, 2, 1, 3)
     qs = qs.reshape(hkv, t_pad * group, d)
-    items, n_items = work_items(live_pages(
-        lens, cu, dist, max_pages=max_pages, page=page, q_tile=q_tile,
-        window=window, sinks=sinks))
+    # the row-blocked form: blocks of `_BLOCK_ROWS` rows at most, whole
+    # tokens, ``blocks`` of them to the step's query tile
+    block_tokens = min(q_tile, max(_BLOCK_ROWS // group, 1))
+    blocks = -(-q_tile // block_tokens) if shared_kv else 0
+    if blocks:
+        items, n_items = row_block_items(
+            lens, cu, dist, max_pages=max_pages, page=page,
+            block_tokens=block_tokens, blocks=blocks, width=t_pad)
+        tile_rows = block_tokens * group
+        # a block is copied whole, so the last token's may reach past
+        # the packed rows: spare rows, nobody's
+        qs = jnp.pad(qs, ((0, 0), (0, tile_rows), (0, 0)))
+    else:
+        items, n_items = work_items(live_pages(
+            lens, cu, dist, max_pages=max_pages, page=page, q_tile=q_tile,
+            window=window, sinks=sinks))
+        tile_rows = _row_tile(q_tile, t_pad, group)
+    rows_total = qs.shape[1]
 
     def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref):
         # the item's table entry, read on prefetched scalars.  An item
@@ -497,17 +690,19 @@ def _ragged_paged_attention_jit(
         # entries that hold none (`live_pages`) may read -1, and fetch
         # page 0 for nobody.
         r, j = _slot_and_page(items_ref[i], max_pages)
+        if blocks:
+            r = jax.lax.div(r, jnp.int32(blocks))
         return (jnp.maximum(tbl_ref[r, j], 0), hd, 0, 0)
 
     def head_index(hd, i, *_):
         return (hd, 0, 0)
 
-    tile_rows = _row_tile(q_tile, t_pad, group)
     kernel = functools.partial(
         _ragged_kernel, max_pages=max_pages, group=group, page=page,
         q_tile=q_tile, t_pad=t_pad, tile_rows=tile_rows,
         softcap2=None if softcap is None else softcap * _LOG2E,
-        window=window, sinks=sinks, variant=variant,
+        window=window, sinks=sinks, variant=variant, dv=dv,
+        shared_kv=shared_kv, blocks=blocks, block_tokens=block_tokens,
     )
     # Scoped-VMEM demand: the head's whole packed q and out blocks stay
     # resident (double-buffered by the pipeline), plus the K/V page
@@ -515,35 +710,53 @@ def _ragged_paged_attention_jit(
     # probability temporaries.  Past Mosaic's ~16 MB default budget
     # (packed width >= 2048 at group 8) the budget is raised to what
     # the call needs, like the forward kernel's big tiles; small steps
-    # keep the default.
+    # keep the default.  The row-blocked form holds one block's rows
+    # in place of the packed axis.
     kv_item = cache.k_pool.dtype.itemsize
+    held_rows = tile_rows if blocks else 2 * t_pad * group
     vmem_need = (
-        2 * t_pad * group * (d * qs.dtype.itemsize + dv * kv_item)
-        + 4 * page * (d + dv) * kv_item
+        held_rows * (d * qs.dtype.itemsize + dv * kv_item)
+        + 4 * page * (d + (0 if shared_kv else dv)) * kv_item
         + tile_rows * (dv + 2 * _STAT_LANES) * 4
         + 3 * tile_rows * page * 4
     )
     vmem_limit = None
     if vmem_need > _DEFAULT_SCOPED_VMEM // 2:
         vmem_limit = min(int(vmem_need * 1.5), _MAX_SCOPED_VMEM)
+    pools = (cache.k_pool,) if shared_kv else (cache.k_pool, cache.v_pool)
+    pool_specs = [pl.BlockSpec((1, 1, page, pool.shape[-1]), kv_index)
+                  for pool in pools]
+    scratch = [
+        pltpu.VMEM((tile_rows, dv), jnp.float32),
+        pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
+    ]
+    out_shape = jax.ShapeDtypeStruct((hkv, rows_total, dv), out_dtype)
+    if blocks:
+        # rows in and out by the kernel's own copies; the result starts
+        # as zeros, which is what a pad token's rows stay
+        in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+        in_specs = [in_hbm, *pool_specs, in_hbm]
+        out_specs = [in_hbm]
+        scratch += [pltpu.VMEM((tile_rows, d), qs.dtype),
+                    pltpu.VMEM((tile_rows, dv), out_dtype),
+                    pltpu.SemaphoreType.DMA((2,))]
+        operands = (qs, *pools, jnp.zeros(out_shape.shape, out_dtype))
+        aliases = {5 + len(operands) - 1: 0}
+    else:
+        in_specs = [pl.BlockSpec((1, rows_total, d), head_index),
+                    *pool_specs]
+        out_specs = [pl.BlockSpec((1, rows_total, dv), head_index)]
+        operands = (qs, *pools)
+        aliases = {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         # the second bound is the step's own count of work items, a
         # traced scalar: one executable whatever the step holds
         grid=(hkv, n_items),
-        in_specs=[
-            pl.BlockSpec((1, t_pad * group, d), head_index),
-            pl.BlockSpec((1, 1, page, d), kv_index),
-            pl.BlockSpec((1, 1, page, dv), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, t_pad * group, dv), head_index),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile_rows, dv), jnp.float32),
-            pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
     # the estimate has to be a number, so it is the longest list's: a
     # table with every entry live
@@ -551,24 +764,22 @@ def _ragged_paged_attention_jit(
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hkv, t_pad * group, dv),
-                                 cache.v_pool.dtype),
-        ],
+        out_shape=[out_shape],
+        input_output_aliases=aliases,
         # NOT parallel: every slot of one head accumulates into the
         # same resident output block
         compiler_params=_compiler_params(("arbitrary", "arbitrary"),
                                          vmem_limit_bytes=vmem_limit),
         cost_estimate=pl.CostEstimate(
             flops=2 * full * tile_rows * page * (d + dv),
-            bytes_accessed=full * page * (d + dv)
-            * cache.k_pool.dtype.itemsize + qs.size * qs.dtype.itemsize,
+            bytes_accessed=full * page * (d + (0 if shared_kv else dv))
+            * kv_item + qs.size * qs.dtype.itemsize,
             transcendentals=full * tile_rows * page,
         ),
         interpret=interpret,
-    )(lens, cu, dist, cache.page_table, items, qs, cache.k_pool,
-      cache.v_pool)
+    )(lens, cu, dist, cache.page_table, items, *operands)
     out = outs[0] if isinstance(outs, (list, tuple)) else outs
+    out = out[:, :t_pad * group]
     out = out.reshape(hkv, t_pad, group, dv).transpose(0, 2, 1, 3)
     return out.reshape(1, h, t_pad, dv)
 
@@ -584,20 +795,20 @@ def ragged_paged_attention(q: jax.Array, cache: RaggedPagedStep,
     return _ragged_paged_attention_jit(q, cache, **kwargs)
 
 
-def _row_append_kernel(page_ref, block_ref, first_ref, count_ref,
-                       k_new_ref, v_new_ref, k_in_ref, v_in_ref,
-                       k_out_ref, v_out_ref):
+def _row_append_kernel(page_ref, block_ref, first_ref, count_ref, *refs):
     """One run a grid step: ``count`` new rows from row ``first`` of
-    one `_APPEND_ROWS`-row block of both pools, the block's other rows
-    as they were.  Steps past the last run stay on its block and write
-    nothing, so nothing moves for them."""
+    one `_APPEND_ROWS`-row block of every pool, the block's other rows
+    as they were.  ``refs``: each pool's new rows, then each pool's
+    block in, then each pool's block out.  Steps past the last run stay
+    on its block and write nothing, so nothing moves for them."""
     j = pl.program_id(0)
     first, count = first_ref[j], count_ref[j]
+    n = len(refs) // 3
 
     @pl.when((j == 0) | (count > 0))
     def _():
-        for new_ref, in_ref, out_ref in ((k_new_ref, k_in_ref, k_out_ref),
-                                         (v_new_ref, v_in_ref, v_out_ref)):
+        for new_ref, in_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                            refs[2 * n:]):
             old = in_ref[0]                          # (Hkv, rows, d)
             row = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1)
             new = (row >= first) & (row < first + count)
@@ -605,18 +816,18 @@ def _row_append_kernel(page_ref, block_ref, first_ref, count_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("max_runs", "interpret"))
-def _append_rows(k_pool, v_pool, k_rows, v_rows, tgt, pos, *, max_runs,
-                 interpret):
-    """``pool[tgt[t], :, pos[t] % page] = rows[:, t]`` for both pools,
-    in place: the pools are aliased to the results, and the kernel
-    moves only the blocks of `_APPEND_ROWS` rows that hold a new row.
-    ``tgt`` equal to the pool's page count writes nothing.
+def _append_rows(pools, new_rows, tgt, pos, *, max_runs, interpret):
+    """``pool[tgt[t], :, pos[t] % page] = rows[:, t]`` for every pool
+    of ``pools`` (K and V, or a latent cache's one), in place: the
+    pools are aliased to the results, and the kernel moves only the
+    blocks of `_APPEND_ROWS` rows that hold a new row.  ``tgt`` equal
+    to the pool's page count writes nothing.
 
     Neighbours on the packed axis that write into one block form a
     RUN, one grid step: a slot's tokens follow each other at rising
     positions, so a step has at most ``max_runs`` of them."""
-    pages, hkv, page, _ = k_pool.shape
-    t = k_rows.shape[1]
+    pages, hkv, page, _ = pools[0].shape
+    t = new_rows[0].shape[1]
     rows = _APPEND_ROWS
     if page % rows:
         raise ValueError(f"page size {page} is not a multiple of the "
@@ -648,33 +859,36 @@ def _append_rows(k_pool, v_pool, k_rows, v_rows, tgt, pos, *, max_runs,
         return pl.BlockSpec((1, hkv, rows, width),
                             lambda j, pg, bl, fi, co: (pg[j], 0, bl[j], 0))
 
-    d, dv = k_pool.shape[3], v_pool.shape[3]
+    widths = [pool.shape[3] for pool in pools]
+    n = len(pools)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(max_runs,),
-        in_specs=[new_spec(d), new_spec(dv), pool_spec(d), pool_spec(dv)],
-        out_specs=[pool_spec(d), pool_spec(dv)],
+        in_specs=([new_spec(w) for w in widths]
+                  + [pool_spec(w) for w in widths]),
+        out_specs=[pool_spec(w) for w in widths],
     )
-    return pl.pallas_call(
+    return tuple(pl.pallas_call(
         _row_append_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype)
+                   for pool in pools],
         # every element no token writes stays as it is: the new pools
         # ARE the old ones, written in place
-        input_output_aliases={4 + 2: 0, 4 + 3: 1},
+        input_output_aliases={4 + n + i: i for i in range(n)},
         compiler_params=_compiler_params(("arbitrary",)),
         name="kv_row_append",
         interpret=interpret,
     )(page_of.astype(jnp.int32), block_of.astype(jnp.int32),
       first.astype(jnp.int32), count,
-      k_rows[:, src], v_rows[:, src], k_pool, v_pool)
+      *(r[:, src] for r in new_rows), *pools))
 
 
 def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
-                        v_new: jax.Array) -> RaggedPagedStep:
+                        v_new: jax.Array | None = None) -> RaggedPagedStep:
     """Write every packed token's K/V row (k/v (1, Hkv, T, d)) at its
     slot's next positions; returns the cache with post-append lengths.
+    A cache of one pool (``v_pool`` None) takes ``k_new`` alone.
 
     The packed analog of `ops.paged.paged_append`, with the same poison
     contract: a token targeting an unclaimed (-1) table entry or past
@@ -691,13 +905,15 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
     over ``S`` slots makes at most ``T // 32 + 2 * S`` such trips."""
     page = cache.page_size
     t = k_new.shape[2]
-    if (k_new.ndim != 4 or v_new.ndim != 4
-            or k_new.shape[:3] != v_new.shape[:3]
+    if (cache.v_pool is None) != (v_new is None):
+        raise ValueError("new V rows go with a V pool, and only with one")
+    new = (k_new,) if v_new is None else (k_new, v_new)
+    if (any(r.ndim != 4 or r.shape[:3] != k_new.shape[:3] for r in new)
             or k_new.shape[0] != 1
             or t != cache.token_slot.shape[0]):
         raise ValueError(
             f"expected (1, Hkv, {cache.token_slot.shape[0]}, d) packed "
-            f"rows: K{k_new.shape} V{v_new.shape}"
+            f"rows: " + " ".join(str(r.shape) for r in new)
         )
     s_slots, max_pages = cache.page_table.shape
     slot = jnp.asarray(cache.token_slot, jnp.int32)
@@ -712,11 +928,10 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
     drop = jnp.logical_or(bad, slot < 0)
     # dropped tokens target one-past-the-end
     tgt = jnp.where(drop, cache.k_pool.shape[0], phys)
-    k_pool, v_pool = _append_rows(
-        cache.k_pool, cache.v_pool,
-        k_new[0].astype(cache.k_pool.dtype),
-        v_new[0].astype(cache.v_pool.dtype), tgt, pos,
-        max_runs=min(t, t // _APPEND_ROWS + 2 * s_slots),
+    pools = tuple(p for p in (cache.k_pool, cache.v_pool) if p is not None)
+    pools = _append_rows(
+        pools, tuple(r[0].astype(p.dtype) for r, p in zip(new, pools)),
+        tgt, pos, max_runs=min(t, t // _APPEND_ROWS + 2 * s_slots),
         interpret=_should_interpret())
     # per-slot sticky poison: any bad REAL token condemns its slot
     bad_slot = jnp.zeros((s_slots + 1,), jnp.bool_).at[
@@ -725,7 +940,8 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
     q_lens = cache.cu_q_lens[1:] - cache.cu_q_lens[:-1]
     new_lens = jnp.where(bad_slot | (cache.kv_lens < 0), -1,
                          cache.kv_lens + q_lens)
-    return cache._replace(k_pool=k_pool, v_pool=v_pool,
+    return cache._replace(k_pool=pools[0],
+                          v_pool=pools[1] if len(pools) > 1 else None,
                           kv_lens=new_lens)
 
 

@@ -332,3 +332,71 @@ def test_the_layout_puts_every_held_pair_on_a_row_of_its_expert(
                                      for c in np.asarray(lay.counts))
     assert int(lay.num_tiles) * tile <= rows
     assert row_tile(8) == 8 and row_tile(64) == 8 and row_tile(384) == 32
+
+
+# -- gated experts behind a softmax router ---------------------------------
+
+def test_the_softmax_router_weighs_without_normalising():
+    """Scores are a softmax over EVERY column, the bias selects and
+    does not weigh, and the chosen weights are scale x score as they
+    are: they do not add up to the scale."""
+    from attention_tpu.models.moe import softmax_top_k
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((6, 16)), jnp.bfloat16)
+    router = jnp.asarray(rng.standard_normal((16, 12)) / 8, jnp.float32)
+    bias = jnp.zeros((12,)).at[7].set(5.0)
+    chosen, weight = softmax_top_k(x, router, bias, top_k=3, scale=6.0)
+    scores = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router,
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    assert chosen.dtype == jnp.int32 and weight.dtype == jnp.float32
+    assert (np.asarray(chosen)[:, 0] == 7).all()       # the bias selects
+    np.testing.assert_allclose(
+        weight, 6.0 * jnp.take_along_axis(scores, chosen, axis=-1),
+        rtol=1e-6)
+    assert (np.asarray(weight).sum(axis=1) < 6.0 - 1e-3).all()
+
+
+@pytest.mark.parametrize("dtype, stored, tol", [
+    (jnp.float32, jnp.float32, 2e-5), (jnp.bfloat16, jnp.bfloat16, 3e-2),
+    (jnp.bfloat16, jnp.float32, 3e-2)])
+def test_the_gated_grouped_product_is_each_rows_own_expert(dtype, stored,
+                                                           tol):
+    """silu(x Wg) * (x Wu) then Wd, a row tile an expert, the hidden
+    width in two tiles; weights cast where they are read."""
+    from attention_tpu.ops.experts import (
+        expert_layout,
+        gated_hidden_tile,
+        grouped_gated_experts,
+    )
+
+    rng = np.random.default_rng(1)
+    held, width, hidden, tokens, tile = 3, 128, 256, 20, 8
+    assert gated_hidden_tile(width, hidden, 4) == 128
+    assert gated_hidden_tile(6144, 2048, 2) == 256
+    local = jnp.asarray(rng.integers(-1, held + 1, size=(tokens, 1)),
+                        jnp.int32)
+    layout = expert_layout(local, jnp.ones((tokens,), bool), held=held,
+                           tile=tile)
+    x = jnp.asarray(rng.standard_normal((tokens, width)), dtype)
+
+    def w(*shape):
+        return jnp.asarray(rng.standard_normal(shape) / 8, dtype).astype(
+            stored)
+
+    wg, wu, wd = w(held, width, hidden), w(held, width, hidden), w(
+        held, hidden, width)
+    y = grouped_gated_experts(x[layout.row_token], wg, wu, wd, layout,
+                              tile=tile)
+    assert y.dtype == jnp.float32
+    f = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    for t in range(tokens):
+        e = int(local[t, 0])
+        if not 0 <= e < held:
+            assert int(layout.dest[t, 0]) == y.shape[0]
+            continue
+        gate, up = f(x[t]) @ f(wg[e]), f(x[t]) @ f(wu[e])
+        want = (gate / (1 + np.exp(-gate)) * up) @ f(wd[e])
+        np.testing.assert_allclose(y[int(layout.dest[t, 0])], want,
+                                   atol=tol * np.abs(want).max())

@@ -404,6 +404,70 @@ def test_the_nemotron_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
     assert mem.alias_size_in_bytes >= pooled
 
 
+def _longcat_cell():
+    """The benchmark's LongCat-Flash-Omni cell whole: 4 double layers
+    (8 latent-attention, 8 dense and 4 expert sublayers), 32 + 1 slots,
+    a table row of 200 pages, 1,824 pages of ONE latent pool a
+    sublayer; parameters in bfloat16, the router's in float32, as the
+    cell's reference makes them."""
+    path = (pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
+            / "longcat-flash-omni.json")
+    config = json.loads(path.read_text())
+    model = decoder_from_config(config)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, a: _a(a.shape, F32 if "router" in jax.tree_util.keystr(p)
+                        else BF16), shapes)
+    heads, (width,) = model.kv_pool_widths()
+    pool = _a((config["engine"]["num_pages"], heads, 128, width), BF16)
+    return model, params, tuple((pool, pool) for _ in range(model.depth)), \
+        dict(slots=33, max_pages=200)
+
+
+@pytest.mark.parametrize("width,q_tile", [(288, 256), (384, 256), (32, 1)],
+                         ids=["chunk_step", "widest_step", "decode_only"])
+def test_the_longcat_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
+    """(k): the whole served step of the LongCat cell compiles for one
+    v5e chip at the cell's sizes, the ragged kernel in its row-blocked
+    form on pools of (1824, 1, 128, 640): every donated pool aliased to
+    its result, and arguments + temporaries inside the chip's 16 GB
+    (12.77 GB of arguments, 10.35 of them parameters and 2.39 the
+    latent cache, + 0.22 GB of temporaries at a chunk step)."""
+    model, params, pools, index = _longcat_cell()
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = _compile_step(
+        model, one, params, _a((1, width), I32), pools,
+        _ragged_index(width, q_tile, **index))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 12.7e9 < total < 13.4e9, total
+    pooled = sum(np.prod(a.shape) * a.dtype.itemsize
+                 for a in jax.tree.leaves(pools))
+    assert pooled == 8 * 1824 * 128 * 640 * 2
+    assert mem.alias_size_in_bytes >= pooled
+    # what a token costs in the cache, tile padding and all: 640 lanes
+    # of 2 bytes in each of 8 pools, and no V pool
+    assert _device_bytes(one, pools) == pooled == 1824 * 128 * 10240
+
+
+def test_the_gated_experts_kernel_compiles_at_the_cells_sizes(v5e):
+    """16 held experts of 6144 x 2048 in bfloat16, top-12 of 768: the
+    row tiles of a decode step (8) and of a chunk step (32)."""
+    for tokens in (32, 288):
+        tile = experts.row_tile(tokens)
+        rows = experts.layout_rows(tokens, 12, 16, tile)
+        layout = experts.ExpertLayout(
+            _a((tokens, 12), I32), _a((rows,), I32), _a((rows // tile,), I32),
+            _a((), I32), _a((16,), I32))
+        _compile(functools.partial(experts.grouped_gated_experts, tile=tile),
+                 jax.sharding.SingleDeviceSharding(v5e[0]),
+                 _a((rows, 6144), BF16), _a((16, 6144, 2048), BF16),
+                 _a((16, 6144, 2048), BF16), _a((16, 2048, 6144), BF16),
+                 layout)
+
+
 def test_ladder_kernels_compile(v5e):
     """One row each of the old kernel ladder: flash forward in bound
     mode, the fused backward, bf16 / int8 / paged decode."""
