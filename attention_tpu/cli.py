@@ -1105,7 +1105,7 @@ def _obs_load(args: argparse.Namespace):
         snapshot, events = obs.load_dump(args.run)
         device = args.device_trace or obs.device_dir_of(args.run)
     else:
-        snapshot, events = obs.REGISTRY.snapshot(), obs.events()
+        snapshot, events = obs.live_snapshot(), obs.events()
         device = args.device_trace
     return snapshot, events, device
 
@@ -1113,8 +1113,9 @@ def _obs_load(args: argparse.Namespace):
 def _cmd_obs_report(args: argparse.Namespace) -> int:
     """Human-oriented run picture: instrument families first (every
     layer that recorded anything, frontend.* through engine.step.*),
-    then counters, gauges, histogram/digest and span aggregates, and
-    per-module device seconds when a capture exists."""
+    then counters, gauges, histogram/digest aggregates, the compile
+    log, span aggregates, and per-module device seconds when a capture
+    exists."""
     snapshot, events, device = _obs_load(args)
 
     def _lbl(labels):
@@ -1211,6 +1212,22 @@ def _cmd_obs_report(args: argparse.Namespace) -> int:
                       f"bound={f['bound']:g}")
         else:
             print("  (no firings)")
+    # the process's compile log (obs.compiles): always on, so every
+    # dump has one
+    log = snapshot.get("compiles")
+    if log is not None:
+        print("== compiles ==")
+        print(f"  trace_s={log['trace_s']:.3f} lower_s={log['lower_s']:.3f} "
+              f"compile_s={log['compile_s']:.3f} all_s={log['all_s']:.3f} "
+              f"traces={log['traces']} programs={log['programs']} "
+              f"cache_hits={log['cache_hits']} "
+              f"cache_misses={log['cache_misses']} "
+              f"cache_retrieval_s={log['cache_retrieval_s']:.3f} "
+              f"time_saved_s={log['time_saved_s']:.3f} "
+              f"dropped={log['dropped']}")
+        for row in log["by_function"]:
+            print(f"  {row['function']} [{row['kind']}]: "
+                  f"n={row['count']} total_s={row['seconds']:.3f}")
     print("== spans ==")
     agg: dict[str, list[float]] = {}
     for e in events:

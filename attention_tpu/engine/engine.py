@@ -39,6 +39,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from attention_tpu import obs
+from attention_tpu.obs import compiles as _compiles
 from attention_tpu.obs import trace as _trace
 from attention_tpu.engine.allocator import BlockAllocator
 from attention_tpu.engine.errors import (
@@ -78,14 +79,6 @@ _LAUNCHES = obs.counter("engine.step.launches",
 _LOGIT_ROWS = obs.counter("engine.step.logit_rows",
                           "logits rows fetched to the host / sampled "
                           "there, by kind")
-# what the recurrent layers' kernel is given each step: real tokens on
-# the packed axis, and slots whose state it reads and writes back
-_RECURRENT_TOKENS = obs.counter(
-    "engine.recurrent.tokens",
-    "tokens a step hands the recurrent layers, summed over steps")
-_RECURRENT_SLOT_STEPS = obs.counter(
-    "engine.recurrent.slot_steps",
-    "request slots whose recurrent state a step reads and writes")
 # what the expert layers' routing gave this chip: token-expert pairs of
 # experts held here and of experts held elsewhere, summed over the
 # expert layers and the steps
@@ -767,11 +760,13 @@ class ServingEngine:
         """Run one scheduler iteration: compose a batch, lower it onto
         ONE packed launch, stream out sampled tokens."""
         t0 = time.perf_counter()
+        compile_rows = _compiles.count
         self._finished_in_step = 0
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
         pad_tokens = kv_pages = qk_pairs = 0
-        occupancy = 0.0
+        width = q_tile = compiled_programs = 0
+        occupancy = compile_s = 0.0
         self._expert_pairs = None
         with obs.span("engine.step", step=self._step,
                       queued=len(self.scheduler.waiting),
@@ -792,9 +787,12 @@ class ServingEngine:
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             if not sched.is_empty:
-                width, kv_pages, qk_pairs = self._run_ragged(sched)
+                width, q_tile, kv_pages, qk_pairs = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
+            if _compiles.count != compile_rows:
+                compile_s, compiled_programs = self._note_compiled(
+                    t0, width, q_tile)
             wall_s = time.perf_counter() - t0
             m = StepMetrics(
                 step=self._step,
@@ -819,11 +817,40 @@ class ServingEngine:
                 kv_pages=kv_pages,
                 attn_qk_pairs=qk_pairs,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
+                compile_s=compile_s,
+                compiled_programs=compiled_programs,
                 **self._expert_fields(),
             )
             self.metrics.record_step(m)
         self._step += 1
         return m
+
+    def _note_compiled(self, since: float, width: int,
+                       q_tile: int) -> tuple[float, int]:
+        """A step in which JAX traced or compiled something (the
+        compile log moved: tracing, lowering and compiling run on the
+        calling thread, so they are over by now) says so: ONE mark on
+        the profiler's timeline with the step's shape and what the
+        log holds since the step opened, which is also the step's
+        ``compile_s`` and ``compiled_programs``.  ``cache`` is "miss"
+        where the persistent cache was written to, "hit" where it was
+        only read, "off" where neither (none is set, or it declined
+        the entries).  Another thread's compiles of the same moments
+        are counted with the step's."""
+        log = _compiles.summary(since=since)
+        top = log["by_function"]
+        with obs.span("engine.program.compiled", step=self._step,
+                      width=width, q_tile=q_tile,
+                      trace_ms=log["trace_s"] * 1e3,
+                      lower_ms=log["lower_s"] * 1e3,
+                      compile_ms=log["compile_s"] * 1e3,
+                      cache=("miss" if log["cache_misses"] else
+                             "hit" if log["cache_hits"] else "off"),
+                      function=top[0]["function"] if top else ""):
+            pass
+        if width:
+            self.metrics.compiled_shapes.add((width, q_tile))
+        return log["all_s"], log["programs"]
 
     def _expert_fields(self) -> dict[str, int]:
         """The step's expert pairs as `StepMetrics` has them."""
@@ -962,11 +989,12 @@ class ServingEngine:
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
-    def _run_ragged(self, sched: ScheduledStep) -> tuple[int, int, int]:
+    def _run_ragged(self, sched: ScheduledStep) -> tuple[int, int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
-        the packed width dispatched, the (slot, page) pairs the
-        attention kernel's grid walks for it, and the (query token,
-        key) pairs one attention sublayer attends.
+        the packed width and the query tile dispatched (the program's
+        shape), the (slot, page) pairs the attention kernel's grid
+        walks for it, and the (query token, key) pairs one attention
+        sublayer attends.
 
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
@@ -1030,9 +1058,6 @@ class ServingEngine:
                 fields["zero_experts"] = self.model.zero_experts
         if obs.is_enabled():
             _LAUNCHES.inc()
-            if self._state_layers:
-                _RECURRENT_TOKENS.inc(total)
-                _RECURRENT_SLOT_STEPS.inc(sampled)
         with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
                       decode_rows=len(sched.decode),
                       prefill_tokens=sched.num_prefill_tokens,
@@ -1050,7 +1075,7 @@ class ServingEngine:
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
-        return width, kv_pages, qk_pairs
+        return width, q_tile, kv_pages, qk_pairs
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut

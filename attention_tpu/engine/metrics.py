@@ -93,6 +93,12 @@ class StepMetrics:
     # step's slots, each query token times the keys it reaches
     attn_qk_pairs: int = 0
     host_overhead_s: float = 0.0     # wall minus the logits device sync
+    # a step in which JAX traced, lowered or compiled something (a
+    # shape the process had not run: `obs.compiles`): the seconds that
+    # took on the host, nested traces counted once, and the programs
+    # compiled or loaded from the persistent cache; 0 / 0 otherwise
+    compile_s: float = 0.0
+    compiled_programs: int = 0
     # a model with expert layers (`models.moe.LatentExperts`,
     # `GatedExperts`), summed over them: token-expert pairs of the
     # experts held here, pairs of experts held elsewhere, the most
@@ -162,6 +168,8 @@ class EngineMetrics:
         self.held_experts = held_experts
         self.steps: list[StepMetrics] = []
         self.requests: list[RequestMetrics] = []
+        # the (width, q_tile) of every step that compiled
+        self.compiled_shapes: set[tuple[int, int]] = set()
         self._t0 = time.perf_counter()
 
     def record_step(self, m: StepMetrics) -> None:
@@ -288,6 +296,16 @@ class EngineMetrics:
             "mean_host_overhead_ms": round(
                 sum(s.host_overhead_s for s in busy) * 1e3 / len(busy),
                 3) if busy else 0.0,
+            # steps that traced or compiled something, what that took,
+            # and the distinct (width, q_tile) shapes among them: a
+            # step of seconds in a served run is one of these, or not.
+            # `programs` here counts SHAPES; `obs.compiles.summary()`'s
+            # `programs` counts backend compiles, several a shape (the
+            # step, its upload and sampling helpers)
+            "compiled_steps": sum(1 for s in self.steps if s.compile_s),
+            "compile_s_total": round(
+                sum(s.compile_s for s in self.steps), 4),
+            "programs": len(self.compiled_shapes),
         }
 
     def to_run_record(self, *, config: str = "engine-serve",

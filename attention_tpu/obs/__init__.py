@@ -2,7 +2,7 @@
 
 The observability layer SURVEY §5 planned and the serving engine needs:
 the reference's entire story was a printf of wall time
-(`attention.c:186-188`); ours is three composable pieces sharing one
+(`attention.c:186-188`); ours is four composable pieces sharing one
 process-wide state:
 
 * **Registry** (`obs.registry`) — counters / gauges / fixed-bucket
@@ -14,7 +14,12 @@ process-wide state:
   records a row into a bounded ring;
 * **Exporters** (`obs.export`) — Prometheus text (:func:`prom_text`),
   JSONL, and a Chrome-trace timeline of the ring's host spans
-  (``cli obs export --format chrome|prom|jsonl``).
+  (``cli obs export --format chrome|prom|jsonl``);
+* **Compile log** (`obs.compiles`) — ALWAYS on, whatever the flag
+  below: ``summary()`` is JAX's trace, lower and compile seconds (unions
+  by kind), programs, persistent-cache hits and misses and the ten
+  costliest functions; seen in ``cli obs report``, ``serve-sim``'s
+  ``compiled_steps`` and a step's ``StepMetrics.compile_s``.
 
 The registry and the ring are **disabled by default**: a disabled
 instrument is a single flag check and a disabled span only the inert
@@ -44,6 +49,7 @@ from attention_tpu.obs.export import (  # noqa: F401
     device_dir_of,
     dump,
     jsonl_lines,
+    live_snapshot,
     load_anomaly,
     load_blackbox,
     load_dump,
@@ -98,6 +104,7 @@ from attention_tpu.obs.spans import (  # noqa: F401
 from attention_tpu.obs import anomaly  # noqa: F401
 from attention_tpu.obs import blackbox  # noqa: F401
 from attention_tpu.obs import capacity  # noqa: F401
+from attention_tpu.obs import compiles  # noqa: F401
 from attention_tpu.obs import forecast  # noqa: F401
 from attention_tpu.obs import postmortem  # noqa: F401
 from attention_tpu.obs import slo  # noqa: F401
@@ -113,7 +120,7 @@ def enabled() -> bool:
 def reset() -> None:
     """Zero every metric series and drop every span event, request
     trace, and flight-recorder record (instrument registrations
-    survive)."""
+    survive, and so does the compile log: `obs.compiles`)."""
     REGISTRY.reset()
     _spans.clear()
     trace.clear()

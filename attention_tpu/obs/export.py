@@ -210,13 +210,23 @@ def chrome_trace(span_events: list[dict] | None = None,
     return {"traceEvents": trace_events, "displayTimeUnit": "ms"}
 
 
+def live_snapshot() -> dict[str, Any]:
+    """What :func:`dump` writes as ``metrics.json`` and
+    :func:`load_dump` gives back: the registry's snapshot with the
+    process's compile log beside it (``compiles``:
+    `obs.compiles.summary`)."""
+    from attention_tpu.obs import compiles as _compiles
+
+    return dict(REGISTRY.snapshot(), compiles=_compiles.summary())
+
+
 def dump(out_dir: str) -> None:
     """Persist the live telemetry state under ``out_dir``."""
     from attention_tpu.obs import blackbox as _blackbox
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, DUMP_METRICS), "w") as f:
-        json.dump(REGISTRY.snapshot(), f, indent=1)
+        json.dump(live_snapshot(), f, indent=1)
         f.write("\n")
     write_jsonl(os.path.join(out_dir, DUMP_EVENTS))
     chains = _trace.all_traces()

@@ -6,8 +6,9 @@ annotations on the capture's own clock (ISSUE 24: nested spans, their
 fields, and the engine step's phases read back from a CPU
 `jax.profiler` capture); Prometheus text that round-trips through a
 parser; the mtime-newest and
-truncated-capture behavior of the profiler parser; the
-zero-overhead-when-disabled contract (<5% on a tight loop, byte-
+truncated-capture behavior of the profiler parser; the compile log
+(`obs.compiles`: always on, unions not sums, bounded, one listener
+pair a process); the zero-overhead-when-disabled contract (<5% on a tight loop, byte-
 identical engine AND multi-replica front-end outputs — the router hot
 path may not depend on telemetry); and the `cli obs` report/export
 family.
@@ -221,6 +222,181 @@ def test_jsonl_export_and_dump_roundtrip(obs_state, tmp_path):
     assert [e["name"] for e in events] == ["obs.test.work"]
     lines = (run / "events.jsonl").read_text().splitlines()
     assert all(json.loads(ln) for ln in lines)
+
+
+# --------------------------------------------------------- compile log
+
+
+def _fresh(name):
+    """A jitted function no other test has: its first call at a shape
+    traces, lowers and compiles."""
+    import jax
+
+    def f(x):
+        return x * 2 + 1
+
+    f.__name__ = f.__qualname__ = name
+    return jax.jit(f)
+
+
+def test_compile_log_counts_a_new_shape_once_and_needs_no_enable():
+    """A jitted function at a new shape moves `obs.compiles.count`, a
+    second call at that shape does not; nothing hangs on
+    `obs.enable()`, and `obs.reset()` leaves the log as it is."""
+    import jax.numpy as jnp
+
+    from attention_tpu.obs import compiles
+
+    assert not obs.is_enabled()
+    f = _fresh("obs_test_new_shape")
+    before = compiles.count
+    t0 = time.perf_counter()
+    f(jnp.zeros((3,), jnp.float32)).block_until_ready()
+    first = compiles.count
+    assert first > before
+    f(jnp.ones((3,), jnp.float32)).block_until_ready()
+    assert compiles.count == first
+    f(jnp.zeros((5,), jnp.float32)).block_until_ready()
+    assert compiles.count > first
+    log = compiles.summary(since=t0)
+    mine = {(r["function"], r["kind"]): r["count"]
+            for r in log["by_function"]
+            if "obs_test_new_shape" in r["function"]}
+    assert sorted(k for _, k in mine) == ["compile", "lower", "trace"]
+    assert set(mine.values()) == {2}                 # one a shape
+    assert log["traces"] >= 2 and log["programs"] >= 2
+    assert log["all_s"] > 0
+    # the kinds' unions add up to no less than the union of all
+    assert log["trace_s"] + log["lower_s"] + log["compile_s"] \
+        >= log["all_s"] - 1e-9
+    whole, rows = compiles.summary(), compiles.count
+    obs.reset()
+    assert compiles.count == rows
+    assert compiles.summary()["traces"] == whole["traces"]
+
+
+def test_compile_log_nested_traces_are_a_union_not_a_sum():
+    """A jitted function traced inside another's trace: `traces`
+    counts both, `trace_s` is the length of the union of the two
+    intervals, so no more than the wall time around the call and less
+    than the durations summed."""
+    import jax
+    import jax.numpy as jnp
+
+    from attention_tpu.obs import compiles
+
+    inner = _fresh("obs_test_nested_inner")
+
+    def outer(x):
+        y = inner(x)
+        for _ in range(20):          # some tracing of the outer's own
+            y = jnp.sin(y) + x
+        return y
+
+    outer.__name__ = outer.__qualname__ = "obs_test_nested_outer"
+    t0 = time.perf_counter()
+    jax.jit(outer)(jnp.zeros((7,), jnp.float32)).block_until_ready()
+    t1 = time.perf_counter()
+    log = compiles.summary(since=t0, until=t1)
+    traced = {r["function"]: r for r in log["by_function"]
+              if r["kind"] == "trace"}
+    assert {"obs_test_nested_inner", "obs_test_nested_outer"} <= set(traced)
+    assert log["traces"] >= 2
+    summed = sum(r["seconds"] for r in traced.values())
+    assert traced["obs_test_nested_outer"]["seconds"] \
+        <= log["trace_s"] < summed
+    # JAX times the events on `time.time`, the stamps are on
+    # `time.perf_counter`: a millisecond of room between the clocks
+    assert log["trace_s"] <= (t1 - t0) + 1e-3
+    assert log["all_s"] <= (t1 - t0) + 1e-3
+
+
+def test_compile_log_since_and_until_cut_at_the_stamp():
+    import jax.numpy as jnp
+
+    from attention_tpu.obs import compiles
+
+    x = jnp.zeros((9,), jnp.float32)
+    t0 = time.perf_counter()
+    _fresh("obs_test_cut_first")(x).block_until_ready()
+    t1 = time.perf_counter()
+    _fresh("obs_test_cut_second")(x).block_until_ready()
+
+    def names(**bounds):
+        return {r["function"].removeprefix("jit(").removesuffix(")")
+                for r in compiles.summary(**bounds)["by_function"]}
+
+    assert "obs_test_cut_first" in names(since=t0, until=t1)
+    assert "obs_test_cut_second" not in names(since=t0, until=t1)
+    assert "obs_test_cut_second" in names(since=t1)
+    assert "obs_test_cut_first" not in names(since=t1)
+    assert {"obs_test_cut_first", "obs_test_cut_second"} <= names(since=t0)
+    assert compiles.summary(until=t0 - 1e9)["traces"] == 0
+    both = compiles.summary(since=t0)
+    assert both["traces"] == (compiles.summary(since=t0, until=t1)["traces"]
+                              + compiles.summary(since=t1)["traces"])
+
+
+def test_compile_log_totals_add_up_after_the_ring_wraps(monkeypatch):
+    """The ring drops its oldest rows; counts, sums and `by_function`
+    over the process's life do not: they are kept apart."""
+    import collections
+
+    from attention_tpu.obs import compiles
+
+    monkeypatch.setattr(compiles, "_rows", collections.deque(maxlen=8))
+    monkeypatch.setattr(compiles, "_totals", {})
+    monkeypatch.setattr(compiles, "count", 0)
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    compile_ = "/jax/core/compile/backend_compile_duration"
+    for i in range(20):
+        compiles._on_duration(trace, 0.5, fun_name=f"f{i % 2}")
+        compiles._on_duration(compile_, 0.25)     # no name: still counts
+        compiles._on_event("/jax/compilation_cache/cache_misses")
+        compiles._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+        compiles._on_duration("/jax/other/duration", 9.0)   # not ours
+        compiles._on_event("/jax/other/event")
+    assert compiles.count == 80 and len(compiles._rows) == 8
+    log = compiles.summary()
+    assert log["dropped"] == 72
+    assert (log["traces"], log["programs"], log["cache_misses"]) \
+        == (20, 20, 20)
+    assert log["cache_retrieval_s"] == 2.5
+    assert {(r["function"], r["kind"]): (r["seconds"], r["count"])
+            for r in log["by_function"]} == {
+        ("f0", "trace"): (5.0, 10), ("f1", "trace"): (5.0, 10),
+        ("", "compile"): (5.0, 20)}
+    # a bounded reading sees the rows the ring still holds
+    assert compiles.summary(since=0.0)["traces"] == 2
+
+
+def test_compile_log_registers_its_listeners_once(tiny_model):
+    """One duration listener and one event listener a process: a
+    second engine or another import of the package adds none."""
+    import importlib
+
+    from jax._src import monitoring
+
+    from attention_tpu.engine import ServingEngine
+    from attention_tpu.obs import compiles
+
+    def ours():
+        return (sum(cb is compiles._on_duration for cb in
+                    monitoring.get_event_duration_listeners()),
+                sum(cb is compiles._on_event for cb in
+                    monitoring.get_event_listeners()))
+
+    assert ours() == (1, 1)
+    n = (len(monitoring.get_event_duration_listeners()),
+         len(monitoring.get_event_listeners()))
+    model, params = tiny_model
+    ServingEngine(model, params, _engine_config())
+    ServingEngine(model, params, _engine_config())
+    assert importlib.import_module("attention_tpu.obs").compiles is compiles
+    assert ours() == (1, 1)
+    assert (len(monitoring.get_event_duration_listeners()),
+            len(monitoring.get_event_listeners())) == n
 
 
 # ----------------------------------------------------- quantile digest
@@ -617,6 +793,50 @@ def test_engine_phase_spans_under_a_capture(tiny_model, tmp_path):
     assert 0 < summary["prefill_p50_ms"] <= summary["prefill_p90_ms"]
 
 
+def test_a_step_that_compiles_marks_the_capture(tmp_path):
+    """A step made to compile inside a profiler capture leaves ONE
+    `engine.program.compiled` event on the host plane, inside its
+    `engine.step`, with the step's shape and what the compile log
+    holds for it; a step at a shape the process has run leaves none."""
+    import jax
+    import jax.numpy as jnp
+
+    from attention_tpu.engine import SamplingParams, ServingEngine
+    from attention_tpu.models import TinyDecoder
+
+    # a model of this test's own: nothing of it is compiled yet
+    model = TinyDecoder(vocab=37, dim=32, depth=1, num_q_heads=4,
+                        num_kv_heads=2, impl="flash", dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = ServingEngine(model, params, _engine_config())
+    engine.add_request(list(range(1, 12)), SamplingParams(max_tokens=4))
+    assert not obs.is_enabled()
+    with _capture(tmp_path):
+        while engine.scheduler.has_work():
+            engine.step()
+    rows = _host_events(tmp_path, ("engine.step", "engine.program."))
+    steps = [r for r in rows if r[1] == "engine.step"]
+    marks = [r for r in rows if r[1] == "engine.program.compiled"]
+    compiled = [m for m in engine.metrics.steps if m.compile_s]
+    assert len(marks) == len(compiled) == 2        # a chunk, then decode
+    assert len(steps) == len(engine.metrics.steps) > len(marks)
+    for mark, m in zip(marks, compiled):
+        stats = mark[4]
+        assert set(stats) == {"step", "width", "q_tile", "trace_ms",
+                              "lower_ms", "compile_ms", "cache",
+                              "function"}
+        assert stats["step"] == m.step
+        assert stats["width"] \
+            == m.decode_tokens + m.prefill_tokens + m.pad_tokens
+        assert stats["q_tile"] > 0 and stats["cache"] == "off"
+        assert "_ragged_apply" in stats["function"]
+        assert stats["trace_ms"] > 0 and stats["compile_ms"] > 0
+        (step,) = [s for s in steps if s[4]["step"] == m.step]
+        assert step[0] == mark[0]                    # the loop's thread
+        assert step[2] <= mark[2] and mark[3] <= step[3]
+
+
 def test_chrome_trace_is_host_spans_with_fields(obs_state):
     """The chrome export lays out the ring's host spans (fields as
     args) and nothing of the device: a profiler capture already holds
@@ -834,10 +1054,16 @@ def test_cli_serve_sim_obs_dump_report_and_export(tmp_path, capsys):
         rc = main(["serve-sim", "--num-requests", "2", "--max-tokens",
                    "2", "--prompt-len-max", "8", "--obs-out", str(run)])
         assert rc == 0
-        capsys.readouterr()
+        summary = json.loads(
+            capsys.readouterr().out.strip().splitlines()[-1])["summary"]
+        # the steps that compiled, by the program's own compile log
+        assert {"compiled_steps", "compile_s_total", "programs"} \
+            <= set(summary)
+        assert summary["compiled_steps"] >= summary["programs"] >= 0
 
         assert main(["obs", "report", "--run", str(run)]) == 0
         report = capsys.readouterr().out
+        assert "== compiles ==" in report and "programs=" in report
         assert "engine.steps.total" in report
         assert "engine.step" in report  # span aggregate
         # the grouped families view covers the PR 6-11 series...

@@ -20,7 +20,10 @@ Pinned here, on tiny CPU shapes:
   * the step returns the logits of the rows it can sample only
     (``(1, slots, vocab)`` once the packed axis is wider than the
     slots), bit-identical to those rows of the whole projection, with
-    no compiled signature added, and the fetch span says so.
+    no compiled signature added, and the fetch span says so;
+  * a step that compiled says so (`StepMetrics.compile_s`,
+    `compiled_programs`, the `engine.program.compiled` mark), and a
+    step at a shape the process has run says nothing.
 """
 
 import dataclasses
@@ -726,3 +729,62 @@ def test_dispatch_span_and_metrics_carry_the_kernels_grid_bound(
     assert all(m.kv_pages == 0 for m in idle)
     assert eng.metrics.summary()["mean_kv_page_share"] == round(
         sum(m.kv_pages for m in busy) / (len(busy) * table), 4)
+
+
+def test_a_step_that_compiled_says_so():
+    """The first step of a ``(width, q_tile)`` the process has not run
+    has `StepMetrics.compile_s` > 0 and `compiled_programs` >= 1, the
+    next step of that shape 0 / 0; the summary counts the steps that
+    compiled and their distinct shapes; under `obs.enable` the ring
+    holds one `engine.program.compiled` row a compiling step."""
+    # a model of this test's own: nothing of it is compiled yet
+    model = TinyDecoder(vocab=41, dim=32, depth=1, num_q_heads=4,
+                        num_kv_heads=2, impl="flash", dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    was = obs.enabled()
+    obs.enable()
+    obs.reset()
+    try:
+        eng = ServingEngine(model, params, _cfg())
+        eng.add_request(list(range(1, 20)), SamplingParams(max_tokens=5))
+        # two more of the first's shapes, after it: nothing compiles
+        while eng.scheduler.has_work():
+            eng.step()
+        eng.add_request(list(range(2, 21)), SamplingParams(max_tokens=3))
+        while eng.scheduler.has_work():
+            eng.step()
+        events = obs.events()
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()
+    shape_of = {}      # step -> (width, q_tile), from the dispatch spans
+    for e in events:
+        if e["name"] == "engine.step.dispatch":
+            shape_of[len(shape_of)] = (e["fields"]["width"],
+                                       e["fields"]["q_tile"])
+    busy = [m for m in eng.metrics.steps
+            if m.decode_tokens or m.prefill_tokens]
+    assert len(busy) == len(shape_of) == len(eng.metrics.steps)
+    seen = set()
+    for i, m in enumerate(busy):
+        if shape_of[i] in seen:
+            assert (m.compile_s, m.compiled_programs) == (0.0, 0)
+        else:
+            seen.add(shape_of[i])
+            assert m.compile_s > 0 and m.compiled_programs >= 1
+            assert m.compile_s <= m.wall_s + 1e-3
+    assert len(seen) == 2 < len(busy)
+    summary = eng.metrics.summary()
+    assert summary["compiled_steps"] == summary["programs"] == len(seen)
+    assert eng.metrics.compiled_shapes == seen
+    assert summary["compile_s_total"] == round(
+        sum(m.compile_s for m in busy), 4) > 0
+    marks = [e["fields"] for e in events
+             if e["name"] == "engine.program.compiled"]
+    compiled = [m for m in busy if m.compile_s]
+    assert [(f["step"], (f["width"], f["q_tile"])) for f in marks] \
+        == [(m.step, shape_of[m.step]) for m in compiled]
+    for f in marks:
+        assert f["cache"] == "off" and "_ragged_apply" in f["function"]
+        assert f["compile_ms"] > 0 and f["trace_ms"] > 0
