@@ -53,7 +53,11 @@ from attention_tpu.engine.metrics import (
     StepMetrics,
 )
 from attention_tpu.engine.request import Request, RequestState, SamplingParams
-from attention_tpu.engine.scheduler import ScheduledStep, Scheduler
+from attention_tpu.engine.scheduler import (
+    ScheduledStep,
+    Scheduler,
+    split_step_buffer,
+)
 from attention_tpu.models.moe import PackedTokens
 from attention_tpu.ops.gated_delta import RaggedStateStep
 from attention_tpu.ops.paged import OutOfPagesError, PagePool
@@ -130,12 +134,13 @@ def require_pages_only(model, feature: str) -> None:
 
 
 class RaggedStepIndex(NamedTuple):
-    """What one packed step tells every layer, uploaded once: the index
-    fields of `RaggedPagedStep` (and of `RaggedStateStep`, which reads
+    """What one packed step tells every layer: the index fields of
+    `RaggedPagedStep` (and of `RaggedStateStep`, which reads
     ``state_rows`` with four of them; None for a model with no
-    recurrent layer).  Kept apart from the pools because the step
-    donates those, and one buffer that every layer reads cannot be
-    given away."""
+    recurrent layer).  The jitted step slices them out of the ONE
+    int32 buffer the engine uploads a step (`_step_inputs`); none is
+    an upload of its own.  Kept apart from the pools because the step
+    donates those, and what every layer reads cannot be given away."""
 
     page_table: jax.Array
     kv_lens: jax.Array
@@ -188,22 +193,51 @@ def _step_pools(model, pools, steps) -> tuple:
                  for layer, (pair, step) in enumerate(zip(pools, steps)))
 
 
-@functools.partial(jax.jit, static_argnames=("model",),
+class StepLayout(NamedTuple):
+    """What places a packed step in its buffer, and its query tile:
+    Python ints, static under the jit.  The first two are an engine's
+    constants; whether the buffer ends with ``state_rows`` is read
+    from the model."""
+
+    slots: int
+    table_width: int
+    q_tile: int
+
+
+def _step_inputs(model, buffer, layout: StepLayout):
+    """``(tokens, index)`` of a packed step's ``buffer``
+    (`PackedBatch.buffer`, on the host or on the device): the token
+    axis ``(1, width)`` and the `RaggedStepIndex` every layer reads,
+    the width read from the buffer's length.  ``q_span`` is made here,
+    ``layout.q_tile`` long: its shape is all anyone reads of it."""
+    seg = split_step_buffer(
+        buffer, slots=layout.slots, table_width=layout.table_width,
+        recurrent=bool(getattr(model, "recurrent_layers", ())))
+    return seg.tokens, RaggedStepIndex(
+        seg.tables, seg.kv_lens, seg.cu_q_lens, seg.distribution,
+        seg.token_pos, seg.token_slot,
+        jnp.zeros((layout.q_tile,), jnp.int32), seg.state_rows)
+
+
+@functools.partial(jax.jit, static_argnames=("model", "layout"),
                    donate_argnames=("pools",))
-def _ragged_apply(model, params, tokens, pools, index):
+def _ragged_apply(model, params, buffer, pools, layout):
     """One PACKED model step: the whole mixed decode/prefill batch as a
     single ``(1, width)`` token axis over per-layer `RaggedPagedStep`
     caches — exactly one attention launch per layer per engine step.
-    Width and the index's q_tile marker are pow2-bucketed by the
-    caller, so distinct compiled signatures stay O(log max_tokens).
+    ``buffer`` is the step's ONE upload (`PackedBatch.buffer`), split
+    here by static slices into the tokens and the index every layer
+    shares (`_step_inputs`).  Its length, a function of the width
+    alone in one engine, and ``layout.q_tile`` are pow2-bucketed by
+    the caller, so distinct compiled signatures stay
+    O(log max_tokens): one a ``(width, q_tile)``.
 
     ``pools`` holds one pair of arrays a layer, in layer order (for a
     double layer the two latent pools of its attention sublayers), and
     is DONATED: the step writes its rows into them in place
     (`ragged_paged_append`, the recurrent layers' kernel) and hands
     the same buffers back, so the caller's arrays are gone after the
-    call and it rebinds from the result.  ``index``
-    (`RaggedStepIndex`) is shared by every layer and stays the
+    call and it rebinds from the result.  ``buffer`` stays the
     caller's.
 
     Returns ``(logits, pools, expert_pairs)``, the logits of the rows
@@ -221,6 +255,7 @@ def _ragged_apply(model, params, tokens, pools, index):
     sizes are input shapes, and the indices come from the
     ``cu_q_lens`` already on the device: no signature and no upload is
     added."""
+    tokens, index = _step_inputs(model, buffer, layout)
     cu = index.cu_q_lens
     rows = None
     if tokens.shape[1] > cu.shape[0] - 1:
@@ -324,8 +359,10 @@ class EngineConfig:
     @property
     def table_width(self) -> int:
         """Page-table row width: the pages of max_seq_len plus one
-        prefill chunk.  A compiled shape: the packed kernel's grid
-        has this many page steps."""
+        prefill chunk.  A compiled shape: it sizes the ``tables``
+        segment of a step's buffer and the compare-and-count of the
+        kernel's work list (`ops.ragged_paged.work_items`); the grid
+        itself walks only the live (slot, page) pairs."""
         return -(-(self.max_seq_len + self.prefill_chunk)
                  // self.page_size)
 
@@ -419,13 +456,13 @@ class ServingEngine:
             # place the parameters on the mesh ONCE: left uncommitted on
             # the default device they would be re-replicated to every
             # shard by each step's launch
-            self.params = jax.device_put(
-                params, NamedSharding(self.mesh, PartitionSpec())
-            )
+            self._replicated = NamedSharding(self.mesh, PartitionSpec())
+            self.params = jax.device_put(params, self._replicated)
         else:
             self.mesh = None
             self._step_model = model
             self._pool_sharding = None
+            self._replicated = None   # the default device
 
         dtype = config.cache_dtype or model.dtype
         # what an attention sublayer keeps, by the model's own word: K
@@ -941,6 +978,12 @@ class ServingEngine:
             return arr
         return jax.device_put(arr, self._pool_sharding)
 
+    def _upload(self, buffer: np.ndarray) -> jax.Array:
+        """A busy step's ONE host-to-device transfer: its whole packed
+        buffer (`PackedBatch.buffer`), replicated on a mesh engine as
+        the parameters are."""
+        return jax.device_put(buffer, self._replicated)
+
     def _layer_pools(self) -> tuple:
         """The pools as `_ragged_apply` takes them: a pair a layer, in
         layer order.  The call consumes these arrays."""
@@ -1015,7 +1058,8 @@ class ServingEngine:
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             width = packed_bucket(max(total, q_tile))
             batch = sched.pack(width=width, slots=slots,
-                               table_width=cfg.table_width)
+                               table_width=cfg.table_width,
+                               recurrent=bool(self._state_layers))
             # the kernel's grid bound for this step, counted here by
             # the rule the device builds it from (a slot the append
             # poisons there reads 1 on the device, its pages here)
@@ -1027,21 +1071,9 @@ class ServingEngine:
                 sinks=self.model.attn_sinks or None, xp=np).sum())
             qk_pairs = _qk_pairs(batch.kv_lens, np.diff(batch.cu_q_lens),
                                  self.model.window)
-        with obs.span("engine.step.upload"):
-            tables = jnp.asarray(batch.tables, jnp.int32)
-            kv_lens = jnp.asarray(batch.kv_lens, jnp.int32)
-            cu = jnp.asarray(batch.cu_q_lens, jnp.int32)
-            dist = jnp.asarray(batch.distribution, jnp.int32)
-            pos = jnp.asarray(batch.token_pos, jnp.int32)
-            slot = jnp.asarray(batch.token_slot, jnp.int32)
-            tokens = jnp.asarray(batch.tokens, jnp.int32)
-            state_rows = None
-            if self._state_layers:
-                state_rows = jnp.asarray(batch.state_rows, jnp.int32)
-            index = RaggedStepIndex(
-                tables, kv_lens, cu, dist, pos, slot,
-                np.zeros((q_tile,), np.int32),  # shape carries q_tile
-                state_rows)
+        with obs.span("engine.step.upload", bytes=batch.buffer.nbytes,
+                      arrays=1):
+            buffer = self._upload(batch.buffer)
         sampled = len(sched.decode) + len(sched.prefill)
         fields = {}
         if self._state_layers:
@@ -1063,8 +1095,9 @@ class ServingEngine:
                       prefill_tokens=sched.num_prefill_tokens,
                       kv_pages=kv_pages, **fields):
             logits_dev, new_pools, pairs_dev = _ragged_apply(
-                self._step_model, self.params, tokens,
-                self._layer_pools(), index)
+                self._step_model, self.params, buffer,
+                self._layer_pools(),
+                StepLayout(slots, cfg.table_width, q_tile))
             self._rebind_pools(new_pools)
         logits = self._fetch_logits(logits_dev, sampled, pairs_dev)
         with obs.span("engine.step.sample", rows=sampled):

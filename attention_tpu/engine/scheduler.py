@@ -24,6 +24,7 @@ waiting for a whole batch to drain).  Policy, deterministically:
 from __future__ import annotations
 
 import dataclasses
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -45,22 +46,86 @@ _STATE_RESETS = obs.counter(
     "request; preempt: a preempted one readmitted)")
 
 
+class StepSegments(NamedTuple):
+    """The segments of one packed step's buffer, in the buffer's order
+    (`split_step_buffer`); ``state_rows`` is None for a model with no
+    recurrent layer, whose buffer ends with ``tables``."""
+
+    tokens: Any
+    token_slot: Any
+    token_pos: Any
+    kv_lens: Any
+    cu_q_lens: Any
+    distribution: Any
+    tables: Any
+    state_rows: Any = None
+
+
+def step_buffer_len(width: int, *, slots: int, table_width: int,
+                    recurrent: bool) -> int:
+    """int32 entries of a packed step's buffer.  Slots, table width
+    and the state segment are one engine's constants, so the length
+    is a function of ``width`` alone there."""
+    return (3 * width + 2 * slots + 3 + slots * table_width
+            + (slots if recurrent else 0))
+
+
+def split_step_buffer(buffer, *, slots: int, table_width: int,
+                      recurrent: bool) -> StepSegments:
+    """The segments of a packed step's ``buffer`` (a 1-D int32 array,
+    NumPy's or the device's): static slices and two reshapes, so the
+    host gets views of the buffer and a jitted caller a handful of
+    slices.  The one place that knows the order; `ScheduledStep.pack`
+    writes through it and `_ragged_apply` reads through it.  Every
+    offset is a Python int made from the buffer's length and the
+    arguments: no arithmetic on a traced value."""
+    fixed = step_buffer_len(0, slots=slots, table_width=table_width,
+                            recurrent=recurrent)
+    width, left = divmod(buffer.size - fixed, 3)
+    if buffer.ndim != 1 or width < 0 or left:
+        raise ValueError(
+            f"a step buffer of shape {buffer.shape} is no packed step of "
+            f"{slots} slots and a table row of {table_width}")
+    off = 0
+
+    def take(n):
+        nonlocal off
+        off += n
+        return buffer[off - n:off]
+
+    # the segments' order: keyword arguments are evaluated as written
+    return StepSegments(
+        tokens=take(width).reshape(1, width),
+        token_slot=take(width),
+        token_pos=take(width),
+        kv_lens=take(slots),
+        cu_q_lens=take(slots + 1),
+        distribution=take(2),
+        tables=take(slots * table_width).reshape(slots, table_width),
+        state_rows=take(slots) if recurrent else None)
+
+
 @dataclasses.dataclass
 class PackedBatch:
     """One step's work flattened onto a single padded token axis — the
     host-side image of `ops.ragged_paged.RaggedPagedStep`.
 
-    ``tokens`` (1, width) int32 feeds the model in one launch;
-    ``token_slot``/``token_pos`` (width,) map each packed token to its
-    owning request slot (-1 = pad) and absolute cache position;
-    ``kv_lens`` (slots,) / ``cu_q_lens`` (slots+1,) / ``tables``
-    (slots, table_width) / ``distribution`` (2,) are the kernel's
-    scalar-prefetch operands.  Decode slots come first (the
-    ``distribution`` contract); ``num_real`` real tokens occupy the
-    packed prefix, the remaining ``width - num_real`` are pad.
-    ``state_rows`` (slots,) is each slot's row of the recurrent-state
-    pools (-1: an empty slot, or a model without such state)."""
+    ``buffer`` is the whole step as ONE contiguous int32 array, what
+    the engine uploads; every other array here is a VIEW of it, in
+    this order of segments (`split_step_buffer`): ``tokens``
+    (1, width) feeds the model in one launch; ``token_slot`` /
+    ``token_pos`` (width,) map each packed token to its owning request
+    slot (-1 = pad) and absolute cache position; ``kv_lens`` (slots,)
+    / ``cu_q_lens`` (slots+1,) / ``distribution`` (2,) / ``tables``
+    (slots, table_width) are the kernel's scalar-prefetch operands;
+    last, for a model with recurrent layers only, ``state_rows``
+    (slots,), each slot's row of the recurrent-state pools (-1: an
+    empty slot; None where the model keeps no such state).  Decode
+    slots come first (the ``distribution`` contract); ``num_real``
+    real tokens occupy the packed prefix, the remaining
+    ``width - num_real`` are pad."""
 
+    buffer: np.ndarray
     tokens: np.ndarray
     token_slot: np.ndarray
     token_pos: np.ndarray
@@ -68,7 +133,7 @@ class PackedBatch:
     cu_q_lens: np.ndarray
     tables: np.ndarray
     distribution: np.ndarray
-    state_rows: np.ndarray
+    state_rows: np.ndarray | None
     width: int
     num_real: int
 
@@ -100,10 +165,18 @@ class ScheduledStep:
     def is_empty(self) -> bool:
         return not self.decode and not self.prefill
 
-    def pack(self, *, width: int, slots: int,
-             table_width: int) -> PackedBatch:
+    def pack(self, *, width: int, slots: int, table_width: int,
+             recurrent: bool = False) -> PackedBatch:
         """Flatten this step onto one padded token axis, decode slots
-        first then prefill chunks, each request's tokens contiguous.
+        first then prefill chunks, each request's tokens contiguous;
+        ``recurrent`` says whether the model keeps a recurrent state,
+        hence whether the buffer has a ``state_rows`` segment.
+
+        Every segment is written in place, into a buffer made for
+        this step: one kept across steps would be rewritten under an
+        upload that has not read it yet (the chip's copy is
+        asynchronous, and the CPU backend may alias the host's memory
+        outright).
 
         CONSUMES pending decode tokens (`Request.feed_pending`) — call
         at most once per step, from the engine's dispatch path."""
@@ -117,35 +190,33 @@ class ScheduledStep:
             raise ValueError(
                 f"step has {total} tokens but packed width is {width}"
             )
-        tokens = np.zeros((1, width), np.int32)
-        token_slot = np.full((width,), -1, np.int32)
-        token_pos = np.zeros((width,), np.int32)
-        kv_lens = np.zeros((slots,), np.int32)
-        cu = np.zeros((slots + 1,), np.int32)
-        tables = np.full((slots, table_width), -1, np.int32)
-        state_rows = np.full((slots,), -1, np.int32)
+        consts = dict(slots=slots, table_width=table_width,
+                      recurrent=recurrent)
+        buffer = np.zeros(step_buffer_len(width, **consts), np.int32)
+        seg = split_step_buffer(buffer, **consts)
+        for empty in (seg.token_slot, seg.tables, seg.state_rows):
+            if empty is not None:
+                empty.fill(-1)
         num_decode = len(self.decode)
         off = 0
         for s, (req, n) in enumerate(items):
             c = req.computed_tokens
             if s < num_decode:
-                tokens[0, off] = req.feed_pending()
+                seg.tokens[0, off] = req.feed_pending()
             else:
-                tokens[0, off:off + n] = req.tokens[c:c + n]
-            token_slot[off:off + n] = s
-            token_pos[off:off + n] = np.arange(c, c + n)
-            kv_lens[s] = c
-            state_rows[s] = req.state_slot
-            tables[s, :len(req.pages)] = req.pages
+                seg.tokens[0, off:off + n] = req.tokens[c:c + n]
+            seg.token_slot[off:off + n] = s
+            seg.token_pos[off:off + n] = np.arange(c, c + n)
+            seg.kv_lens[s] = c
+            if recurrent:
+                seg.state_rows[s] = req.state_slot
+            seg.tables[s, :len(req.pages)] = req.pages
             off += n
-            cu[s + 1] = off
-        cu[len(items) + 1:] = off
-        return PackedBatch(
-            tokens=tokens, token_slot=token_slot, token_pos=token_pos,
-            kv_lens=kv_lens, cu_q_lens=cu, tables=tables,
-            distribution=np.asarray([num_decode, len(items)], np.int32),
-            state_rows=state_rows, width=width, num_real=total,
-        )
+            seg.cu_q_lens[s + 1] = off
+        seg.cu_q_lens[len(items) + 1:] = off
+        seg.distribution[:] = (num_decode, len(items))
+        return PackedBatch(buffer=buffer, width=width, num_real=total,
+                           **seg._asdict())
 
 
 class Scheduler:

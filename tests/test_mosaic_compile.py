@@ -28,7 +28,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from attention_tpu.engine.engine import RaggedStepIndex, _ragged_apply
+from attention_tpu.engine.engine import StepLayout, _ragged_apply
+from attention_tpu.engine.scheduler import step_buffer_len
 from attention_tpu.models import TinyDecoder, decoder_from_config
 from attention_tpu.ops import (
     decode,
@@ -87,29 +88,31 @@ def _compile(fn, sharding, *args):
     return jax.jit(fn).lower(*_placed(sharding, args)).compile()
 
 
-def _compile_step(model, sharding, *args):
+def _compile_step(model, sharding, params, pools, width, q_tile, *,
+                  slots=10, max_pages=34):
     """The engine's own jitted step, donation and all (a jit around it
-    would keep the caller's pools alive)."""
-    return _ragged_apply.lower(model, *_placed(sharding, args)).compile()
+    would keep the caller's pools alive), over the ONE int32 buffer a
+    step of ``width`` uploads.  ``sharding`` places params, buffer and
+    pools (one sharding, or one each)."""
+    buffer = _a((step_buffer_len(
+        width, slots=slots, table_width=max_pages,
+        recurrent=bool(getattr(model, "recurrent_layers", ()))),), I32)
+    return _ragged_apply.lower(
+        model, *_placed(sharding, (params, buffer, pools)),
+        StepLayout(slots, max_pages, q_tile)).compile()
 
 
 def _a(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _ragged_index(width, q_tile, *, slots=10, max_pages=34,
-                  recurrent=False):
-    return RaggedStepIndex(
-        _a((slots, max_pages), I32), _a((slots,), I32),
-        _a((slots + 1,), I32), _a((2,), I32), _a((width,), I32),
-        _a((width,), I32), _a((q_tile,), I32),
-        _a((slots,), I32) if recurrent else None)
-
-
-def _ragged_cache(hkv, width, q_tile, dtype, *, pages=64, d=128, **index):
+def _ragged_cache(hkv, width, q_tile, dtype, *, pages=64, d=128, slots=10,
+                  max_pages=34):
     pool = _a((pages, hkv, 128, d), dtype)
-    return RaggedPagedStep(pool, pool,
-                           *_ragged_index(width, q_tile, **index)[:-1])
+    return RaggedPagedStep(
+        pool, pool, _a((slots, max_pages), I32), _a((slots,), I32),
+        _a((slots + 1,), I32), _a((2,), I32), _a((width,), I32),
+        _a((width,), I32), _a((q_tile,), I32))
 
 
 def _device_bytes(sharding, pools):
@@ -177,9 +180,7 @@ def test_ragged_engine_step_at_smoke_width(v5e):
     pool = _a((2048, 4, 128, 128), BF16)
     pools = ((pool, pool),) * model.depth
     one = jax.sharding.SingleDeviceSharding(v5e[0])
-    compiled = _compile_step(
-        model, one,
-        params, _a((1, 512), I32), pools, _ragged_index(512, 256))
+    compiled = _compile_step(model, one, params, pools, 512, 256)
     # the pools are donated and written in place: the step holds them
     # once, and every byte of them is the caller's buffer
     _assert_in_place(compiled, model, pools, one)
@@ -210,7 +211,7 @@ def _olmo_hybrid_cell():
     pair = (_a((10, *state), F32), _a((10, *conv), BF16))
     pools = tuple(pair if layer in model.recurrent_layers else (pool, pool)
                   for layer in range(model.depth))
-    return model, pools, dict(slots=9, max_pages=52, recurrent=True)
+    return model, pools, dict(slots=9, max_pages=52)
 
 
 @pytest.mark.parametrize("width,q_tile", [(384, 256), (8, 8)],
@@ -230,9 +231,8 @@ def test_ragged_engine_step_updates_the_cells_pools_in_place(
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), I32))["params"]
     one = jax.sharding.SingleDeviceSharding(v5e[0])
-    compiled = _compile_step(
-        model, one, params, _a((1, width), I32), pools,
-        _ragged_index(width, q_tile, **index))
+    compiled = _compile_step(model, one, params, pools, width, q_tile,
+                             **index)
     _assert_in_place(compiled, model, pools, one)
     _assert_one_kernel_a_layer(compiled, model)
 
@@ -256,8 +256,7 @@ def test_ragged_engine_step_projects_the_sampled_rows(v5e, width, q_tile,
     pool = _a((768, 4, 128, 128), BF16)
     compiled = _compile_step(
         model, jax.sharding.SingleDeviceSharding(v5e[0]),
-        params, _a((1, width), I32), ((pool, pool),),
-        _ragged_index(width, q_tile, slots=33, max_pages=32))
+        params, ((pool, pool),), width, q_tile, slots=33, max_pages=32)
     logits = compiled.out_info[0]
     assert (logits.shape, logits.dtype) == ((1, rows, 49152), F32)
 
@@ -272,9 +271,8 @@ def test_ragged_engine_step_head_sharded_over_four_devices(v5e):
     rep = NamedSharding(mesh, P())
     by_head = NamedSharding(mesh, P(None, "tp", None, None))
     pool = _a((256, 4, 128, 128), BF16)
-    compiled = _compile_step(model, (rep, rep, by_head, rep),
-                        params, _a((1, 512), I32), ((pool, pool),),
-                        _ragged_index(512, 256))
+    compiled = _compile_step(model, (rep, rep, by_head),
+                             params, ((pool, pool),), 512, 256)
     # each device's quarter of the pools, in place there too
     _assert_in_place(compiled, model, ((pool, pool),), by_head)
     assert _device_bytes(by_head, (pool, pool)) == 2 * 256 * 128 * 128 * 2
@@ -379,7 +377,7 @@ def _nemotron_cell():
     pools = tuple(pair if layer in model.recurrent_layers
                   else (pool, pool) if layer in model.attention_layers
                   else None for layer in range(model.depth))
-    return model, pools, dict(slots=65, max_pages=18, recurrent=True)
+    return model, pools, dict(slots=65, max_pages=18)
 
 
 @pytest.mark.parametrize("width,q_tile", [(384, 256), (64, 1)],
@@ -392,9 +390,8 @@ def test_the_nemotron_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), I32))["params"]
     one = jax.sharding.SingleDeviceSharding(v5e[0])
-    compiled = _compile_step(
-        model, one, params, _a((1, width), I32), pools,
-        _ragged_index(width, q_tile, **index))
+    compiled = _compile_step(model, one, params, pools, width, q_tile,
+                             **index)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -436,9 +433,8 @@ def test_the_longcat_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
     latent cache, + 0.22 GB of temporaries at a chunk step)."""
     model, params, pools, index = _longcat_cell()
     one = jax.sharding.SingleDeviceSharding(v5e[0])
-    compiled = _compile_step(
-        model, one, params, _a((1, width), I32), pools,
-        _ragged_index(width, q_tile, **index))
+    compiled = _compile_step(model, one, params, pools, width, q_tile,
+                             **index)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)

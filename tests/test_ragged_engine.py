@@ -591,9 +591,10 @@ def ragged_calls(monkeypatch):
     a recorded call can be run again."""
     calls = []
 
-    def spy(model, params, tokens, pools, index):
+    def spy(model, params, buffer, pools, layout):
         before = jax.tree.map(jnp.copy, pools)
-        out = _ragged_apply(model, params, tokens, pools, index)
+        out = _ragged_apply(model, params, buffer, pools, layout)
+        tokens, index = engine_mod._step_inputs(model, buffer, layout)
         calls.append((tokens, engine_mod._layer_steps(model, before, index),
                       out[0]))
         return out
