@@ -197,18 +197,19 @@ class Spans:
 
 class SliceTracer:
     """The profiler over the last ``trace_seconds`` of a ``--trace 1``
-    run's window, when queues and caches are in their steady state: a
+    run's window (or, with ``stop_after``, a slice that ends earlier),
+    when queues and caches are in their steady state: a
     whole window of a serving cell is over a million events, which
     take minutes to reduce.  Host TraceMe events are on, the Python
     tracer is off (it would slow the loop that is being measured).
     The span ``bench.traced`` marks the slice on the trace's clock."""
 
     def __init__(self, enabled: bool, spans: Spans, out_dir: str,
-                 start_after: float):
+                 start_after: float, stop_after: float = math.inf):
         self.enabled, self.spans, self.out_dir = enabled, spans, out_dir
-        self.start_after = start_after
+        self.start_after, self.stop_after = start_after, stop_after
         self.started_at: float | None = None   # on the spans' clock
-        self._span = None
+        self._span, self._running = None, False
         if enabled:
             # the first start of the profiler in a process sets it up,
             # which can take seconds: paid here, in set-up, and not by
@@ -225,22 +226,35 @@ class SliceTracer:
 
     def tick(self, elapsed: float) -> None:
         """Call between units of work with the seconds since the
-        window opened; starts the trace once its time has come."""
+        window opened; starts the trace once its time has come, and
+        stops it at ``stop_after`` where the slice ends before the
+        window does (arrivals that end early, so that all are served):
+        the span closes there, the profiler runs on to the window's end,
+        since stopping it takes seconds that the requests still in
+        flight would wait."""
+        if self._span is not None and elapsed >= self.stop_after:
+            self._close_span()
         if (not self.enabled or self.started_at is not None
                 or elapsed < self.start_after):
             return
         self._start()
+        self._running = True
         self.spans.annotate = True
         self.started_at = self.spans.clock()
         self._span = self.spans.span("bench.traced")
         self._span.__enter__()
 
-    def stop(self) -> None:
-        if self._span is None:
-            return
+    def _close_span(self) -> None:
         self._span.__exit__(None, None, None)
         self._span = None
         self.spans.annotate = False
+
+    def stop(self) -> None:
+        if not self._running:
+            return
+        if self._span is not None:
+            self._close_span()
+        self._running = False
         jax.profiler.stop_trace()
 
 
@@ -282,12 +296,32 @@ class Checks:
     def correct(self) -> bool:
         return bool(self.rows) and all(r["ok"] for r in self.rows)
 
+    def compared(self) -> dict:
+        """``{check: {"value", "limit"}}`` for the result line.  JSON
+        has no NaN and no infinity: such a number goes as its name."""
+        def plain(x):
+            return float(x) if math.isfinite(x) else str(float(x))
+        return {r["check"]: {"value": plain(r["value"]),
+                             "limit": plain(r["limit"])}
+                for r in self.rows}
+
+    def report(self, file) -> None:
+        """Each number compared beside its limit, one to a line: the
+        last lines a run writes to its standard error."""
+        for r in self.rows:
+            print(f"compared: {r['check']} {float(r['value']):.9g} limit "
+                  f"{float(r['limit']):.9g} {'ok' if r['ok'] else 'NOT OK'}",
+                  file=file, flush=True)
+
 
 def result_line(*, checks: Checks, attempted: int, failed: int,
                 metrics: dict, units: dict, device: dict,
                 breakdown: dict | None = None) -> str:
     """The last line of a run: one JSON object, numbers as measured.
-    A metric whose value is missing or not finite is left out."""
+    A metric whose value is missing or not finite is left out.  The
+    numbers that decided ``correct`` come last, each beside its limit
+    (the driver keeps the end of this line of a run that is not
+    correct, and nothing else of its standard output)."""
     out = {}
     for name, value in metrics.items():
         if value is None or not math.isfinite(value):
@@ -297,4 +331,5 @@ def result_line(*, checks: Checks, attempted: int, failed: int,
             "failed": int(failed), "metrics": out, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = checks.compared()
     return json.dumps(line)

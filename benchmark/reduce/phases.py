@@ -3,9 +3,8 @@
 The program marks the phases of `ServingEngine.step` with profiler
 annotations (`attention_tpu.obs.span`): ``engine.step`` around the whole
 step and, inside it, ``engine.step.schedule``, ``.pack``, ``.upload``,
-``.dispatch``, ``.fetch`` and ``.sample`` (``.overlap`` too when the
-engine steps asynchronously), all on the thread that runs the step loop
-and on the clock of the device's ``XLA Ops`` lane.
+``.dispatch``, ``.fetch`` and ``.sample``, all on the thread that runs
+the step loop and on the clock of the device's ``XLA Ops`` lane.
 
 A phase's *exposed* time is the part of chip 0's idle intervals that
 lies under the spans of that phase: the time the chip stood still
@@ -26,8 +25,7 @@ import bisect
 from benchmark.reduce import trace
 
 STEP = "engine.step"
-PHASES = ("schedule", "pack", "upload", "dispatch", "overlap", "fetch",
-          "sample")
+PHASES = ("schedule", "pack", "upload", "dispatch", "fetch", "sample")
 MARK = "bench.traced"      # the slice's own span: names the loop's thread
 
 

@@ -14,14 +14,21 @@ lacks `summary`, fails the run."""
 import importlib.util
 
 
-def before_window(ctx):
-    """`obs.compiles.summary` of everything stamped before the window
-    opened (the window's clock is the log's, `time.perf_counter`)."""
+def log_until(until: float):
+    """`obs.compiles.summary` of everything stamped up to ``until`` on
+    the log's clock, `time.perf_counter`; None for a program without
+    the log."""
     if importlib.util.find_spec("attention_tpu.obs.compiles") is None:
         return None
     from attention_tpu.obs import compiles
 
-    return compiles.summary(until=ctx["window"][0])
+    return compiles.summary(until=until)
+
+
+def before_window(ctx):
+    """The log before the window opened (the window's clock is the
+    log's)."""
+    return log_until(ctx["window"][0])
 
 
 def _reading(ctx, key):

@@ -25,20 +25,6 @@ def op_share_of_step(ctx, pattern: str):
     return 100.0 * sum(e.dur for e in ops) / sum(e.dur for e in mods)
 
 
-def host_ms_per_step(ctx):
-    """The host's part of an engine step: the mean of the benchmark's
-    span around `engine.step()` minus the mean device time of the
-    step's program, both over the traced slice of the window."""
-    since = ctx["facts"].get("traced_from") or 0.0
-    spans = [b - a for name, a, b in ctx["spans"].records
-             if name == "bench.step" and a >= since]
-    mods = step_modules(ctx)
-    if not spans or not mods:
-        return None
-    return 1e3 * (sum(spans) / len(spans)
-                  - sum(e.dur for e in mods) / len(mods))
-
-
 def step_device_ms_p50(ctx):
     """Device time of one engine step: the median duration of the
     ragged step's program on chip 0 (``XLA Modules`` lane)."""

@@ -6,7 +6,9 @@
 Runs one cell of ``BENCHMARK.json`` on the TPU it is started on and
 prints, as the last line of its standard output, one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device``
-(and, traced, ``breakdown``).  Exits non-zero, printing no result,
+(and, traced, ``breakdown``), then ``compared``: each number that
+decided ``correct`` beside its limit, which are also the last lines of
+its standard error.  Exits non-zero, printing no result,
 when JAX finds no TPU or fewer chips than the cell asks for (code 2),
 or when the program under test is not beside it.
 
@@ -15,6 +17,15 @@ per-layer metric is a file of its own, found by its name:
 ``configs/<config>.json`` (+ ``<config>_reference.py``),
 ``traffic/<traffic>.json``, ``generators/<kind>.py``,
 ``runners/<kind>.py``, ``layer_metrics/<metric>.py``.
+
+A cell is added with NEW FILES for its configuration, reference,
+traffic, runner, readers and their ``*_flops.py``; NEW ENTRIES in
+``configs``, ``workloads`` and ``per_layer``; and the cell's name
+APPENDED to ``out_tok_per_s`` or ``tpot_p50_ms`` and to every existing
+per-layer metric it reports, the six ``startup.*`` among them: no other
+line of a file that is there.  Run first the test that makes this very
+extension in memory and holds it to every rule of the file:
+``tests/benchharness/test_bench_contract.py -k one_more_cell``.
 """
 
 from __future__ import annotations
@@ -94,10 +105,12 @@ def run_cell(cell: harness.Cell, runner, *, seed: int, seconds: float,
     else:
         metrics = {m["name"]: ran["values"].get(m["name"])
                    for m in cell.end_to_end}
-    return harness.result_line(
+    line = harness.result_line(
         checks=ran["checks"], attempted=ran["attempted"],
         failed=ran["failed"], metrics=metrics, units=units, device=device,
         breakdown=breakdown)
+    ran["checks"].report(sys.stderr)
+    return line
 
 
 def main(argv=None) -> int:

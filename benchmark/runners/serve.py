@@ -27,6 +27,7 @@ from attention_tpu.models import TinyDecoder
 from attention_tpu.ops.ragged_paged import packed_bucket, recommended_q_tile
 
 from benchmark import harness
+from benchmark.reduce import startup
 
 
 # -- building the system under test ------------------------------------------
@@ -242,7 +243,7 @@ def drive(engine, load: Load, *, seconds: float, drain_seconds: float,
         else:
             due = load.next_due()
             with spans.span("bench.idle"):
-                time.sleep(max(0.0, min(0.002, (due or now) - now)))
+                time.sleep(min(0.002, max(0.0, due - now) if due else 0.002))
     t1 = clock()
     if tracer is not None:
         tracer.stop()
@@ -347,6 +348,24 @@ def compare_sample(reference, params, config, traffic, records, sample,
 
 # -- a run ---------------------------------------------------------------------------
 
+def compile_log_summary(until: float) -> str:
+    """What the program's compile log holds up to ``until`` (a
+    `time.perf_counter` stamp), for the ``setup:`` line: the numbers the
+    `startup.*` readers take from a traced run, and the three costliest
+    (function, kind) rows, so that an untraced run says what its
+    `setup_s` is made of.  Empty for a program without the log."""
+    log = startup.log_until(until)
+    if log is None:
+        return ""
+    costliest = "; ".join(
+        f"{row['function']} {row['kind']} {row['seconds']:.2f} s x "
+        f"{row['count']}" for row in log["by_function"][:3])
+    return (f"; compile log: trace_s {log['trace_s']:.2f}, lower_s "
+            f"{log['lower_s']:.2f}, compile_s {log['compile_s']:.2f}, "
+            f"cache_misses {log['cache_misses']}, programs "
+            f"{log['programs']}; costliest: {costliest}")
+
+
 def serve_once(cell, config, traffic, *, seed, seconds, devices, clock,
                spans, trace=False, trace_dir="", t_start=None) -> dict:
     """Set-up, window and the reading of the device: everything of a
@@ -378,13 +397,14 @@ def serve_once(cell, config, traffic, *, seed, seconds, devices, clock,
     setup_s = clock() - t_start
     print("setup: " + ", ".join(
         f"{k} {v:.2f}" if isinstance(v, float) else f"{k} {v}"
-        for k, v in parts.items()) + f", setup_s {setup_s:.2f}")
+        for k, v in parts.items()) + f", setup_s {setup_s:.2f}"
+        + compile_log_summary(time.perf_counter()))
 
     compiled_before = compiles.count
     steps_before = engine.current_step
-    tracer = harness.SliceTracer(
-        trace, spans, trace_dir,
-        start_after=seconds - float(traffic["trace_seconds"]))
+    ends = seconds - float(traffic.get("trace_lead_seconds", 0.0))
+    tracer = harness.SliceTracer(trace, spans, trace_dir, stop_after=ends,
+        start_after=ends - float(traffic["trace_seconds"]))
     window = drive(engine, load, seconds=seconds,
                    drain_seconds=float(traffic.get("drain_seconds", 0.0)),
                    clock=clock, spans=spans, tracer=tracer)

@@ -113,8 +113,10 @@ def test_the_engine_blocks_hold_every_request_at_its_longest():
     # the control cell reads none of the new kernels' metrics
     assert not [m["name"] for m in harness.Cell(HEAVY).per_layer
                 if "ssm" in m["name"] or "expert" in m["name"]]
-    assert len([m for m in harness.Cell(CELL).per_layer
-                if "ssm" in m["name"] or "expert" in m["name"]]) == 5
+    assert {"kernel.ssm_share_of_step.closed", "kernel.ssm_roofline",
+            "kernel.experts_share_of_step.closed", "kernel.experts_roofline",
+            "engine.expert_load_max_over_mean.closed"} <= {
+                m["name"] for m in harness.Cell(CELL).per_layer}
 
 
 def test_the_cell_keeps_the_issues_prefill_row():

@@ -40,7 +40,7 @@ def toy_run(seed, seconds=5.0, cell=CELL, sizes=TOY):
 
 def test_a_whole_run_at_toy_size_is_correct(capsys):
     line = toy_run(3_000_000_019)
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     assert line["correct"] is True, out
     assert line["attempted"] >= 4 and line["failed"] == 0
     assert set(line["metrics"]) == {
@@ -48,6 +48,16 @@ def test_a_whole_run_at_toy_size_is_correct(capsys):
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert '"check": "widest_logit_gap"' in out
     assert '"check": "compiles_in_window", "value": 0' in out
+    # each number compared beside its limit: the end of the standard
+    # error, and the last key of the result line
+    assert list(line)[-1] == "compared"
+    assert list(line["compared"]) == [
+        "widest_logit_gap", "finished_with_wrong_token_count",
+        "nonfinite_logit_rows", "compiles_in_window"]
+    assert line["compared"]["widest_logit_gap"]["limit"] == 0.25
+    last = err.splitlines()[-4:]
+    assert last[0].startswith("compared: widest_logit_gap ")
+    assert last[3] == "compared: compiles_in_window 0 limit 0 ok"
 
 
 def test_a_token_altered_where_it_is_produced_is_not_correct(

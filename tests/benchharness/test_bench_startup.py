@@ -1,8 +1,11 @@
 """The six `startup.*` readers (`benchmark/reduce/startup.py`): the
 program's compile log up to the opening of the window, on a hand-made
-log and on a toy engine's warm-up."""
+log and on a toy engine's warm-up.  That the six entries list every
+cell of `BENCHMARK.json` is `test_bench_contract.py`'s
+``the_six_startup_entries_move_setup_s_in_every_cell``."""
 
 import collections
+import importlib.util
 import time
 
 import numpy as np
@@ -14,7 +17,6 @@ from benchmark.runners import serve
 
 METRICS = ("startup.trace_s", "startup.lower_s", "startup.compile_s",
            "startup.cache_misses", "startup.programs", "startup.rest_s")
-BENCH = harness.load_benchmark()
 
 # (kind, fun_name, end, seconds): one program before the window opens
 # at 3.0 (traced 0.5-1.0 with a function nested in it, lowered
@@ -87,25 +89,18 @@ def test_a_program_without_the_log_gives_the_readers_nothing(monkeypatch):
         startup.before_window(CTX)
 
 
-def test_the_six_entries_move_setup_s_in_every_cell_but_one():
-    """Eight of the nine cells list them.  `docqa-closed` does not yet:
-    `test_bench_latent.py` pins the count of that cell's per-layer
-    metrics at 19, and a PR that is no `benchmark` PR edits no file the
-    benchmark has (PERF.md section 7 names the edit).  The readers read
-    the same there."""
-    cells = [w["name"] for w in BENCH["workloads"]]
-    entries = {m["name"]: m for m in BENCH["per_layer"]
-               if m["name"].startswith("startup.")}
-    assert tuple(entries) == METRICS
-    listed = [c for c in cells if c != "longcat-flash-omni.docqa-closed"]
-    assert len(listed) == 8
-    for m in entries.values():
-        assert (m["moves"], m["source"], m["layer"], m["better"]) == (
-            "setup_s", "program_counter", "startup", "lower")
-        assert m["workloads"] == listed
-    for name in listed:
-        reported = {m["name"] for m in harness.Cell(name, BENCH).per_layer}
-        assert set(METRICS) <= reported
+def test_the_setup_line_says_what_the_log_holds(made_log, monkeypatch):
+    """An untraced run prints no `startup.*`: its ``setup:`` line ends
+    with the same numbers and the costliest functions.  A program
+    without the log prints the line as it was."""
+    assert serve.compile_log_summary(3.0) == (
+        "; compile log: trace_s 0.50, lower_s 0.25, compile_s 0.50, "
+        "cache_misses 1, programs 1; costliest: jit(f) compile 0.50 s x 1; "
+        "f trace 0.50 s x 1; jit(f) lower 0.25 s x 1")
+    assert "programs 2; costliest: jit(g) compile 1.00 s x 1; " in (
+        serve.compile_log_summary(6.0))
+    monkeypatch.setattr(importlib.util, "find_spec", lambda name, *a: None)
+    assert serve.compile_log_summary(3.0) == ""
 
 
 def test_programs_are_no_fewer_than_the_warm_ups_shapes():
