@@ -89,6 +89,12 @@ _LOGIT_ROWS = obs.counter("engine.step.logit_rows",
 _EXPERT_PAIRS = obs.counter(
     "engine.experts.pairs",
     "token-expert pairs a step routed, by where the expert is held")
+# a model whose attention chooses its keys: (query token, key) pairs
+# the selectors scored and the pairs attention then attended, summed
+# over the sublayers and the steps
+_ATTENTION_KEYS = obs.counter(
+    "engine.attention.keys",
+    "query-key pairs of the choosing sublayers, scored or attended")
 # mesh-serving surface: how many KV-head shards the per-step launches
 # lower onto (1 = single-device).  In the zero-collective head-sharded
 # design the kernels exchange nothing; the only cross-shard cost is
@@ -119,7 +125,8 @@ def require_pages_only(model, feature: str) -> None:
     """Refuse ``feature`` for a model with recurrent layers: it carries
     KV pages only, and pages alone do not restore such a request.  And
     for a model with latent-attention layers: it carries K / V pool
-    pairs, one a layer, and such a model keeps one pool a sublayer."""
+    pairs, one a layer, and such a model keeps one pool a sublayer, or
+    a latent pool and its selector's index pool."""
     layers = tuple(getattr(model, "recurrent_layers", ()))
     if layers:
         raise RecurrentStateUnsupportedError(
@@ -131,6 +138,12 @@ def require_pages_only(model, feature: str) -> None:
             f"{feature} carries a K and a V pool a layer, and "
             f"{type(model).__name__} keeps ONE latent pool for each "
             f"attention sublayer of layers {list(layers)}")
+    layers = tuple(getattr(model, "indexed_layers", ()))
+    if layers:
+        raise LatentCacheUnsupportedError(
+            f"{feature} carries a K and a V pool a layer, and "
+            f"{type(model).__name__} keeps a latent pool and a "
+            f"selector's index pool in layers {list(layers)}")
 
 
 class RaggedStepIndex(NamedTuple):
@@ -158,9 +171,12 @@ def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
     layer that keeps nothing (``pools`` holds None for it) is told
     which tokens are pads; a double layer (``pools`` holds the latent
     pool of each of its attention sublayers) gets a step a sublayer,
-    each of ONE pool."""
+    each of ONE pool; a layer whose attention chooses its keys
+    (``pools`` holds its latent pool and its index pool) one step of
+    both."""
     recurrent = set(getattr(model, "recurrent_layers", ()))
     latent = set(getattr(model, "latent_layers", ()))
+    indexed = set(getattr(model, "indexed_layers", ()))
 
     def cache(layer, pair):
         if pair is None:
@@ -172,6 +188,9 @@ def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
         if layer in latent:
             return tuple(RaggedPagedStep(pool, None, *index[:-1])
                          for pool in pair)
+        if layer in indexed:
+            return RaggedPagedStep(pair[0], None, *index[:-1],
+                                   index_pool=pair[1])
         return RaggedPagedStep(*pair, *index[:-1])  # all but state_rows
 
     return tuple(cache(layer, pair) for layer, pair in enumerate(pools))
@@ -181,12 +200,15 @@ def _step_pools(model, pools, steps) -> tuple:
     """What `_ragged_apply` hands back of the layers' ``steps``: the
     arrays ``pools`` held, in their places."""
     latent = set(getattr(model, "latent_layers", ()))
+    indexed = set(getattr(model, "indexed_layers", ()))
 
     def kept(layer, pair, step):
         if pair is None:
             return None
         if layer in latent:
             return tuple(sub.k_pool for sub in step)
+        if layer in indexed:
+            return step.k_pool, step.index_pool
         return step[:2]
 
     return tuple(kept(layer, pair, step)
@@ -240,12 +262,15 @@ def _ragged_apply(model, params, buffer, pools, layout):
     call and it rebinds from the result.  ``buffer`` stays the
     caller's.
 
-    Returns ``(logits, pools, expert_pairs)``, the logits of the rows
+    Returns ``(logits, pools, counts)``, the logits of the rows
     a step can sample, not of every packed position, and for a model
     with expert layers the sum over them of what each sowed
     (`models.moe.LatentExperts`: pairs per held expert, pairs of
-    experts held elsewhere, held experts that received a pair), else
-    None.  When the packed axis is
+    experts held elsewhere, held experts that received a pair), with,
+    LAST, for a model whose attention chooses its keys the pairs its
+    masks let through, summed over the sublayers
+    (`models.latent_attention`); None for a model with neither.  When
+    the packed axis is
     wider than the slot count, each slot's last row
     (`_slot_last_rows`) is gathered before the final norm and the
     float32 head, and the result is ``(1, slots, vocab)`` with slot
@@ -260,16 +285,20 @@ def _ragged_apply(model, params, buffer, pools, layout):
     rows = None
     if tokens.shape[1] > cu.shape[0] - 1:
         rows = _slot_last_rows(cu)
-    counted = bool(getattr(model, "expert_layers", ()))
+    counted = [name for name, layers in (
+        ("expert_stats", "expert_layers"),
+        ("attention_stats", "indexed_layers")) if getattr(model, layers, ())]
     out = model.apply({"params": params}, tokens,
                       _layer_steps(model, pools, index), logit_rows=rows,
-                      mutable=["expert_stats"] if counted else False)
-    pairs = None
+                      mutable=counted or False)
+    counts = None
     if counted:
         out, sown = out
-        pairs = sum(jax.tree_util.tree_leaves(sown))
+        counts = jnp.concatenate([
+            jnp.atleast_1d(sum(jax.tree_util.tree_leaves(sown[name])))
+            for name in counted])
     logits, steps = out
-    return logits, _step_pools(model, pools, steps), pairs
+    return logits, _step_pools(model, pools, steps), counts
 
 
 def _qk_pairs(kv_before: np.ndarray, q_lens: np.ndarray,
@@ -405,10 +434,14 @@ class ServingEngine:
         self._kv_layers = tuple(getattr(
             model, "attention_sublayers", range(model.depth)))
         self._latent_layers = tuple(getattr(model, "latent_layers", ()))
+        self._indexed_layers = tuple(getattr(model, "indexed_layers", ()))
+        # the keys a row of those layers keeps at most (0: no selector)
+        self._index_topk = (dict(model.sublayer)["index_topk"]
+                            if self._indexed_layers else 0)
         self._state_layers = tuple(getattr(model, "recurrent_layers", ()))
         self._expert_layers = tuple(getattr(model, "expert_layers", ()))
         if config.mesh_shards:
-            if self._latent_layers:
+            if self._latent_layers or self._indexed_layers:
                 from attention_tpu.parallel.serving import MeshConfigError
 
                 raise MeshConfigError(
@@ -466,7 +499,8 @@ class ServingEngine:
 
         dtype = config.cache_dtype or model.dtype
         # what an attention sublayer keeps, by the model's own word: K
-        # and V pools of the head size, or ONE latent pool and no V
+        # and V pools of the head size, or ONE latent pool and no V,
+        # or a latent pool and its selector's index pool
         kv_heads, widths = model.kv_pool_widths()
 
         def pools(width):
@@ -476,7 +510,9 @@ class ServingEngine:
 
         # one pool (pair) per attention SUBLAYER, in layer order
         self._k_pools = pools(widths[0])
-        self._v_pools = pools(widths[1]) if len(widths) > 1 else []
+        second = pools(widths[1]) if len(widths) > 1 else []
+        self._v_pools, self._index_pools = (
+            ([], second) if self._indexed_layers else (second, []))
         # one state and one convolution-tail pool per RECURRENT layer;
         # the last row is nobody's (empty slots of a step land there)
         state_slots = 0
@@ -509,8 +545,10 @@ class ServingEngine:
         self.metrics = EngineMetrics(
             table_entries=(config.max_decode_batch
                            + config.max_prefill_rows) * config.table_width,
-            held_experts=getattr(model, "held_experts", 0))
-        # the last step's expert pairs, fetched with its logits
+            held_experts=getattr(model, "held_experts", 0),
+            sparse_sublayers=len(self._indexed_layers))
+        # the last step's counts from the device (`_ragged_apply`):
+        # expert pairs, keys attended; fetched with its logits
         self._expert_pairs: np.ndarray | None = None
         self._step = 0
         # plain int (not itertools.count) so snapshots can persist the
@@ -801,7 +839,7 @@ class ServingEngine:
         self._finished_in_step = 0
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
-        pad_tokens = kv_pages = qk_pairs = 0
+        pad_tokens = kv_pages = qk_pairs = keys_selected = 0
         width = q_tile = compiled_programs = 0
         occupancy = compile_s = 0.0
         self._expert_pairs = None
@@ -824,7 +862,8 @@ class ServingEngine:
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             if not sched.is_empty:
-                width, q_tile, kv_pages, qk_pairs = self._run_ragged(sched)
+                (width, q_tile, kv_pages, qk_pairs,
+                 keys_selected) = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
             if _compiles.count != compile_rows:
@@ -853,6 +892,7 @@ class ServingEngine:
                 ragged_occupancy=occupancy,
                 kv_pages=kv_pages,
                 attn_qk_pairs=qk_pairs,
+                attn_keys_selected=keys_selected,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
                 compile_s=compile_s,
                 compiled_programs=compiled_programs,
@@ -890,10 +930,21 @@ class ServingEngine:
         return log["all_s"], log["programs"]
 
     def _expert_fields(self) -> dict[str, int]:
-        """The step's expert pairs as `StepMetrics` has them."""
+        """The step's counts from the device as `StepMetrics` has
+        them: the expert pairs, and LAST the keys attended where the
+        attention chooses its keys."""
         pairs = self._expert_pairs
         if pairs is None:
             return {}
+        fields = {}
+        if self._indexed_layers:
+            pairs, fields = pairs[:-1], {
+                "attn_keys_attended": int(pairs[-1])}
+            if obs.is_enabled():
+                _ATTENTION_KEYS.inc(fields["attn_keys_attended"],
+                                    which="attended")
+        if not self._expert_layers:
+            return fields
         # (`models.moe.pair_counts`) the held experts' pairs, then the
         # absent pairs, the experts reached and, where the layers have
         # zero-compute experts, the pairs that went to those
@@ -905,7 +956,8 @@ class ServingEngine:
             _EXPERT_PAIRS.inc(absent, where="absent")
             if more:
                 _EXPERT_PAIRS.inc(zero, where="zero")
-        return {"expert_pairs_local": local,
+        return {**fields,
+                "expert_pairs_local": local,
                 "expert_pairs_absent": absent,
                 "expert_load_max": int(held.max()),
                 "experts_reached": reached,
@@ -993,7 +1045,8 @@ class ServingEngine:
             # K and V, or a latent sublayer's ONE pool; a double
             # layer's two sublayers follow each other into its tuple
             pairs[layer] = (pairs[layer] or ()) + tuple(
-                pools[i] for pools in (self._k_pools, self._v_pools)
+                pools[i] for pools in (self._k_pools, self._v_pools,
+                                       self._index_pools)
                 if pools)
         for i, layer in enumerate(self._state_layers):
             pairs[layer] = (self._state_pools[i], self._conv_pools[i])
@@ -1004,8 +1057,9 @@ class ServingEngine:
                 for pool in pairs[layer])
         for i in range(len(self._kv_layers)):     # `_layer_pools`' order
             self._k_pools[i] = next(kept)
-            if self._v_pools:
-                self._v_pools[i] = next(kept)
+            for second in (self._v_pools, self._index_pools):
+                if second:
+                    second[i] = next(kept)
         for i, layer in enumerate(self._state_layers):
             self._state_pools[i], self._conv_pools[i] = pairs[layer]
 
@@ -1032,12 +1086,14 @@ class ServingEngine:
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
-    def _run_ragged(self, sched: ScheduledStep) -> tuple[int, int, int, int]:
+    def _run_ragged(self, sched: ScheduledStep
+                    ) -> tuple[int, int, int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
         the packed width and the query tile dispatched (the program's
         shape), the (slot, page) pairs the attention kernel's grid
-        walks for it, and the (query token, key) pairs one attention
-        sublayer attends.
+        walks for it, the (query token, key) pairs one attention
+        sublayer attends (or, where it chooses its keys, scores), and
+        the pairs the choice keeps by its rule (0 without one).
 
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
@@ -1071,6 +1127,11 @@ class ServingEngine:
                 sinks=self.model.attn_sinks or None, xp=np).sum())
             qk_pairs = _qk_pairs(batch.kv_lens, np.diff(batch.cu_q_lens),
                                  self.model.window)
+            # a row keeps the ``index_topk`` best of the keys it sees:
+            # the count of a window that wide
+            keys_selected = _qk_pairs(
+                batch.kv_lens, np.diff(batch.cu_q_lens), self._index_topk
+            ) if self._indexed_layers else 0
         with obs.span("engine.step.upload", bytes=batch.buffer.nbytes,
                       arrays=1):
             buffer = self._upload(batch.buffer)
@@ -1082,6 +1143,14 @@ class ServingEngine:
                       "state_layers": len(self._state_layers)}
         if self._latent_layers:
             fields["latent_layers"] = len(self._kv_layers)
+        if self._indexed_layers:
+            # sublayers whose selector scores `qk_pairs` and whose
+            # attention keeps ``index_topk`` of a row's keys at most
+            fields["sparse_layers"] = len(self._indexed_layers)
+            fields["index_topk"] = self._index_topk
+            if obs.is_enabled():
+                _ATTENTION_KEYS.inc(qk_pairs * len(self._indexed_layers),
+                                    which="scored")
         if self._expert_layers:
             fields["expert_layers"] = len(self._expert_layers)
             if getattr(self.model, "zero_experts", 0):
@@ -1108,13 +1177,13 @@ class ServingEngine:
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
-        return width, q_tile, kv_pages, qk_pairs
+        return width, q_tile, kv_pages, qk_pairs, keys_selected
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut
         runs this before it reads them."""
-        for a in (*self._k_pools, *self._v_pools, *self._state_pools,
-                  *self._conv_pools):
+        for a in (*self._k_pools, *self._v_pools, *self._index_pools,
+                  *self._state_pools, *self._conv_pools):
             jax.block_until_ready(a)
 
     def _post_decode(self, req: Request, logits_row: np.ndarray) -> None:

@@ -92,6 +92,15 @@ class StepMetrics:
     # (query token, key) pairs one attention sublayer attends: over the
     # step's slots, each query token times the keys it reaches
     attn_qk_pairs: int = 0
+    # a model whose attention CHOOSES its keys (`ops.sparse_index`):
+    # the (query token, key) pairs the attention kernels' masks let
+    # through, counted on the DEVICE and summed over the sublayers;
+    # ``attn_qk_pairs`` above is what a sublayer's selector scored,
+    # and ``attn_keys_selected`` what ONE sublayer keeps by the rule
+    # (each query row the ``index_topk`` best of the keys it sees),
+    # counted on the host from the step's own lengths
+    attn_keys_attended: int = 0
+    attn_keys_selected: int = 0
     host_overhead_s: float = 0.0     # wall minus the logits device sync
     # a step in which JAX traced, lowered or compiled something (a
     # shape the process had not run: `obs.compiles`): the seconds that
@@ -161,9 +170,13 @@ class RequestMetrics:
 class EngineMetrics:
     """Collects step and request rows over an engine's lifetime."""
 
-    def __init__(self, *, table_entries: int = 0, held_experts: int = 0):
+    def __init__(self, *, table_entries: int = 0, held_experts: int = 0,
+                 sparse_sublayers: int = 0):
         # slots x table width: what `StepMetrics.kv_pages` is a share of
         self.table_entries = table_entries
+        # attention sublayers that choose their keys: the scored
+        # pairs' multiple in `selected_key_share`
+        self.sparse_sublayers = sparse_sublayers
         # experts an expert layer holds: the mean load's denominator
         self.held_experts = held_experts
         self.steps: list[StepMetrics] = []
@@ -225,6 +238,8 @@ class EngineMetrics:
         pairs_zero = sum(s.expert_pairs_zero for s in self.steps)
         pairs_all = pairs_local + pairs_zero + sum(
             s.expert_pairs_absent for s in self.steps)
+        scored = self.sparse_sublayers * sum(
+            s.attn_qk_pairs for s in self.steps)
         wait_dig, prefill_dig = QuantileDigest(), QuantileDigest()
         for r in self.requests:
             wait_dig.add(r.queue_wait_s * 1e3)
@@ -280,6 +295,12 @@ class EngineMetrics:
             "mean_attn_qk_pairs": round(
                 sum(s.attn_qk_pairs for s in busy) / len(busy), 1)
             if busy else 0.0,
+            # of the pairs the selectors scored, the share attention
+            # attended: 1.0 while every row sees fewer keys than it
+            # may keep, top_k / context far beyond (0: no selector)
+            "selected_key_share": round(
+                sum(s.attn_keys_attended for s in self.steps) / scored, 4)
+            if scored else 0.0,
             # expert layers: the share of routed pairs whose expert is
             # held here (1 / shares at an even router), and the fullest
             # held expert's pairs over the mean's, both over all steps

@@ -44,6 +44,30 @@ absent, a ``zero_expert_type`` other than ``"identity"``,
 ``rope_scaling``, ``sliding_window``, ``norm_topk_prob`` true (the
 softmax router's weights are not normalised), a group-limited router,
 ``n_shared_experts``.
+
+A configuration with ``kv_lora_rank`` and no ``attention_method`` is a
+stack of pre-norm blocks of TWO sublayers (`transformer.LatentBlock`):
+latent attention (``q_lora_rank``, ``kv_lora_rank``,
+``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``; the
+normalised latents NOT scaled) whose keys a selector chooses
+(``index_n_heads``, ``index_head_dim``, ``index_topk``), rotary
+frequencies stretched by ``rope_scaling`` of type ``yarn`` (``factor``,
+``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``;
+``mscale_all_dim`` into the softmax scale), then a feed-forward: dense
+SwiGLU at ``intermediate_size`` in the first ``first_k_dense_replace``
+layers, after them gated experts at ``moe_intermediate_size``
+(``n_routed_experts`` and ``expert_share`` as above,
+``num_experts_per_tok``, ``routed_scaling_factor``) behind a sigmoid
+router limited to ``topk_group`` of ``n_group`` groups, plus ONE
+shared expert (``n_shared_experts``); norms at ``rms_norm_eps``.  The
+decoder has ONE KV head, the latent.  Refused by the key's name: a
+``topk_method`` other than ``"noaux_tc"``, a ``scoring_func`` other
+than ``"sigmoid"``, ``norm_topk_prob`` false, a ``rope_scaling.type``
+other than ``"yarn"`` or one whose ``mscale`` and ``mscale_all_dim``
+differ, ``moe_layer_freq`` other than 1, ``n_shared_experts`` other
+than 1, a ``hidden_act`` other than ``"silu"``, ``attention_bias``,
+``sliding_window``, and a configuration without ``index_topk`` (the
+block is served with its selector).
 """
 
 from __future__ import annotations
@@ -53,12 +77,15 @@ import jax.numpy as jnp
 from attention_tpu.models.transformer import (
     ATTENTION,
     FULL_ATTENTION,
+    LATENT_DENSE,
+    LATENT_EXPERTS,
     LINEAR_ATTENTION,
     SHORTCUT_EXPERTS,
     SPARSE_EXPERTS,
     STATE_SPACE,
     TinyDecoder,
 )
+from attention_tpu.ops.rope import YarnScaling, yarn_mscale
 
 _GELU = ("gelu", "gelu_new", "gelu_pytorch_tanh")
 
@@ -201,10 +228,92 @@ def _latent_decoder(config: dict, *, impl: str) -> TinyDecoder:
         norm_eps=float(config.get("rms_norm_eps", 1e-6)))
 
 
+def _yarn(config: dict) -> tuple[YarnScaling | None, float]:
+    """``rope_scaling`` as the rotary frequencies' stretch and the
+    factor ``m`` whose square multiplies the softmax scale."""
+    scaling = config.get("rope_scaling")
+    if scaling is None:
+        return None, 1.0
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling.type {kind!r}: the builder knows "
+                         "\"yarn\"")
+    factor = float(scaling["factor"])
+    all_dim = float(scaling.get("mscale_all_dim", 0))
+    if float(scaling.get("mscale", 1)) != all_dim:
+        raise ValueError("rope_scaling.mscale: cos and sin are not "
+                         "scaled, so it has to equal mscale_all_dim")
+    return YarnScaling(
+        factor, int(scaling["original_max_position_embeddings"]),
+        float(scaling.get("beta_fast", 32)),
+        float(scaling.get("beta_slow", 1))), yarn_mscale(factor, all_dim)
+
+
+def _indexed_latent_decoder(config: dict, *, impl: str) -> TinyDecoder:
+    """The decoder of a ``kv_lora_rank`` without ``attention_method``:
+    blocks of latent attention behind a selector of keys and a dense
+    or expert feed-forward; see the module's docstring for the keys."""
+    if "index_topk" not in config:
+        raise ValueError("index_topk: the two-sublayer latent block is "
+                         "served with its selector of keys")
+    refusals = (
+        ("topk_method", "noaux_tc", "the router's choice-only bias"),
+        ("scoring_func", "sigmoid", "the group-limited router scores "
+         "by sigmoid"),
+        ("norm_topk_prob", True, "the sigmoid router normalises the "
+         "chosen experts' weights"),
+        ("moe_layer_freq", 1, "every layer after the leading dense ones "
+         "is an expert layer"),
+        ("n_shared_experts", 1, "an expert layer has ONE shared expert"),
+        ("hidden_act", "silu", "the feed-forwards are SwiGLU"),
+        ("attention_bias", False, "the projections carry no bias"),
+        ("sliding_window", None, "the latent attention layer has none"),
+    )
+    for key, want, why in refusals:
+        if config.get(key, want) != want:
+            raise ValueError(f"{key} {config[key]!r}: {why}")
+    scaling, mscale = _yarn(config)
+    share = config.get("expert_share") or {"index": 0, "of": 1}
+    held = int(config["n_routed_experts"])
+    depth = int(config["num_hidden_layers"])
+    dense = min(int(config.get("first_k_dense_replace", 0)), depth)
+    fields = dict(
+        q_lora_rank=int(config["q_lora_rank"]),
+        kv_lora_rank=int(config["kv_lora_rank"]),
+        nope_dim=int(config["qk_nope_head_dim"]),
+        rope_dim=int(config["qk_rope_head_dim"]),
+        v_dim=int(config["v_head_dim"]),
+        index_heads=int(config["index_n_heads"]),
+        index_dim=int(config["index_head_dim"]),
+        index_topk=int(config["index_topk"]),
+        rope_scaling=scaling, softmax_mscale=mscale,
+        mlp_hidden=int(config["intermediate_size"]),
+        experts=held * int(share["of"]), experts_held=held,
+        experts_share=int(share["index"]),
+        experts_top_k=int(config["num_experts_per_tok"]),
+        experts_hidden=int(config["moe_intermediate_size"]),
+        experts_scale=float(config.get("routed_scaling_factor", 1.0)),
+        experts_groups=int(config.get("n_group", 1)),
+        experts_top_groups=int(config.get("topk_group", 1)))
+    # one latent KV head a layer: every query head is its group
+    return TinyDecoder(
+        vocab=int(config["vocab_size"]), dim=int(config["hidden_size"]),
+        depth=depth, num_q_heads=int(config["num_attention_heads"]),
+        num_kv_heads=1, impl=impl,
+        dtype=jnp.dtype(config.get("torch_dtype", "bfloat16")),
+        rope=True, rope_theta=float(config["rope_theta"]),
+        layer_types=((LATENT_DENSE,) * dense
+                     + (LATENT_EXPERTS,) * (depth - dense)),
+        sublayer=tuple(sorted(fields.items())),
+        norm_eps=float(config.get("rms_norm_eps", 1e-6)))
+
+
 def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
     """The program's decoder at the configuration's sizes."""
     if "attention_method" in config:
         return _latent_decoder(config, impl=impl)
+    if "kv_lora_rank" in config:
+        return _indexed_latent_decoder(config, impl=impl)
     if "hybrid_override_pattern" in config:
         return _sublayer_decoder(config, impl=impl)
     common = _common(config, impl=impl)

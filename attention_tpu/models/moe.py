@@ -197,16 +197,29 @@ class ReluSquaredMLP(nn.Module):
                         name="down_proj")(_relu2(h))
 
 
-def sigmoid_top_k(x, router, bias, *, top_k: int, scale: float):
+def sigmoid_top_k(x, router, bias, *, top_k: int, scale: float,
+                  groups: int = 1, top_groups: int = 1):
     """The router of `LatentExperts`, in float32 whatever ``x`` is:
     scores ``sigmoid(x W_r)``, the ``top_k`` experts by ``score +
     bias``, weighted by their scores normalised over the chosen, times
-    ``scale``.  Returns ``(chosen (T, k) int32, weight (T, k)
-    float32)``."""
+    ``scale``.  The bias chooses and never weighs.  With ``groups`` > 1
+    the choice is GROUP-LIMITED: the columns are ``groups`` equal runs,
+    a group's mark is the sum of its two largest ``score + bias``, and
+    only the ``top_groups`` groups of largest mark (ties to the lower
+    group) can be chosen from.  Returns ``(chosen (T, k) int32, weight
+    (T, k) float32)``."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    choice = scores + bias
+    if groups > 1:
+        by_group = choice.reshape(choice.shape[0], groups, -1)
+        mark = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(mark, top_groups)
+        open_ = jnp.any(best[..., None] == jnp.arange(groups), axis=-2)
+        choice = jnp.where(open_[..., None], by_group, -jnp.inf).reshape(
+            choice.shape)
+    _, chosen = jax.lax.top_k(choice, top_k)
     weight = jnp.take_along_axis(scores, chosen, axis=-1)
     weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
     return chosen.astype(jnp.int32), weight * scale
@@ -330,7 +343,9 @@ def softmax_top_k(x, router, bias, *, top_k: int, scale: float):
 class GatedExperts(nn.Module):
     """Sparse gated (SwiGLU) experts beside ZERO-COMPUTE experts, as
     ONE CHIP'S SHARE of an expert-parallel deployment: (B, S, D) ->
-    (B, S, D).
+    (B, S, D).  ``router`` = ``"softmax"``, as below, or ``"sigmoid"``:
+    `sigmoid_top_k`'s scores, normalised weights and, with ``groups``
+    > 1, group-limited choice (no zero experts behind that one).
 
         s = softmax(W_r y)                  float32, ``num_experts + zero_experts`` columns, real first
         chosen = top_k of (s + bias)        the bias selects, s weighs
@@ -361,6 +376,9 @@ class GatedExperts(nn.Module):
     hidden: int = 128
     scale: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
+    router: str = "softmax"
+    groups: int = 1
+    top_groups: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array, cache: PackedTokens | None = None):
@@ -371,15 +389,29 @@ class GatedExperts(nn.Module):
                 f"share {self.share} of {self.held} experts, top "
                 f"{self.top_k}, does not fit {self.num_experts} experts "
                 f"and {self.zero_experts} zero experts")
+        limited = {}
+        if self.router == "sigmoid":
+            if self.zero_experts or columns % self.groups or not (
+                    1 <= self.top_groups <= self.groups
+                    and self.top_k <= self.top_groups
+                    * (columns // self.groups)):
+                raise ValueError(
+                    f"a sigmoid router over {columns} columns in "
+                    f"{self.groups} groups of which {self.top_groups}, "
+                    f"top {self.top_k}, {self.zero_experts} zero experts")
+            limited = dict(groups=self.groups, top_groups=self.top_groups)
+        elif self.router != "softmax" or self.groups != 1:
+            raise ValueError(f"router {self.router!r} in {self.groups} "
+                             "groups: softmax (one group) or sigmoid")
         batch, seq, dim = x.shape
         tokens = batch * seq
         xt, valid = packed_rows(x, cache)
-        chosen, weight = softmax_top_k(
+        chosen, weight = (sigmoid_top_k if limited else softmax_top_k)(
             xt, self.param("router", nn.initializers.lecun_normal(),
                            (dim, columns), jnp.float32),
             self.param("router_bias", nn.initializers.zeros,
                        (columns,), jnp.float32),
-            top_k=self.top_k, scale=self.scale)
+            top_k=self.top_k, scale=self.scale, **limited)
 
         def experts(name, shape):
             return self.param(name, nn.initializers.lecun_normal(

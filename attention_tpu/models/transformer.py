@@ -21,6 +21,7 @@ from attention_tpu.models.attention_layer import (
 )
 from attention_tpu.models.latent_attention import (
     LatentAttention,
+    index_row_width,
     latent_row_width,
 )
 from attention_tpu.models.linear_attention import GatedDeltaNet
@@ -48,8 +49,14 @@ SUBLAYER_KINDS = (ATTENTION, STATE_SPACE, SPARSE_EXPERTS)
 #: two dense feed-forward sublayers, and one expert branch that reads
 #: the first half and lands after the second
 SHORTCUT_EXPERTS = "shortcut_experts"
+#: blocks of TWO sublayers whose mixer is latent attention behind a
+#: selector of keys (`LatentBlock`): the feed-forward is dense, or
+#: gated experts beside a shared expert
+LATENT_DENSE = "latent_dense"
+LATENT_EXPERTS = "latent_experts"
+LATENT_KINDS = (LATENT_DENSE, LATENT_EXPERTS)
 LAYER_KINDS = ((FULL_ATTENTION, LINEAR_ATTENTION) + SUBLAYER_KINDS
-               + (SHORTCUT_EXPERTS,))
+               + (SHORTCUT_EXPERTS,) + LATENT_KINDS)
 
 _ACTIVATIONS = {"gelu": nn.gelu, "silu": nn.silu}
 
@@ -303,6 +310,84 @@ class ShortcutExpertsBlock(nn.Module):
         return x if cache is None else (x, tuple(steps))
 
 
+class LatentBlock(nn.Module):
+    """A pre-norm block of two sublayers around latent attention:
+
+        x = x + LatentAttention(norm(x))     behind its selector of keys
+        x = x + F(norm(x))
+
+    ``F`` is a dense SwiGLU of ``mlp_hidden`` (``sparse`` false: a
+    leading dense layer) or `GatedExperts` behind the group-limited
+    sigmoid router plus ONE shared expert of the experts' width, which
+    every token takes and every chip computes whole.  With a cache (a
+    packed engine step of a latent pool and an index pool) it returns
+    ``(x, step)``."""
+
+    sparse: bool
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    index_heads: int
+    index_dim: int
+    index_topk: int
+    mlp_hidden: int
+    experts: int = 0          # routed over, held, share
+    experts_held: int = 0
+    experts_share: int = 0
+    experts_top_k: int = 1
+    experts_hidden: int = 0
+    experts_scale: float = 1.0
+    experts_groups: int = 1
+    experts_top_groups: int = 1
+    rope_theta: float = 10000.0
+    rope_scaling: Any = None
+    softmax_mscale: float = 1.0
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, cache=None):
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name=name)
+
+        attn = LatentAttention(
+            num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank, nope_dim=self.nope_dim,
+            rope_dim=self.rope_dim, v_dim=self.v_dim,
+            rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+            dtype=self.dtype, scale_latents=False,
+            rope_scaling=self.rope_scaling,
+            softmax_mscale=self.softmax_mscale,
+            index_heads=self.index_heads, index_dim=self.index_dim,
+            index_topk=self.index_topk, name="attn")(
+                norm("attn_norm")(x), cache)
+        if cache is not None:
+            attn, cache = attn
+        x = x + attn
+        y = norm("mlp_norm")(x)
+        if self.sparse:
+            out = GatedExperts(
+                num_experts=self.experts, held=self.experts_held,
+                share=self.experts_share, top_k=self.experts_top_k,
+                hidden=self.experts_hidden, scale=self.experts_scale,
+                router="sigmoid", groups=self.experts_groups,
+                top_groups=self.experts_top_groups, dtype=self.dtype,
+                name="experts")(
+                    y, None if cache is None
+                    else PackedTokens(cache.token_slot))
+            out = out + GatedMLP(hidden=self.experts_hidden,
+                                 dtype=self.dtype, name="shared_expert")(y)
+        else:
+            out = GatedMLP(hidden=self.mlp_hidden, dtype=self.dtype,
+                           name="mlp")(y)
+        x = x + out
+        return x if cache is None else (x, cache)
+
+
 class TinyDecoder(nn.Module):
     """Decoder-only LM: embed -> N blocks -> norm -> logits.
 
@@ -401,22 +486,39 @@ class TinyDecoder(nn.Module):
         return self._layers_of(SHORTCUT_EXPERTS)
 
     @property
+    def indexed_layers(self) -> tuple[int, ...]:
+        """Layers whose latent attention chooses its keys
+        (`LatentBlock`): each keeps a latent pool AND its selector's
+        index pool, under one page table."""
+        return self._layers_of(*LATENT_KINDS)
+
+    @property
     def attention_sublayers(self) -> tuple[int, ...]:
         """The layer of every sublayer that keeps pages, in order: a
         double layer is named twice."""
-        return tuple(sorted(self.attention_layers + 2 * self.latent_layers))
+        return tuple(sorted(self.attention_layers + 2 * self.latent_layers
+                            + self.indexed_layers))
 
     def kv_pool_widths(self) -> tuple[int, tuple[int, ...]]:
         """What an attention sublayer keeps a token: the KV heads, and
-        the row width of each pool: K and V of the head size, or the
+        the row width of each pool: K and V of the head size; or the
         ONE latent pool's ``[c | k_r]`` of ONE head
-        (`latent_attention.latent_row_width`)."""
+        (`latent_attention.latent_row_width`); or that latent pool and
+        a selector's index pool beside it
+        (`latent_attention.index_row_width`)."""
+        kinds = [k for k in (self.attention_layers, self.latent_layers,
+                             self.indexed_layers) if k]
+        if len(kinds) > 1:
+            raise ValueError(
+                "K / V pools, one latent pool, and a latent pool with an "
+                "index pool are three pool layouts; an engine's "
+                "sublayers share one")
+        f = dict(self.sublayer)
         if self.latent_layers:
-            if self.attention_layers:
-                raise ValueError("latent and K / V attention layers in "
-                                 "one model would need two pool shapes")
-            f = dict(self.sublayer)
             return 1, (latent_row_width(f["kv_lora_rank"], f["rope_dim"]),)
+        if self.indexed_layers:
+            return 1, (latent_row_width(f["kv_lora_rank"], f["rope_dim"]),
+                       index_row_width(f["index_dim"]))
         return self.num_kv_heads, (self.dim // self.num_q_heads,) * 2
 
     @property
@@ -424,7 +526,8 @@ class TinyDecoder(nn.Module):
         """Layers with sparse experts: the experts keep nothing per
         request, and report their pairs (`LatentExperts`,
         `GatedExperts`)."""
-        return self._layers_of(SPARSE_EXPERTS, SHORTCUT_EXPERTS)
+        return self._layers_of(SPARSE_EXPERTS, SHORTCUT_EXPERTS,
+                               LATENT_EXPERTS)
 
     @property
     def zero_experts(self) -> int:
@@ -484,7 +587,13 @@ class TinyDecoder(nn.Module):
                     impl=self.impl, dtype=self.dtype, rope=self.rope,
                     rope_theta=self.rope_theta, norm_eps=self.norm_eps,
                     **dict(self.sublayer), name=f"SublayerBlock_{i}")
-            if kind in SUBLAYER_KINDS + (SHORTCUT_EXPERTS,):
+            elif kind in LATENT_KINDS:
+                block = LatentBlock(
+                    sparse=kind == LATENT_EXPERTS,
+                    num_heads=self.num_q_heads, dtype=self.dtype,
+                    rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                    **dict(self.sublayer), name=f"LatentBlock_{i}")
+            if kind in SUBLAYER_KINDS + (SHORTCUT_EXPERTS,) + LATENT_KINDS:
                 if caches is None:
                     x = block(x)
                 else:
@@ -550,7 +659,8 @@ class TinyDecoder(nn.Module):
         ``rolling=True`` (windowed models only) returns ring-buffer
         caches whose memory is bounded by the window, not by
         ``capacity``/sequence length."""
-        if self.recurrent_layers or self.latent_layers:
+        if (self.recurrent_layers or self.latent_layers
+                or self.indexed_layers):
             raise ValueError(
                 "a model with recurrent or latent-attention layers serves "
                 "through the engine's packed step; it has no dense "

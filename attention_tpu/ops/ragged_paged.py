@@ -122,6 +122,13 @@ class RaggedPagedStep(NamedTuple):
     A LATENT cache is ONE pool: ``v_pool`` is None and a token's row in
     ``k_pool`` (P, 1, page_size, d) is both its key and, in its first
     lanes, its value (`ragged_paged_attention` with ``value_dim``).
+
+    ``index_pool`` (P, 1, page_size, d_i), beside a latent cache: a
+    SELECTOR's key of every cached token, under the same page ids as
+    ``k_pool`` (a page names the same tokens in both).  Nothing
+    attends it; `ops.sparse_index.select_keys` scores it to choose the
+    keys a query row attends (`ragged_paged_attention` with
+    ``select``).  None for a cache without a selector.
     """
 
     k_pool: jax.Array
@@ -133,6 +140,7 @@ class RaggedPagedStep(NamedTuple):
     token_pos: jax.Array
     token_slot: jax.Array
     q_span: jax.Array
+    index_pool: jax.Array | None = None
 
     @property
     def length(self):
@@ -214,7 +222,20 @@ def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
     one (16 query heads a KV head) would otherwise get tiles of 1, 2
     and 4 for the short remainders of a prompt, each a compiled
     program at every packed width.  A decode-only step keeps the tile
-    `tile_tokens` gives one token."""
+    `tile_tokens` gives one token.
+
+    A group so large that 8 tokens of it fill a block of the
+    row-blocked form (`_BLOCK_ROWS` rows: 128 query heads on one
+    latent head, which no other form can hold) is cut into blocks of 8
+    tokens whatever the tile: the tile is then nothing but the bound
+    on a span's blocks, and a tier of it buys nothing but one more
+    compiled program at every packed width.  Such a group gets no
+    tile under 256 tokens (7 step shapes where the ten tiers of 8 to
+    256 gave 34, at a chunk of 256 beside 32 decode rows), and the
+    common tiers above.  The packed width is then 256 or more in
+    every step that holds a chunk: at 256 rows the weights' products
+    sit on the ridge between the memory's roof and the matrix unit's,
+    so the padding costs no time that the weights' bytes do not."""
     t = packed_bucket(max_q_len, minimum=1)
     try:
         from attention_tpu.tuning.lookup import key_fields, lookup
@@ -232,6 +253,8 @@ def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
         pass
     if max_q_len > 1:
         t = max(t, 8)
+        if 8 * group >= _BLOCK_ROWS:
+            t = max(t, 256)
     return tile_tokens(t, group)
 
 
@@ -297,18 +320,43 @@ def _slot_and_page(item, max_pages: int):
     return jax.lax.div(item, width), jax.lax.rem(item, width)
 
 
-def row_block_items(kv_lens, cu_q_lens, distribution, *, max_pages: int,
-                    page: int, block_tokens: int, blocks: int, width: int):
+class RowBlocks(NamedTuple):
+    """The work of the ROW-BLOCKED form (`row_block_list`).  A GROUP
+    is one block of ``block_tokens`` tokens of one slot's span;
+    ``items[:n]`` are the (group, page) pairs to visit, coded ``(slot
+    * blocks + block) * max_pages + page``, every later entry the
+    sentinel; ``group[i]`` is item ``i``'s group, ``slot`` / ``block``
+    a group's slot and its place in the slot's span, and the first
+    ``live`` groups are the step's."""
+
+    items: jax.Array
+    n: jax.Array
+    group: jax.Array
+    slot: jax.Array
+    block: jax.Array
+    live: jax.Array
+
+
+def row_block_shape(q_tile: int, group: int) -> tuple[int, int]:
+    """``(block_tokens, blocks)`` of the row-blocked form at a step's
+    query tile: blocks of `_BLOCK_ROWS` rows at most, whole tokens,
+    ``blocks`` of them to the tile."""
+    block_tokens = min(q_tile, max(_BLOCK_ROWS // group, 1))
+    return block_tokens, -(-q_tile // block_tokens)
+
+
+def row_block_list(kv_lens, cu_q_lens, distribution, *, max_pages: int,
+                   page: int, block_tokens: int, blocks: int,
+                   width: int) -> RowBlocks:
     """The work list of the ROW-BLOCKED form (`_ragged_kernel` with
-    ``blocks``): ``(items, n)`` as `work_items` gives them, an item
-    being ``(slot * blocks + b) * max_pages + j``: page ``j`` for the
-    ``b``-th block of ``block_tokens`` tokens of the slot's span.  Slot
-    major, then block, then page, so that a block's pages follow each
-    other.  A block's pages are those its LAST row reaches (causal, no
-    window), which is a prefix of the table row, so the list is built
-    from counts: which slot a block belongs to, then which block an
-    item belongs to, two small searches where the mask form would
-    search ``slots * blocks * max_pages`` entries.
+    ``blocks``): an item is page ``j`` for the ``b``-th block of
+    ``block_tokens`` tokens of a slot's span.  Slot major, then block,
+    then page, so that a block's pages follow each other.  A block's
+    pages are those its LAST row reaches (causal, no window), which is
+    a prefix of the table row, so the list is built from counts: which
+    slot a block belongs to, then which block an item belongs to, two
+    small searches where the mask form would search ``slots * blocks *
+    max_pages`` entries.
 
     The two entries `live_pages` keeps are kept here too: an active
     slot whose length is poisoned still has one item a block (it is
@@ -345,7 +393,9 @@ def row_block_items(kv_lens, cu_q_lens, distribution, *, max_pages: int,
         groups - 1)
     j = i - (group_end - pages)[of]
     items = (g_slot[of] * blocks + g_block[of]) * max_pages + j
-    return jnp.where(i < n, items, s_slots * blocks * max_pages), n
+    return RowBlocks(
+        jnp.where(i < n, items, s_slots * blocks * max_pages), n, of,
+        g_slot, g_block, slot_end[-1])
 
 
 def _ragged_kernel(
@@ -353,7 +403,7 @@ def _ragged_kernel(
     max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
     tile_rows: int, softcap2, window: int | None, sinks: int | None,
     variant: str = "online", dv: int = 0, shared_kv: bool = False,
-    blocks: int = 0, block_tokens: int = 0,
+    blocks: int = 0, block_tokens: int = 0, select: bool = False,
 ):
     """One (kv-head, work item) grid step: item ``i`` is page ``j`` of
     slot ``r`` (`work_items`).
@@ -373,17 +423,31 @@ def _ragged_kernel(
     rows do not fit VMEM (one latent KV head is the group of EVERY
     query head).  The packed query and result stay in HBM; an item is
     a page of one BLOCK of ``block_tokens`` tokens of a slot's span
-    (`row_block_items`), whose rows are copied in at the block's first
+    (`row_block_list`), whose rows are copied in at the block's first
     item and out at its last.  A span of one token (a decode row)
     takes a tile of that token's rows alone, whatever the step's
     ``q_tile``: a page is read once for all of a decode row's heads,
     and a chunk in the same step does not widen it.  The tile
-    arithmetic is the resident form's."""
+    arithmetic is the resident form's.
+
+    ``select`` (row-blocked form only): one more input, the block of
+    `ops.sparse_index.select_keys`' result for this item's (group,
+    page), 1.0 where a token of the group CHOSE a key of the page; a
+    row attends a key only if its token chose it.  One more result,
+    what the mask let through: the rows of the mask the softmax is
+    given (causal, inside the span AND chosen), a count a (row modulo
+    8, key of a page) place, summed over the items; ``group`` rows are
+    one (query token, key) pair."""
     if shared_kv:
         v_ref = None
     else:
         v_ref, *rest = rest
-    if blocks:
+    keep_ref = cnt_ref = None
+    if select:
+        keep_ref, *rest = rest
+        _, o_ref, cnt_ref, *rest = rest
+        acc_scr, m_scr, l_scr, q_scr, o_scr, sem = rest
+    elif blocks:
         _, o_ref, acc_scr, m_scr, l_scr, q_scr, o_scr, sem = rest
     else:
         o_ref, acc_scr, m_scr, l_scr = rest
@@ -456,6 +520,25 @@ def _ragged_kernel(
             if sinks is not None:
                 win = jnp.logical_or(win, col < sinks)
             mask = jnp.logical_and(mask, win)
+        if keep_ref is not None:
+            # a token's choice, spread over its heads' rows: row r of
+            # the tile is token r // group of the block
+            kept = keep_ref[0]
+            of = jax.lax.broadcasted_iota(
+                jnp.int32, (s.shape[0], kept.shape[0]), 0) // group
+            tok = jax.lax.broadcasted_iota(
+                jnp.int32, (s.shape[0], kept.shape[0]), 1)
+            chosen = jax.lax.dot_general(
+                (of == tok).astype(jnp.float32), kept,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            mask = jnp.logical_and(mask, chosen > 0.5)
+            # the FINAL mask's rows (causal, the span's and the chosen
+            # ones at once), folded eight to a place: whole registers
+            # added, no reduction across sublanes
+            cnt_ref[...] += jnp.sum(
+                mask.astype(jnp.float32).reshape(-1, 8, mask.shape[-1]),
+                axis=0).astype(jnp.int32)
         s = jnp.where(mask, s, NEG_INF)
         p, update_acc = _softmax_variant_update(
             s, m, l, variant=variant, masked=True)
@@ -503,6 +586,11 @@ def _ragged_kernel(
                 mine, res, cur.astype(jnp.float32)
             ).astype(o_ref.dtype)
         return
+
+    if select:
+        @pl.when(i == 0)
+        def _zero_count():
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     def block_of(rows: int, mine_too):
         """The three phases of a block at a tile of ``rows`` rows, the
@@ -567,6 +655,7 @@ def _ragged_paged_attention_jit(
     sinks: int | None = None,
     max_mode: str = "online",
     value_dim: int | None = None,
+    select: jax.Array | None = None,
 ) -> jax.Array:
     """softmax(q K^T * scale) V for every packed token through its
     slot's page table, causal within each request — (1, Hq, T, dv).
@@ -586,7 +675,14 @@ def _ragged_paged_attention_jit(
     one KV head is the group of every query head, so a token's rows
     alone are a tile, and the whole packed rows of that head (89 MB at
     a width of 320, 64 heads and 576 / 512 lanes) are not held in
-    VMEM.  No window there."""
+    VMEM.  No window there.
+
+    ``select`` (a cache of one pool only): `ops.sparse_index.
+    select_keys`' result, which keys each token CHOSE; a row then
+    attends the chosen keys alone, and the call returns ``(out,
+    attended)``, ``attended`` the int32 count of (query token, key)
+    pairs the mask let through, counted in the kernel from the mask
+    the softmax is given and not from ``select``."""
     check_softcap(softcap)
     check_band(window, sinks)
     if q.ndim != 4 or q.shape[0] != 1:
@@ -636,6 +732,9 @@ def _ragged_paged_attention_jit(
         )
     if q_tile > t_pad:
         raise ValueError(f"q_tile {q_tile} > packed width {t_pad}")
+    if select is not None and not shared_kv:
+        raise ValueError("select: a choice of keys goes with a cache of "
+                         "one pool")
     if shared_kv and group % 8:
         raise ValueError(f"a cache of one pool needs a group that is a "
                          f"multiple of 8, got {group}")
@@ -667,12 +766,23 @@ def _ragged_paged_attention_jit(
     qs = qs.reshape(hkv, t_pad * group, d)
     # the row-blocked form: blocks of `_BLOCK_ROWS` rows at most, whole
     # tokens, ``blocks`` of them to the step's query tile
-    block_tokens = min(q_tile, max(_BLOCK_ROWS // group, 1))
-    blocks = -(-q_tile // block_tokens) if shared_kv else 0
+    block_tokens, blocks = row_block_shape(q_tile, group)
+    if not shared_kv:
+        blocks = 0
+    prefetch = (lens, cu, dist, cache.page_table)
     if blocks:
-        items, n_items = row_block_items(
+        listed = row_block_list(
             lens, cu, dist, max_pages=max_pages, page=page,
             block_tokens=block_tokens, blocks=blocks, width=t_pad)
+        items, n_items = listed.items, listed.n
+        prefetch += (items,)
+        if select is not None:
+            want = (listed.slot.shape[0], max_pages * page)
+            if (select.shape[0], select.shape[2]) != want:
+                raise ValueError(
+                    f"select {select.shape}: the step has {want[0]} "
+                    f"groups of {want[1]} keys")
+            prefetch += (listed.group,)
         tile_rows = block_tokens * group
         # a block is copied whole, so the last token's may reach past
         # the packed rows: spare rows, nobody's
@@ -681,10 +791,12 @@ def _ragged_paged_attention_jit(
         items, n_items = work_items(live_pages(
             lens, cu, dist, max_pages=max_pages, page=page, q_tile=q_tile,
             window=window, sinks=sinks))
+        prefetch += (items,)
         tile_rows = _row_tile(q_tile, t_pad, group)
     rows_total = qs.shape[1]
 
-    def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref):
+    def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref,
+                 *_):
         # the item's table entry, read on prefetched scalars.  An item
         # that holds a page to attend has its entry claimed; the kept
         # entries that hold none (`live_pages`) may read -1, and fetch
@@ -703,7 +815,13 @@ def _ragged_paged_attention_jit(
         softcap2=None if softcap is None else softcap * _LOG2E,
         window=window, sinks=sinks, variant=variant, dv=dv,
         shared_kv=shared_kv, blocks=blocks, block_tokens=block_tokens,
+        select=select is not None,
     )
+    if select is not None:
+        # the item's group is read by the index maps alone
+        def kernel(lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, _,
+                   *refs, kernel=kernel):
+            kernel(lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, *refs)
     # Scoped-VMEM demand: the head's whole packed q and out blocks stay
     # resident (double-buffered by the pipeline), plus the K/V page
     # buffers, the fp32 scratch, and the tile's (rows, page) score /
@@ -731,7 +849,7 @@ def _ragged_paged_attention_jit(
         pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
         pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
     ]
-    out_shape = jax.ShapeDtypeStruct((hkv, rows_total, dv), out_dtype)
+    out_shape = [jax.ShapeDtypeStruct((hkv, rows_total, dv), out_dtype)]
     if blocks:
         # rows in and out by the kernel's own copies; the result starts
         # as zeros, which is what a pad token's rows stay
@@ -741,8 +859,20 @@ def _ragged_paged_attention_jit(
         scratch += [pltpu.VMEM((tile_rows, d), qs.dtype),
                     pltpu.VMEM((tile_rows, dv), out_dtype),
                     pltpu.SemaphoreType.DMA((2,))]
-        operands = (qs, *pools, jnp.zeros(out_shape.shape, out_dtype))
-        aliases = {5 + len(operands) - 1: 0}
+        operands = (qs, *pools, jnp.zeros(out_shape[0].shape, out_dtype))
+        if select is not None:
+            def keep_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref,
+                           items_ref, group_ref):
+                return (group_ref[i], 0,
+                        _slot_and_page(items_ref[i], max_pages)[1])
+
+            tally = (8, page)
+            in_specs.insert(-1, pl.BlockSpec(
+                (1, select.shape[1], page), keep_index))
+            out_specs.append(pl.BlockSpec(tally, lambda hd, i, *_: (0, 0)))
+            out_shape.append(jax.ShapeDtypeStruct(tally, jnp.int32))
+            operands = (*operands[:-1], select, operands[-1])
+        aliases = {len(prefetch) + len(operands) - 1: 0}
     else:
         in_specs = [pl.BlockSpec((1, rows_total, d), head_index),
                     *pool_specs]
@@ -750,7 +880,7 @@ def _ragged_paged_attention_jit(
         operands = (qs, *pools)
         aliases = {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=len(prefetch),
         # the second bound is the step's own count of work items, a
         # traced scalar: one executable whatever the step holds
         grid=(hkv, n_items),
@@ -764,7 +894,7 @@ def _ragged_paged_attention_jit(
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[out_shape],
+        out_shape=out_shape,
         input_output_aliases=aliases,
         # NOT parallel: every slot of one head accumulates into the
         # same resident output block
@@ -777,11 +907,18 @@ def _ragged_paged_attention_jit(
             transcendentals=full * tile_rows * page,
         ),
         interpret=interpret,
-    )(lens, cu, dist, cache.page_table, items, *operands)
-    out = outs[0] if isinstance(outs, (list, tuple)) else outs
-    out = out[:, :t_pad * group]
+    )(*prefetch, *operands)
+    out = outs[0][:, :t_pad * group]
     out = out.reshape(hkv, t_pad, group, dv).transpose(0, 2, 1, 3)
-    return out.reshape(1, h, t_pad, dv)
+    out = out.reshape(1, h, t_pad, dv)
+    if select is None:
+        return out
+    # every head of a token has the token's mask, so the rows are a
+    # multiple of ``group``; a remainder counts one pair more, and the
+    # count is off whatever the rule says
+    rows, per = jnp.sum(outs[1]), jnp.int32(group)
+    return out, (jax.lax.div(rows, per)
+                 + (jax.lax.rem(rows, per) != 0).astype(jnp.int32))
 
 
 def ragged_paged_attention(q: jax.Array, cache: RaggedPagedStep,
@@ -885,10 +1022,14 @@ def _append_rows(pools, new_rows, tgt, pos, *, max_runs, interpret):
 
 
 def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
-                        v_new: jax.Array | None = None) -> RaggedPagedStep:
+                        v_new: jax.Array | None = None,
+                        index_new: jax.Array | None = None
+                        ) -> RaggedPagedStep:
     """Write every packed token's K/V row (k/v (1, Hkv, T, d)) at its
     slot's next positions; returns the cache with post-append lengths.
-    A cache of one pool (``v_pool`` None) takes ``k_new`` alone.
+    A cache of one pool (``v_pool`` None) takes ``k_new`` alone, and
+    one with a selector's pool (``index_pool``) the selector's keys
+    ``index_new`` with it: the same place in the same pages.
 
     The packed analog of `ops.paged.paged_append`, with the same poison
     contract: a token targeting an unclaimed (-1) table entry or past
@@ -907,7 +1048,13 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
     t = k_new.shape[2]
     if (cache.v_pool is None) != (v_new is None):
         raise ValueError("new V rows go with a V pool, and only with one")
-    new = (k_new,) if v_new is None else (k_new, v_new)
+    if (cache.index_pool is None) != (index_new is None):
+        raise ValueError("new index keys go with an index pool, and only "
+                         "with one")
+    held = {"k_pool": (cache.k_pool, k_new), "v_pool": (cache.v_pool, v_new),
+            "index_pool": (cache.index_pool, index_new)}
+    held = {f: pair for f, pair in held.items() if pair[0] is not None}
+    new = tuple(rows for _, rows in held.values())
     if (any(r.ndim != 4 or r.shape[:3] != k_new.shape[:3] for r in new)
             or k_new.shape[0] != 1
             or t != cache.token_slot.shape[0]):
@@ -928,7 +1075,7 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
     drop = jnp.logical_or(bad, slot < 0)
     # dropped tokens target one-past-the-end
     tgt = jnp.where(drop, cache.k_pool.shape[0], phys)
-    pools = tuple(p for p in (cache.k_pool, cache.v_pool) if p is not None)
+    pools = tuple(pool for pool, _ in held.values())
     pools = _append_rows(
         pools, tuple(r[0].astype(p.dtype) for r, p in zip(new, pools)),
         tgt, pos, max_runs=min(t, t // _APPEND_ROWS + 2 * s_slots),
@@ -940,9 +1087,7 @@ def ragged_paged_append(cache: RaggedPagedStep, k_new: jax.Array,
     q_lens = cache.cu_q_lens[1:] - cache.cu_q_lens[:-1]
     new_lens = jnp.where(bad_slot | (cache.kv_lens < 0), -1,
                          cache.kv_lens + q_lens)
-    return cache._replace(k_pool=pools[0],
-                          v_pool=pools[1] if len(pools) > 1 else None,
-                          kv_lens=new_lens)
+    return cache._replace(**dict(zip(held, pools)), kv_lens=new_lens)
 
 
 __all__ = [

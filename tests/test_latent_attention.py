@@ -20,7 +20,7 @@ from attention_tpu.ops.ragged_paged import (
     ragged_paged_append,
     ragged_paged_attention,
     recommended_q_tile,
-    row_block_items,
+    row_block_list,
     work_items,
 )
 
@@ -108,7 +108,7 @@ def test_a_poisoned_slot_is_nan_and_its_neighbours_are_not():
 
 
 def _mask_form(lens, cu, dist, block_tokens, blocks):
-    """`row_block_items` spelled as a mask over (slot, block, page)."""
+    """`row_block_list`'s items spelled as a mask over (slot, block, page)."""
     q = np.diff(cu)
     want = []
     for s in range(SLOTS):
@@ -134,9 +134,10 @@ def test_the_row_blocked_work_list(q_lens, kv_after):
     lens = np.zeros(SLOTS, np.int32)
     lens[:n] = kv_after
     dist = np.asarray([sum(1 for q in q_lens if q == 1), n], np.int32)
-    items, count = row_block_items(
+    items, count = row_block_list(
         jnp.asarray(lens), jnp.asarray(cu), jnp.asarray(dist),
-        max_pages=MAX_PAGES, page=PAGE, block_tokens=64, blocks=4, width=256)
+        max_pages=MAX_PAGES, page=PAGE, block_tokens=64, blocks=4,
+        width=256)[:2]
     want = _mask_form(lens, cu, dist, 64, 4)
     assert int(count) == len(want)
     assert np.asarray(items)[:len(want)].tolist() == want

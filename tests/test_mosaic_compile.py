@@ -39,6 +39,7 @@ from attention_tpu.ops import (
     paged,
     quant,
     ragged_paged,
+    sparse_index,
     ssm,
 )
 from attention_tpu.ops.flash_vjp import flash_attention_diff
@@ -67,7 +68,7 @@ def v5e():
                     f"{type(e).__name__}: {str(e)[:200]}")
     with pytest.MonkeyPatch.context() as mp:
         for mod in (flash, decode, paged, quant, ragged_paged, gated_delta,
-                    ssm, experts):
+                    ssm, experts, sparse_index):
             mp.setattr(mod, "_should_interpret", lambda: False)
         yield list(topo.devices)
 
@@ -462,6 +463,78 @@ def test_the_gated_experts_kernel_compiles_at_the_cells_sizes(v5e):
                  _a((rows, 6144), BF16), _a((16, 6144, 2048), BF16),
                  _a((16, 6144, 2048), BF16), _a((16, 2048, 6144), BF16),
                  layout)
+
+
+def _deepseek_cell():
+    """The benchmark's DeepSeek-V3.2-Exp cell whole: one dense and four
+    expert layers, 32 + 1 slots, a table row of 392 pages, 1,856 pages
+    of a latent pool AND an index pool a layer; parameters in bfloat16,
+    the router's in float32, as the cell's reference makes them."""
+    path = (pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
+            / "deepseek-v3.2-exp.json")
+    config = json.loads(path.read_text())
+    model = decoder_from_config(config)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), I32))["params"]
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, a: _a(a.shape, F32 if "router" in jax.tree_util.keystr(p)
+                        else BF16), shapes)
+    heads, widths = model.kv_pool_widths()
+    pools = tuple(
+        tuple(_a((config["engine"]["num_pages"], heads, 128, w), BF16)
+              for w in widths) for _ in range(model.depth))
+    return model, params, pools, dict(slots=33, max_pages=392)
+
+
+@pytest.mark.parametrize("width,q_tile", [(384, 256), (32, 1)],
+                         ids=["widest_step", "decode_only"])
+def test_the_deepseek_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
+    """The whole served step of the DeepSeek cell compiles for one v5e
+    chip at the cell's sizes: `index_scores` over pools of (1856, 1,
+    128, 128), `index_select` over 8 x 50,176 scores a group, the
+    ragged kernel's row-blocked form masked by the choice on pools of
+    (1856, 1, 128, 640): every donated pool aliased to its result, and
+    arguments + temporaries inside the chip's 16 GB (11.11 GB of
+    arguments, 9.29 of them parameters and 1.82 the two caches, + 0.34
+    GB of temporaries at the widest step)."""
+    model, params, pools, index = _deepseek_cell()
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = _compile_step(model, one, params, pools, width, q_tile,
+                             **index)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11.2e9 < total < 11.6e9, total
+    pooled = sum(np.prod(a.shape) * a.dtype.itemsize
+                 for a in jax.tree.leaves(pools))
+    # what a token costs in the caches: 640 + 128 lanes of 2 bytes in
+    # each of 5 layers
+    assert pooled == 1856 * 128 * 5 * 1536
+    assert mem.alias_size_in_bytes >= pooled
+    text = compiled.as_text()
+    for name in ("index_scores", "index_select", "kv_row_append"):
+        assert text.count(f'"{name}"') >= 5 or text.count(name) >= 5, name
+
+
+def test_the_choosing_kernels_compile_at_the_cells_sizes(v5e):
+    """`select_keys` and the masked ragged kernel alone, at a chunk
+    step and a decode-only step of the cell."""
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def chosen_attention(q, q_i, w_i, cache):
+        select = sparse_index.select_keys(q_i, w_i, cache, top_k=2048,
+                                          group=128)
+        return ragged_paged_attention(q, cache, scale=0.1, value_dim=512,
+                                      select=select)
+
+    for width, q_tile in ((288, 256), (32, 1)):
+        cache = RaggedPagedStep(
+            _a((1856, 1, 128, 640), BF16), None, _a((33, 392), I32),
+            _a((33,), I32), _a((34,), I32), _a((2,), I32), _a((width,), I32),
+            _a((width,), I32), _a((q_tile,), I32),
+            _a((1856, 1, 128, 128), BF16))
+        _compile(chosen_attention, one, _a((1, 128, width, 640), BF16),
+                 _a((width, 64, 128), BF16), _a((width, 64), F32), cache)
 
 
 def test_ladder_kernels_compile(v5e):
