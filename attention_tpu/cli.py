@@ -1138,8 +1138,16 @@ def _cmd_obs_report(args: argparse.Namespace) -> int:
                           sorted(fams[fam].items()))
         print(f"  {fam}: {parts}")
     print("== counters ==")
+    keys = {}
     for s in snapshot.get("counters", []):
         print(f"  {s['name']}{_lbl(s['labels'])} = {s['value']:g}")
+        if s["name"] == "engine.attention.keys":
+            keys[s["labels"].get("which")] = s["value"]
+    if keys.get("attended"):
+        # a selector's model: 1 where attention reads the chosen rows
+        # alone (`EngineMetrics.summary`)
+        print("  rows_read_per_key_attended = "
+              f"{keys.get('rows_read', 0) / keys['attended']:g}")
     print("== gauges ==")
     for s in snapshot.get("gauges", []):
         print(f"  {s['name']}{_lbl(s['labels'])} = {s['value']:g}")

@@ -90,11 +90,13 @@ _EXPERT_PAIRS = obs.counter(
     "engine.experts.pairs",
     "token-expert pairs a step routed, by where the expert is held")
 # a model whose attention chooses its keys: (query token, key) pairs
-# the selectors scored and the pairs attention then attended, summed
-# over the sublayers and the steps
+# the selectors scored, the pairs attention then attended and the cache
+# rows its kernels read for them, summed over the sublayers and the
+# steps
 _ATTENTION_KEYS = obs.counter(
     "engine.attention.keys",
-    "query-key pairs of the choosing sublayers, scored or attended")
+    "query-key pairs of the choosing sublayers, scored or attended, and "
+    "the cache rows read")
 # mesh-serving surface: how many KV-head shards the per-step launches
 # lower onto (1 = single-device).  In the zero-collective head-sharded
 # design the kernels exchange nothing; the only cross-shard cost is
@@ -839,7 +841,7 @@ class ServingEngine:
         self._finished_in_step = 0
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
-        pad_tokens = kv_pages = qk_pairs = keys_selected = 0
+        pad_tokens = kv_pages = qk_pairs = keys_selected = rows_read = 0
         width = q_tile = compiled_programs = 0
         occupancy = compile_s = 0.0
         self._expert_pairs = None
@@ -862,8 +864,8 @@ class ServingEngine:
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             if not sched.is_empty:
-                (width, q_tile, kv_pages, qk_pairs,
-                 keys_selected) = self._run_ragged(sched)
+                (width, q_tile, kv_pages, qk_pairs, keys_selected,
+                 rows_read) = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
             if _compiles.count != compile_rows:
@@ -893,6 +895,7 @@ class ServingEngine:
                 kv_pages=kv_pages,
                 attn_qk_pairs=qk_pairs,
                 attn_keys_selected=keys_selected,
+                attn_rows_read=rows_read,
                 host_overhead_s=max(0.0, wall_s - self._last_fetch_s),
                 compile_s=compile_s,
                 compiled_programs=compiled_programs,
@@ -1087,13 +1090,15 @@ class ServingEngine:
         return out
 
     def _run_ragged(self, sched: ScheduledStep
-                    ) -> tuple[int, int, int, int, int]:
+                    ) -> tuple[int, int, int, int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
         the packed width and the query tile dispatched (the program's
-        shape), the (slot, page) pairs the attention kernel's grid
-        walks for it, the (query token, key) pairs one attention
-        sublayer attends (or, where it chooses its keys, scores), and
-        the pairs the choice keeps by its rule (0 without one).
+        shape), the step's live (slot, page) pairs (what the attention
+        kernel's grid walks, or, where a selector chooses, its
+        scoring's), the (query token, key) pairs one attention sublayer
+        attends (or, where it chooses its keys, scores), the pairs the
+        choice keeps by its rule (0 without one), and the cache rows one
+        sublayer's attention reads.
 
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
@@ -1132,6 +1137,9 @@ class ServingEngine:
             keys_selected = _qk_pairs(
                 batch.kv_lens, np.diff(batch.cu_q_lens), self._index_topk
             ) if self._indexed_layers else 0
+            # a walk reads its pages whole; a choice is read as a list
+            rows_read = (keys_selected if self._indexed_layers
+                         else kv_pages * cfg.page_size)
         with obs.span("engine.step.upload", bytes=batch.buffer.nbytes,
                       arrays=1):
             buffer = self._upload(batch.buffer)
@@ -1151,6 +1159,8 @@ class ServingEngine:
             if obs.is_enabled():
                 _ATTENTION_KEYS.inc(qk_pairs * len(self._indexed_layers),
                                     which="scored")
+                _ATTENTION_KEYS.inc(rows_read * len(self._indexed_layers),
+                                    which="rows_read")
         if self._expert_layers:
             fields["expert_layers"] = len(self._expert_layers)
             if getattr(self.model, "zero_experts", 0):
@@ -1162,7 +1172,7 @@ class ServingEngine:
         with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
                       decode_rows=len(sched.decode),
                       prefill_tokens=sched.num_prefill_tokens,
-                      kv_pages=kv_pages, **fields):
+                      kv_pages=kv_pages, attn_rows=rows_read, **fields):
             logits_dev, new_pools, pairs_dev = _ragged_apply(
                 self._step_model, self.params, buffer,
                 self._layer_pools(),
@@ -1177,7 +1187,7 @@ class ServingEngine:
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
-        return width, q_tile, kv_pages, qk_pairs, keys_selected
+        return width, q_tile, kv_pages, qk_pairs, keys_selected, rows_read
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut

@@ -101,6 +101,13 @@ class StepMetrics:
     # counted on the host from the step's own lengths
     attn_keys_attended: int = 0
     attn_keys_selected: int = 0
+    # the cache rows ONE attention sublayer's kernels read this step,
+    # counted on the host by the device's rule: a kernel that walks
+    # pages reads every row of every live (slot, page) pair
+    # (``kv_pages`` x the page size), one that attends a list of rows
+    # the list (``attn_keys_selected``; a listed row is moved as the
+    # 8-row memory tile it lies in, which this count does not weigh)
+    attn_rows_read: int = 0
     host_overhead_s: float = 0.0     # wall minus the logits device sync
     # a step in which JAX traced, lowered or compiled something (a
     # shape the process had not run: `obs.compiles`): the seconds that
@@ -244,6 +251,13 @@ class EngineMetrics:
         for r in self.requests:
             wait_dig.add(r.queue_wait_s * 1e3)
             prefill_dig.add(r.prefill_s * 1e3)
+        attended = sum(s.attn_keys_attended for s in self.steps)
+        # a selector's model alone: the rows its attention kernels read
+        # for every key they attended (1.0: the chosen rows and no
+        # other; context / top_k for a walk of every page that masks)
+        chosen = {"rows_read_per_key_attended": round(
+            self.sparse_sublayers * sum(s.attn_rows_read for s in self.steps)
+            / attended, 4)} if attended else {}
         return {
             "num_requests": len(self.requests),
             "num_steps": len(self.steps),
@@ -298,9 +312,9 @@ class EngineMetrics:
             # of the pairs the selectors scored, the share attention
             # attended: 1.0 while every row sees fewer keys than it
             # may keep, top_k / context far beyond (0: no selector)
-            "selected_key_share": round(
-                sum(s.attn_keys_attended for s in self.steps) / scored, 4)
+            "selected_key_share": round(attended / scored, 4)
             if scored else 0.0,
+            **chosen,
             # expert layers: the share of routed pairs whose expert is
             # held here (1 / shares at an even router), and the fullest
             # held expert's pairs over the mean's, both over all steps

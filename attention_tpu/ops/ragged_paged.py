@@ -31,6 +31,12 @@ scalar-prefetched page table like `ops.paged` — so the pad waste of a
 step is just ``T - total_real`` bucketed tokens, not ``(D - d) +
 (P*S - real)`` poison rows.
 
+Where a SELECTOR chose each token's keys (`ops.sparse_index`), nothing
+above applies: no page is walked.  `ragged_paged_attention` with
+``select`` runs a kernel of its own (`_list_kernel`), a grid step a
+packed token, which fetches the cache rows of the token's list by its
+own copies and attends them in one softmax.
+
 Static tile discipline: the per-request query tile is ``q_tile`` tokens
 (>= the longest span; the engine buckets it to a power of two), and
 ``T`` is pow2-bucketed, so the whole serving life compiles O(log)
@@ -70,6 +76,7 @@ from attention_tpu.ops.flash import (
     _STAT_LANES,
     NEG_INF,
     _compiler_params,
+    _online_softmax_update,
     _should_interpret,
     _softmax_variant_update,
     _tuned_max_mode,
@@ -403,7 +410,7 @@ def _ragged_kernel(
     max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
     tile_rows: int, softcap2, window: int | None, sinks: int | None,
     variant: str = "online", dv: int = 0, shared_kv: bool = False,
-    blocks: int = 0, block_tokens: int = 0, select: bool = False,
+    blocks: int = 0, block_tokens: int = 0,
 ):
     """One (kv-head, work item) grid step: item ``i`` is page ``j`` of
     slot ``r`` (`work_items`).
@@ -428,26 +435,12 @@ def _ragged_kernel(
     takes a tile of that token's rows alone, whatever the step's
     ``q_tile``: a page is read once for all of a decode row's heads,
     and a chunk in the same step does not widen it.  The tile
-    arithmetic is the resident form's.
-
-    ``select`` (row-blocked form only): one more input, the block of
-    `ops.sparse_index.select_keys`' result for this item's (group,
-    page), 1.0 where a token of the group CHOSE a key of the page; a
-    row attends a key only if its token chose it.  One more result,
-    what the mask let through: the rows of the mask the softmax is
-    given (causal, inside the span AND chosen), a count a (row modulo
-    8, key of a page) place, summed over the items; ``group`` rows are
-    one (query token, key) pair."""
+    arithmetic is the resident form's."""
     if shared_kv:
         v_ref = None
     else:
         v_ref, *rest = rest
-    keep_ref = cnt_ref = None
-    if select:
-        keep_ref, *rest = rest
-        _, o_ref, cnt_ref, *rest = rest
-        acc_scr, m_scr, l_scr, q_scr, o_scr, sem = rest
-    elif blocks:
+    if blocks:
         _, o_ref, acc_scr, m_scr, l_scr, q_scr, o_scr, sem = rest
     else:
         o_ref, acc_scr, m_scr, l_scr = rest
@@ -520,25 +513,6 @@ def _ragged_kernel(
             if sinks is not None:
                 win = jnp.logical_or(win, col < sinks)
             mask = jnp.logical_and(mask, win)
-        if keep_ref is not None:
-            # a token's choice, spread over its heads' rows: row r of
-            # the tile is token r // group of the block
-            kept = keep_ref[0]
-            of = jax.lax.broadcasted_iota(
-                jnp.int32, (s.shape[0], kept.shape[0]), 0) // group
-            tok = jax.lax.broadcasted_iota(
-                jnp.int32, (s.shape[0], kept.shape[0]), 1)
-            chosen = jax.lax.dot_general(
-                (of == tok).astype(jnp.float32), kept,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            mask = jnp.logical_and(mask, chosen > 0.5)
-            # the FINAL mask's rows (causal, the span's and the chosen
-            # ones at once), folded eight to a place: whole registers
-            # added, no reduction across sublanes
-            cnt_ref[...] += jnp.sum(
-                mask.astype(jnp.float32).reshape(-1, 8, mask.shape[-1]),
-                axis=0).astype(jnp.int32)
         s = jnp.where(mask, s, NEG_INF)
         p, update_acc = _softmax_variant_update(
             s, m, l, variant=variant, masked=True)
@@ -587,11 +561,6 @@ def _ragged_kernel(
             ).astype(o_ref.dtype)
         return
 
-    if select:
-        @pl.when(i == 0)
-        def _zero_count():
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
     def block_of(rows: int, mine_too):
         """The three phases of a block at a tile of ``rows`` rows, the
         head of each scratch.  The result's rows past the span are
@@ -632,6 +601,236 @@ def _ragged_kernel(
     else:
         block_of(one_token, q_len <= 1)
         block_of(block_tokens * group, q_len > 1)
+
+
+#: cache rows one copy of the list form moves: a memory tile's rows,
+#: the least of a pool that a copy can name
+_LIST_ROWS = 8
+#: copies the list form starts a turn of its loop
+_LIST_UNROLL = 16
+#: list entries the list form attends at a time: their copies share a
+#: semaphore, and their tiles are one block of the softmax
+_LIST_PART = 128
+
+
+def _list_rows_kernel(slot_ref, list_ref, tbl_ref, o_ref, *, page: int):
+    """Where a token's listed positions lie in the pool: ``o`` (1, 1,
+    entries) = the first row of the memory tile that holds position
+    ``list[n]`` of the token's slot, ``(table[slot, pos // page] *
+    page + pos % page) // 8 * 8``, for all entries at once: the
+    slot's table row (``tbl_ref`` (max_pages, slots): the table,
+    pages down) against the entries' pages, a compare and a sum.  An
+    entry that is none, and a table entry that is unclaimed, give a
+    row that exists (position 0's, page 0's): it is fetched for
+    nobody."""
+    at = jnp.maximum(list_ref[0], 0)                        # (1, entries)
+    held = tbl_ref[...]
+    mine = jnp.sum(
+        jnp.where(jax.lax.broadcasted_iota(jnp.int32, held.shape, 1)
+                  == slot_ref[pl.program_id(0)], held, 0),
+        axis=1, keepdims=True)                              # (max_pages, 1)
+    of_page = jax.lax.broadcasted_iota(jnp.int32, mine.shape, 0)
+    there = jnp.sum(jnp.where(of_page == at // page, mine, 0), axis=0,
+                    keepdims=True)
+    o_ref[0] = (jnp.maximum(there, 0) * page
+                + at % page // _LIST_ROWS * _LIST_ROWS)
+
+
+def _list_kernel(pos_ref, list_ref, q_ref, keys_ref, pool_ref, o_ref,
+                 cnt_ref, buf, sem, m_scr, l_scr, acc_scr, *, dv: int):
+    """One packed token a grid step, a step LATE: step ``i`` starts the
+    copies of token ``i`` and attends token ``i - 1`` (``q_ref`` (1,
+    group, d) its heads' rows, pre-scaled into the log2 domain)
+    against the cache rows of ITS list and nothing else of the pool.
+
+    ``list_ref`` (1, 1, entries) in SMEM: for each position token
+    ``i`` chose, the first row of the memory tile it lies in
+    (`_list_rows_kernel`; ``pool_ref``: the pool as rows, in HBM).  A
+    copy moves that tile's `_LIST_ROWS` rows to rows ``[8 n, 8 n +
+    8)`` of the token's half of ``buf``, so the softmax runs over ``8
+    x entries`` rows of which ``keys_ref`` (1, 1, 8 x entries) names
+    the wanted ones: the position where row ``8 n + u`` is entry
+    ``n``'s own, -1 where it is a neighbour (or the entry is none).
+    The softmax takes `_LIST_PART` entries' tiles at a time, online,
+    float32, each part as soon as
+    its own copies have landed.  Token ``i``'s copies go to the other
+    half of ``buf``, and land while token ``i - 1``'s products run.
+
+    ``pos_ref[t]``: the token's position, -1 for a token that is
+    nobody's (zeros), -2 for one of a poisoned slot (NaN): neither
+    fetches anything.  ``cnt_ref`` (1, 8 x entries) int32, resident:
+    the mask the softmax is given (wanted, causal, a real token's),
+    summed over the tokens."""
+    step = pl.program_id(0)
+    t = jnp.maximum(step - 1, 0)                # the token attended
+    mine = pos_ref[t]
+    parts = list_ref.shape[-1] // _LIST_PART
+    wide = _LIST_PART * _LIST_ROWS              # a part's rows of ``buf``
+
+    def copy(first, half, part, n):
+        return pltpu.make_async_copy(
+            pool_ref.at[pl.ds(pl.multiple_of(first, _LIST_ROWS), _LIST_ROWS)],
+            buf.at[half, pl.ds(pl.multiple_of(n * _LIST_ROWS, _LIST_ROWS),
+                               _LIST_ROWS)],
+            sem.at[half, part])
+
+    @pl.when(step == 0)
+    def _zero_count():
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    @pl.when(jnp.logical_and(step < pl.num_programs(0) - 1,
+                             pos_ref[jnp.minimum(step, pos_ref.shape[0] - 1)]
+                             >= 0))
+    def _fetch():
+        half = jax.lax.rem(step, 2)
+
+        def turn(i, _):
+            part = i // (_LIST_PART // _LIST_UNROLL)
+            for u in range(_LIST_UNROLL):
+                n = i * _LIST_UNROLL + u
+                copy(list_ref[0, 0, n], half, part, n).start()
+            return _
+
+        jax.lax.fori_loop(0, parts * _LIST_PART // _LIST_UNROLL, turn, 0)
+
+    @pl.when(jnp.logical_and(step > 0, mine < 0))
+    def _nobody():
+        o_ref[...] = jnp.full_like(o_ref, jnp.where(mine == -2, jnp.nan, 0.0))
+
+    @pl.when(jnp.logical_and(step > 0, mine >= 0))
+    def _attend():
+        half = jax.lax.rem(t, 2)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        def attend(part, _):
+            span = pl.ds(pl.multiple_of(part * wide, wide), wide)
+            # ONE wait for the part's copies: they signal one semaphore,
+            # which counts what has landed, and this asks for their sum
+            pltpu.make_async_copy(pool_ref.at[pl.ds(0, wide)],
+                                  buf.at[half, span],
+                                  sem.at[half, part]).wait()
+            rows = buf[half, span, :]
+            s = jax.lax.dot_general(
+                q_ref[0], rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)     # (group, wide)
+            at = keys_ref[0, :, span]
+            mask = jnp.logical_and(at >= 0, at <= mine)
+            cnt_ref[:, span] += mask.astype(jnp.int32)
+            p, corr = _online_softmax_update(
+                jnp.where(mask, s, NEG_INF), m_scr, l_scr, masked=True)
+            acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+                p.astype(rows.dtype), rows[:, :dv], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return _
+
+        jax.lax.fori_loop(0, parts, attend, 0)
+        l = jnp.max(l_scr[...], axis=-1, keepdims=True)
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def _list_attention(qs, cache: RaggedPagedStep, select, *, dv: int,
+                    out_dtype, interpret: bool):
+    """The LIST form (`_list_kernel`): ``qs`` (T, group, d) token-major
+    scaled rows, ``select`` (T, entries) int32 the positions each token
+    attends.  Returns ``((T, group, dv), attended)``."""
+    t_pad, group, d = qs.shape
+    pool = cache.k_pool
+    page = pool.shape[2]
+    s_slots, max_pages = cache.page_table.shape
+    if (select.ndim != 2 or select.shape[0] != t_pad
+            or select.shape[1] % _LIST_PART):
+        raise ValueError(
+            f"select {select.shape}: a list of positions a packed token, "
+            f"({t_pad}, a multiple of {_LIST_PART})")
+    if page % _LIST_ROWS:
+        raise ValueError(f"page size {page} is not a multiple of the "
+                         f"{_LIST_ROWS} rows one copy moves")
+    entries = select.shape[1]
+    i32 = jnp.int32
+    lens = jnp.asarray(cache.kv_lens, i32)
+    cu = jnp.asarray(cache.cu_q_lens, i32)
+    slot = jnp.asarray(cache.token_slot, i32)
+    at = jnp.clip(slot, 0, s_slots - 1)
+    q_len = (cu[1:] - cu[:-1])[at]
+    off = jnp.arange(t_pad, dtype=i32) - cu[at]
+    real = ((slot >= 0) & (slot < cache.distribution[1])
+            & (off >= 0) & (off < q_len))
+    # a token's position by the device's rule; -1 nobody's, -2 poisoned
+    mine = jnp.where(real, jnp.where(lens[at] < 0, -2,
+                                     lens[at] - q_len + off), -1)
+    # which of a tile's rows is the entry's own: positions and pool
+    # rows agree modulo `_LIST_ROWS` (a page is whole tiles)
+    select = jnp.asarray(select, i32)
+    keys = jnp.where(
+        (select[:, :, None] >= 0)
+        & (select[:, :, None] % _LIST_ROWS
+           == jnp.arange(_LIST_ROWS, dtype=i32)), select[:, :, None], -1)
+    wide = entries * _LIST_ROWS
+    item = pool.dtype.itemsize
+    vmem = (2 * wide * d * item
+            + 5 * group * _LIST_PART * _LIST_ROWS * 4
+            + 4 * group * (d + dv) * item + group * dv * 8)
+    listed = pl.BlockSpec((1, 1, entries), lambda i, *_: (i, 0, 0))
+    tiles = pl.pallas_call(
+        functools.partial(_list_rows_kernel, page=page),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t_pad,),
+            in_specs=[listed,
+                      pl.BlockSpec((max_pages, s_slots),
+                                   lambda i, *_: (0, 0))],
+            out_specs=listed),
+        out_shape=jax.ShapeDtypeStruct((t_pad, 1, entries), i32),
+        compiler_params=_compiler_params(("parallel",)),
+        name="ragged_paged_list_rows",
+        interpret=interpret,
+    )(at, select[:, None, :], cache.page_table.T)
+
+    def late(i, *_):
+        return (jnp.maximum(i - 1, 0), 0, 0)
+
+    out, count = pl.pallas_call(
+        functools.partial(_list_kernel, dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # a step more than tokens: step i fetches token i's rows
+            # and attends token i - 1
+            grid=(t_pad + 1,),
+            in_specs=[
+                pl.BlockSpec((1, 1, entries),
+                             lambda i, *_: (jnp.minimum(i, t_pad - 1), 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, group, d), late),
+                pl.BlockSpec((1, 1, wide), late),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[
+                pl.BlockSpec((1, group, dv), late),
+                pl.BlockSpec((1, wide), lambda i, *_: (0, 0))],
+            scratch_shapes=[pltpu.VMEM((2, wide, d), pool.dtype),
+                            pltpu.SemaphoreType.DMA(
+                                (2, entries // _LIST_PART)),
+                            pltpu.VMEM((group, _STAT_LANES), jnp.float32),
+                            pltpu.VMEM((group, _STAT_LANES), jnp.float32),
+                            pltpu.VMEM((group, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((t_pad, group, dv), out_dtype),
+                   jax.ShapeDtypeStruct((1, wide), i32)],
+        # NOT parallel: the count's block is every token's
+        compiler_params=_compiler_params(
+            ("arbitrary",),
+            vmem_limit_bytes=min(max(int(vmem * 1.25), _DEFAULT_SCOPED_VMEM),
+                                 _MAX_SCOPED_VMEM)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * t_pad * group * wide * (d + dv),
+            bytes_accessed=t_pad * (wide * d * item + group * (d + dv) * item
+                                    + wide * 4),
+            transcendentals=t_pad * group * wide),
+        name="ragged_paged_list_attention",
+        interpret=interpret,
+    )(mine, tiles, qs, keys.reshape(t_pad, 1, wide), pool.reshape(-1, d))
+    return out, jnp.sum(count)
 
 
 #: rows of a block of the row-blocked form (`_ragged_kernel`): what a
@@ -678,11 +877,12 @@ def _ragged_paged_attention_jit(
     VMEM.  No window there.
 
     ``select`` (a cache of one pool only): `ops.sparse_index.
-    select_keys`' result, which keys each token CHOSE; a row then
-    attends the chosen keys alone, and the call returns ``(out,
-    attended)``, ``attended`` the int32 count of (query token, key)
-    pairs the mask let through, counted in the kernel from the mask
-    the softmax is given and not from ``select``."""
+    select_keys`' result, the positions each token CHOSE.  The pool is
+    then not walked at all: a kernel of its own reads the listed cache
+    rows and nothing else (`_list_attention`), and the call returns
+    ``(out, attended)``, ``attended`` the int32 count of (query token,
+    key) pairs the mask let through, counted in the kernel from the
+    mask the softmax is given and not from the list's length."""
     check_softcap(softcap)
     check_band(window, sinks)
     if q.ndim != 4 or q.shape[0] != 1:
@@ -764,25 +964,24 @@ def _ragged_paged_attention_jit(
     qs = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
     qs = qs[0].reshape(hkv, group, t_pad, d).transpose(0, 2, 1, 3)
     qs = qs.reshape(hkv, t_pad * group, d)
+    if select is not None:
+        if softcap is not None or sinks is not None:
+            raise ValueError("select: the list form has no softcap and no "
+                             "sinks")
+        out, attended = _list_attention(
+            qs.reshape(t_pad, group, d), cache, select, dv=dv,
+            out_dtype=out_dtype, interpret=interpret)
+        return out.transpose(1, 0, 2)[None], attended
     # the row-blocked form: blocks of `_BLOCK_ROWS` rows at most, whole
     # tokens, ``blocks`` of them to the step's query tile
     block_tokens, blocks = row_block_shape(q_tile, group)
     if not shared_kv:
         blocks = 0
-    prefetch = (lens, cu, dist, cache.page_table)
     if blocks:
         listed = row_block_list(
             lens, cu, dist, max_pages=max_pages, page=page,
             block_tokens=block_tokens, blocks=blocks, width=t_pad)
         items, n_items = listed.items, listed.n
-        prefetch += (items,)
-        if select is not None:
-            want = (listed.slot.shape[0], max_pages * page)
-            if (select.shape[0], select.shape[2]) != want:
-                raise ValueError(
-                    f"select {select.shape}: the step has {want[0]} "
-                    f"groups of {want[1]} keys")
-            prefetch += (listed.group,)
         tile_rows = block_tokens * group
         # a block is copied whole, so the last token's may reach past
         # the packed rows: spare rows, nobody's
@@ -791,12 +990,10 @@ def _ragged_paged_attention_jit(
         items, n_items = work_items(live_pages(
             lens, cu, dist, max_pages=max_pages, page=page, q_tile=q_tile,
             window=window, sinks=sinks))
-        prefetch += (items,)
         tile_rows = _row_tile(q_tile, t_pad, group)
     rows_total = qs.shape[1]
 
-    def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref,
-                 *_):
+    def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref):
         # the item's table entry, read on prefetched scalars.  An item
         # that holds a page to attend has its entry claimed; the kept
         # entries that hold none (`live_pages`) may read -1, and fetch
@@ -815,13 +1012,7 @@ def _ragged_paged_attention_jit(
         softcap2=None if softcap is None else softcap * _LOG2E,
         window=window, sinks=sinks, variant=variant, dv=dv,
         shared_kv=shared_kv, blocks=blocks, block_tokens=block_tokens,
-        select=select is not None,
     )
-    if select is not None:
-        # the item's group is read by the index maps alone
-        def kernel(lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, _,
-                   *refs, kernel=kernel):
-            kernel(lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, *refs)
     # Scoped-VMEM demand: the head's whole packed q and out blocks stay
     # resident (double-buffered by the pipeline), plus the K/V page
     # buffers, the fp32 scratch, and the tile's (rows, page) score /
@@ -849,7 +1040,7 @@ def _ragged_paged_attention_jit(
         pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
         pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
     ]
-    out_shape = [jax.ShapeDtypeStruct((hkv, rows_total, dv), out_dtype)]
+    out_shape = jax.ShapeDtypeStruct((hkv, rows_total, dv), out_dtype)
     if blocks:
         # rows in and out by the kernel's own copies; the result starts
         # as zeros, which is what a pad token's rows stay
@@ -859,20 +1050,8 @@ def _ragged_paged_attention_jit(
         scratch += [pltpu.VMEM((tile_rows, d), qs.dtype),
                     pltpu.VMEM((tile_rows, dv), out_dtype),
                     pltpu.SemaphoreType.DMA((2,))]
-        operands = (qs, *pools, jnp.zeros(out_shape[0].shape, out_dtype))
-        if select is not None:
-            def keep_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref,
-                           items_ref, group_ref):
-                return (group_ref[i], 0,
-                        _slot_and_page(items_ref[i], max_pages)[1])
-
-            tally = (8, page)
-            in_specs.insert(-1, pl.BlockSpec(
-                (1, select.shape[1], page), keep_index))
-            out_specs.append(pl.BlockSpec(tally, lambda hd, i, *_: (0, 0)))
-            out_shape.append(jax.ShapeDtypeStruct(tally, jnp.int32))
-            operands = (*operands[:-1], select, operands[-1])
-        aliases = {len(prefetch) + len(operands) - 1: 0}
+        operands = (qs, *pools, jnp.zeros(out_shape.shape, out_dtype))
+        aliases = {5 + len(operands) - 1: 0}
     else:
         in_specs = [pl.BlockSpec((1, rows_total, d), head_index),
                     *pool_specs]
@@ -880,7 +1059,7 @@ def _ragged_paged_attention_jit(
         operands = (qs, *pools)
         aliases = {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=5,
         # the second bound is the step's own count of work items, a
         # traced scalar: one executable whatever the step holds
         grid=(hkv, n_items),
@@ -894,7 +1073,7 @@ def _ragged_paged_attention_jit(
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
+        out_shape=[out_shape],
         input_output_aliases=aliases,
         # NOT parallel: every slot of one head accumulates into the
         # same resident output block
@@ -907,18 +1086,10 @@ def _ragged_paged_attention_jit(
             transcendentals=full * tile_rows * page,
         ),
         interpret=interpret,
-    )(*prefetch, *operands)
+    )(lens, cu, dist, cache.page_table, items, *operands)
     out = outs[0][:, :t_pad * group]
     out = out.reshape(hkv, t_pad, group, dv).transpose(0, 2, 1, 3)
-    out = out.reshape(1, h, t_pad, dv)
-    if select is None:
-        return out
-    # every head of a token has the token's mask, so the rows are a
-    # multiple of ``group``; a remainder counts one pair more, and the
-    # count is off whatever the rule says
-    rows, per = jnp.sum(outs[1]), jnp.int32(group)
-    return out, (jax.lax.div(rows, per)
-                 + (jax.lax.rem(rows, per) != 0).astype(jnp.int32))
+    return out.reshape(1, h, t_pad, dv)
 
 
 def ragged_paged_attention(q: jax.Array, cache: RaggedPagedStep,

@@ -28,12 +28,19 @@ Two kernels, each under its own operation name in a device trace:
     ``tau`` = its ``k``-th largest score (32 counts over the row, a
     bit of ``tau`` each), then the place up to which scores EQUAL to
     ``tau`` are still taken (a bit of the position a count), so that
-    exactly ``k`` keys are kept whatever ties there are.
+    exactly ``k`` keys are kept whatever ties there are.  The kept
+    places of a row are then written as a LIST, rising: the row is
+    cut into blocks of 256 positions, a block's running count of kept
+    places is one small product, and entry ``n`` of the list is the
+    block the ``n``-th kept place falls in (a count over the blocks'
+    totals) and its place there (a count over that block's running
+    counts, fetched for all entries at once by a one-hot product).
+    Only the rows that are a token's get a list.
 
-`select_keys` returns ``(groups, rows, max_tokens)`` float32, 1.0 where
-the group's token chose the key, which `ragged_paged_attention` takes
-as ``select``: laid out by group so that the attention kernel's item
-(group, page) finds its block by index.
+`select_keys` returns ``(T, width)`` int32: for every packed token the
+POSITIONS it chose, exactly ``min(top_k, position + 1)`` of them, every
+other entry -1, which `ragged_paged_attention` takes as ``select`` and
+reads as a list of cache rows.
 """
 
 from __future__ import annotations
@@ -81,12 +88,22 @@ def _scores_kernel(items_ref, group_ref, slot_ref, tbl_ref, q_ref, w_ref,
             preferred_element_type=jnp.float32)
 
 
-def _select_kernel(live_ref, pos_ref, s_ref, o_ref, *, top_k: int,
-                   bits: int):
-    """One group: ``o`` = 1.0 where row ``u`` keeps key ``s``.
-    ``pos_ref`` (1, rows, 128): the row's position (every lane), -1
-    for a row that is nobody's."""
-    @pl.when(pl.program_id(0) < live_ref[0])
+#: a register's lanes: the list's width is whole registers
+_LANES = 128
+
+
+def _select_kernel(live_ref, mine_ref, pos_ref, s_ref, o_ref, keep_scr,
+                   run_scr, *, top_k: int, bits: int):
+    """One group: ``o`` (1, rows, width) = the positions row ``u``
+    keeps, rising, then -1.  ``pos_ref`` (1, rows, 128): the row's
+    position (every lane), -1 for a row that is nobody's;
+    ``mine_ref[g]`` the group's rows that are a token's (its first
+    ones)."""
+    o_ref[...] = jnp.full_like(o_ref, -1)
+    group = pl.program_id(0)
+    tokens = mine_ref[group]
+
+    @pl.when(group < live_ref[0])
     def _():
         pos = pos_ref[0][:, :1]
         x = s_ref[0] + 0.0                      # -0.0 is 0.0
@@ -121,7 +138,70 @@ def _select_kernel(live_ref, pos_ref, s_ref, o_ref, *, top_k: int,
 
         cut = jax.lax.fori_loop(0, bits, place_bit, jnp.zeros_like(pos))
         keep = seen & ((key > tau) | (tied & (col <= cut)))
-        o_ref[0] = keep.astype(jnp.float32)
+        keep_scr[...] = keep.astype(keep_scr.dtype)
+        rows, width = o_ref.shape[1:]
+        halves = run_scr.shape[0]               # registers a block
+        size = halves * _LANES                  # a block's positions
+        blocks = x.shape[1] // size
+        f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
+
+        # every row cut into blocks of ``size`` positions, block major
+        # and a register of lanes at a time: rows [b * rows, (b + 1) *
+        # rows) of ``run_scr[h]`` are register h of block b of each
+        def lay(b, _):
+            for h in range(halves):
+                run_scr[h, pl.ds(pl.multiple_of(b * rows, rows), rows), :] = (
+                    keep_scr[:, pl.ds(pl.multiple_of(
+                        b * size + h * _LANES, _LANES), _LANES)])
+            return _
+
+        jax.lax.fori_loop(0, blocks, lay, 0)
+        # a block's running count of kept places: ONE product a register
+        # for every block of every row (counts up to 256 are exact in
+        # bfloat16), the registers before it added
+        lane = jax.lax.broadcasted_iota(i32, (_LANES, _LANES), 0)
+        upto = (lane <= jax.lax.broadcasted_iota(i32, (_LANES, _LANES), 1)
+                ).astype(bf16)
+        for h in range(halves):
+            run = jax.lax.dot_general(
+                run_scr[h].astype(bf16), upto, (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
+            if h:
+                run = run + run_scr[h - 1][:, _LANES - 1:]
+            run_scr[h] = run
+        n = jax.lax.broadcasted_iota(i32, (1, width), 1).astype(f32)
+        of_block = jax.lax.broadcasted_iota(i32, (blocks, 1), 0)
+        earlier = (jax.lax.broadcasted_iota(i32, (blocks, blocks), 1)
+                   <= of_block).astype(bf16)
+        of_block = of_block.astype(f32)
+
+        def listed(u, _):
+            run = jnp.concatenate(
+                [run_scr.at[h][pl.ds(u, blocks, stride=rows), :]
+                 for h in range(halves)], axis=1)           # (blocks, size)
+            total = run[:, size - 1:]
+            # the row's count up to each block's end
+            end = jax.lax.dot_general(
+                earlier, jnp.broadcast_to(total, run.shape).astype(bf16),
+                (((1,), (0,)), ((), ())), preferred_element_type=f32)[:, :1]
+            # entry n sits in the block after those that end at n or
+            # before, past the places they hold
+            before = end <= n
+            b = jnp.sum(before.astype(f32), axis=0, keepdims=True)
+            held = jnp.sum(jnp.where(before, total, 0.0), axis=0,
+                           keepdims=True)
+            # that block's running counts, for every entry at once
+            there = jax.lax.dot_general(
+                run.astype(bf16), (of_block == b).astype(bf16),
+                (((0,), (0,)), ((), ())), preferred_element_type=f32)
+            place = jnp.sum((there <= n - held).astype(f32), axis=0,
+                            keepdims=True)
+            kept = jnp.minimum(pos_ref[0, pl.ds(u, 1), :][:, :1] + 1, top_k)
+            o_ref[0, pl.ds(u, 1), :] = jnp.where(
+                n < kept.astype(f32), b * size + place, -1.0).astype(i32)
+            return _
+
+        jax.lax.fori_loop(0, tokens, listed, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "group", "interpret"))
@@ -198,27 +278,48 @@ def _select_keys_jit(q_idx, w_idx, cache: RaggedPagedStep, *, top_k: int,
     )(listed.items, listed.group, listed.slot, cache.page_table,
       q_g.astype(pool.dtype), w_g, *([pool] * per))
 
-    def whole(g, live_ref):
+    def whole(g, live_ref, mine_ref):
         return (g, 0, 0)
 
-    return pl.pallas_call(
+    max_tokens = max_pages * page
+    width = min(-(-top_k // _LANES) * _LANES, max_tokens)
+    # the list-making's blocks: two registers of positions where the
+    # table allows (the block to search and the blocks to count are
+    # then about as many), else one
+    size = 2 * _LANES if max_tokens % (2 * _LANES) == 0 else _LANES
+    lists = pl.pallas_call(
         functools.partial(_select_kernel, top_k=top_k,
-                          bits=max((max_pages * page - 1).bit_length(), 1)),
+                          bits=max((max_tokens - 1).bit_length(), 1)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(groups,),
             in_specs=[pl.BlockSpec((1, rows, 128), whole),
-                      pl.BlockSpec((1, rows, max_pages * page), whole)],
-            out_specs=pl.BlockSpec((1, rows, max_pages * page), whole)),
-        out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.float32),
-        # the choice is written over the scores it was made from
-        input_output_aliases={2: 0},
+                      pl.BlockSpec((1, rows, max_tokens), whole)],
+            out_specs=pl.BlockSpec((1, rows, width), whole),
+            scratch_shapes=[
+                pltpu.VMEM((rows, max_tokens), jnp.float32),
+                pltpu.VMEM((size // _LANES, max_tokens // size * rows,
+                            _LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((groups, rows, width), jnp.int32),
         compiler_params=_compiler_params(("arbitrary",),
                                          vmem_limit_bytes=_SELECT_VMEM),
         name="index_select",
         interpret=interpret,
-    )(listed.live.reshape(1), jnp.broadcast_to(
-        pos[:, :, None], (groups, rows, 128)), scores)
+    )(listed.live.reshape(1), jnp.sum(mine, axis=1, dtype=i32),
+      jnp.broadcast_to(pos[:, :, None], (groups, rows, 128)), scores)
+    # a token's list: row ``u`` of its span's block ``b``, the group
+    # ``b`` after its slot's first
+    t_slot = jnp.asarray(cache.token_slot, i32)
+    at = jnp.maximum(t_slot, 0)
+    off = jnp.arange(t_pad, dtype=i32) - cu[at]
+    first = jnp.searchsorted(listed.slot, at, side="left",
+                             method="compare_all").astype(i32)
+    row = ((first + off // block_tokens) * rows + off % block_tokens)
+    mine_too = (t_slot >= 0) & (off >= 0) & (off < block_tokens * blocks)
+    return jnp.where(
+        mine_too[:, None],
+        lists.reshape(groups * rows, width)[
+            jnp.clip(row, 0, groups * rows - 1)], -1)
 
 
 def select_keys(q_idx: jax.Array, w_idx: jax.Array, cache: RaggedPagedStep,
@@ -229,10 +330,12 @@ def select_keys(q_idx: jax.Array, w_idx: jax.Array, cache: RaggedPagedStep,
     constant factor folded in), ``cache`` the step AFTER its append
     (``kv_lens`` post-append, ``index_pool`` holding the step's own
     keys too), ``group`` the query heads a KV head of the attention
-    that will read the result (it fixes the blocks the row-blocked
-    form cuts a span into).  Returns ``(groups, rows, max_tokens)``
-    float32, 1.0 where token ``u`` of a group chose the key at that
-    position of its slot: `ragged_paged_attention`'s ``select``."""
+    (it fixes the blocks of tokens the scoring cuts a span into).
+    Returns ``(T, width)`` int32, ``width`` = ``top_k`` rounded up to
+    whole registers (the table's capacity at most): token ``t``'s
+    ``min(top_k, position + 1)`` chosen positions in its slot, rising,
+    every other entry -1 (a pad token's all of them):
+    `ragged_paged_attention`'s ``select``."""
     if interpret is None:
         interpret = _should_interpret()
     return _select_keys_jit(q_idx, w_idx, cache, top_k=top_k, group=group,

@@ -278,6 +278,42 @@ def test_the_count_on_every_step_is_the_hosts_own(served):
             want += sum(min(16, t + 1) for t in range(a, b))
         assert m.attn_keys_attended == 3 * want, m.step
         assert m.attn_keys_selected == want
+        # the list form reads the chosen rows and no other
+        assert m.attn_rows_read == want
+    assert eng.metrics.summary()["rows_read_per_key_attended"] == 1.0
+
+
+def test_the_dispatch_span_carries_the_rows_the_attention_reads(served):
+    """`engine.step.dispatch` has ``attn_rows``, the rule's count of a
+    step with a selector (what `StepMetrics.attn_rows_read` holds), and
+    the counter beside the attended pairs adds up to the same ratio."""
+    from attention_tpu import obs
+
+    model, params, _ = served
+    was = obs.enabled()
+    obs.enable()
+    obs.reset()
+    try:
+        eng = ServingEngine(model, params, EngineConfig(**ENGINE))
+        for p in _prompts(3, 40, 21):
+            eng.add_request(p, SamplingParams(max_tokens=3))
+        while eng.scheduler.has_work():
+            eng.step()
+        spans = [e["fields"] for e in obs.events()
+                 if e["name"] == "engine.step.dispatch"]
+        keys = {s["labels"]["which"]: s["value"]
+                for s in obs.REGISTRY.snapshot()["counters"]
+                if s["name"] == "engine.attention.keys"}
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()
+    busy = [m for m in eng.metrics.steps
+            if m.decode_tokens or m.prefill_tokens]
+    assert len(spans) == len(busy) > 0
+    for span, m in zip(spans, busy):
+        assert span["attn_rows"] == m.attn_rows_read == m.attn_keys_selected
+    assert keys["rows_read"] == keys["attended"] == 3 * sum(
+        m.attn_rows_read for m in busy)
 
 
 def test_a_prefix_hit_brings_back_latents_and_index_keys(served):

@@ -491,9 +491,10 @@ def _deepseek_cell():
 def test_the_deepseek_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
     """The whole served step of the DeepSeek cell compiles for one v5e
     chip at the cell's sizes: `index_scores` over pools of (1856, 1,
-    128, 128), `index_select` over 8 x 50,176 scores a group, the
-    ragged kernel's row-blocked form masked by the choice on pools of
-    (1856, 1, 128, 640): every donated pool aliased to its result, and
+    128, 128), `index_select` over 8 x 50,176 scores a group and the
+    lists of 2,048 positions it makes of them, the list kernel
+    fetching those rows of pools of (1856, 1, 128, 640): every donated
+    pool aliased to its result, and
     arguments + temporaries inside the chip's 16 GB (11.11 GB of
     arguments, 9.29 of them parameters and 1.82 the two caches, + 0.34
     GB of temporaries at the widest step)."""
@@ -512,22 +513,26 @@ def test_the_deepseek_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
     assert pooled == 1856 * 128 * 5 * 1536
     assert mem.alias_size_in_bytes >= pooled
     text = compiled.as_text()
-    for name in ("index_scores", "index_select", "kv_row_append"):
+    for name in ("index_scores", "index_select", "kv_row_append",
+                 "ragged_paged_list_attention"):
         assert text.count(f'"{name}"') >= 5 or text.count(name) >= 5, name
 
 
 def test_the_choosing_kernels_compile_at_the_cells_sizes(v5e):
-    """`select_keys` and the masked ragged kernel alone, at a chunk
-    step and a decode-only step of the cell."""
+    """`select_keys` (the scoring, the threshold and the list-making)
+    and the list kernel alone, at the widest step and a decode-only
+    step of the cell: 128 heads, rows of 640 lanes, a table row of 392
+    pages, lists of 2,048 positions."""
     one = jax.sharding.SingleDeviceSharding(v5e[0])
 
     def chosen_attention(q, q_i, w_i, cache):
         select = sparse_index.select_keys(q_i, w_i, cache, top_k=2048,
                                           group=128)
+        assert select.shape == (q.shape[2], 2048)
         return ragged_paged_attention(q, cache, scale=0.1, value_dim=512,
                                       select=select)
 
-    for width, q_tile in ((288, 256), (32, 1)):
+    for width, q_tile in ((384, 256), (32, 1)):
         cache = RaggedPagedStep(
             _a((1856, 1, 128, 640), BF16), None, _a((33, 392), I32),
             _a((33,), I32), _a((34,), I32), _a((2,), I32), _a((width,), I32),

@@ -726,10 +726,16 @@ def test_dispatch_span_and_metrics_carry_the_kernels_grid_bound(
             q_tile=c.q_tile, window=None, sinks=None))
         assert span["kv_pages"] == m.kv_pages == int(n)
         assert m.num_decode_reqs + m.num_prefill_reqs <= m.kv_pages < table
+        # a walk reads its pages whole: the rows one sublayer reads
+        assert (span["attn_rows"] == m.attn_rows_read
+                == m.kv_pages * c.page_size)
     idle = [m for m in eng.metrics.steps if m not in busy]
-    assert all(m.kv_pages == 0 for m in idle)
-    assert eng.metrics.summary()["mean_kv_page_share"] == round(
+    assert all(m.kv_pages == 0 and m.attn_rows_read == 0 for m in idle)
+    summary = eng.metrics.summary()
+    assert summary["mean_kv_page_share"] == round(
         sum(m.kv_pages for m in busy) / (len(busy) * table), 4)
+    # no selector, no choice to read by
+    assert "rows_read_per_key_attended" not in summary
 
 
 def test_a_step_that_compiled_says_so():
