@@ -32,6 +32,29 @@ state pools).  A request takes a slot at admission and gives it back
 with its pages (`release`).  Pages do not hold that state, so for such
 a model the prefix cache matches and publishes nothing: a hit would
 skip tokens whose state nobody kept.
+
+TWO PAGE SPACES (``window_pool``): a model whose sliding-window layers
+stand beside full-attention layers keeps the window layers' K and V in
+pools of their own, under page ids of their own.  A request's
+``window_pages`` is parallel to its ``pages`` (entry ``j`` is logical
+page ``j`` of either space) and holds -1 where the request holds no
+window page: below its BAND.  A step's attention kernel reads, of a
+window layer, the pages that reach back ``window + q_tile - 1`` keys
+from the slot's newest (`ops.ragged_paged._band_window`), ``band``
+here with the widest tile the engine dispatches; a page that lies
+wholly below ``computed_tokens + 1 - band`` can never be read again,
+and `release_window` gives it back (`window_pages_bound` is what a
+request can hold).  The prefix tree's entry holds a window page id or
+none: `commit_prefix` keeps window pages for the committed prefix's
+trailing `tail_blocks` blocks, and `lookup_prefix` returns the longest
+match whose trailing ``tail_blocks`` all have one, since the first
+step after the hit reads them.  Both pools are evicted in ONE LRU
+order (``last_use`` of the shared entries): a full-pool eviction drops
+a leaf entry and the window page it holds; a window-pool eviction takes
+the window page of the least recently used entry that has one nobody
+else references, leaf or not, and leaves the entry (a hit through it
+gets shorter).  A model with one kind of attention layer has no window
+pool, and none of this runs.
 """
 
 from __future__ import annotations
@@ -53,6 +76,9 @@ _PREFIX_HIT_TOKENS = obs.counter("engine.allocator.prefix_hit_tokens")
 _PREFIX_EVICTIONS = obs.counter("engine.allocator.prefix_evictions")
 _STATE_SLOTS = obs.gauge("engine.state.slots_in_use",
                          "recurrent-state slots held by running requests")
+_WINDOW_RELEASED = obs.counter(
+    "engine.allocator.window_released",
+    "window pages given back as a request's band slid past them")
 
 
 def pages_for_tokens(n_tokens: int, page_size: int) -> int:
@@ -67,13 +93,18 @@ class _PrefixEntry:
     parent: tuple[int, ...] | None
     children: set = dataclasses.field(default_factory=set)
     last_use: int = 0
+    # the window pool's page of the same tokens (two page spaces); None
+    # where the cache keeps none: outside a committed prefix's tail, or
+    # evicted
+    window_page: int | None = None
 
 
 class BlockAllocator:
     """Watermark-guarded page allocation + prefix cache for one pool."""
 
     def __init__(self, pool: PagePool, page_size: int, *,
-                 watermark_pages: int = 0, state_slots: int = 0):
+                 watermark_pages: int = 0, state_slots: int = 0,
+                 window_pool: PagePool | None = None, band: int = 0):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if not (0 <= watermark_pages < pool.num_pages):
@@ -87,6 +118,17 @@ class BlockAllocator:
         # > 0: the model keeps a recurrent state per request
         self.state_slots = state_slots
         self._free_state_slots = list(range(state_slots))
+        # the second page space, and the keys a window layer's kernel
+        # reaches back from a slot's newest at the widest query tile
+        if window_pool is not None and not (
+                band >= 1 and watermark_pages < window_pool.num_pages):
+            raise ValueError(
+                f"a window pool needs a band >= 1 (got {band}) and more "
+                f"than the watermark's {watermark_pages} pages (has "
+                f"{window_pool.num_pages})")
+        self.window_pool = window_pool
+        self.band = band
+        self.window_pages_released = 0
         self._prefix: dict[tuple[int, ...], _PrefixEntry] = {}
         # counters the metrics layer reports
         self.prefix_hits = 0
@@ -118,12 +160,33 @@ class BlockAllocator:
         if victim.parent is not None and victim.parent in self._prefix:
             self._prefix[victim.parent].children.discard(victim.key)
         self.pool.free([victim.page])
+        if victim.window_page is not None:
+            self.window_pool.free([victim.window_page])
         self.prefix_evictions += 1
         _PREFIX_EVICTIONS.inc()
         return victim.page
 
-    def allocate(self, n: int, *, for_decode: bool = False) -> list[int]:
-        """Allocate ``n`` pages, evicting LRU prefix pages as needed.
+    def evict_window_lru(self) -> int | None:
+        """Take the window page of the least recently used entry whose
+        window page only the cache references (the same clock and
+        tie-break as `evict_lru`); the entry stays.  Returns the freed
+        window page id, or None when there is none to take."""
+        victims = [e for e in self._prefix.values()
+                   if e.window_page is not None
+                   and self.window_pool.refcount(e.window_page) == 1]
+        if not victims:
+            return None
+        victim = min(victims, key=lambda e: (e.last_use, e.key))
+        page, victim.window_page = victim.window_page, None
+        self.window_pool.free([page])
+        self.prefix_evictions += 1
+        _PREFIX_EVICTIONS.inc()
+        return page
+
+    def allocate(self, n: int, *, for_decode: bool = False,
+                 window: bool = False) -> list[int]:
+        """Allocate ``n`` pages, evicting LRU prefix pages as needed;
+        with ``window``, of the window pool.
 
         Admission/prefill calls (``for_decode=False``) must leave the
         watermark reserve free *after* the allocation; decode appends
@@ -133,24 +196,27 @@ class BlockAllocator:
         if n == 0:
             return []
         path = "decode" if for_decode else "admit"
+        pool, evict = ((self.window_pool, self.evict_window_lru) if window
+                       else (self.pool, self.evict_lru))
         with obs.span("allocator.alloc"):
             reserve = 0 if for_decode else self.watermark_pages
             # evict until the allocation fits above the reserve;
             # evicting a leaf can expose its parent, so the loop
             # re-scans each round
-            while self.pool.free_pages < n + reserve:
-                if self.evict_lru() is None:
+            while pool.free_pages < n + reserve:
+                if evict() is None:
                     _OOM.inc(path=path)
                     if not for_decode:
                         _WATERMARK.inc()
                     raise OutOfPagesError(
                         f"allocation of {n} page(s) would breach the "
                         f"{'decode floor' if for_decode else 'watermark'}"
-                        f": free {self.pool.free_pages}, nothing "
+                        f": free {pool.free_pages}"
+                        f"{' window pages' if window else ''}, nothing "
                         f"evictable, reserve {reserve}"
                     )
             _ALLOC_PAGES.inc(n, path=path)
-            return self.pool.alloc(n)
+            return pool.alloc(n)
 
     def free(self, pages) -> None:
         """Drop the caller's reference on ``pages`` (cache references,
@@ -187,11 +253,70 @@ class BlockAllocator:
         if req.pages:
             self.free(req.pages)
         req.pages = []
+        held = [p for p in req.window_pages if p >= 0]
+        if held:
+            self.window_pool.free(held)
+        req.window_pages = []
         if req.state_slot >= 0:
             self._free_state_slots.append(req.state_slot)
             self._free_state_slots.sort()
             req.state_slot = -1
             _STATE_SLOTS.set(float(self.state_slots_in_use))
+
+    # -- the window layers' page space ------------------------------------
+
+    @property
+    def tail_blocks(self) -> int:
+        """Trailing blocks of a page-aligned prefix whose window pages
+        the first step after a hit on it can read."""
+        return pages_for_tokens(self.band, self.page_size)
+
+    def window_pages_bound(self, step_tokens: int = 1) -> int:
+        """The most window pages a request holds while it issues
+        ``step_tokens`` tokens a step: its band, the tokens, and one
+        page more for where the band's start falls in a page."""
+        return pages_for_tokens(self.band + step_tokens - 1,
+                                self.page_size) + 1
+
+    def _first_band_page(self, computed_tokens: int) -> int:
+        """The lowest logical page a request with ``computed_tokens``
+        keys cached can still read of a window layer: its next step
+        leaves ``computed_tokens + 1`` keys or more, and reaches back
+        ``band`` from there."""
+        return max(computed_tokens + 1 - self.band, 0) // self.page_size
+
+    def release_window(self, req) -> int:
+        """Give back the window pages ``req``'s band has slid past (its
+        reference on them: a page the cache also holds stays cached);
+        returns how many."""
+        # what a request holds is one run of pages: below it everything
+        # was given back already, or never held (a prefix hit's -1s)
+        gone = []
+        for j in range(min(self._first_band_page(req.computed_tokens),
+                           len(req.window_pages)) - 1, -1, -1):
+            if req.window_pages[j] < 0:
+                break
+            gone.append(j)
+        if gone:
+            self.window_pool.free([req.window_pages[j] for j in gone])
+            for j in gone:
+                req.window_pages[j] = -1
+            self.window_pages_released += len(gone)
+            _WINDOW_RELEASED.inc(len(gone))
+        return len(gone)
+
+    def cover(self, req, cover_tokens: int, *, for_decode: bool) -> None:
+        """Extend ``req``'s pages to hold ``cover_tokens`` tokens, in
+        both page spaces where there are two.  What was allocated
+        before an `OutOfPagesError` stays the request's."""
+        need = pages_for_tokens(cover_tokens, self.page_size)
+        if need > len(req.pages):
+            req.pages.extend(self.allocate(need - len(req.pages),
+                                           for_decode=for_decode))
+        if self.window_pool is not None and need > len(req.window_pages):
+            req.window_pages.extend(self.allocate(
+                need - len(req.window_pages), for_decode=for_decode,
+                window=True))
 
     # -- prefix cache -----------------------------------------------------
 
@@ -211,12 +336,13 @@ class BlockAllocator:
         refresh its entries)."""
         toks = tuple(tokens)
         limit = self._prefix_limit(toks)
-        n = 0
+        chain = []
         for i in range(1, limit + 1):
-            if toks[: i * self.page_size] not in self._prefix:
+            entry = self._prefix.get(toks[: i * self.page_size])
+            if entry is None:
                 break
-            n += 1
-        return n
+            chain.append(entry)
+        return self._window_hit(chain)
 
     def cached_chain(self, tokens) -> list[int]:
         """Physical pages of the longest cached page-aligned prefix of
@@ -235,9 +361,13 @@ class BlockAllocator:
             pages.append(entry.page)
         return pages
 
-    def lookup_prefix(self, tokens, *, now: int) -> list[int]:
+    def lookup_prefix(self, tokens, *, now: int,
+                      window_out: list | None = None) -> list[int]:
         """Longest cached page-aligned prefix of ``tokens``; increfs and
         returns the matched pages (caller owns one reference each).
+        With two page spaces ``window_out`` receives the hit's window
+        pages, parallel to the result: the window page of each of the
+        trailing `tail_blocks` (an own reference taken) and -1 below.
 
         At least one token is always left uncached — the last prompt
         token must run through the model to produce the logits the
@@ -245,13 +375,20 @@ class BlockAllocator:
         """
         toks = tuple(tokens)
         limit = self._prefix_limit(toks)
-        pages: list[int] = []
+        chain: list[_PrefixEntry] = []
         for i in range(1, limit + 1):
             entry = self._prefix.get(toks[: i * self.page_size])
             if entry is None:
                 break
+            chain.append(entry)
+        del chain[self._window_hit(chain):]
+        for entry in chain:
             entry.last_use = now
-            pages.append(entry.page)
+        pages = [entry.page for entry in chain]
+        if pages and window_out is not None and self.window_pool:
+            tail = [e.window_page for e in chain[-self.tail_blocks:]]
+            self.window_pool.incref(tail)
+            window_out[:] = [-1] * (len(chain) - len(tail)) + tail
         if pages:
             self.pool.incref(pages)
             self.prefix_hits += 1
@@ -263,13 +400,30 @@ class BlockAllocator:
             _PREFIX_MISSES.inc()
         return pages
 
-    def commit_prefix(self, tokens, pages, *, now: int) -> int:
+    def _window_hit(self, chain) -> int:
+        """Entries of a matched ``chain`` a hit may cover: all of them
+        with one page space, else the longest run from the start whose
+        trailing `tail_blocks` entries all hold a window page."""
+        if self.window_pool is None:
+            return len(chain)
+        run = 0          # entries ending here that hold a window page
+        best = 0
+        for m, entry in enumerate(chain, start=1):
+            run = run + 1 if entry.window_page is not None else 0
+            if run >= min(m, self.tail_blocks):
+                best = m
+        return best
+
+    def commit_prefix(self, tokens, pages, *, now: int,
+                      window_pages=None) -> int:
         """Publish every full page of ``tokens`` (whose KV now lives in
         ``pages``, logical order) into the cache; returns how many new
         entries were inserted.  Already-published prefixes are just
         touched — a concurrent identical prompt that missed keeps its
         private pages and the first publisher's copy stays canonical
-        (content-identical, so reads through either id agree)."""
+        (content-identical, so reads through either id agree).
+        ``window_pages``, with two page spaces, is the committer's list
+        parallel to ``pages``, -1 where it holds none."""
         if self.state_slots:
             return 0  # pages without the state they led to are no prefix
         toks = tuple(tokens)
@@ -280,7 +434,8 @@ class BlockAllocator:
             )
         inserted = 0
         parent: tuple[int, ...] | None = None
-        for i in range(1, len(toks) // self.page_size + 1):
+        full = len(toks) // self.page_size
+        for i in range(1, full + 1):
             key = toks[: i * self.page_size]
             entry = self._prefix.get(key)
             if entry is None:
@@ -294,5 +449,12 @@ class BlockAllocator:
                 inserted += 1
             else:
                 entry.last_use = now
+            # two page spaces: the trailing `tail_blocks` entries keep
+            # the committer's window page where they have none
+            if (window_pages is not None and i > full - self.tail_blocks
+                    and entry.window_page is None
+                    and window_pages[i - 1] >= 0):
+                entry.window_page = window_pages[i - 1]
+                self.window_pool.incref([entry.window_page])
             parent = key
         return inserted

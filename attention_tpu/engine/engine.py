@@ -3,7 +3,11 @@
 `ServingEngine` turns many concurrent requests into batched kernel
 steps.  Memory is ONE page-id space across all model layers (per-layer
 physical pools share the geometry, so a single `PagePool`/
-`BlockAllocator` and one table row per request drive the whole stack);
+`BlockAllocator` and one table row per request drive the whole stack),
+or TWO where sliding-window layers stand beside full-attention layers
+(``model.window_layers``: a second `PagePool` and a second table row a
+request, in the same step buffer, for the window layers' pools, of
+which a request holds its trailing band and no more);
 compute is the model's packed cache path — `ragged_paged_append` +
 `ragged_paged_attention` over the same pools and page tables that
 `generate_paged` steps one request at a time, which is what the
@@ -45,6 +49,7 @@ from attention_tpu.engine.allocator import BlockAllocator
 from attention_tpu.engine.errors import (
     DeadlineExceededError,
     LatentCacheUnsupportedError,
+    PageSpacesUnsupportedError,
     RecurrentStateUnsupportedError,
 )
 from attention_tpu.engine.metrics import (
@@ -60,12 +65,17 @@ from attention_tpu.engine.scheduler import (
 )
 from attention_tpu.models.moe import PackedTokens
 from attention_tpu.ops.gated_delta import RaggedStateStep
-from attention_tpu.ops.paged import OutOfPagesError, PagePool
+from attention_tpu.ops.paged import (
+    OutOfPagesError,
+    PageAccountingError,
+    PagePool,
+)
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
     live_pages,
     packed_bucket,
     recommended_q_tile,
+    tile_tokens,
 )
 
 _CANCELLED = obs.counter("engine.requests.cancelled",
@@ -128,7 +138,15 @@ def require_pages_only(model, feature: str) -> None:
     KV pages only, and pages alone do not restore such a request.  And
     for a model with latent-attention layers: it carries K / V pool
     pairs, one a layer, and such a model keeps one pool a sublayer, or
-    a latent pool and its selector's index pool."""
+    a latent pool and its selector's index pool.  And for a model of
+    two page spaces: it carries one list of page ids a request."""
+    layers = tuple(getattr(model, "window_layers", ()))
+    if layers:
+        raise PageSpacesUnsupportedError(
+            f"{feature} carries ONE list of page ids a request, and "
+            f"{type(model).__name__} keeps the pages of its "
+            f"sliding-window layers {list(layers)} in a page space of "
+            "their own")
     layers = tuple(getattr(model, "recurrent_layers", ()))
     if layers:
         raise RecurrentStateUnsupportedError(
@@ -152,7 +170,9 @@ class RaggedStepIndex(NamedTuple):
     """What one packed step tells every layer: the index fields of
     `RaggedPagedStep` (and of `RaggedStateStep`, which reads
     ``state_rows`` with four of them; None for a model with no
-    recurrent layer).  The jitted step slices them out of the ONE
+    recurrent layer), and for a model of two page spaces the window
+    layers' ``window_table``, which stands in ``page_table``'s place
+    in their steps.  The jitted step slices them out of the ONE
     int32 buffer the engine uploads a step (`_step_inputs`); none is
     an upload of its own.  Kept apart from the pools because the step
     donates those, and what every layer reads cannot be given away."""
@@ -165,6 +185,7 @@ class RaggedStepIndex(NamedTuple):
     token_slot: jax.Array
     q_span: jax.Array
     state_rows: jax.Array | None = None
+    window_table: jax.Array | None = None
 
 
 def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
@@ -175,10 +196,14 @@ def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
     pool of each of its attention sublayers) gets a step a sublayer,
     each of ONE pool; a layer whose attention chooses its keys
     (``pools`` holds its latent pool and its index pool) one step of
-    both."""
+    both; a window layer of a model with two page spaces reads the
+    window table."""
     recurrent = set(getattr(model, "recurrent_layers", ()))
     latent = set(getattr(model, "latent_layers", ()))
     indexed = set(getattr(model, "indexed_layers", ()))
+    window = set(getattr(model, "window_layers", ()))
+    # the fields of `RaggedPagedStep` after its pools
+    shared = index[:7]
 
     def cache(layer, pair):
         if pair is None:
@@ -188,12 +213,14 @@ def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
                                    index.cu_q_lens, index.token_slot,
                                    index.q_span)
         if layer in latent:
-            return tuple(RaggedPagedStep(pool, None, *index[:-1])
+            return tuple(RaggedPagedStep(pool, None, *shared)
                          for pool in pair)
         if layer in indexed:
-            return RaggedPagedStep(pair[0], None, *index[:-1],
+            return RaggedPagedStep(pair[0], None, *shared,
                                    index_pool=pair[1])
-        return RaggedPagedStep(*pair, *index[:-1])  # all but state_rows
+        if layer in window:
+            return RaggedPagedStep(*pair, index.window_table, *shared[1:])
+        return RaggedPagedStep(*pair, *shared)
 
     return tuple(cache(layer, pair) for layer, pair in enumerate(pools))
 
@@ -236,11 +263,13 @@ def _step_inputs(model, buffer, layout: StepLayout):
     ``layout.q_tile`` long: its shape is all anyone reads of it."""
     seg = split_step_buffer(
         buffer, slots=layout.slots, table_width=layout.table_width,
-        recurrent=bool(getattr(model, "recurrent_layers", ())))
+        recurrent=bool(getattr(model, "recurrent_layers", ())),
+        window_tables=bool(getattr(model, "window_layers", ())))
     return seg.tokens, RaggedStepIndex(
         seg.tables, seg.kv_lens, seg.cu_q_lens, seg.distribution,
         seg.token_pos, seg.token_slot,
-        jnp.zeros((layout.q_tile,), jnp.int32), seg.state_rows)
+        jnp.zeros((layout.q_tile,), jnp.int32), seg.state_rows,
+        seg.window_tables)
 
 
 @functools.partial(jax.jit, static_argnames=("model", "layout"),
@@ -317,6 +346,19 @@ def _qk_pairs(kv_before: np.ndarray, q_lens: np.ndarray,
     return int(pairs.sum())
 
 
+def _band_pages(kv_before: np.ndarray, q_lens: np.ndarray,
+                window: int | None, page: int) -> int:
+    """Pages of an attention sublayer's cache that hold a key SOME
+    query row of the step attends: slot by slot, from the page of the
+    first row's oldest key (``window`` back from it; key 0 without
+    one) to the page of the last row's own.  What any kernel has to
+    read, whatever tile it walks in."""
+    kv, q = kv_before.astype(np.int64), q_lens.astype(np.int64)
+    first = 0 if window is None else np.maximum(kv - window + 1, 0)
+    pages = (kv + q - 1) // page - first // page + 1
+    return int(pages[q > 0].sum())
+
+
 def _slot_last_rows(cu_q_lens):
     """The packed row each slot samples: the last of its span
     ``[cu[s], cu[s + 1])``.  Empty slots repeat the last offset, so
@@ -340,6 +382,10 @@ class EngineConfig:
     production configs scale ``num_pages``/batch widths up."""
 
     num_pages: int = 64
+    # pages of the SECOND page space, the sliding-window layers' of a
+    # model that also has full-attention layers (`model.window_layers`):
+    # required for such a model, and no other has one
+    num_window_pages: int = 0
     page_size: int = 128           # paged-kernel granule: 128-multiple
     max_seq_len: int = 1024        # per-request prompt + generated cap
     max_decode_batch: int = 8      # decode requests per step, at most
@@ -347,6 +393,11 @@ class EngineConfig:
     prefill_chunk: int = 64        # tokens per prefill slice, at most
     token_budget: int = 128        # real tokens scheduled per step
     watermark_pages: int = 1       # admission must leave this reserve
+    # > 0: a step that holds a prefill chunk gets no query tile under
+    # this many tokens, so that a prompt's short last chunk runs the
+    # whole chunks' program instead of compiling one of its own at
+    # every tier (fewer step shapes, more padding in those steps)
+    min_prefill_tile: int = 0
     cache_dtype: Any = None        # None -> model dtype
     # one value left, and it selects nothing: the benchmark's config
     # files pass "step_mode": "ragged" into this constructor, so the
@@ -376,6 +427,9 @@ class EngineConfig:
                self.max_prefill_rows, self.prefill_chunk,
                self.token_budget) < 1:
             raise ValueError("engine config fields must all be >= 1")
+        if min(self.num_window_pages, self.min_prefill_tile) < 0:
+            raise ValueError(
+                "num_window_pages and min_prefill_tile must be >= 0")
         if not (0 <= self.watermark_pages < self.num_pages):
             raise ValueError(
                 f"watermark_pages {self.watermark_pages} outside "
@@ -442,6 +496,18 @@ class ServingEngine:
                             if self._indexed_layers else 0)
         self._state_layers = tuple(getattr(model, "recurrent_layers", ()))
         self._expert_layers = tuple(getattr(model, "expert_layers", ()))
+        # the layers of the second page space, and the window of each
+        # kind of attention sublayer: one kind, or full then sliding
+        self._window_layers = tuple(getattr(model, "window_layers", ()))
+        self._windows = ((None, model.window) if self._window_layers
+                         else (model.window,))
+        if bool(self._window_layers) != bool(config.num_window_pages):
+            raise ValueError(
+                f"num_window_pages {config.num_window_pages}: the pages "
+                "of a second page space, which a model has where "
+                "sliding-window layers stand beside full-attention "
+                f"layers (this one's window layers: "
+                f"{list(self._window_layers)})")
         if config.mesh_shards:
             if self._latent_layers or self._indexed_layers:
                 from attention_tpu.parallel.serving import MeshConfigError
@@ -506,9 +572,14 @@ class ServingEngine:
         kv_heads, widths = model.kv_pool_widths()
 
         def pools(width):
-            shape = (config.num_pages, kv_heads, config.page_size, width)
-            return [self._place_pool(jnp.zeros(shape, dtype))
-                    for _ in self._kv_layers]
+            def shape(layer):
+                pages = (config.num_window_pages
+                         if layer in self._window_layers
+                         else config.num_pages)
+                return (pages, kv_heads, config.page_size, width)
+
+            return [self._place_pool(jnp.zeros(shape(layer), dtype))
+                    for layer in self._kv_layers]
 
         # one pool (pair) per attention SUBLAYER, in layer order
         self._k_pools = pools(widths[0])
@@ -532,10 +603,19 @@ class ServingEngine:
             _MESH_SHARDS.set(float(config.mesh_shards or 1))
 
         self.pool = PagePool(config.num_pages)
+        self.window_pool = (PagePool(config.num_window_pages)
+                            if self._window_layers else None)
+        # the widest query tile a step is dispatched with: what a
+        # window layer's kernel reaches back beyond the window
+        self._widest_tile = max(self._q_tile(1),
+                                self._q_tile(config.prefill_chunk))
         self.allocator = BlockAllocator(
             self.pool, config.page_size,
             watermark_pages=config.watermark_pages,
             state_slots=state_slots,
+            window_pool=self.window_pool,
+            band=(model.window + self._widest_tile - 1
+                  if self._window_layers else 0),
         )
         self.scheduler = Scheduler(
             self.allocator,
@@ -552,6 +632,9 @@ class ServingEngine:
         # the last step's counts from the device (`_ragged_apply`):
         # expert pairs, keys attended; fetched with its logits
         self._expert_pairs: np.ndarray | None = None
+        # the last step's counts of the second page space, by
+        # `StepMetrics`' names (empty without one)
+        self._window_fields: dict[str, int] = {}
         self._step = 0
         # plain int (not itertools.count) so snapshots can persist the
         # position: auto request-ids and FCFS tiebreaks survive restore
@@ -845,6 +928,7 @@ class ServingEngine:
         width = q_tile = compiled_programs = 0
         occupancy = compile_s = 0.0
         self._expert_pairs = None
+        self._window_fields = {}
         with obs.span("engine.step", step=self._step,
                       queued=len(self.scheduler.waiting),
                       running=len(self.scheduler.running)):
@@ -863,6 +947,9 @@ class ServingEngine:
                               else "prefill_start")
                         self._trace_event(req, ev)
             total = sched.num_decode_tokens + sched.num_prefill_tokens
+            if self.window_pool is not None:
+                self._window_fields["window_pages_released"] = (
+                    sched.window_pages_released)
             if not sched.is_empty:
                 (width, q_tile, kv_pages, qk_pairs, keys_selected,
                  rows_read) = self._run_ragged(sched)
@@ -900,6 +987,8 @@ class ServingEngine:
                 compile_s=compile_s,
                 compiled_programs=compiled_programs,
                 **self._expert_fields(),
+                **self._window_fields,
+                **self._window_pool_pages(),
             )
             self.metrics.record_step(m)
         self._step += 1
@@ -931,6 +1020,14 @@ class ServingEngine:
         if width:
             self.metrics.compiled_shapes.add((width, q_tile))
         return log["all_s"], log["programs"]
+
+    def _window_pool_pages(self) -> dict[str, int]:
+        """The second page space's pages free and in use, by
+        `StepMetrics`' names; nothing for a model without one."""
+        if self.window_pool is None:
+            return {}
+        return {"window_free_pages": self.window_pool.free_pages,
+                "window_used_pages": self.window_pool.used_pages}
 
     def _expert_fields(self) -> dict[str, int]:
         """The step's counts from the device as `StepMetrics` has
@@ -1008,6 +1105,7 @@ class ServingEngine:
             "page_utilization": self.pool.used_pages
             / self.pool.num_pages,
             "cached_pages": self.allocator.cached_pages,
+            **self._window_pool_pages(),
             "preemptions": self.scheduler.num_preemptions,
             "nonfinite_events": self.nonfinite_events,
             "step_virtual_cost": self.last_step_virtual_cost,
@@ -1089,6 +1187,31 @@ class ServingEngine:
             self._last_fetch_s += time.perf_counter() - t0
         return out
 
+    def _q_tile(self, max_q: int) -> int:
+        """The query tile of a step whose longest span is ``max_q``
+        tokens: the kernel's recommendation, and for a step that holds
+        a chunk no less than ``min_prefill_tile``."""
+        cfg, model = self.config, self.model
+        group = model.num_q_heads // model.num_kv_heads
+        tile = recommended_q_tile(
+            max_q, group, heads=model.num_q_heads,
+            kv_heads=model.num_kv_heads, seq=cfg.max_seq_len,
+            dim=getattr(model, "head_size", model.dim // model.num_q_heads),
+            batch=cfg.max_decode_batch + cfg.max_prefill_rows,
+            dtype=cfg.cache_dtype or model.dtype,
+        )
+        if max_q > 1 and cfg.min_prefill_tile > tile:
+            tile = tile_tokens(cfg.min_prefill_tile, group)
+        return tile
+
+    def step_shape(self, decoding: int, chunk: int) -> tuple[int, int]:
+        """The ``(width, q_tile)`` of the program a step of
+        ``decoding`` decode rows beside one prefill chunk of ``chunk``
+        tokens (0: none) runs: `_run_ragged`'s own arithmetic, for a
+        caller that warms the shapes up."""
+        q_tile = self._q_tile(max(chunk, 1))
+        return packed_bucket(max(decoding + chunk, q_tile)), q_tile
+
     def _run_ragged(self, sched: ScheduledStep
                     ) -> tuple[int, int, int, int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
@@ -1098,7 +1221,10 @@ class ServingEngine:
         scoring's), the (query token, key) pairs one attention sublayer
         attends (or, where it chooses its keys, scores), the pairs the
         choice keeps by its rule (0 without one), and the cache rows one
-        sublayer's attention reads.
+        sublayer's attention reads.  Where window layers stand beside
+        full layers, pages, pairs and rows are the SUM of one sublayer
+        of each kind, and the window kind's part goes to
+        ``_window_fields``.
 
         The per-request query tile covers the longest prefill chunk and
         the packed width covers every real token, both pow2-bucketed —
@@ -1107,31 +1233,44 @@ class ServingEngine:
         cfg = self.config
         with obs.span("engine.step.pack"):
             slots = cfg.max_decode_batch + cfg.max_prefill_rows
-            group = self.model.num_q_heads // self.model.num_kv_heads
-            head_dim = self.model.dim // self.model.num_q_heads
             max_q = max((n for _, n in sched.prefill), default=1)
-            q_tile = recommended_q_tile(
-                max_q, group, heads=self.model.num_q_heads,
-                kv_heads=self.model.num_kv_heads, seq=cfg.max_seq_len,
-                dim=head_dim, batch=slots,
-                dtype=cfg.cache_dtype or self.model.dtype,
-            )
+            q_tile = self._q_tile(max_q)
+            if q_tile > self._widest_tile and self._window_layers:
+                # the kernel would reach below the band, into pages
+                # given back and handed to another request
+                raise PageAccountingError(
+                    f"a query tile of {q_tile} tokens, wider than the "
+                    f"{self._widest_tile} the window pages' band was "
+                    "sized for")
             total = sched.num_decode_tokens + sched.num_prefill_tokens
             width = packed_bucket(max(total, q_tile))
             batch = sched.pack(width=width, slots=slots,
                                table_width=cfg.table_width,
-                               recurrent=bool(self._state_layers))
+                               recurrent=bool(self._state_layers),
+                               window_tables=bool(self._window_layers))
+            q_lens = np.diff(batch.cu_q_lens)
             # the kernel's grid bound for this step, counted here by
             # the rule the device builds it from (a slot the append
-            # poisons there reads 1 on the device, its pages here)
-            kv_pages = int(live_pages(
-                batch.kv_lens + np.diff(batch.cu_q_lens), batch.cu_q_lens,
+            # poisons there reads 1 on the device, its pages here),
+            # and the pairs attended: one sublayer of each kind
+            walked = [int(live_pages(
+                batch.kv_lens + q_lens, batch.cu_q_lens,
                 batch.distribution, max_pages=cfg.table_width,
-                page=cfg.page_size, q_tile=q_tile,
-                window=self.model.window,
+                page=cfg.page_size, q_tile=q_tile, window=window,
                 sinks=self.model.attn_sinks or None, xp=np).sum())
-            qk_pairs = _qk_pairs(batch.kv_lens, np.diff(batch.cu_q_lens),
-                                 self.model.window)
+                for window in self._windows]
+            pairs = [_qk_pairs(batch.kv_lens, q_lens, window)
+                     for window in self._windows]
+            kv_pages, qk_pairs = sum(walked), sum(pairs)
+            if self._window_layers:
+                needed = [_band_pages(batch.kv_lens, q_lens, window,
+                                      cfg.page_size)
+                          for window in self._windows]
+                self._window_fields.update(
+                    kv_pages_window=walked[1],
+                    attn_qk_pairs_window=pairs[1],
+                    attn_band_pages=sum(needed),
+                    attn_band_pages_window=needed[1])
             # a row keeps the ``index_topk`` best of the keys it sees:
             # the count of a window that wide
             keys_selected = _qk_pairs(
@@ -1149,6 +1288,9 @@ class ServingEngine:
             fields = {"recurrent_tokens": total,
                       "recurrent_slot_steps": sampled,
                       "state_layers": len(self._state_layers)}
+        if self._window_layers:
+            fields["window_layers"] = len(self._window_layers)
+            fields["window_kv_pages"] = walked[1]
         if self._latent_layers:
             fields["latent_layers"] = len(self._kv_layers)
         if self._indexed_layers:
@@ -1264,7 +1406,9 @@ class ServingEngine:
         full = req.num_prompt_tokens // self.config.page_size
         if full and not self._state_layers:
             self.allocator.commit_prefix(
-                req.prompt, req.pages[:full], now=self._step
+                req.prompt, req.pages[:full], now=self._step,
+                window_pages=(req.window_pages[:full]
+                              if self.window_pool is not None else None),
             )
             if self.prefix_store is not None:
                 # fleet export rides the local commit: the pages just

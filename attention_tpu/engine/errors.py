@@ -63,6 +63,12 @@ Recurrent layers (ISSUE 27) add the reach half:
   such a request, so the feature refuses instead of serving wrong
   tokens.
 
+Attention layers that differ inside one model (ISSUE 43):
+
+* :class:`PageSpacesUnsupportedError` — a feature that carries ONE
+  list of page ids a request met a model whose window layers and full
+  layers keep their pages in two page spaces.
+
 All subclass RuntimeError, the `OutOfPagesError` lineage — the
 ATP401 contract (attention_tpu/analysis/errors.py) extends over
 ``frontend/`` and ``prefixstore/`` so generic raises cannot creep
@@ -198,3 +204,18 @@ class LatentCacheUnsupportedError(RuntimeError):
     and each of them raises this for it
     (`ServingEngine.require_pages_only`).  The local prefix cache is
     not among them: page ids are head- and pool-agnostic."""
+
+
+class PageSpacesUnsupportedError(RuntimeError):
+    """A feature that carries ONE list of page ids a request met a
+    model with TWO page spaces.
+
+    A model whose sliding-window layers stand beside full-attention
+    layers keeps the window layers' K and V in a pool of their own,
+    under page ids of their own, and a request holds of them its
+    trailing band and no more.  Snapshot save / restore, prefix-store
+    export and import, the fleet's KV hand-off and ``mesh_shards > 0``
+    write one list of pages a request and one K / V pool pair a layer
+    of one size; each raises this for such a model
+    (`ServingEngine.require_pages_only`) until the page wire format
+    has a record for the second space."""

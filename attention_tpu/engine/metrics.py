@@ -42,8 +42,12 @@ _PREFILL_TOKENS = obs.counter("engine.tokens.prefill",
 _FINISHED = obs.counter("engine.requests.finished", "requests finished")
 _QUEUE = obs.gauge("engine.queue.depth", "waiting requests after step")
 _RUNNING = obs.gauge("engine.queue.running", "running requests after step")
-_PAGES_USED = obs.gauge("engine.pages.used", "pool pages in use")
-_PAGES_FREE = obs.gauge("engine.pages.free", "pool pages free")
+_PAGES_USED = obs.gauge("engine.pages.used",
+                        "pool pages in use (pool=window: the second page "
+                        "space's)")
+_PAGES_FREE = obs.gauge("engine.pages.free",
+                        "pool pages free (pool=window: the second page "
+                        "space's)")
 _STEP_WALL = obs.histogram("engine.step.wall_ms", "engine step wall ms",
                            buckets=(0.1, 0.25, 0.5, 1, 2.5, 5, 10, 25,
                                     50, 100, 250, 500, 1000))
@@ -125,6 +129,23 @@ class StepMetrics:
     expert_load_max: int = 0
     experts_reached: int = 0
     expert_pairs_zero: int = 0
+    # a model of TWO page spaces (sliding-window layers beside
+    # full-attention layers): ``kv_pages``, ``attn_qk_pairs`` and
+    # ``attn_rows_read`` above are then the SUM of one sublayer of each
+    # kind, and these the window kind's part of the first two; the
+    # pages that hold a key some row attends, whatever tile the kernel
+    # walks in (one sublayer of each kind, and the window kind's); the
+    # window pool's pages in use (requests' and the prefix cache's) and
+    # free after the step, as ``used_pages`` / ``free_pages`` are the
+    # full layers' pool's; and the window pages the running requests'
+    # bands slid past and gave back before the step
+    kv_pages_window: int = 0
+    attn_qk_pairs_window: int = 0
+    attn_band_pages: int = 0
+    attn_band_pages_window: int = 0
+    window_used_pages: int = 0
+    window_free_pages: int = 0
+    window_pages_released: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
@@ -204,6 +225,9 @@ class EngineMetrics:
             _RUNNING.set(m.running)
             _PAGES_USED.set(m.used_pages)
             _PAGES_FREE.set(m.free_pages)
+            if m.window_used_pages or m.window_free_pages:
+                _PAGES_USED.set(m.window_used_pages, pool="window")
+                _PAGES_FREE.set(m.window_free_pages, pool="window")
             _STEP_WALL.observe(m.wall_s * 1e3)
             if m.pad_tokens:
                 _PAD_TOKENS.inc(m.pad_tokens)

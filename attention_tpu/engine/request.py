@@ -152,6 +152,9 @@ class Request:
     pending_token: int | None = None
     computed_tokens: int = 0
     pages: list[int] = dataclasses.field(default_factory=list)
+    # two page spaces (`engine.allocator`): logical page j of the
+    # window layers' pool, -1 below the request's band; empty otherwise
+    window_pages: list[int] = dataclasses.field(default_factory=list)
     prefix_cached_tokens: int = 0
     preemptions: int = 0
     # row of the engine's recurrent-state pools while the request runs

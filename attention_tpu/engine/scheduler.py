@@ -29,7 +29,7 @@ from typing import Any, NamedTuple
 import numpy as np
 
 from attention_tpu import obs
-from attention_tpu.engine.allocator import BlockAllocator, pages_for_tokens
+from attention_tpu.engine.allocator import BlockAllocator
 from attention_tpu.engine.request import Request, RequestState
 from attention_tpu.ops.paged import OutOfPagesError
 
@@ -49,7 +49,8 @@ _STATE_RESETS = obs.counter(
 class StepSegments(NamedTuple):
     """The segments of one packed step's buffer, in the buffer's order
     (`split_step_buffer`); ``state_rows`` is None for a model with no
-    recurrent layer, whose buffer ends with ``tables``."""
+    recurrent layer, and ``window_tables`` for one with a single page
+    space: its buffer ends with ``tables``."""
 
     tokens: Any
     token_slot: Any
@@ -59,19 +60,23 @@ class StepSegments(NamedTuple):
     distribution: Any
     tables: Any
     state_rows: Any = None
+    window_tables: Any = None
 
 
 def step_buffer_len(width: int, *, slots: int, table_width: int,
-                    recurrent: bool) -> int:
-    """int32 entries of a packed step's buffer.  Slots, table width
-    and the state segment are one engine's constants, so the length
-    is a function of ``width`` alone there."""
+                    recurrent: bool, window_tables: bool = False) -> int:
+    """int32 entries of a packed step's buffer.  Slots, table width,
+    the state segment and the second page space's tables are one
+    engine's constants, so the length is a function of ``width`` alone
+    there."""
     return (3 * width + 2 * slots + 3 + slots * table_width
-            + (slots if recurrent else 0))
+            + (slots if recurrent else 0)
+            + (slots * table_width if window_tables else 0))
 
 
 def split_step_buffer(buffer, *, slots: int, table_width: int,
-                      recurrent: bool) -> StepSegments:
+                      recurrent: bool,
+                      window_tables: bool = False) -> StepSegments:
     """The segments of a packed step's ``buffer`` (a 1-D int32 array,
     NumPy's or the device's): static slices and two reshapes, so the
     host gets views of the buffer and a jitted caller a handful of
@@ -80,7 +85,8 @@ def split_step_buffer(buffer, *, slots: int, table_width: int,
     offset is a Python int made from the buffer's length and the
     arguments: no arithmetic on a traced value."""
     fixed = step_buffer_len(0, slots=slots, table_width=table_width,
-                            recurrent=recurrent)
+                            recurrent=recurrent,
+                            window_tables=window_tables)
     width, left = divmod(buffer.size - fixed, 3)
     if buffer.ndim != 1 or width < 0 or left:
         raise ValueError(
@@ -102,7 +108,9 @@ def split_step_buffer(buffer, *, slots: int, table_width: int,
         cu_q_lens=take(slots + 1),
         distribution=take(2),
         tables=take(slots * table_width).reshape(slots, table_width),
-        state_rows=take(slots) if recurrent else None)
+        state_rows=take(slots) if recurrent else None,
+        window_tables=(take(slots * table_width).reshape(slots, table_width)
+                       if window_tables else None))
 
 
 @dataclasses.dataclass
@@ -120,9 +128,12 @@ class PackedBatch:
     (slots, table_width) are the kernel's scalar-prefetch operands;
     last, for a model with recurrent layers only, ``state_rows``
     (slots,), each slot's row of the recurrent-state pools (-1: an
-    empty slot; None where the model keeps no such state).  Decode
-    slots come first (the ``distribution`` contract); ``num_real``
-    real tokens occupy the packed prefix, the remaining
+    empty slot; None where the model keeps no such state); and for a
+    model of two page spaces only, ``window_tables`` (slots,
+    table_width), each slot's row of WINDOW page ids, as wide as
+    ``tables``' and -1 below the request's band (the kernel never reads
+    there).  Decode slots come first (the ``distribution`` contract);
+    ``num_real`` real tokens occupy the packed prefix, the remaining
     ``width - num_real`` are pad."""
 
     buffer: np.ndarray
@@ -134,6 +145,7 @@ class PackedBatch:
     tables: np.ndarray
     distribution: np.ndarray
     state_rows: np.ndarray | None
+    window_tables: np.ndarray | None
     width: int
     num_real: int
 
@@ -152,6 +164,9 @@ class ScheduledStep:
     )
     preempted: list[Request] = dataclasses.field(default_factory=list)
     admitted: list[Request] = dataclasses.field(default_factory=list)
+    # two page spaces: window pages the running requests' bands slid
+    # past and gave back before this step was composed
+    window_pages_released: int = 0
 
     @property
     def num_decode_tokens(self) -> int:
@@ -166,11 +181,14 @@ class ScheduledStep:
         return not self.decode and not self.prefill
 
     def pack(self, *, width: int, slots: int, table_width: int,
-             recurrent: bool = False) -> PackedBatch:
+             recurrent: bool = False,
+             window_tables: bool = False) -> PackedBatch:
         """Flatten this step onto one padded token axis, decode slots
         first then prefill chunks, each request's tokens contiguous;
         ``recurrent`` says whether the model keeps a recurrent state,
-        hence whether the buffer has a ``state_rows`` segment.
+        hence whether the buffer has a ``state_rows`` segment, and
+        ``window_tables`` whether it has two page spaces, hence a
+        second table.
 
         Every segment is written in place, into a buffer made for
         this step: one kept across steps would be rewritten under an
@@ -191,10 +209,11 @@ class ScheduledStep:
                 f"step has {total} tokens but packed width is {width}"
             )
         consts = dict(slots=slots, table_width=table_width,
-                      recurrent=recurrent)
+                      recurrent=recurrent, window_tables=window_tables)
         buffer = np.zeros(step_buffer_len(width, **consts), np.int32)
         seg = split_step_buffer(buffer, **consts)
-        for empty in (seg.token_slot, seg.tables, seg.state_rows):
+        for empty in (seg.token_slot, seg.tables, seg.state_rows,
+                      seg.window_tables):
             if empty is not None:
                 empty.fill(-1)
         num_decode = len(self.decode)
@@ -211,6 +230,9 @@ class ScheduledStep:
             if recurrent:
                 seg.state_rows[s] = req.state_slot
             seg.tables[s, :len(req.pages)] = req.pages
+            if window_tables:
+                seg.window_tables[s, :len(req.window_pages)] = (
+                    req.window_pages)
             off += n
             seg.cu_q_lens[s + 1] = off
         seg.cu_q_lens[len(items) + 1:] = off
@@ -302,16 +324,20 @@ class Scheduler:
 
     def _ensure_pages(self, req: Request, cover_tokens: int, *,
                       for_decode: bool) -> None:
-        need = pages_for_tokens(cover_tokens, self.allocator.page_size) \
-            - len(req.pages)
-        if need > 0:
-            req.pages.extend(
-                self.allocator.allocate(need, for_decode=for_decode)
-            )
+        self.allocator.cover(req, cover_tokens, for_decode=for_decode)
 
     def schedule(self, step: int) -> ScheduledStep:
         sched = ScheduledStep(step=step)
         budget = self.token_budget
+
+        # 0) two page spaces: every running request gives back the
+        # window pages its band has slid past, before anyone asks for
+        # one
+        if self.allocator.window_pool is not None:
+            with obs.span("allocator.release_window",
+                          running=len(self.running)):
+                sched.window_pages_released = sum(
+                    self.allocator.release_window(r) for r in self.running)
 
         # 1) decode: every DECODING request in FCFS order, up to the
         # batch width; each needs page coverage for one appended row
@@ -376,11 +402,13 @@ class Scheduler:
                 break
             with obs.span("scheduler.admit", rid=req.request_id):
                 self.allocator.release(req)  # defensive: queued hold nothing
-                pages = (self.allocator.lookup_prefix(req.tokens,
-                                                      now=step)
-                         if self.prefix_admission else [])
+                window_pages: list[int] = []
+                pages = (self.allocator.lookup_prefix(
+                    req.tokens, now=step, window_out=window_pages)
+                    if self.prefix_admission else [])
+                hit = len(pages)
                 try:
-                    req.pages = pages
+                    req.pages, req.window_pages = pages, window_pages
                     req.computed_tokens = (
                         len(pages) * self.allocator.page_size)
                     req.prefix_cached_tokens = req.computed_tokens
@@ -391,15 +419,15 @@ class Scheduler:
                             "admission chunk not scheduled")
                 except OutOfPagesError:
                     # watermark refusal: return the prefix references
-                    # and wait — running requests drain the queue
-                    # eventually
-                    if pages:
-                        self.allocator.free(pages)
+                    # (and what one page space gave before the other
+                    # refused) and wait — running requests drain the
+                    # queue eventually
+                    if hit:
                         self.allocator.prefix_hits -= 1
                         self.allocator.prefix_hit_tokens -= (
-                            len(pages) * self.allocator.page_size
+                            hit * self.allocator.page_size
                         )
-                    req.pages = []
+                    self.allocator.release(req)
                     req.computed_tokens = 0
                     req.prefix_cached_tokens = 0
                     _ADMIT_WAITS.inc()

@@ -253,6 +253,14 @@ class GQASelfAttention(nn.Module):
     # RMSNorm over the WHOLE q and k projections (all heads together),
     # ahead of the rotation: the OLMo 2 convention
     qk_norm: bool = False
+    # RMSNorm over each HEAD of q and k (one scale of ``head_dim`` for
+    # every head, at ``norm_eps``), ahead of the rotation
+    head_norm: bool = False
+    # a sigmoid OUTPUT GATE: a fourth projection of the input, as wide
+    # as q, whose sigmoid multiplies the attention's result ahead of
+    # ``o_proj``
+    gate: bool = False
+    norm_eps: float = 1e-6
     # Context parallelism: when set (training under a mesh whose
     # ``cp_axis`` shards the sequence), batch attention runs a
     # differentiable CP composition — the Pallas flash custom VJP under
@@ -326,6 +334,14 @@ class GQASelfAttention(nn.Module):
         q = dense("q_proj", self.num_q_heads)(x)  # (B, S, Hq, dh)
         k = dense("k_proj", self.num_kv_heads)(x)
         v = dense("v_proj", self.num_kv_heads)(x)
+        if self.qk_norm and self.head_norm:
+            raise ValueError("qk_norm (the whole projection) and head_norm "
+                             "(each head) are two norms of q and k; a "
+                             "layer has one")
+        if self.head_norm:
+            q, k = (nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                               name=name)(t)
+                    for t, name in ((q, "q_norm"), (k, "k_norm")))
         if self.qk_norm:
             def whole(t, name):
                 flat = t.reshape(t.shape[:2] + (-1,))
@@ -422,6 +438,10 @@ class GQASelfAttention(nn.Module):
         else:
             out, cache = self._cached_attention(q, k, v, cache)
         out = out.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], -1)
+        if self.gate:
+            g = dense("gate_proj", self.num_q_heads)(x)
+            out = out * jax.nn.sigmoid(
+                g.reshape(out.shape).astype(jnp.float32))
         proj = nn.DenseGeneral(
             features=x.shape[-1], use_bias=False, dtype=self.dtype, name="o_proj"
         )(out.astype(self.dtype))

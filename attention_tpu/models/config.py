@@ -68,6 +68,36 @@ differ, ``moe_layer_freq`` other than 1, ``n_shared_experts`` other
 than 1, a ``hidden_act`` other than ``"silu"``, ``attention_bias``,
 ``sliding_window``, and a configuration without ``index_topk`` (the
 block is served with its selector).
+
+A configuration whose ``layer_types`` hold ``sliding_attention`` and
+which has ``num_experts`` is a stack of two-sublayer blocks whose
+ATTENTION LAYERS DIFFER (`transformer.TransformerBlock`, kinds
+``sliding_attention`` and ``full_attention``): a sliding layer attends
+behind ``sliding_window`` and rotates q and k by ``rope_theta``, a full
+layer attends every key and rotates only where the file states
+``full_attention_rotary`` (false where absent: NoPE); heads of
+``head_dim``, a key of its own (``hidden_size / num_attention_heads``
+where absent); the feed-forward is dense SwiGLU at
+``intermediate_size`` in the first ``num_dense_layers`` layers, after
+them gated experts at ``moe_intermediate_size`` (``num_experts`` HELD
+HERE and ``expert_share`` as above, ``num_experts_per_tok``,
+``route_scale``) behind a sigmoid router whose chosen weights are
+normalised (``route_norm``), plus ONE shared expert
+(``num_shared_experts``); ``mup_enabled`` scales the embedding by
+``sqrt(hidden_size)``; norms at ``rms_norm_eps``; an untied head.  What
+no published key states the file states, each false where absent:
+``qk_head_norm`` (RMSNorm over each head of q and k), ``attention_gate``
+(a sigmoid output gate), ``sandwich_norm`` (a norm before AND after
+each sublayer).  A file cut in depth may name the published layers it
+serves, ``served_layers`` (indices into ``layer_types``, which stays
+whole; absent: the first ``num_hidden_layers``).  The two kinds of
+layer keep their pages in two page spaces (`engine.allocator`).
+Refused by the key's name: ``n_group`` / ``topk_group`` /
+``num_expert_groups`` / ``num_limited_groups`` other than 1, a
+``score_func`` other than ``"sigmoid"``, ``route_norm`` false,
+``rope_scaling``, ``tie_word_embeddings`` true, a ``hidden_act`` other
+than ``"silu"``, ``num_shared_experts`` other than 1, and a
+``layer_types`` of ``sliding_attention`` without ``sliding_window``.
 """
 
 from __future__ import annotations
@@ -81,6 +111,7 @@ from attention_tpu.models.transformer import (
     LATENT_EXPERTS,
     LINEAR_ATTENTION,
     SHORTCUT_EXPERTS,
+    SLIDING_ATTENTION,
     SPARSE_EXPERTS,
     STATE_SPACE,
     TinyDecoder,
@@ -99,7 +130,9 @@ def _common(config: dict, *, impl: str) -> dict:
     heads = int(config["num_attention_heads"])
     head_dim = config.get("head_dim")
     if head_dim is not None and dim // heads != int(head_dim):
-        raise ValueError("hidden_size / num_attention_heads != head_dim")
+        raise ValueError("head_dim: hidden_size / num_attention_heads in "
+                         "every family but the one whose attention layers "
+                         "differ (layer_types with sliding_attention)")
     theta = config.get("rope_theta")
     if theta is None:
         theta = (config.get("rope_parameters") or {}).get("rope_theta")
@@ -308,8 +341,75 @@ def _indexed_latent_decoder(config: dict, *, impl: str) -> TinyDecoder:
         norm_eps=float(config.get("rms_norm_eps", 1e-6)))
 
 
+def _mixed_window_decoder(config: dict, *, impl: str) -> TinyDecoder:
+    """The decoder of a ``layer_types`` with ``sliding_attention`` and
+    ``num_experts``: window and full attention layers in one model, a
+    dense or expert feed-forward; see the module's docstring for the
+    keys."""
+    refusals = (
+        ("n_group", 1, "the router has no group limit"),
+        ("topk_group", 1, "the router has no group limit"),
+        ("num_expert_groups", 1, "the router has no group limit"),
+        ("num_limited_groups", 1, "the router has no group limit"),
+        ("score_func", "sigmoid", "the router scores by sigmoid"),
+        ("route_norm", True, "the sigmoid router normalises the chosen "
+         "experts' weights"),
+        ("rope_scaling", None, "the sliding layers rotate by rope_theta "
+         "as it stands"),
+        ("tie_word_embeddings", False, "the head is a matrix of its own"),
+        ("hidden_act", "silu", "the feed-forwards are SwiGLU"),
+        ("num_shared_experts", 1, "an expert layer has ONE shared expert"),
+    )
+    for key, want, why in refusals:
+        if config.get(key, want) != want:
+            raise ValueError(f"{key} {config[key]!r}: {why}")
+    if config.get("sliding_window") is None:
+        raise ValueError("sliding_window: a sliding_attention layer "
+                         "attends behind one")
+    dim, depth = int(config["hidden_size"]), int(config["num_hidden_layers"])
+    heads = int(config["num_attention_heads"])
+    published = config["layer_types"]
+    served = config.get("served_layers", range(depth))
+    kinds = tuple(published[i] for i in served)
+    if len(kinds) != depth or set(kinds) - {FULL_ATTENTION,
+                                            SLIDING_ATTENTION}:
+        raise ValueError(
+            f"layer_types / served_layers must name {depth} layers of "
+            f"{(SLIDING_ATTENTION, FULL_ATTENTION)}; they name {kinds}")
+    share = config.get("expert_share") or {"index": 0, "of": 1}
+    held = int(config["num_experts"])
+    fields = dict(
+        experts=held * int(share["of"]), experts_held=held,
+        experts_share=int(share["index"]),
+        experts_top_k=int(config["num_experts_per_tok"]),
+        experts_hidden=int(config["moe_intermediate_size"]),
+        experts_scale=float(config.get("route_scale", 1.0)))
+    head_dim = int(config.get("head_dim", dim // heads))
+    return TinyDecoder(
+        vocab=int(config["vocab_size"]), dim=dim, depth=depth,
+        num_q_heads=heads,
+        num_kv_heads=int(config.get("num_key_value_heads", heads)),
+        impl=impl, dtype=jnp.dtype(config.get("torch_dtype", "bfloat16")),
+        head_dim=None if head_dim == dim // heads else head_dim,
+        window=int(config["sliding_window"]), rope=True,
+        rope_theta=float(config["rope_theta"]),
+        global_rope=bool(config.get("full_attention_rotary", False)),
+        layer_types=kinds, sublayer=tuple(sorted(fields.items())),
+        num_dense_layers=min(int(config.get("num_dense_layers", 0)), depth),
+        mlp_hidden=int(config["intermediate_size"]),
+        head_norm=bool(config.get("qk_head_norm", False)),
+        attn_gate=bool(config.get("attention_gate", False)),
+        sandwich_norm=bool(config.get("sandwich_norm", False)),
+        embed_scale=(float(dim) ** 0.5 if config.get("mup_enabled")
+                     else 1.0),
+        norm_eps=float(config.get("rms_norm_eps", 1e-6)))
+
+
 def decoder_from_config(config: dict, *, impl: str = "flash") -> TinyDecoder:
     """The program's decoder at the configuration's sizes."""
+    if (SLIDING_ATTENTION in (config.get("layer_types") or ())
+            and "num_experts" in config):
+        return _mixed_window_decoder(config, impl=impl)
     if "attention_method" in config:
         return _latent_decoder(config, impl=impl)
     if "kv_lora_rank" in config:

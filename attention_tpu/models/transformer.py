@@ -38,6 +38,11 @@ from attention_tpu.models.moe import (
 #: DeltaNet) and an MLP, each under its own residual
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
+#: attention behind the model's sliding ``window``.  In a model that
+#: has such layers the others (``full_attention``) attend every key,
+#: and the two kinds keep their pages in page spaces of their own
+#: (`TinyDecoder.window_layers`)
+SLIDING_ATTENTION = "sliding_attention"
 #: the rest are blocks of ONE sublayer, ``x + f(norm(x))``: attention
 #: alone, a Mamba-2 state-space mixer, or latent sparse experts
 #: (`LatentExperts`)
@@ -56,7 +61,7 @@ LATENT_DENSE = "latent_dense"
 LATENT_EXPERTS = "latent_experts"
 LATENT_KINDS = (LATENT_DENSE, LATENT_EXPERTS)
 LAYER_KINDS = ((FULL_ATTENTION, LINEAR_ATTENTION) + SUBLAYER_KINDS
-               + (SHORTCUT_EXPERTS,) + LATENT_KINDS)
+               + (SHORTCUT_EXPERTS,) + LATENT_KINDS + (SLIDING_ATTENTION,))
 
 _ACTIVATIONS = {"gelu": nn.gelu, "silu": nn.silu}
 
@@ -92,6 +97,28 @@ class GatedMLP(nn.Module):
         return dense("down_proj", x.shape[-1])(h)
 
 
+def expert_feed_forward(y, packed, *, dtype, experts: int, experts_held: int,
+                        experts_share: int = 0, experts_top_k: int = 1,
+                        experts_hidden: int = 0, experts_scale: float = 1.0,
+                        experts_groups: int = 1,
+                        experts_top_groups: int = 1):
+    """`GatedExperts` behind the sigmoid router (limited to
+    ``experts_top_groups`` of ``experts_groups`` groups; one group: no
+    limit) plus ONE shared expert of the experts' width, which every
+    token takes and every chip computes whole.  Called inside a
+    block's ``__call__``: the two submodules are the block's own,
+    ``experts`` and ``shared_expert``.  ``packed`` names the pads of a
+    packed engine step (None: no cache)."""
+    out = GatedExperts(
+        num_experts=experts, held=experts_held, share=experts_share,
+        top_k=experts_top_k, hidden=experts_hidden, scale=experts_scale,
+        router="sigmoid", groups=experts_groups,
+        top_groups=experts_top_groups, dtype=dtype, name="experts")(
+            y, packed)
+    return out + GatedMLP(hidden=experts_hidden, dtype=dtype,
+                          name="shared_expert")(y)
+
+
 class TransformerBlock(nn.Module):
     num_q_heads: int
     num_kv_heads: int
@@ -123,6 +150,16 @@ class TransformerBlock(nn.Module):
     linear_value_dim: int = 0
     linear_conv: int = 4
     linear_neg_eigval: bool = True
+    head_norm: bool = False   # RMSNorm over each head of q and k
+    attn_gate: bool = False   # sigmoid output gate on the attention
+    # a norm before AND after each sublayer, x + norm(f(norm(x))):
+    # four norms a block
+    sandwich_norm: bool = False
+    norm_eps: float = 1e-6
+    # `expert_feed_forward`'s fields by name, as a tuple of pairs so
+    # that the module hashes: the feed-forward is gated experts beside
+    # a shared expert (empty: the dense MLP above)
+    experts: tuple[tuple[str, Any], ...] = ()
 
     def _mixer(self):
         if self.kind == LINEAR_ATTENTION:
@@ -131,7 +168,7 @@ class TransformerBlock(nn.Module):
                 value_dim=self.linear_value_dim,
                 conv_width=self.linear_conv,
                 neg_eigval=self.linear_neg_eigval, dtype=self.dtype)
-        if self.kind != FULL_ATTENTION:
+        if self.kind not in (FULL_ATTENTION, SLIDING_ATTENTION):
             raise ValueError(
                 f"unknown layer kind {self.kind!r}; one of {LAYER_KINDS}")
         return GQASelfAttention(
@@ -147,6 +184,9 @@ class TransformerBlock(nn.Module):
             rope_theta=self.rope_theta,
             softcap=self.softcap,
             qk_norm=self.qk_norm,
+            head_norm=self.head_norm,
+            gate=self.attn_gate,
+            norm_eps=self.norm_eps,
             cp_axis=self.cp_axis,
             cp_impl=self.cp_impl,
             tp_axis=self.tp_axis,
@@ -156,14 +196,22 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x, cache=None):
         def norm(t):
-            return nn.RMSNorm(dtype=self.dtype)(t)
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype)(t)
 
-        attn_out = self._mixer()(x if self.post_norm else norm(x), cache)
+        before = self.sandwich_norm or not self.post_norm
+        after = self.sandwich_norm or self.post_norm
+        attn_out = self._mixer()(norm(x) if before else x, cache)
         if cache is not None:
             attn_out, cache = attn_out
-        x = x + (norm(attn_out) if self.post_norm else attn_out)
-        y = x if self.post_norm else norm(x)
-        if self.mlp_hidden is not None:
+        x = x + (norm(attn_out) if after else attn_out)
+        y = norm(x) if before else x
+        if self.experts:
+            # a packed engine step names its pads; a dense cache has none
+            slot = getattr(cache, "token_slot", None)
+            mlp_out = expert_feed_forward(
+                y, None if slot is None else PackedTokens(slot),
+                dtype=self.dtype, **dict(self.experts))
+        elif self.mlp_hidden is not None:
             mlp_out = GatedMLP(hidden=self.mlp_hidden, act=self.mlp_act,
                                dtype=self.dtype)(y)
         elif self.moe_experts:
@@ -176,7 +224,7 @@ class TransformerBlock(nn.Module):
             )(y)
         else:
             mlp_out = MLP(dtype=self.dtype)(y)
-        x = x + (norm(mlp_out) if self.post_norm else mlp_out)
+        x = x + (norm(mlp_out) if after else mlp_out)
         return x if cache is None else (x, cache)
 
 
@@ -317,9 +365,9 @@ class LatentBlock(nn.Module):
         x = x + F(norm(x))
 
     ``F`` is a dense SwiGLU of ``mlp_hidden`` (``sparse`` false: a
-    leading dense layer) or `GatedExperts` behind the group-limited
-    sigmoid router plus ONE shared expert of the experts' width, which
-    every token takes and every chip computes whole.  With a cache (a
+    leading dense layer) or `expert_feed_forward`: `GatedExperts`
+    behind the group-limited sigmoid router plus ONE shared expert.
+    With a cache (a
     packed engine step of a latent pool and an index pool) it returns
     ``(x, step)``."""
 
@@ -370,17 +418,16 @@ class LatentBlock(nn.Module):
         x = x + attn
         y = norm("mlp_norm")(x)
         if self.sparse:
-            out = GatedExperts(
-                num_experts=self.experts, held=self.experts_held,
-                share=self.experts_share, top_k=self.experts_top_k,
-                hidden=self.experts_hidden, scale=self.experts_scale,
-                router="sigmoid", groups=self.experts_groups,
-                top_groups=self.experts_top_groups, dtype=self.dtype,
-                name="experts")(
-                    y, None if cache is None
-                    else PackedTokens(cache.token_slot))
-            out = out + GatedMLP(hidden=self.experts_hidden,
-                                 dtype=self.dtype, name="shared_expert")(y)
+            out = expert_feed_forward(
+                y, None if cache is None else PackedTokens(cache.token_slot),
+                dtype=self.dtype, experts=self.experts,
+                experts_held=self.experts_held,
+                experts_share=self.experts_share,
+                experts_top_k=self.experts_top_k,
+                experts_hidden=self.experts_hidden,
+                experts_scale=self.experts_scale,
+                experts_groups=self.experts_groups,
+                experts_top_groups=self.experts_top_groups)
         else:
             out = GatedMLP(hidden=self.mlp_hidden, dtype=self.dtype,
                            name="mlp")(y)
@@ -454,6 +501,26 @@ class TinyDecoder(nn.Module):
     post_norm: bool = False   # x + norm(f(x)): the OLMo 2 block
     mlp_hidden: int | None = None  # gated MLP of this width (None: 4x gelu)
     mlp_act: str = "silu"
+    # Attention layers that DIFFER inside one model: a
+    # ``sliding_attention`` layer attends behind ``window`` and rotates
+    # by ``rope``; beside such layers a ``full_attention`` layer has no
+    # window, and rotates only where ``global_rope`` says so (false:
+    # NoPE).  A model of ``full_attention`` layers alone gives every one
+    # ``window`` and ``rope``, as before.
+    global_rope: bool = True
+    head_dim: int | None = None    # a head's size (None: dim / heads)
+    head_norm: bool = False   # RMSNorm over each head of q and k
+    attn_gate: bool = False   # sigmoid output gate on the attention
+    sandwich_norm: bool = False    # x + norm(f(norm(x))): four norms
+    embed_scale: float = 1.0  # the embedding's rows times this
+    # With ``num_dense_layers`` set, the two-sublayer blocks after
+    # that many leading ones take `expert_feed_forward` (its fields in
+    # ``sublayer``) in place of the dense MLP.
+    num_dense_layers: int | None = None
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.dim // self.num_q_heads
 
     @property
     def kinds(self) -> tuple[str, ...]:
@@ -472,7 +539,32 @@ class TinyDecoder(nn.Module):
     @property
     def attention_layers(self) -> tuple[int, ...]:
         """Layers that keep K and V rows (paged KV pools)."""
-        return self._layers_of(FULL_ATTENTION, ATTENTION)
+        return self._layers_of(FULL_ATTENTION, ATTENTION, SLIDING_ATTENTION)
+
+    @property
+    def window_layers(self) -> tuple[int, ...]:
+        """The sliding-window layers of a model that ALSO has attention
+        layers without a window: the two kinds keep their pages in page
+        spaces of their own, and a request holds of a window layer its
+        trailing band and no more.  Empty for a model of one kind,
+        sliding or not: one page space, as ever."""
+        sliding = self._layers_of(SLIDING_ATTENTION)
+        return sliding if len(sliding) < len(self.attention_layers) else ()
+
+    def _beside_sliding(self, layer: int) -> bool:
+        """Whether ``layer`` is a layer WITHOUT the window in a model
+        that has sliding layers."""
+        return (SLIDING_ATTENTION in self.kinds
+                and self.kinds[layer] != SLIDING_ATTENTION)
+
+    def layer_window(self, layer: int) -> int | None:
+        """The window attention layer ``layer`` attends behind."""
+        return None if self._beside_sliding(layer) else self.window
+
+    def layer_rope(self, layer: int) -> bool:
+        """Whether attention layer ``layer`` rotates q and k."""
+        return self.rope and (self.global_rope
+                              or not self._beside_sliding(layer))
 
     @property
     def recurrent_layers(self) -> tuple[int, ...]:
@@ -519,15 +611,20 @@ class TinyDecoder(nn.Module):
         if self.indexed_layers:
             return 1, (latent_row_width(f["kv_lora_rank"], f["rope_dim"]),
                        index_row_width(f["index_dim"]))
-        return self.num_kv_heads, (self.dim // self.num_q_heads,) * 2
+        return self.num_kv_heads, (self.head_size,) * 2
 
     @property
     def expert_layers(self) -> tuple[int, ...]:
         """Layers with sparse experts: the experts keep nothing per
         request, and report their pairs (`LatentExperts`,
         `GatedExperts`)."""
-        return self._layers_of(SPARSE_EXPERTS, SHORTCUT_EXPERTS,
-                               LATENT_EXPERTS)
+        layers = self._layers_of(SPARSE_EXPERTS, SHORTCUT_EXPERTS,
+                                 LATENT_EXPERTS)
+        if self.num_dense_layers is not None:
+            layers += tuple(i for i in self._layers_of(
+                FULL_ATTENTION, SLIDING_ATTENTION, LINEAR_ATTENTION)
+                if i >= self.num_dense_layers)
+        return tuple(sorted(layers))
 
     @property
     def zero_experts(self) -> int:
@@ -563,10 +660,12 @@ class TinyDecoder(nn.Module):
     def __call__(self, tokens: jax.Array, caches=None,
                  return_hidden: bool = False,
                  logit_rows: jax.Array | None = None):  # (B, S) int32
-        head_dim = self.dim // self.num_q_heads
+        head_dim = self.head_size
         # rows first, then the cast: `Embed(dtype=...)` would cast the
         # whole float32 table in every call and only then take the rows
         x = nn.Embed(self.vocab, self.dim)(tokens).astype(self.dtype)
+        if self.embed_scale != 1.0:
+            x = x * jnp.asarray(self.embed_scale, self.dtype)
         new_caches = []
         block_cls = (
             nn.remat(TransformerBlock)
@@ -608,9 +707,9 @@ class TinyDecoder(nn.Module):
                 head_dim=head_dim,
                 impl=self.impl,
                 dtype=self.dtype,
-                window=self.window,
+                window=self.layer_window(i),
                 attn_sinks=self.attn_sinks,
-                rope=self.rope,
+                rope=self.layer_rope(i),
                 rope_theta=self.rope_theta,
                 softcap=self.softcap,
                 moe_experts=self.moe_experts,
@@ -631,6 +730,11 @@ class TinyDecoder(nn.Module):
                 linear_value_dim=self.linear_value_dim,
                 linear_conv=self.linear_conv,
                 linear_neg_eigval=self.linear_neg_eigval,
+                head_norm=self.head_norm,
+                attn_gate=self.attn_gate,
+                sandwich_norm=self.sandwich_norm,
+                norm_eps=self.norm_eps,
+                experts=(self.sublayer if i in self.expert_layers else ()),
                 name=f"TransformerBlock_{i}",
             )
             if caches is None:
@@ -665,7 +769,7 @@ class TinyDecoder(nn.Module):
                 "a model with recurrent or latent-attention layers serves "
                 "through the engine's packed step; it has no dense "
                 "per-layer caches")
-        head_dim = self.dim // self.num_q_heads
+        head_dim = self.head_size
         if rolling:
             if self.window is None:
                 raise ValueError("rolling caches require a windowed model")

@@ -97,7 +97,8 @@ def _compile_step(model, sharding, params, pools, width, q_tile, *,
     pools (one sharding, or one each)."""
     buffer = _a((step_buffer_len(
         width, slots=slots, table_width=max_pages,
-        recurrent=bool(getattr(model, "recurrent_layers", ()))),), I32)
+        recurrent=bool(getattr(model, "recurrent_layers", ())),
+        window_tables=bool(getattr(model, "window_layers", ()))),), I32)
     return _ragged_apply.lower(
         model, *_placed(sharding, (params, buffer, pools)),
         StepLayout(slots, max_pages, q_tile)).compile()
@@ -465,13 +466,12 @@ def test_the_gated_experts_kernel_compiles_at_the_cells_sizes(v5e):
                  layout)
 
 
-def _deepseek_cell():
-    """The benchmark's DeepSeek-V3.2-Exp cell whole: one dense and four
-    expert layers, 32 + 1 slots, a table row of 392 pages, 1,856 pages
-    of a latent pool AND an index pool a layer; parameters in bfloat16,
-    the router's in float32, as the cell's reference makes them."""
+def _config_cell(name: str):
+    """A benchmark configuration's file, the model the program builds
+    from it, and its parameters' shapes in bfloat16 (the router's in
+    float32), as the cells' references make them."""
     path = (pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
-            / "deepseek-v3.2-exp.json")
+            / f"{name}.json")
     config = json.loads(path.read_text())
     model = decoder_from_config(config)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
@@ -479,6 +479,15 @@ def _deepseek_cell():
     params = jax.tree_util.tree_map_with_path(
         lambda p, a: _a(a.shape, F32 if "router" in jax.tree_util.keystr(p)
                         else BF16), shapes)
+    return config, model, params
+
+
+def _deepseek_cell():
+    """The benchmark's DeepSeek-V3.2-Exp cell whole: one dense and four
+    expert layers, 32 + 1 slots, a table row of 392 pages, 1,856 pages
+    of a latent pool AND an index pool a layer; parameters in bfloat16,
+    the router's in float32, as the cell's reference makes them."""
+    config, model, params = _config_cell("deepseek-v3.2-exp")
     heads, widths = model.kv_pool_widths()
     pools = tuple(
         tuple(_a((config["engine"]["num_pages"], heads, 128, w), BF16)
@@ -516,6 +525,52 @@ def test_the_deepseek_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
     for name in ("index_scores", "index_select", "kv_row_append",
                  "ragged_paged_list_attention"):
         assert text.count(f'"{name}"') >= 5 or text.count(name) >= 5, name
+
+
+def _trinity_cell():
+    """The benchmark's Trinity-Mini cell whole: one dense and eight
+    expert layers, 7 of the 9 behind a window; 32 + 1 slots, TWO table
+    rows of 266 pages a slot, 9,024 pages of K and V in each full
+    layer and 1,280 in each window layer; parameters in bfloat16, the
+    router's in float32, as the cell's reference makes them."""
+    config, model, params = _config_cell("trinity-mini")
+    heads, widths = model.kv_pool_widths()
+    engine = config["engine"]
+    pools = tuple(
+        tuple(_a((engine["num_window_pages" if layer in model.window_layers
+                         else "num_pages"], heads, 128, w), BF16)
+              for w in widths) for layer in range(model.depth))
+    return model, params, pools, dict(slots=33, max_pages=266)
+
+
+@pytest.mark.parametrize("width,q_tile", [(384, 256), (32, 1)],
+                         ids=["widest_step", "decode_only"])
+def test_the_trinity_cells_step_fits_the_chip_in_place(v5e, width, q_tile):
+    """The whole served step of the Trinity-Mini cell compiles for one
+    v5e chip at the cell's sizes: the K / V ragged kernel at 32 query
+    heads on 4 of 128 lanes behind a window of 2,048 in seven layers
+    and without one in two, pools of two sizes under two tables, the
+    gated experts at a width of 1,024: every donated pool aliased to
+    its result, and arguments + temporaries inside the chip's 16 GB
+    (9.57 GB of arguments: 2.49 parameters, 4.73 + 2.35 the two page
+    spaces)."""
+    model, params, pools, index = _trinity_cell()
+    assert model.window_layers == (0, 1, 2, 3, 5, 6, 7)
+    one = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = _compile_step(model, one, params, pools, width, q_tile,
+                             **index)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print("trinity step", width, q_tile, mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes, total)
+    assert 9.5e9 < total < 11e9, total
+    pooled = sum(np.prod(a.shape) * a.dtype.itemsize
+                 for a in jax.tree.leaves(pools))
+    assert pooled == (2 * 9024 + 7 * 1280) * 262144
+    assert mem.alias_size_in_bytes >= pooled
+    calls, _ = _ragged_kernel_calls(compiled)
+    assert len(calls) == 9
 
 
 def test_the_choosing_kernels_compile_at_the_cells_sizes(v5e):
