@@ -75,6 +75,7 @@ from attention_tpu.ops.ragged_paged import (
     live_pages,
     packed_bucket,
     recommended_q_tile,
+    span_tile_rows,
     tile_tokens,
 )
 
@@ -925,7 +926,7 @@ class ServingEngine:
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
         pad_tokens = kv_pages = qk_pairs = keys_selected = rows_read = 0
-        width = q_tile = compiled_programs = 0
+        width = q_tile = compiled_programs = own_tile = 0
         occupancy = compile_s = 0.0
         self._expert_pairs = None
         self._window_fields = {}
@@ -952,7 +953,7 @@ class ServingEngine:
                     sched.window_pages_released)
             if not sched.is_empty:
                 (width, q_tile, kv_pages, qk_pairs, keys_selected,
-                 rows_read) = self._run_ragged(sched)
+                 rows_read, own_tile) = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
             if _compiles.count != compile_rows:
@@ -980,6 +981,7 @@ class ServingEngine:
                 pad_tokens=pad_tokens,
                 ragged_occupancy=occupancy,
                 kv_pages=kv_pages,
+                own_tile_spans=own_tile,
                 attn_qk_pairs=qk_pairs,
                 attn_keys_selected=keys_selected,
                 attn_rows_read=rows_read,
@@ -1213,17 +1215,18 @@ class ServingEngine:
         return packed_bucket(max(decoding + chunk, q_tile)), q_tile
 
     def _run_ragged(self, sched: ScheduledStep
-                    ) -> tuple[int, int, int, int, int, int]:
+                    ) -> tuple[int, int, int, int, int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
         the packed width and the query tile dispatched (the program's
         shape), the step's live (slot, page) pairs (what the attention
         kernel's grid walks, or, where a selector chooses, its
         scoring's), the (query token, key) pairs one attention sublayer
         attends (or, where it chooses its keys, scores), the pairs the
-        choice keeps by its rule (0 without one), and the cache rows one
-        sublayer's attention reads.  Where window layers stand beside
-        full layers, pages, pairs and rows are the SUM of one sublayer
-        of each kind, and the window kind's part goes to
+        choice keeps by its rule (0 without one), the cache rows one
+        sublayer's attention reads, and the spans of one token that the
+        kernel served at a tile of their own.  Where window layers
+        stand beside full layers, pages, pairs and rows are the SUM of
+        one sublayer of each kind, and the window kind's part goes to
         ``_window_fields``.
 
         The per-request query tile covers the longest prefill chunk and
@@ -1262,6 +1265,15 @@ class ServingEngine:
             pairs = [_qk_pairs(batch.kv_lens, q_lens, window)
                      for window in self._windows]
             kv_pages, qk_pairs = sum(walked), sum(pairs)
+            # beside a chunk the kernel gives a decode row its own tile
+            # (a list of rows is attended at no tile)
+            wide, one_token = span_tile_rows(
+                q_tile, width,
+                self.model.num_q_heads // self.model.num_kv_heads,
+                row_blocked=bool(self._latent_layers))
+            own_tile = (int((q_lens == 1).sum())
+                        if one_token < wide and not self._indexed_layers
+                        else 0)
             if self._window_layers:
                 needed = [_band_pages(batch.kv_lens, q_lens, window,
                                       cfg.page_size)
@@ -1314,7 +1326,8 @@ class ServingEngine:
         with obs.span("engine.step.dispatch", width=width, q_tile=q_tile,
                       decode_rows=len(sched.decode),
                       prefill_tokens=sched.num_prefill_tokens,
-                      kv_pages=kv_pages, attn_rows=rows_read, **fields):
+                      kv_pages=kv_pages, own_tile_spans=own_tile,
+                      attn_rows=rows_read, **fields):
             logits_dev, new_pools, pairs_dev = _ragged_apply(
                 self._step_model, self.params, buffer,
                 self._layer_pools(),
@@ -1329,7 +1342,8 @@ class ServingEngine:
             for s, (req, real) in enumerate(sched.prefill):
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
-        return width, q_tile, kv_pages, qk_pairs, keys_selected, rows_read
+        return (width, q_tile, kv_pages, qk_pairs, keys_selected, rows_read,
+                own_tile)
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut

@@ -93,6 +93,13 @@ class StepMetrics:
     pad_tokens: int = 0              # pads dispatched this step
     ragged_occupancy: float = 0.0    # real / dispatched width
     kv_pages: int = 0                # (slot, page) pairs the kernel walked
+    # the step's spans of ONE token (decode rows) that the attention
+    # kernel served at a tile of their own beside a wider one
+    # (`ops.ragged_paged.span_tile_rows`: groups that are a multiple
+    # of 8): 0 on a step whose tile is the one-token tile (every
+    # decode-only step), at another group, and where a list of rows is
+    # attended and no tile is
+    own_tile_spans: int = 0
     # (query token, key) pairs one attention sublayer attends: over the
     # step's slots, each query token times the keys it reaches
     attn_qk_pairs: int = 0

@@ -43,6 +43,17 @@ Static tile discipline: the per-request query tile is ``q_tile`` tokens
 executables instead of one per (D, P) composition — the no-recompile-
 cliff property the two fixed shapes bought, kept.
 
+Where the group is a multiple of 8, a span of ONE token (a decode
+row) is attended at the one-token tile (`span_tile_rows`: 8 rows at a
+group of 8, 16 at 16) whatever the step's ``q_tile``: a chunk of 256
+tokens in the same step does not make 31 decode slots multiply each of
+their pages against 2,048 rows of which they own 8.  A program of such
+a group whose tile is wider than one token's holds two tile bodies,
+chosen an item by its span's length; a decode-only program, and every
+program of another group, holds one, as it always did.  The work list,
+the grid, the scratch and the page band are the step's tile's in both:
+nothing is chosen outside the kernel, and no option selects it.
+
 ``q_tile`` rides in the SHAPE of the cache's ``q_span`` marker field
 (shapes are static under jit, values are not) so the engine can pick
 the tile per step without threading a static argument through
@@ -87,13 +98,16 @@ from attention_tpu.ops.flash import (
 # per host-side dispatch; calls inside an enclosing jit tick per trace.
 # `ops.ragged.lowered` ticks at TRACE time inside the jitted body and
 # records which rescaling-math variant the dispatch actually lowered
-# (the ragged equivalent of `ops.flash.lowered`).
+# (the ragged equivalent of `ops.flash.lowered`) and how many tile
+# bodies the kernel holds: ``bodies`` "one", "two" (a span of one
+# token at its own tile beside a wider one), "list" (no tile).
 _RAGGED_CALLS = obs.counter(
     "ops.ragged.calls",
     "ragged paged-attention dispatches by (tokens, capacity, dim) bucket")
 _RAGGED_LOWERED = obs.counter(
     "ops.ragged.lowered",
-    "ragged kernel lowerings by requested/resolved max mode")
+    "ragged kernel lowerings by requested/resolved max mode and tile "
+    "bodies")
 
 # Mosaic's default scoped-VMEM budget, and the ceiling a raised budget
 # may ask for (the forward kernel's big-tile figure: v4+ cores hold it).
@@ -212,6 +226,35 @@ def _row_tile(q_tile: int, t_pad: int, group: int) -> int:
     if group % 8 == 0 or q_tile == t_pad:
         return q_rows
     return q_rows + 8
+
+
+def span_tile_rows(q_tile: int, t_pad: int, group: int, *,
+                   row_blocked: bool = False) -> tuple[int, int]:
+    """``(rows, one_token_rows)`` of the program of a ``(t_pad,
+    q_tile)`` step: the rows of the tile a span of several tokens is
+    attended at, and of the tile a span of ONE token (a decode row) is
+    (`_ragged_kernel`).  Equal where the step's tile is the one-token
+    tile, every decode-only shape: that program holds one tile body,
+    every other two.  The engine counts from this what a step's decode
+    rows were served at (``StepMetrics.own_tile_spans``).
+
+    Only a group that is a multiple of 8 gets the second body (8 rows
+    at a group of 8, 16 at 16).  At any other group a token's rows do
+    not start on the sublane granule and the one-token tile carries
+    the rounded start's spare rows (80 rows at a group of 9, 16 at 1).
+    That form runs and is right on the chip too (StarCoder2's group of
+    9 a quarter faster or more where half the steps hold a chunk), but
+    its second body took 0.37 s more to lower in each of that model's
+    23 chunk programs, 12% of its set-up: those groups keep the step's
+    tile for every span (PERF.md section 6, PR 44; ROADMAP S1 says
+    what has to come first)."""
+    if row_blocked:
+        rows = row_block_shape(q_tile, group)[0] * group
+    else:
+        rows = _row_tile(q_tile, t_pad, group)
+    if group % 8:
+        return rows, rows
+    return rows, min(rows, _row_tile(tile_tokens(1, group), t_pad, group))
 
 
 def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
@@ -408,9 +451,9 @@ def row_block_list(kv_lens, cu_q_lens, distribution, *, max_pages: int,
 def _ragged_kernel(
     lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, q_ref, k_ref, *rest,
     max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
-    tile_rows: int, softcap2, window: int | None, sinks: int | None,
-    variant: str = "online", dv: int = 0, shared_kv: bool = False,
-    blocks: int = 0, block_tokens: int = 0,
+    tile_rows: int, one_token_rows: int, softcap2, window: int | None,
+    sinks: int | None, variant: str = "online", dv: int = 0,
+    shared_kv: bool = False, blocks: int = 0, block_tokens: int = 0,
 ):
     """One (kv-head, work item) grid step: item ``i`` is page ``j`` of
     slot ``r`` (`work_items`).
@@ -423,6 +466,18 @@ def _ragged_kernel(
     sequential ("arbitrary" semantics), so the masked
     read-modify-write at finalize is race-free.
 
+    A span is loaded, attended and finalised at ``tile_rows`` rows, but
+    a span of ONE token (``q_len <= 1``: a decode row) at
+    ``one_token_rows``, with a tile start of its own, on the head of
+    each scratch (`span_tile_rows`).  Where the two differ, every
+    program that can hold a chunk at a group that is a multiple of 8,
+    the kernel holds a body for each and an item takes the one its
+    span's length names; where they are equal (every decode-only
+    shape, every other group) it holds one.  The rows a decode slot no
+    longer computes are rows its finalize's mask threw away.  The
+    slot's PAGES are still those the step's tile bands (`live_pages`):
+    the list is the host's count and the window pages' band too.
+
     ``shared_kv``: the values are the first ``dv`` lanes of the key
     block (a latent cache: one pool, no ``v_ref``).
 
@@ -431,10 +486,9 @@ def _ragged_kernel(
     query head).  The packed query and result stay in HBM; an item is
     a page of one BLOCK of ``block_tokens`` tokens of a slot's span
     (`row_block_list`), whose rows are copied in at the block's first
-    item and out at its last.  A span of one token (a decode row)
-    takes a tile of that token's rows alone, whatever the step's
-    ``q_tile``: a page is read once for all of a decode row's heads,
-    and a chunk in the same step does not widen it.  The tile
+    item and out at its last.  A span of one token takes its own
+    tile here too (a block's, where the resident form has the step's):
+    a page is read once for all of a decode row's heads.  The tile
     arithmetic is the resident form's."""
     if shared_kv:
         v_ref = None
@@ -462,38 +516,38 @@ def _ragged_kernel(
     active = jnp.logical_and(r < dist_ref[1], q_len > 0)
     if blocks:
         # a block starts at a token, and ``group`` is a multiple of 8
-        tile_start = pl.multiple_of(
+        block_start = pl.multiple_of(
             (q_start + block * block_tokens) * group, 8)
         # what the block's last row reaches: later pages are no items
         live = jnp.logical_and(active, j * page < kv_len - q_len
                                + jnp.minimum((block + 1) * block_tokens,
                                              q_len))
     else:
-        # tile start, in packed ROWS (token * group + head): the span
-        # head rounded down to the 8-row sublane granule, clamped so
-        # the tile stays in-bounds.  Mosaic refuses a dynamic sublane
-        # slice of a 16-bit ref unless it can prove the start
-        # 8-aligned, and ``q_start * group`` is only provably so when
-        # group % 8 == 0 — so the start is aligned here and the tile
-        # carries `_row_tile`'s 8 spare rows (q_len <= q_tile by the
-        # caller contract, so the span always fits; rows outside it
-        # are masked per row below).
-        tile_start = pl.multiple_of(
-            jnp.minimum(q_start * group // 8 * 8,
-                        t_pad * group - tile_rows),
-            8)
         # false only for the two kept entries that hold no page
         # (`live_pages`)
         live = jnp.logical_and(
             active, banded_live(j, kv_len, page,
                                 _band_window(window, q_tile), sinks))
 
+    def span_start(rows: int):
+        """Where a tile of ``rows`` rows starts, in packed ROWS (token
+        * group + head): the span head rounded down to the 8-row
+        sublane granule, clamped so the tile stays in-bounds.  Mosaic
+        refuses a dynamic sublane slice of a 16-bit ref unless it can
+        prove the start 8-aligned, and ``q_start * group`` is only
+        provably so when group % 8 == 0 — so the start is aligned here
+        and the tile carries `_row_tile`'s 8 spare rows (a span fits
+        the tile it is given by the caller contract, ``q_len <=
+        q_tile``; rows outside it are masked per row)."""
+        return pl.multiple_of(
+            jnp.minimum(q_start * group // 8 * 8, t_pad * group - rows), 8)
+
     def init(m, l, acc):
         m[...] = jnp.full_like(m, NEG_INF)
         l[...] = jnp.zeros_like(l)
         acc[...] = jnp.zeros_like(acc)
 
-    def attend(qb, m, l, acc):
+    def attend(qb, tile_start, m, l, acc):
         keys = k_ref[0, 0]
         s = jax.lax.dot_general(
             qb, keys, (((1,), (1,)), ((), ())),
@@ -523,7 +577,7 @@ def _ragged_kernel(
         )
         acc[...] = update_acc(acc[...], pv)
 
-    def result(l, acc):
+    def result(tile_start, l, acc):
         """The tile's rows, and which of them are this span's."""
         if variant == "flashd":
             # the accumulator is already normalized (flashd's hidden
@@ -538,43 +592,43 @@ def _ragged_kernel(
         seg = (tile_start + row) // group - q_start
         return res, jnp.logical_and(seg >= 0, seg < q_len)
 
-    if not blocks:
-        @pl.when(i == 0)
-        def _zero_out():
-            o_ref[...] = jnp.zeros_like(o_ref)
+    def head(ref, rows: int):
+        return ref.at[pl.ds(0, rows)]
 
-        @pl.when(first)
+    def span_of(rows: int, mine_too):
+        """The three phases of a span of the RESIDENT form at a tile of
+        ``rows`` rows, the head of each scratch."""
+        tile_start = span_start(rows)
+        m, l, acc = head(m_scr, rows), head(l_scr, rows), head(acc_scr, rows)
+
+        @pl.when(jnp.logical_and(mine_too, first))
         def _init():
-            init(m_scr, l_scr, acc_scr)
+            init(m, l, acc)
 
-        @pl.when(live)
+        @pl.when(jnp.logical_and(mine_too, live))
         def _tile():
-            attend(q_ref[0, pl.ds(tile_start, tile_rows), :], m_scr, l_scr,
-                   acc_scr)
+            attend(q_ref[0, pl.ds(tile_start, rows), :], tile_start, m, l,
+                   acc)
 
-        @pl.when(jnp.logical_and(last, active))
+        @pl.when(jnp.logical_and(mine_too, jnp.logical_and(last, active)))
         def _finalize():
-            res, mine = result(l_scr, acc_scr)
-            cur = o_ref[0, pl.ds(tile_start, tile_rows), :]
-            o_ref[0, pl.ds(tile_start, tile_rows), :] = jnp.where(
+            res, mine = result(tile_start, l, acc)
+            cur = o_ref[0, pl.ds(tile_start, rows), :]
+            o_ref[0, pl.ds(tile_start, rows), :] = jnp.where(
                 mine, res, cur.astype(jnp.float32)
             ).astype(o_ref.dtype)
-        return
 
     def block_of(rows: int, mine_too):
         """The three phases of a block at a tile of ``rows`` rows, the
         head of each scratch.  The result's rows past the span are
         zeros: they are pad tokens' rows, or rows of a later block or
         slot, which writes them after this one."""
-        def head(ref):
-            return ref.at[pl.ds(0, rows)]
-
-        m, l, acc = head(m_scr), head(l_scr), head(acc_scr)
+        m, l, acc = head(m_scr, rows), head(l_scr, rows), head(acc_scr, rows)
 
         @pl.when(jnp.logical_and(mine_too, jnp.logical_and(first, active)))
         def _load():
             rows_in = pltpu.make_async_copy(
-                q_ref.at[hd, pl.ds(tile_start, rows)], head(q_scr),
+                q_ref.at[hd, pl.ds(block_start, rows)], head(q_scr, rows),
                 sem.at[0])
             rows_in.start()
             init(m, l, acc)
@@ -582,25 +636,30 @@ def _ragged_kernel(
 
         @pl.when(jnp.logical_and(mine_too, live))
         def _tile():
-            attend(q_scr[pl.ds(0, rows), :], m, l, acc)
+            attend(q_scr[pl.ds(0, rows), :], block_start, m, l, acc)
 
         @pl.when(jnp.logical_and(mine_too, jnp.logical_and(last, active)))
         def _store():
-            res, mine = result(l, acc)
+            res, mine = result(block_start, l, acc)
             o_scr[pl.ds(0, rows), :] = jnp.where(mine, res, 0.0).astype(
                 o_scr.dtype)
             rows_out = pltpu.make_async_copy(
-                head(o_scr), o_ref.at[hd, pl.ds(tile_start, rows)],
+                head(o_scr, rows), o_ref.at[hd, pl.ds(block_start, rows)],
                 sem.at[1])
             rows_out.start()
             rows_out.wait()
 
-    one_token = tile_tokens(1, group) * group
-    if block_tokens * group == one_token:
-        block_of(one_token, True)
+    if not blocks:
+        @pl.when(i == 0)
+        def _zero_out():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    phases = block_of if blocks else span_of
+    if tile_rows == one_token_rows:
+        phases(tile_rows, True)
     else:
-        block_of(one_token, q_len <= 1)
-        block_of(block_tokens * group, q_len > 1)
+        phases(one_token_rows, q_len <= 1)
+        phases(tile_rows, q_len > 1)
 
 
 #: cache rows one copy of the list form moves: a memory tile's rows,
@@ -952,8 +1011,15 @@ def _ragged_paged_attention_jit(
             "ragged", dtype=q.dtype, allowed=("online", "flashd", "amla"),
             heads=h, kv_heads=hkv, seq=cache.max_tokens, dim=d,
             batch=s_slots, window=window, sinks=sinks)
+    # the tile of a span of several tokens and of a span of one: where
+    # they differ the kernel holds a body for each (`_ragged_kernel`)
+    tile_rows, one_token_rows = span_tile_rows(
+        q_tile, t_pad, group, row_blocked=shared_kv)
     if obs.is_enabled():
-        _RAGGED_LOWERED.inc(requested=max_mode, lowered=variant)
+        _RAGGED_LOWERED.inc(
+            requested=max_mode, lowered=variant,
+            bodies=("list" if select is not None else
+                    "one" if tile_rows == one_token_rows else "two"))
 
     lens = jnp.asarray(cache.kv_lens, jnp.int32)
     cu = jnp.asarray(cache.cu_q_lens, jnp.int32)
@@ -982,7 +1048,6 @@ def _ragged_paged_attention_jit(
             lens, cu, dist, max_pages=max_pages, page=page,
             block_tokens=block_tokens, blocks=blocks, width=t_pad)
         items, n_items = listed.items, listed.n
-        tile_rows = block_tokens * group
         # a block is copied whole, so the last token's may reach past
         # the packed rows: spare rows, nobody's
         qs = jnp.pad(qs, ((0, 0), (0, tile_rows), (0, 0)))
@@ -990,7 +1055,6 @@ def _ragged_paged_attention_jit(
         items, n_items = work_items(live_pages(
             lens, cu, dist, max_pages=max_pages, page=page, q_tile=q_tile,
             window=window, sinks=sinks))
-        tile_rows = _row_tile(q_tile, t_pad, group)
     rows_total = qs.shape[1]
 
     def kv_index(hd, i, lens_ref, cu_ref, dist_ref, tbl_ref, items_ref):
@@ -1009,6 +1073,7 @@ def _ragged_paged_attention_jit(
     kernel = functools.partial(
         _ragged_kernel, max_pages=max_pages, group=group, page=page,
         q_tile=q_tile, t_pad=t_pad, tile_rows=tile_rows,
+        one_token_rows=one_token_rows,
         softcap2=None if softcap is None else softcap * _LOG2E,
         window=window, sinks=sinks, variant=variant, dv=dv,
         shared_kv=shared_kv, blocks=blocks, block_tokens=block_tokens,

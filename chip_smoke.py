@@ -136,12 +136,13 @@ class CompileClock:
 
 
 def _lowered(name: str) -> dict[str, int]:
-    """``requested->lowered`` tallies of one ``ops.*.lowered`` counter."""
-    return {
-        f"{s['labels'].get('requested')}->{s['labels'].get('lowered')}":
-            int(s["value"])
-        for s in obs.counter(name).series()
-    }
+    """``requested->lowered`` tallies of one ``ops.*.lowered`` counter
+    (summed over any further label, the ragged kernel's ``bodies``)."""
+    out: dict[str, int] = {}
+    for s in obs.counter(name).series():
+        key = f"{s['labels'].get('requested')}->{s['labels'].get('lowered')}"
+        out[key] = out.get(key, 0) + int(s["value"])
+    return out
 
 
 def _memory(devices) -> list[dict]:

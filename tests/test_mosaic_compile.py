@@ -281,27 +281,39 @@ def test_ragged_engine_step_head_sharded_over_four_devices(v5e):
     _assert_one_kernel_a_layer(compiled, model)
 
 
-_CELL_TABLES = (dict(slots=33, max_pages=34), dict(slots=9, max_pages=52))
+_CELL_TABLES = (dict(slots=33, max_pages=34), dict(slots=9, max_pages=52),
+                dict(slots=33, max_pages=266))
 
 
-@pytest.mark.parametrize("hq,hkv,width,q_tile,dtype,table", [
+@pytest.mark.parametrize("hq,hkv,width,q_tile,dtype,table,band", [
     # 16-bit with group < 8: Mosaic refused the unaligned dynamic
     # sublane slice ("cannot statically prove ... a multiple of 8")
-    (8, 2, 512, 256, BF16, {}),     # group 4
-    (8, 4, 24, 4, BF16, {}),        # group 2, a decode-only step
-    (8, 8, 512, 64, BF16, {}),      # group 1 (MHA)
-    (8, 2, 512, 256, F32, {}),
-    (32, 4, 2048, 1024, BF16, {}),  # 26 MB scoped VMEM > 16 MB default
-    # the benchmark's cells, decode-only and with a chunk: a grid of
-    # (4, n <= 33 x 34) at a group of 9, of (30, n <= 9 x 52) at 1
-    (36, 4, 8, 8, BF16, _CELL_TABLES[0]),
-    (36, 4, 384, 256, BF16, _CELL_TABLES[0]),
-    (30, 30, 8, 8, BF16, _CELL_TABLES[1]),
-    (30, 30, 384, 256, BF16, _CELL_TABLES[1]),
+    (8, 2, 512, 256, BF16, {}, {}),     # group 4
+    (8, 4, 24, 4, BF16, {}, {}),        # group 2, a decode-only step
+    (8, 8, 512, 64, BF16, {}, {}),      # group 1 (MHA)
+    (8, 2, 512, 256, F32, {}, {}),
+    (32, 4, 2048, 1024, BF16, {}, {}),  # 26 MB scoped VMEM > 16 MB default
+    # the benchmark's cells, decode-only and MIXED (a chunk of 256
+    # beside decode rows): a grid of (4, n <= 33 x 34) at a group of 9,
+    # of (30, n <= 9 x 52) at 1, one tile body each; of (4, n <= 33 x
+    # 266) at Trinity's 8, where the mixed programs hold TWO, the
+    # chunk's 2,048 rows and a decode row's 8, a window layer's and a
+    # full layer's
+    (36, 4, 8, 8, BF16, _CELL_TABLES[0], {}),
+    (36, 4, 384, 256, BF16, _CELL_TABLES[0], {}),
+    (30, 30, 8, 8, BF16, _CELL_TABLES[1], {}),
+    (30, 30, 384, 256, BF16, _CELL_TABLES[1], {}),
+    (32, 4, 32, 1, BF16, _CELL_TABLES[2], {}),
+    (32, 4, 384, 256, BF16, _CELL_TABLES[2], {}),
+    (32, 4, 384, 256, BF16, _CELL_TABLES[2], dict(window=2048)),
+    (32, 4, 256, 256, BF16, _CELL_TABLES[2], dict(window=2048)),
+    # Nemotron's group of 16, mixed: two bodies, 4,096 rows and 16
+    (32, 2, 384, 256, BF16, {}, {}),
 ])
-def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype, table):
+def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype, table,
+                                band):
     compiled = _compile(
-        ragged_paged_attention,
+        functools.partial(ragged_paged_attention, **band),
         jax.sharding.SingleDeviceSharding(v5e[0]),
         _a((1, hq, width, 128), dtype),
         _ragged_cache(hkv, width, q_tile, dtype, **table))

@@ -729,6 +729,8 @@ def test_dispatch_span_and_metrics_carry_the_kernels_grid_bound(
         # a walk reads its pages whole: the rows one sublayer reads
         assert (span["attn_rows"] == m.attn_rows_read
                 == m.kv_pages * c.page_size)
+        # this model's group is 2: the kernel has one tile for every span
+        assert span["own_tile_spans"] == m.own_tile_spans == 0
     idle = [m for m in eng.metrics.steps if m not in busy]
     assert all(m.kv_pages == 0 and m.attn_rows_read == 0 for m in idle)
     summary = eng.metrics.summary()
@@ -795,3 +797,43 @@ def test_a_step_that_compiled_says_so():
     for f in marks:
         assert f["cache"] == "off" and "_ragged_apply" in f["function"]
         assert f["compile_ms"] > 0 and f["trace_ms"] > 0
+
+
+def test_31_decode_rows_beside_a_chunk_are_31_spans_at_their_own_tile():
+    """`StepMetrics.own_tile_spans` and the dispatch span's field, at a
+    group of 8: 31 on a step of 31 decode rows and a chunk (the
+    kernel's program holds two tile bodies there), 0 on a decode-only
+    step (one)."""
+    model = TinyDecoder(vocab=43, dim=64, depth=1, num_q_heads=8,
+                        num_kv_heads=1, impl="flash", dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ServingEngine(model, params, _cfg(
+        num_pages=40, max_decode_batch=32, max_prefill_rows=1,
+        token_budget=128))
+    rng = np.random.default_rng(5)
+    for _ in range(31):
+        eng.add_request(rng.integers(0, model.vocab, size=4).tolist(),
+                        SamplingParams(max_tokens=48))
+    while len([r for r in eng.scheduler.running if r.output_tokens]) < 31:
+        eng.step()
+        assert eng.current_step < 40
+    eng.add_request(rng.integers(0, model.vocab, size=40).tolist(),
+                    SamplingParams(max_tokens=4))
+    was = obs.enabled()
+    obs.enable()
+    obs.reset()
+    try:
+        steps = [eng.step() for _ in range(4)]
+        spans = [e["fields"] for e in obs.events()
+                 if e["name"] == "engine.step.dispatch"]
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()
+    # the prompt's chunks of 32 and 8 beside the 31, then 32 decode rows
+    assert [(m.num_decode_reqs, m.prefill_tokens) for m in steps] == [
+        (31, 32), (31, 8), (32, 0), (32, 0)]
+    assert [m.own_tile_spans for m in steps] == [31, 31, 0, 0]
+    assert [s["own_tile_spans"] for s in spans] == [31, 31, 0, 0]
+    assert [(s["width"], s["q_tile"]) for s in spans] == [
+        (64, 32), (48, 8), (32, 1), (32, 1)]

@@ -13,13 +13,20 @@ table shapes (33 x 34 at a group of 9; 9 x 52 at 30 KV heads):
     kept entries that hold no page; the host's count (the engine's
     ``kv_pages``) is the device's;
   * the bound is a VALUE: steps of other lengths at one shape add no
-    compiled entry.
+    compiled entry;
+  * a MIXED step (decode slots beside a chunk) at the cells' groups
+    (8, 9, 1, 16), with the decode slot first, last and at the clamped
+    end of the packed axis: where the group is a multiple of 8 a span
+    of one token is attended at the one-token tile and a longer one at
+    the step's, two bodies in one kernel; at another group one body.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from attention_tpu import obs
 from attention_tpu.ops.decode import banded_live
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
@@ -27,6 +34,7 @@ from attention_tpu.ops.ragged_paged import (
     live_pages,
     packed_bucket,
     ragged_paged_attention,
+    span_tile_rows,
     tile_tokens,
     work_items,
 )
@@ -40,9 +48,10 @@ _PAGE, _D = 128, 16
 def _step(*, slots, max_pages, hq, hkv, spans, active=None, share=(),
           pool_pages=None):
     """One packed step, lengths POST-append: ``spans`` holds (kv_len,
-    q_len) per used slot, decode slots (q_len 1) first; ``kv_len`` -1
-    poisons the slot.  Every slot gets pages of its own but for
-    ``share`` = (slot, other, n): ``slot``'s first ``n`` table entries
+    q_len) per used slot, in the order of the packed axis (the engine
+    puts decode slots, q_len 1, first; the kernel takes any order);
+    ``kv_len`` -1 poisons the slot.  Every slot gets pages of its own
+    but for ``share`` = (slot, other, n): ``slot``'s first ``n`` table entries
     are ``other``'s.  The pools hold the pages used, or
     ``pool_pages``."""
     r = np.random.default_rng(0)
@@ -79,6 +88,7 @@ def _step(*, slots, max_pages, hq, hkv, spans, active=None, share=(),
 
 
 _STARCODER2 = dict(slots=33, max_pages=34, hq=36, hkv=4)
+_TRINITY = dict(slots=6, max_pages=20, hq=32, hkv=4)
 _CASES = {
     # (a) a decode-only step of the StarCoder2 cells: 3 of 33 slots
     "three_of_33_slots_group_9": (
@@ -112,6 +122,56 @@ _CASES = {
     "shared_prefix_pages": (
         dict(slots=4, max_pages=5, hq=4, hkv=2,
              spans=[(400, 1), (300, 1)], share=[(1, 0, 2)]), {}),
+    # MIXED steps.  (i) (j) Trinity's group of 8 (a tile of 384 rows, a
+    # decode row's of 8): a full layer and a window layer whose band
+    # binds, a decode slot first and last before the chunk
+    "mixed_group_8_full_layer": (
+        dict(_TRINITY, spans=[(300, 1), (2300, 1), (129, 1), (2400, 40)]),
+        {}),
+    "mixed_group_8_window_2048": (
+        dict(_TRINITY, spans=[(300, 1), (2300, 1), (129, 1), (2400, 40)]),
+        dict(window=2048)),
+    # (k) the chunk FIRST, decode slots after it
+    "mixed_group_8_decode_after_the_chunk": (
+        dict(_TRINITY, spans=[(2400, 33), (2300, 1), (7, 1)]),
+        dict(window=2048)),
+    # (l) a poisoned decode slot beside the chunk: NaN for its 8 rows
+    "mixed_group_8_poisoned_decode": (
+        dict(_TRINITY, spans=[(300, 1), (-1, 1), (129, 1), (700, 40)]), {}),
+    # (m) group 9, one tile of 144 rows: the last decode slot's start is
+    # clamped to the packed axis' end
+    "mixed_group_9_clamped_end": (
+        dict(slots=8, max_pages=4, hq=36, hkv=4,
+             spans=[(290, 10), (300, 1), (129, 1), (5, 1), (77, 1),
+                    (128, 1), (500, 1)]), {}),
+    # (n) StarCoder2's window and sinks beside a chunk
+    "mixed_group_9_window_and_sinks": (
+        dict(_STARCODER2, spans=[(700, 1), (1000, 1), (290, 40)]),
+        dict(window=100, sinks=4)),
+    # (o) group 1, one tile of 32 rows: decode slots before the chunk
+    # and after it
+    "mixed_group_1_both_ends": (
+        dict(slots=11, max_pages=5, hq=6, hkv=6,
+             spans=[(130, 1)] * 2 + [(300, 22)] + [(40, 1)] * 8), {}),
+    # (p) Nemotron's group of 16 (256 rows and 16), with a softcap
+    "mixed_group_16_softcap": (
+        dict(slots=5, max_pages=5, hq=32, hkv=2,
+             spans=[(130, 1), (300, 9), (40, 1)]), dict(softcap=30.0)),
+}
+# the (tile rows, one-token rows) of each mixed case's program: two
+# tile bodies where the group is a multiple of 8, one at any other
+_MIXED_TILES = {
+    "group_1_30_heads_9_slots": (32, 32),
+    "every_slot_full": (40, 40),
+    "chunk_beside_decode": (432, 432),
+    "mixed_group_8_full_layer": (384, 8),
+    "mixed_group_8_window_2048": (384, 8),
+    "mixed_group_8_decode_after_the_chunk": (384, 8),
+    "mixed_group_8_poisoned_decode": (384, 8),
+    "mixed_group_9_clamped_end": (144, 144),
+    "mixed_group_9_window_and_sinks": (432, 432),
+    "mixed_group_1_both_ends": (32, 32),
+    "mixed_group_16_softcap": (256, 16),
 }
 
 
@@ -163,8 +223,12 @@ def test_kernel_matches_the_oracle_over_its_work_list(case):
     items, n = work_items(mask)
     live, kept = _guarded_entries(cache, band)
     assert int(n) == live + kept
-    assert kept == {"poisoned_between_sound": 1,
-                    "no_active_slot": 1}.get(case, 0)
+    assert kept == {"poisoned_between_sound": 1, "no_active_slot": 1,
+                    "mixed_group_8_poisoned_decode": 1}.get(case, 0)
+    group = step["hq"] // step["hkv"]
+    tiles = span_tile_rows(cache.q_tile, q.shape[2], group)
+    # one tile where every span is of one token, else a mixed step's two
+    assert tiles == _MIXED_TILES.get(case, tiles[:1] * 2)
     if case == "every_slot_full":
         assert int(n) == slots * max_pages
     host = live_pages(np.asarray(cache.kv_lens),
@@ -194,3 +258,89 @@ def test_other_lengths_at_one_shape_add_no_compiled_entry():
             sinks=None))[1]))
     assert counts == [1, 21, 64]
     assert _ragged_paged_attention_jit._cache_size() == 1
+
+
+def _dot_rows(jaxpr):
+    """The row counts of every product's left operand, kernels' bodies
+    and their branches included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn.invars[0].aval.shape[0]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dot_rows(sub)
+
+
+@pytest.mark.parametrize("case", sorted(_MIXED_TILES))
+def test_a_decode_row_beside_a_chunk_is_served_at_its_own_tile(case):
+    """The decode rows of a mixed step are, to the bit, the same rows
+    of the decode-only step over the same cache, and where the group
+    is a multiple of 8 the mixed program's kernel holds the score and
+    value products at BOTH row counts, the step's tile's and the
+    one-token tile's (which is the decode-only program's only tile);
+    at another group it holds the step's alone."""
+    step, band = _CASES[case]
+    q, cache, _ = _step(**step)
+    group = step["hq"] // step["hkv"]
+    wide, one_token = _MIXED_TILES[case]
+    assert (one_token < wide) == (group % 8 == 0)
+    mixed = jax.make_jaxpr(
+        lambda q, cache: _ragged_paged_attention_jit(q, cache, **band))(
+            jnp.asarray(q), cache)
+    assert sorted(set(_dot_rows(mixed.jaxpr))) == sorted({one_token, wide})
+    got = np.asarray(ragged_paged_attention(jnp.asarray(q), cache, **band))
+
+    # the same slots with the chunk's span emptied: a decode-only step.
+    # The band the PAGES are walked in follows the step's tile, so the
+    # decode-only step visits fewer of them; what a row attends is its
+    # own mask's, the same in both
+    cu = np.asarray(cache.cu_q_lens)
+    q_lens = np.diff(cu)
+    slots = np.flatnonzero(q_lens == 1)
+    q_tile = tile_tokens(1, group)
+    width = packed_bucket(max(len(slots), q_tile))
+    alone = np.zeros((1, step["hq"], width, _D), np.float32)
+    alone[0, :, :len(slots)] = q[0][:, cu[slots]]
+    cu_alone = np.cumsum([0] + [int(n == 1) for n in q_lens]).astype(np.int32)
+    only = cache._replace(
+        cu_q_lens=jnp.asarray(cu_alone),
+        token_pos=jnp.zeros((width,), jnp.int32),
+        token_slot=jnp.full((width,), -1, jnp.int32),
+        q_span=np.zeros((q_tile,), np.int32))
+    tiles = span_tile_rows(q_tile, width, group)
+    assert tiles[0] == tiles[1]
+    if group % 8 == 0:
+        assert tiles[0] == one_token
+    decode_only = jax.make_jaxpr(
+        lambda q, cache: _ragged_paged_attention_jit(q, cache, **band))(
+            jnp.asarray(alone), only)
+    assert set(_dot_rows(decode_only.jaxpr)) == {tiles[0]}
+    want = np.asarray(ragged_paged_attention(jnp.asarray(alone), only,
+                                             **band))
+    np.testing.assert_array_equal(got[0][:, cu[slots]],
+                                  want[0][:, :len(slots)])
+
+
+def test_the_lowering_says_how_many_tile_bodies_it_holds():
+    """`ops.ragged.lowered` carries ``bodies``: "two" for a program of
+    a group of 8 whose tile is wider than one token's, "one" for a
+    decode-only shape and for a mixed step at a group of 9."""
+    was = obs.is_enabled()
+    obs.reset()
+    obs.enable()
+    _ragged_paged_attention_jit.clear_cache()   # it ticks at trace time
+    try:
+        lowered = obs.counter("ops.ragged.lowered")
+        q, cache, _ = _step(**_CASES["mixed_group_8_full_layer"][0])
+        ragged_paged_attention(jnp.asarray(q), cache)
+        assert [s["labels"] for s in lowered.series()] == [
+            {"requested": "online", "lowered": "online", "bodies": "two"}]
+        for case in ("three_of_33_slots_group_9", "chunk_beside_decode"):
+            q, cache, _ = _step(**_CASES[case][0])
+            ragged_paged_attention(jnp.asarray(q), cache)
+        assert lowered.value(requested="online", lowered="online",
+                             bodies="one") == 2
+        assert lowered.value(requested="online", lowered="online",
+                             bodies="two") == 1
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()

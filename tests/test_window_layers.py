@@ -32,6 +32,7 @@ from attention_tpu.engine.scheduler import (
 from attention_tpu.models import decoder_from_config
 from attention_tpu.models.transformer import expert_feed_forward
 from attention_tpu.ops.paged import OutOfPagesError, PagePool
+from attention_tpu.ops.ragged_paged import span_tile_rows
 from benchmark import harness
 
 VOCAB = 97
@@ -445,6 +446,22 @@ def test_the_steps_count_each_kind_of_layer(session):
     assert any(m.attn_qk_pairs_window < 0.4 * m.attn_qk_pairs
                for m in late)       # the window binds
     assert all(m.attn_rows_read == m.kv_pages * 128 for m in busy)
+
+
+def test_own_tile_spans_follow_the_kernels_rule_for_the_group(session):
+    """`own_tile_spans` is the kernel's rule, not the step's shape: the
+    turn's request decodes beside the fresh prompt's chunks of 64, but
+    this model's group is 4, where the kernel keeps one tile for every
+    span (`ops.ragged_paged.span_tile_rows`; 8 and 16 get two), so no
+    step counts a span at a tile of its own."""
+    eng, _, _, _, _ = session
+    busy = [m for m in eng.metrics.steps if m.decode_tokens
+            or m.prefill_tokens]
+    mixed = [m for m in busy if m.decode_tokens and m.prefill_tokens > 1]
+    assert mixed and all(m.num_decode_reqs == 1 for m in mixed)
+    assert span_tile_rows(64, 72, 4) == (264, 264)
+    assert span_tile_rows(64, 72, 8) == (512, 8)
+    assert all(m.own_tile_spans == 0 for m in busy)
 
 
 def test_the_slide_has_a_span_inside_the_schedule_phase(served):
