@@ -72,9 +72,12 @@ from attention_tpu.ops.paged import (
 )
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
+    head_block,
     live_pages,
     packed_bucket,
     recommended_q_tile,
+    row_block_count,
+    row_block_shape,
     span_tile_rows,
     tile_tokens,
 )
@@ -926,7 +929,7 @@ class ServingEngine:
         self.last_step_virtual_cost = self.step_cost_multiplier
         self._last_fetch_s = 0.0
         pad_tokens = kv_pages = qk_pairs = keys_selected = rows_read = 0
-        width = q_tile = compiled_programs = own_tile = 0
+        width = q_tile = compiled_programs = own_tile = grid_steps = 0
         occupancy = compile_s = 0.0
         self._expert_pairs = None
         self._window_fields = {}
@@ -953,7 +956,7 @@ class ServingEngine:
                     sched.window_pages_released)
             if not sched.is_empty:
                 (width, q_tile, kv_pages, qk_pairs, keys_selected,
-                 rows_read, own_tile) = self._run_ragged(sched)
+                 rows_read, own_tile, grid_steps) = self._run_ragged(sched)
                 pad_tokens = width - total
                 occupancy = total / width
             if _compiles.count != compile_rows:
@@ -982,6 +985,7 @@ class ServingEngine:
                 ragged_occupancy=occupancy,
                 kv_pages=kv_pages,
                 own_tile_spans=own_tile,
+                ragged_grid_steps=grid_steps,
                 attn_qk_pairs=qk_pairs,
                 attn_keys_selected=keys_selected,
                 attn_rows_read=rows_read,
@@ -1215,7 +1219,7 @@ class ServingEngine:
         return packed_bucket(max(decoding + chunk, q_tile)), q_tile
 
     def _run_ragged(self, sched: ScheduledStep
-                    ) -> tuple[int, int, int, int, int, int, int]:
+                    ) -> tuple[int, int, int, int, int, int, int, int]:
         """Lower the WHOLE step onto one jitted packed launch; returns
         the packed width and the query tile dispatched (the program's
         shape), the step's live (slot, page) pairs (what the attention
@@ -1223,8 +1227,9 @@ class ServingEngine:
         scoring's), the (query token, key) pairs one attention sublayer
         attends (or, where it chooses its keys, scores), the pairs the
         choice keeps by its rule (0 without one), the cache rows one
-        sublayer's attention reads, and the spans of one token that the
-        kernel served at a tile of their own.  Where window layers
+        sublayer's attention reads, the spans of one token that the
+        kernel served at a tile of their own, and the grid steps of the
+        sublayers' page-walking kernels.  Where window layers
         stand beside full layers, pages, pairs and rows are the SUM of
         one sublayer of each kind, and the window kind's part goes to
         ``_window_fields``.
@@ -1274,6 +1279,8 @@ class ServingEngine:
             own_tile = (int((q_lens == 1).sum())
                         if one_token < wide and not self._indexed_layers
                         else 0)
+            grid_steps = self._grid_steps(batch, q_lens, walked, width,
+                                          q_tile)
             if self._window_layers:
                 needed = [_band_pages(batch.kv_lens, q_lens, window,
                                       cfg.page_size)
@@ -1327,7 +1334,8 @@ class ServingEngine:
                       decode_rows=len(sched.decode),
                       prefill_tokens=sched.num_prefill_tokens,
                       kv_pages=kv_pages, own_tile_spans=own_tile,
-                      attn_rows=rows_read, **fields):
+                      ragged_grid_steps=grid_steps, attn_rows=rows_read,
+                      **fields):
             logits_dev, new_pools, pairs_dev = _ragged_apply(
                 self._step_model, self.params, buffer,
                 self._layer_pools(),
@@ -1343,7 +1351,34 @@ class ServingEngine:
                 self._post_prefill(
                     req, real, logits[0, row_of[num_decode + s]])
         return (width, q_tile, kv_pages, qk_pairs, keys_selected, rows_read,
-                own_tile)
+                own_tile, grid_steps)
+
+    def _grid_steps(self, batch, q_lens, walked: list[int], width: int,
+                    q_tile: int) -> int:
+        """The grid steps of the step's page-walking attention kernels,
+        by the kernel's own rules: each sublayer's work items (a kind's
+        ``walked`` pairs, or the row-blocked form's pages a block)
+        times the blocks its KV heads are carried in
+        (`ops.ragged_paged.head_block`); 0 where a list is attended."""
+        cfg, model = self.config, self.model
+        if self._indexed_layers:
+            return 0
+        group = model.num_q_heads // model.num_kv_heads
+        if self._latent_layers:
+            block_tokens, blocks = row_block_shape(q_tile, group)
+            return len(self._kv_layers) * row_block_count(
+                batch.kv_lens + q_lens, batch.cu_q_lens, batch.distribution,
+                max_pages=cfg.table_width, page=cfg.page_size,
+                block_tokens=block_tokens, blocks=blocks)
+        kv_heads, (d, dv) = model.kv_pool_widths()
+        kv_heads //= cfg.mesh_shards or 1   # a shard's kernel sees its own
+        head_blocks = kv_heads // head_block(
+            kv_heads, q_tile, width, group, d=d, dv=dv, page=cfg.page_size,
+            q_itemsize=jnp.dtype(model.dtype).itemsize,
+            kv_itemsize=jnp.dtype(cfg.cache_dtype or model.dtype).itemsize)
+        layers = [len(self._kv_layers) - len(self._window_layers),
+                  len(self._window_layers)]
+        return head_blocks * sum(n * of for n, of in zip(walked, layers))
 
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut

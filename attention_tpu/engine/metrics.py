@@ -100,6 +100,13 @@ class StepMetrics:
     # decode-only step), at another group, and where a list of rows is
     # attended and no tile is
     own_tile_spans: int = 0
+    # the grid steps of the step's page-walking attention kernels,
+    # summed over the attention sublayers: a sublayer's work items
+    # (``kv_pages`` of its kind; a block's pages in the row-blocked
+    # form) times the blocks its KV heads are carried in
+    # (`ops.ragged_paged.head_block`: one block where a grid step
+    # carries every head); 0 where a list of rows is attended
+    ragged_grid_steps: int = 0
     # (query token, key) pairs one attention sublayer attends: over the
     # step's slots, each query token times the keys it reaches
     attn_qk_pairs: int = 0

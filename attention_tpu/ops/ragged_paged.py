@@ -15,21 +15,33 @@ a PACKED token axis (the tpu_commons ``ragged_paged_attention`` shape):
     the request: the token at span offset ``s`` attends cache positions
     ``<= kv_len - q_len + s``.
 
-The grid is ``(Hkv, n)``: for each kv head, the step's ``n`` WORK ITEMS
-— the (slot, logical page) pairs that hold something to attend, slot
-major and page minor (`work_items`).  The list is built on the device
-from the lengths the step already carries, and ``n`` is a traced
-scalar, a grid bound that is a VALUE: a decode-only step of five short
-requests on a 33 x 34 table walks ~35 items a head, a full table all
-1,122, and both run the one compiled kernel.  A slot the step does not
-use, and a table entry past a request's prefix or below its window
-band, is no grid step at all.  One head's packed output block stays
-VMEM-resident while every slot accumulates into its own row span
-(slots never overlap rows, so the read-modify-write at finalize
-composes), and each item's page translates through the
-scalar-prefetched page table like `ops.paged` — so the pad waste of a
-step is just ``T - total_real`` bucketed tokens, not ``(D - d) +
-(P*S - real)`` poison rows.
+The grid is ``(Hkv // hb, n)``: for each BLOCK of ``hb`` kv heads, the
+step's ``n`` WORK ITEMS — the (slot, logical page) pairs that hold
+something to attend, slot major and page minor (`work_items`).  The
+list is built on the device from the lengths the step already carries,
+and ``n`` is a traced scalar, a grid bound that is a VALUE: a
+decode-only step of five short requests on a 33 x 34 table walks ~35
+items, a full table all 1,122, and both run the one compiled kernel.
+A slot the step does not use, and a table entry past a request's
+prefix or below its window band, is no grid step at all.  The packed
+output block of the block's heads stays VMEM-resident while every slot
+accumulates into its own row span (slots never overlap rows, so the
+read-modify-write at finalize composes), and each item's page
+translates through the scalar-prefetched page table like `ops.paged` —
+so the pad waste of a step is just ``T - total_real`` bucketed tokens,
+not ``(D - d) + (P*S - real)`` poison rows.
+
+A grid step carries EVERY kv head of its page that the core's VMEM
+lets the packed rows be resident for (`head_block`: all of them at a
+packed width of 512 or less, which is every step the engine makes).
+The heads of a page lie together in a pool ``(pages, Hkv, page, d)``,
+so the item's keys are one copy of ``hb x 32 KB`` and not ``hb``
+copies a grid visit apart; slot, lengths, band and mask are worked out
+once an item; and at a decode row's tile the heads' products and
+softmax updates run as one batch, independent chains where one head's
+waits on itself.  A visit costs what it costs whatever it moves
+(~0.4 us at 64 KB, a fifth of it the bytes): four heads a visit took a
+(slot, page) of 32 query heads on 4 from 1.67 to 0.67 us on a v5e.
 
 Where a SELECTOR chose each token's keys (`ops.sparse_index`), nothing
 above applies: no page is walked.  `ragged_paged_attention` with
@@ -77,6 +89,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -98,16 +111,17 @@ from attention_tpu.ops.flash import (
 # per host-side dispatch; calls inside an enclosing jit tick per trace.
 # `ops.ragged.lowered` ticks at TRACE time inside the jitted body and
 # records which rescaling-math variant the dispatch actually lowered
-# (the ragged equivalent of `ops.flash.lowered`) and how many tile
+# (the ragged equivalent of `ops.flash.lowered`), how many tile
 # bodies the kernel holds: ``bodies`` "one", "two" (a span of one
-# token at its own tile beside a wider one), "list" (no tile).
+# token at its own tile beside a wider one), "list" (no tile), and the
+# KV heads a grid step carries of those there are: ``heads`` "4/4".
 _RAGGED_CALLS = obs.counter(
     "ops.ragged.calls",
     "ragged paged-attention dispatches by (tokens, capacity, dim) bucket")
 _RAGGED_LOWERED = obs.counter(
     "ops.ragged.lowered",
-    "ragged kernel lowerings by requested/resolved max mode and tile "
-    "bodies")
+    "ragged kernel lowerings by requested/resolved max mode, tile "
+    "bodies and heads a grid step")
 
 # Mosaic's default scoped-VMEM budget, and the ceiling a raised budget
 # may ask for (the forward kernel's big-tile figure: v4+ cores hold it).
@@ -255,6 +269,65 @@ def span_tile_rows(q_tile: int, t_pad: int, group: int, *,
     if group % 8:
         return rows, rows
     return rows, min(rows, _row_tile(tile_tokens(1, group), t_pad, group))
+
+
+#: rows a batch over the heads of a block holds at most, all heads
+#: together: score temporaries of 256 KB.  Every one-token tile of the
+#: cells is under it (4 x 8 rows at a group of 8, 4 x 80 at 9, 30 x 16
+#: at 1, 2 x 16 at 16); a wider tile's heads go one after the other
+_BATCH_ROWS = 512
+
+
+def _batched(heads: int, rows: int) -> bool:
+    """Whether the ``heads`` heads of a block go through a tile of
+    ``rows`` rows as one batch (`_ragged_kernel`)."""
+    return 1 < heads and heads * rows <= _BATCH_ROWS
+
+
+def _vmem_need(heads: int, tile_rows: int, one_token_rows: int, *,
+               held_rows: int, d: int, dv: int, kv_lanes: int, page: int,
+               q_itemsize: int, kv_itemsize: int) -> int:
+    """Scoped-VMEM demand of the kernel at a block of ``heads`` KV
+    heads: ``held_rows`` rows of query and result a head (the whole
+    packed axis, double-buffered by the pipeline, or one block's rows
+    in the row-blocked form), the page buffers of ``kv_lanes`` lanes a
+    key (K and V, or one pool's), the fp32 scratch, and the (rows,
+    page) score / probability temporaries, which a batch over the
+    heads holds for all of them and a loop for one."""
+    temp_rows = max(heads * rows if _batched(heads, rows) else rows
+                    for rows in (tile_rows, one_token_rows))
+    return (
+        heads * held_rows * (d * q_itemsize + dv * kv_itemsize)
+        + heads * 4 * page * kv_lanes * kv_itemsize
+        + heads * tile_rows * (dv + 2 * _STAT_LANES) * 4
+        + 3 * temp_rows * page * 4
+    )
+
+
+def head_block(hkv: int, q_tile: int, t_pad: int, group: int, *, d: int,
+               dv: int, page: int, q_itemsize: int, kv_itemsize: int,
+               row_blocked: bool = False,
+               budget: int = _MAX_SCOPED_VMEM) -> int:
+    """KV heads a grid step of the program of a ``(t_pad, q_tile)``
+    step carries (`_ragged_kernel`): the largest divisor of ``hkv``
+    whose scoped-VMEM demand, with the half again the call asks for
+    over it, stays under ``budget``.  At every serving cell's shapes
+    that is every head (a packed width of 512 or less); a wider step
+    falls to a smaller block, and a block of 1 is the kernel of one
+    head a grid step.  The row-blocked form has one KV head.  The
+    engine counts a step's grid from this
+    (``StepMetrics.ragged_grid_steps``)."""
+    if row_blocked:
+        return 1
+    tile_rows, one_token_rows = span_tile_rows(q_tile, t_pad, group)
+    for heads in range(hkv, 1, -1):
+        if hkv % heads == 0 and 1.5 * _vmem_need(
+                heads, tile_rows, one_token_rows,
+                held_rows=2 * t_pad * group, d=d, dv=dv, kv_lanes=d + dv,
+                page=page, q_itemsize=q_itemsize,
+                kv_itemsize=kv_itemsize) <= budget:
+            return heads
+    return 1
 
 
 def recommended_q_tile(max_q_len: int, group: int, *, heads: int = 1,
@@ -448,22 +521,48 @@ def row_block_list(kv_lens, cu_q_lens, distribution, *, max_pages: int,
         g_slot, g_block, slot_end[-1])
 
 
+def row_block_count(kv_lens, cu_q_lens, distribution, *, max_pages: int,
+                    page: int, block_tokens: int, blocks: int) -> int:
+    """`row_block_list`'s ``n``, the row-blocked form's grid, counted
+    on the host in NumPy by its rule: a block of an active slot's span
+    has the pages its last row reaches, one at the least, and a step
+    with no active slot one item."""
+    q_lens = np.diff(cu_q_lens)
+    active = (np.arange(len(kv_lens)) < distribution[1]) & (q_lens > 0)
+    b = np.arange(blocks)[None, :]
+    held = active[:, None] & (b * block_tokens < q_lens[:, None])
+    reach = (np.maximum(kv_lens, 0) - q_lens)[:, None] + np.minimum(
+        (b + 1) * block_tokens, q_lens[:, None])
+    pages = np.clip(-(-reach // page), 1, max_pages)
+    return int((pages * held).sum()) or 1
+
+
 def _ragged_kernel(
     lens_ref, cu_ref, dist_ref, tbl_ref, items_ref, q_ref, k_ref, *rest,
     max_pages: int, group: int, page: int, q_tile: int, t_pad: int,
     tile_rows: int, one_token_rows: int, softcap2, window: int | None,
     sinks: int | None, variant: str = "online", dv: int = 0,
     shared_kv: bool = False, blocks: int = 0, block_tokens: int = 0,
+    heads: int = 1,
 ):
-    """One (kv-head, work item) grid step: item ``i`` is page ``j`` of
-    slot ``r`` (`work_items`).
+    """One (head block, work item) grid step: item ``i`` is page ``j``
+    of slot ``r`` (`work_items`), for the ``heads`` KV heads of the
+    block at once (`head_block`).  Slot, page, lengths, band and tile
+    start are the item's and are worked out once; the heads then go
+    through each phase as ONE BATCH where their tiles together are
+    small (`_BATCH_ROWS`: every one-token tile of the cells, four
+    independent softmax chains where one waited on itself), and one
+    after the other in a loop at a wider tile, whose products bind and
+    whose score temporaries stay one head's.  A head's result is the
+    same operations on the same operands at any block; a block of one
+    head indexes head 0 and holds no batch and no loop.
 
-    The output block is the head's FULL packed row axis, index-mapped
-    constant over the items, so it stays VMEM-resident while every
-    slot finalizes its own row span into it — the single-launch analog
-    of one out-block per decode row.  Slot spans never overlap, a
-    slot's items follow each other in page order, and the grid is
-    sequential ("arbitrary" semantics), so the masked
+    The output block is the FULL packed row axis of the block's heads,
+    index-mapped constant over the items, so it stays VMEM-resident
+    while every slot finalizes its own row span into it — the
+    single-launch analog of one out-block per decode row.  Slot spans
+    never overlap, a slot's items follow each other in page order, and
+    the grid is sequential ("arbitrary" semantics), so the masked
     read-modify-write at finalize is race-free.
 
     A span is loaded, attended and finalised at ``tile_rows`` rows, but
@@ -547,18 +646,22 @@ def _ragged_kernel(
         l[...] = jnp.zeros_like(l)
         acc[...] = jnp.zeros_like(acc)
 
-    def attend(qb, tile_start, m, l, acc):
-        keys = k_ref[0, 0]
+    def attend(h, qb, tile_start, m, l, acc):
+        """Head ``h``'s tile against the item's page; ``h`` a slice:
+        every head of the block, one batch."""
+        keys = k_ref[0, h]
+        batch = ((0,), (0,)) if qb.ndim == 3 else ((), ())
         s = jax.lax.dot_general(
-            qb, keys, (((1,), (1,)), ((), ())),
+            qb, keys, (((qb.ndim - 1,), (qb.ndim - 1,)), batch),
             preferred_element_type=jnp.float32,
         )  # (q_rows, page), log2-domain (q pre-scaled by scale*log2e)
         if softcap2 is not None:
             s = softcap2 * jnp.tanh(s / softcap2)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        # the mask is the item's, the same for every head of a batch
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
         seg = (tile_start + row) // group - q_start  # span offset per row
         pos = kv_len - q_len + seg             # absolute cache position
-        col = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        col = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
         mask = jnp.logical_and(
             jnp.logical_and(seg >= 0, seg < q_len), col <= pos
         )
@@ -570,9 +673,10 @@ def _ragged_kernel(
         s = jnp.where(mask, s, NEG_INF)
         p, update_acc = _softmax_variant_update(
             s, m, l, variant=variant, masked=True)
-        values = keys[:, :dv] if shared_kv else v_ref[0, 0]
+        values = keys[:, :dv] if shared_kv else v_ref[0, h]
         pv = jax.lax.dot_general(
-            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+            p.astype(values.dtype), values,
+            (((p.ndim - 1,), (p.ndim - 2,)), batch),
             preferred_element_type=jnp.float32,
         )
         acc[...] = update_acc(acc[...], pv)
@@ -588,35 +692,57 @@ def _ragged_kernel(
             res = acc[...] / jnp.where(l_max == 0.0, 1.0, l_max)
         # poisoned slots (bad append, length -1) emit NaN, loudly
         res = jnp.where(raw_len < 0, jnp.nan, res)
-        row = jax.lax.broadcasted_iota(jnp.int32, res.shape, 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, res.shape[-2:], 0)
         seg = (tile_start + row) // group - q_start
         return res, jnp.logical_and(seg >= 0, seg < q_len)
 
     def head(ref, rows: int):
         return ref.at[pl.ds(0, rows)]
 
+    def over_heads(rows: int, phase):
+        """``phase(h)`` for the block's heads: all at once, ``h`` a
+        slice, where a tile of ``rows`` rows a head keeps the batch
+        small; else head by head in a loop, never unrolled."""
+        if heads == 1:
+            phase(0)
+        elif _batched(heads, rows):
+            phase(slice(None))
+        else:
+            def turn(h, carry):
+                phase(h)
+                return carry
+
+            jax.lax.fori_loop(0, heads, turn, 0)
+
     def span_of(rows: int, mine_too):
         """The three phases of a span of the RESIDENT form at a tile of
-        ``rows`` rows, the head of each scratch."""
+        ``rows`` rows, the head of each head's scratch."""
         tile_start = span_start(rows)
-        m, l, acc = head(m_scr, rows), head(l_scr, rows), head(acc_scr, rows)
+
+        def scratch(h):
+            return [ref.at[h, pl.ds(0, rows)]
+                    for ref in (m_scr, l_scr, acc_scr)]
 
         @pl.when(jnp.logical_and(mine_too, first))
         def _init():
-            init(m, l, acc)
+            init(*scratch(slice(None)))
 
         @pl.when(jnp.logical_and(mine_too, live))
         def _tile():
-            attend(q_ref[0, pl.ds(tile_start, rows), :], tile_start, m, l,
-                   acc)
+            over_heads(rows, lambda h: attend(
+                h, q_ref[h, pl.ds(tile_start, rows), :], tile_start,
+                *scratch(h)))
 
         @pl.when(jnp.logical_and(mine_too, jnp.logical_and(last, active)))
         def _finalize():
-            res, mine = result(tile_start, l, acc)
-            cur = o_ref[0, pl.ds(tile_start, rows), :]
-            o_ref[0, pl.ds(tile_start, rows), :] = jnp.where(
-                mine, res, cur.astype(jnp.float32)
-            ).astype(o_ref.dtype)
+            def write(h):
+                res, mine = result(tile_start, *scratch(h)[1:])
+                cur = o_ref[h, pl.ds(tile_start, rows), :]
+                o_ref[h, pl.ds(tile_start, rows), :] = jnp.where(
+                    mine, res, cur.astype(jnp.float32)
+                ).astype(o_ref.dtype)
+
+            over_heads(rows, write)
 
     def block_of(rows: int, mine_too):
         """The three phases of a block at a tile of ``rows`` rows, the
@@ -636,7 +762,7 @@ def _ragged_kernel(
 
         @pl.when(jnp.logical_and(mine_too, live))
         def _tile():
-            attend(q_scr[pl.ds(0, rows), :], block_start, m, l, acc)
+            attend(0, q_scr[pl.ds(0, rows), :], block_start, m, l, acc)
 
         @pl.when(jnp.logical_and(mine_too, jnp.logical_and(last, active)))
         def _store():
@@ -1015,11 +1141,19 @@ def _ragged_paged_attention_jit(
     # they differ the kernel holds a body for each (`_ragged_kernel`)
     tile_rows, one_token_rows = span_tile_rows(
         q_tile, t_pad, group, row_blocked=shared_kv)
+    # the KV heads a grid step carries: every one the budget lets the
+    # resident form hold (`head_block`)
+    kv_item = cache.k_pool.dtype.itemsize
+    heads = head_block(
+        hkv, q_tile, t_pad, group, d=d, dv=dv, page=page,
+        q_itemsize=q.dtype.itemsize, kv_itemsize=kv_item,
+        row_blocked=shared_kv)
     if obs.is_enabled():
         _RAGGED_LOWERED.inc(
             requested=max_mode, lowered=variant,
             bodies=("list" if select is not None else
-                    "one" if tile_rows == one_token_rows else "two"))
+                    "one" if tile_rows == one_token_rows else "two"),
+            heads=f"{heads}/{hkv}")
 
     lens = jnp.asarray(cache.kv_lens, jnp.int32)
     cu = jnp.asarray(cache.cu_q_lens, jnp.int32)
@@ -1077,33 +1211,30 @@ def _ragged_paged_attention_jit(
         softcap2=None if softcap is None else softcap * _LOG2E,
         window=window, sinks=sinks, variant=variant, dv=dv,
         shared_kv=shared_kv, blocks=blocks, block_tokens=block_tokens,
+        heads=heads,
     )
-    # Scoped-VMEM demand: the head's whole packed q and out blocks stay
-    # resident (double-buffered by the pipeline), plus the K/V page
-    # buffers, the fp32 scratch, and the tile's (rows, page) score /
-    # probability temporaries.  Past Mosaic's ~16 MB default budget
-    # (packed width >= 2048 at group 8) the budget is raised to what
-    # the call needs, like the forward kernel's big tiles; small steps
-    # keep the default.  The row-blocked form holds one block's rows
-    # in place of the packed axis.
-    kv_item = cache.k_pool.dtype.itemsize
-    held_rows = tile_rows if blocks else 2 * t_pad * group
-    vmem_need = (
-        held_rows * (d * qs.dtype.itemsize + dv * kv_item)
-        + 4 * page * (d + (0 if shared_kv else dv)) * kv_item
-        + tile_rows * (dv + 2 * _STAT_LANES) * 4
-        + 3 * tile_rows * page * 4
-    )
+    # Scoped-VMEM demand (`_vmem_need`).  Past Mosaic's ~16 MB default
+    # budget (packed width >= 2048 at group 8 and one head; 512 at
+    # four) the budget is raised to what the call needs, like the
+    # forward kernel's big tiles; small steps keep the default.
+    vmem_need = _vmem_need(
+        heads, tile_rows, one_token_rows,
+        held_rows=tile_rows if blocks else 2 * t_pad * group, d=d, dv=dv,
+        kv_lanes=d + (0 if shared_kv else dv), page=page,
+        q_itemsize=qs.dtype.itemsize, kv_itemsize=kv_item)
     vmem_limit = None
     if vmem_need > _DEFAULT_SCOPED_VMEM // 2:
         vmem_limit = min(int(vmem_need * 1.5), _MAX_SCOPED_VMEM)
     pools = (cache.k_pool,) if shared_kv else (cache.k_pool, cache.v_pool)
-    pool_specs = [pl.BlockSpec((1, 1, page, pool.shape[-1]), kv_index)
+    # the heads of a page lie together in a pool: one copy a pool
+    pool_specs = [pl.BlockSpec((1, heads, page, pool.shape[-1]), kv_index)
                   for pool in pools]
+    # a scratch a head of the block; the row-blocked form has one head
+    lead = () if blocks else (heads,)
     scratch = [
-        pltpu.VMEM((tile_rows, dv), jnp.float32),
-        pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
-        pltpu.VMEM((tile_rows, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((*lead, tile_rows, dv), jnp.float32),
+        pltpu.VMEM((*lead, tile_rows, _STAT_LANES), jnp.float32),
+        pltpu.VMEM((*lead, tile_rows, _STAT_LANES), jnp.float32),
     ]
     out_shape = jax.ShapeDtypeStruct((hkv, rows_total, dv), out_dtype)
     if blocks:
@@ -1118,16 +1249,16 @@ def _ragged_paged_attention_jit(
         operands = (qs, *pools, jnp.zeros(out_shape.shape, out_dtype))
         aliases = {5 + len(operands) - 1: 0}
     else:
-        in_specs = [pl.BlockSpec((1, rows_total, d), head_index),
+        in_specs = [pl.BlockSpec((heads, rows_total, d), head_index),
                     *pool_specs]
-        out_specs = [pl.BlockSpec((1, rows_total, dv), head_index)]
+        out_specs = [pl.BlockSpec((heads, rows_total, dv), head_index)]
         operands = (qs, *pools)
         aliases = {}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         # the second bound is the step's own count of work items, a
         # traced scalar: one executable whatever the step holds
-        grid=(hkv, n_items),
+        grid=(hkv // heads, n_items),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,

@@ -256,6 +256,7 @@ def test_the_device_counts_the_keys_the_rule_selects(float32_run):
         assert m.expert_pairs_zero == 0
         assert 0 < m.attn_keys_attended <= 3 * min(m.attn_qk_pairs,
                                                    16 * tokens)
+        assert m.ragged_grid_steps == 0     # a list is attended: no page walked
     summary = eng.metrics.summary()
     assert 0.1 < summary["selected_key_share"] < 0.6
     assert 0.1 < summary["local_pair_share"] < 0.45

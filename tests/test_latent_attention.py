@@ -20,6 +20,7 @@ from attention_tpu.ops.ragged_paged import (
     ragged_paged_append,
     ragged_paged_attention,
     recommended_q_tile,
+    row_block_count,
     row_block_list,
     work_items,
 )
@@ -140,6 +141,9 @@ def test_the_row_blocked_work_list(q_lens, kv_after):
         width=256)[:2]
     want = _mask_form(lens, cu, dist, 64, 4)
     assert int(count) == len(want)
+    # the host's count of the same grid (`StepMetrics.ragged_grid_steps`)
+    assert row_block_count(lens, cu, dist, max_pages=MAX_PAGES, page=PAGE,
+                           block_tokens=64, blocks=4) == len(want)
     assert np.asarray(items)[:len(want)].tolist() == want
     assert (np.asarray(items)[len(want):] == SLOTS * 4 * MAX_PAGES).all()
     assert items.shape == ((SLOTS + 256 // 64) * MAX_PAGES + 1,)

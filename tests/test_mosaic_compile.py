@@ -309,9 +309,29 @@ _CELL_TABLES = (dict(slots=33, max_pages=34), dict(slots=9, max_pages=52),
     (32, 4, 256, 256, BF16, _CELL_TABLES[2], dict(window=2048)),
     # Nemotron's group of 16, mixed: two bodies, 4,096 rows and 16
     (32, 2, 384, 256, BF16, {}, {}),
+    # a grid step carries every KV head of its page (`head_block`): the
+    # cells' widest steps, 4 heads x 4,096 rows of query and result
+    # resident at Trinity's width of 512, the chunk's tile a loop over
+    # the heads and a decode row's a batch of them; a decode-only window
+    # layer; StarCoder2's window of 4,096 (a batch of 4 x 80 rows, and a
+    # loop at 2,312); Olmo's 30 heads at a width of 512; Nemotron's 2 at
+    # its 64 decode rows
+    (32, 4, 512, 256, BF16, _CELL_TABLES[2], {}),
+    (32, 4, 512, 256, BF16, _CELL_TABLES[2], dict(window=2048)),
+    (32, 4, 32, 1, BF16, _CELL_TABLES[2], dict(window=2048)),
+    (36, 4, 8, 8, BF16, _CELL_TABLES[0], dict(window=4096)),
+    (36, 4, 512, 256, BF16, _CELL_TABLES[0], dict(window=4096)),
+    (30, 30, 512, 256, BF16, _CELL_TABLES[1], {}),
+    (32, 2, 64, 1, BF16, dict(slots=65, max_pages=16), {}),
 ])
 def test_ragged_kernel_compiles(v5e, hq, hkv, width, q_tile, dtype, table,
                                 band):
+    # every head in one block at the cells' widths; half of them where
+    # four heads' rows and scratch are over the core's VMEM
+    assert ragged_paged.head_block(
+        hkv, q_tile, width, hq // hkv, d=128, dv=128, page=128,
+        q_itemsize=dtype.dtype.itemsize, kv_itemsize=dtype.dtype.itemsize,
+    ) == (hkv if width < 2048 else 2)
     compiled = _compile(
         functools.partial(ragged_paged_attention, **band),
         jax.sharding.SingleDeviceSharding(v5e[0]),

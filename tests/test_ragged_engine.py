@@ -731,6 +731,10 @@ def test_dispatch_span_and_metrics_carry_the_kernels_grid_bound(
                 == m.kv_pages * c.page_size)
         # this model's group is 2: the kernel has one tile for every span
         assert span["own_tile_spans"] == m.own_tile_spans == 0
+        # and a grid step carries both of its KV heads: a layer's grid
+        # is its work items
+        assert (span["ragged_grid_steps"] == m.ragged_grid_steps
+                == len(caches) * m.kv_pages)
     idle = [m for m in eng.metrics.steps if m not in busy]
     assert all(m.kv_pages == 0 and m.attn_rows_read == 0 for m in idle)
     summary = eng.metrics.summary()
@@ -835,5 +839,7 @@ def test_31_decode_rows_beside_a_chunk_are_31_spans_at_their_own_tile():
         (31, 32), (31, 8), (32, 0), (32, 0)]
     assert [m.own_tile_spans for m in steps] == [31, 31, 0, 0]
     assert [s["own_tile_spans"] for s in spans] == [31, 31, 0, 0]
+    assert [s["ragged_grid_steps"] for s in spans] == [
+        m.kv_pages for m in steps]          # one layer, one KV head
     assert [(s["width"], s["q_tile"]) for s in spans] == [
         (64, 32), (48, 8), (32, 1), (32, 1)]

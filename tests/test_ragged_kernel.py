@@ -18,8 +18,16 @@ table shapes (33 x 34 at a group of 9; 9 x 52 at 30 KV heads):
     (8, 9, 1, 16), with the decode slot first, last and at the clamped
     end of the packed axis: where the group is a multiple of 8 a span
     of one token is attended at the one-token tile and a longer one at
-    the step's, two bodies in one kernel; at another group one body.
+    the step's, two bodies in one kernel; at another group one body;
+  * a grid step carries a BLOCK of KV heads (`head_block`): at the
+    cells' groups and head counts, decode-only and beside a chunk,
+    the result is the oracle's and, to the bit, the kernel's at a
+    block of one head; a budget that holds half the heads gives half,
+    and a mesh shard blocks its own heads.
 """
+
+import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,10 +35,13 @@ import numpy as np
 import pytest
 
 from attention_tpu import obs
+from attention_tpu.ops import ragged_paged
 from attention_tpu.ops.decode import banded_live
 from attention_tpu.ops.ragged_paged import (
     RaggedPagedStep,
     _ragged_paged_attention_jit,
+    _vmem_need,
+    head_block,
     live_pages,
     packed_bucket,
     ragged_paged_attention,
@@ -260,14 +271,20 @@ def test_other_lengths_at_one_shape_add_no_compiled_entry():
     assert _ragged_paged_attention_jit._cache_size() == 1
 
 
-def _dot_rows(jaxpr):
-    """The row counts of every product's left operand, kernels' bodies
-    and their branches included."""
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, kernels' bodies and their branches
+    included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            yield eqn.invars[0].aval.shape[0]
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _dot_rows(sub)
+            yield from _eqns(sub)
+
+
+def _dot_rows(jaxpr):
+    """The row counts of every product's left operand (a head's, where
+    the heads of a block go through as a batch)."""
+    return {eqn.invars[0].aval.shape[-2] for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "dot_general"}
 
 
 @pytest.mark.parametrize("case", sorted(_MIXED_TILES))
@@ -286,7 +303,7 @@ def test_a_decode_row_beside_a_chunk_is_served_at_its_own_tile(case):
     mixed = jax.make_jaxpr(
         lambda q, cache: _ragged_paged_attention_jit(q, cache, **band))(
             jnp.asarray(q), cache)
-    assert sorted(set(_dot_rows(mixed.jaxpr))) == sorted({one_token, wide})
+    assert _dot_rows(mixed.jaxpr) == {one_token, wide}
     got = np.asarray(ragged_paged_attention(jnp.asarray(q), cache, **band))
 
     # the same slots with the chunk's span emptied: a decode-only step.
@@ -313,17 +330,138 @@ def test_a_decode_row_beside_a_chunk_is_served_at_its_own_tile(case):
     decode_only = jax.make_jaxpr(
         lambda q, cache: _ragged_paged_attention_jit(q, cache, **band))(
             jnp.asarray(alone), only)
-    assert set(_dot_rows(decode_only.jaxpr)) == {tiles[0]}
+    assert _dot_rows(decode_only.jaxpr) == {tiles[0]}
     want = np.asarray(ragged_paged_attention(jnp.asarray(alone), only,
                                              **band))
     np.testing.assert_array_equal(got[0][:, cu[slots]],
                                   want[0][:, :len(slots)])
 
 
-def test_the_lowering_says_how_many_tile_bodies_it_holds():
+@contextlib.contextmanager
+def _head_budget(budget: int):
+    """The kernel under `head_block`'s rule at another ``budget``: 0
+    holds no block but one head's."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ragged_paged, "head_block",
+                   functools.partial(head_block, budget=budget))
+        _ragged_paged_attention_jit.clear_cache()
+        try:
+            yield
+        finally:
+            _ragged_paged_attention_jit.clear_cache()
+
+
+def _grid_head_blocks(q, cache, band):
+    """The first bound of the kernel's grid: the blocks its KV heads
+    are carried in."""
+    jaxpr = jax.make_jaxpr(
+        lambda q, cache: _ragged_paged_attention_jit(q, cache, **band))(
+            q, cache)
+    (blocks,) = [eqn.params["grid_mapping"].grid[0]
+                 for eqn in _eqns(jaxpr.jaxpr)
+                 if eqn.primitive.name == "pallas_call"]
+    return blocks
+
+
+# the cells' groups and KV heads: Trinity, StarCoder2, Olmo, Nemotron
+_HEADS = {"group_8": (32, 4), "group_9": (36, 4), "group_1": (30, 30),
+          "group_16": (32, 2)}
+# a poisoned slot among the decode rows; window + sinks leave a hole in
+# the long slots' pages; the packed axis ends in pad tokens
+_DECODE_ONLY = [(700, 1), (-1, 1), (1000, 1), (60, 1), (129, 1)]
+_BESIDE_A_CHUNK = [(700, 1), (-1, 1), (1000, 1), (60, 1), (600, 21)]
+_BAND = dict(window=300, sinks=4, softcap=30.0)
+_BLOCKED = [(heads, step, how)
+            for heads in _HEADS
+            for step in ("decode_only", "beside_a_chunk")
+            for how in ("every_head",)]
+_BLOCKED += [("group_8", "beside_a_chunk", "half_the_heads"),
+             ("group_9", "decode_only", "a_mesh_shard")]
+
+
+@pytest.mark.parametrize("heads,step,how", _BLOCKED)
+def test_a_grid_step_carries_a_block_of_heads(heads, step, how):
+    """Against the oracle, and bit-equal to the kernel that carries one
+    head a grid step: every head in one block at the cells' shapes;
+    half of them under a budget that holds no more; a mesh shard's
+    own."""
+    hq, hkv = _HEADS[heads]
+    spans = _DECODE_ONLY if step == "decode_only" else _BESIDE_A_CHUNK
+    q, cache, total = _step(slots=7, max_pages=8, hq=hq, hkv=hkv, spans=spans)
+    q = jnp.asarray(q)
+    assert total < q.shape[2]                     # pad tokens at the end
+    want = ragged_paged_reference(
+        np.asarray(q), np.asarray(cache.k_pool), np.asarray(cache.v_pool),
+        np.asarray(cache.page_table), np.asarray(cache.kv_lens),
+        np.asarray(cache.cu_q_lens), np.asarray(cache.distribution), **_BAND)
+    with _head_budget(0):
+        assert _grid_head_blocks(q, cache, _BAND) == hkv
+        one_head = np.asarray(ragged_paged_attention(q, cache, **_BAND))
+
+    rule = dict(d=_D, dv=_D, page=_PAGE, q_itemsize=4, kv_itemsize=4)
+    shape = (cache.q_tile, q.shape[2], hq // hkv)
+    if how == "every_head":
+        assert head_block(hkv, *shape, **rule) == hkv
+        assert _grid_head_blocks(q, cache, _BAND) == 1
+        got = np.asarray(ragged_paged_attention(q, cache, **_BAND))
+    elif how == "half_the_heads":
+        # what two heads ask for, with the call's half again, and not
+        # a byte for a third
+        tiles = span_tile_rows(*shape)
+        budget = int(1.5 * _vmem_need(
+            hkv // 2, *tiles, held_rows=2 * q.shape[2] * hq // hkv,
+            kv_lanes=2 * _D, **rule))
+        assert head_block(hkv, *shape, budget=budget, **rule) == hkv // 2
+        with _head_budget(budget):
+            assert _grid_head_blocks(q, cache, _BAND) == 2
+            got = np.asarray(ragged_paged_attention(q, cache, **_BAND))
+    else:
+        from jax.sharding import Mesh
+
+        from attention_tpu.parallel.serving import head_sharded_ragged_step
+
+        # the step before its append, whose rows are nobody's: the
+        # lengths advance and no pool row is written
+        q_lens = jnp.diff(cache.cu_q_lens)
+        before = cache._replace(kv_lens=jnp.where(
+            cache.kv_lens < 0, -1, cache.kv_lens - q_lens))
+        nobody = jnp.zeros((1, hkv, q.shape[2], _D), jnp.float32)
+        got, after = head_sharded_ragged_step(
+            q, before, nobody, nobody,
+            mesh=Mesh(np.asarray(jax.devices()[:2]), ("tp",)), **_BAND)
+        got = np.asarray(got)
+        np.testing.assert_array_equal(np.asarray(after.kv_lens),
+                                      np.asarray(cache.kv_lens))
+    np.testing.assert_array_equal(got, one_head)
+    # NaN for the poisoned slot's rows and nowhere else, zeros for pads
+    np.testing.assert_array_equal(np.isnan(got), np.isnan(want))
+    assert np.isnan(got[0, :, 1]).all()
+    sound = ~np.isnan(want)
+    assert np.abs(got[sound] - want[sound]).max() < 2e-5
+    assert np.all(got[..., total:, :] == 0.0)
+
+
+def test_the_head_block_follows_the_budget_at_the_cells_shapes():
+    """Every head at each cell's widest step (bf16, heads of 128); half
+    of Trinity's at a packed width of 2,048 and a tile of 1,024, where
+    four heads' rows and scratch ask for more than the core holds; one
+    head a grid step where nothing fits, and in the row-blocked
+    form."""
+    rule = dict(d=128, dv=128, page=128, q_itemsize=2, kv_itemsize=2)
+    for hq, hkv, width in ((32, 4, 512), (36, 4, 512), (30, 30, 512),
+                           (32, 2, 512)):
+        for q_tile in (256, tile_tokens(1, hq // hkv)):
+            assert head_block(hkv, q_tile, width, hq // hkv, **rule) == hkv
+    assert head_block(4, 1024, 2048, 8, **rule) == 2
+    assert head_block(4, 1024, 2048, 8, budget=0, **rule) == 1
+    assert head_block(1, 256, 384, 64, row_blocked=True, **rule) == 1
+
+
+def test_the_lowering_says_how_many_tile_bodies_and_heads_it_holds():
     """`ops.ragged.lowered` carries ``bodies``: "two" for a program of
     a group of 8 whose tile is wider than one token's, "one" for a
-    decode-only shape and for a mixed step at a group of 9."""
+    decode-only shape and for a mixed step at a group of 9; and
+    ``heads``: the KV heads a grid step carries of those there are."""
     was = obs.is_enabled()
     obs.reset()
     obs.enable()
@@ -333,14 +471,19 @@ def test_the_lowering_says_how_many_tile_bodies_it_holds():
         q, cache, _ = _step(**_CASES["mixed_group_8_full_layer"][0])
         ragged_paged_attention(jnp.asarray(q), cache)
         assert [s["labels"] for s in lowered.series()] == [
-            {"requested": "online", "lowered": "online", "bodies": "two"}]
+            {"requested": "online", "lowered": "online", "bodies": "two",
+             "heads": "4/4"}]
         for case in ("three_of_33_slots_group_9", "chunk_beside_decode"):
             q, cache, _ = _step(**_CASES[case][0])
             ragged_paged_attention(jnp.asarray(q), cache)
         assert lowered.value(requested="online", lowered="online",
-                             bodies="one") == 2
+                             bodies="one", heads="4/4") == 2
         assert lowered.value(requested="online", lowered="online",
-                             bodies="two") == 1
+                             bodies="two", heads="4/4") == 1
+        q, cache, _ = _step(**_CASES["group_1_30_heads_9_slots"][0])
+        ragged_paged_attention(jnp.asarray(q), cache)
+        assert lowered.value(requested="online", lowered="online",
+                             bodies="one", heads="30/30") == 1
     finally:
         obs.reset()
         (obs.enable if was else obs.disable)()

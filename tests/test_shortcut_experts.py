@@ -162,6 +162,11 @@ def test_the_steps_counters_add_up(float32_run):
     first = busy[0]          # the first chunk of the first prompt alone
     assert first.prefill_tokens == 32
     assert first.attn_qk_pairs == 32 * 33 // 2
+    # the row-blocked kernel's grid, a sublayer: the chunk's one block
+    # of 32 tokens x 8 heads on its one page; a decode row's pages
+    assert first.ragged_grid_steps == 4 * 1
+    assert all(m.ragged_grid_steps == 4 * m.kv_pages for m in busy
+               if not m.prefill_tokens)
     summary = eng.metrics.summary()
     # 4 held of 8 real + 4 zero columns: a third each at an even router
     assert 0.2 < summary["local_pair_share"] < 0.45

@@ -21,6 +21,7 @@ import pytest
 from attention_tpu import obs
 from attention_tpu.engine import EngineConfig, SamplingParams, ServingEngine
 from attention_tpu.engine.allocator import BlockAllocator
+from attention_tpu.engine import engine as engine_mod
 from attention_tpu.engine.engine import _band_pages, _qk_pairs
 from attention_tpu.engine.errors import PageSpacesUnsupportedError
 from attention_tpu.engine.request import Request
@@ -32,7 +33,13 @@ from attention_tpu.engine.scheduler import (
 from attention_tpu.models import decoder_from_config
 from attention_tpu.models.transformer import expert_feed_forward
 from attention_tpu.ops.paged import OutOfPagesError, PagePool
-from attention_tpu.ops.ragged_paged import span_tile_rows
+from attention_tpu.ops.ragged_paged import (
+    _ragged_paged_attention_jit,
+    head_block,
+    live_pages,
+    span_tile_rows,
+    work_items,
+)
 from benchmark import harness
 
 VOCAB = 97
@@ -462,6 +469,60 @@ def test_own_tile_spans_follow_the_kernels_rule_for_the_group(session):
     assert span_tile_rows(64, 72, 4) == (264, 264)
     assert span_tile_rows(64, 72, 8) == (512, 8)
     assert all(m.own_tile_spans == 0 for m in busy)
+
+
+def test_the_grid_steps_are_each_layers_own_grid(served, monkeypatch):
+    """`StepMetrics.ragged_grid_steps`, and the dispatch span's field,
+    on steps that hold a decode row beside a chunk: for each attention
+    layer the work items the DEVICE builds from the layer's own table
+    and window, times the blocks its KV heads are carried in, which at
+    this model's 2 KV heads is one (`ops.ragged.lowered`: "2/2")."""
+    model, params = served
+    grids, apply = [], engine_mod._ragged_apply
+
+    def spy(model, params, buffer, pools, layout):
+        _, index = engine_mod._step_inputs(model, buffer, layout)
+        total = 0
+        for layer, c in enumerate(engine_mod._layer_steps(model, pools,
+                                                          index)):
+            hkv = c.k_pool.shape[1]
+            group = model.num_q_heads // hkv
+            width = c.token_slot.shape[0]
+            _, n = work_items(live_pages(
+                c.kv_lens + jnp.diff(c.cu_q_lens), c.cu_q_lens,
+                c.distribution, max_pages=c.page_table.shape[1],
+                page=c.page_size, q_tile=c.q_tile,
+                window=model.layer_window(layer), sinks=None))
+            total += int(n) * (hkv // head_block(
+                hkv, c.q_tile, width, group, d=16, dv=16, page=c.page_size,
+                q_itemsize=4, kv_itemsize=4))
+        grids.append(total)
+        return apply(model, params, buffer, pools, layout)
+
+    monkeypatch.setattr(engine_mod, "_ragged_apply", spy)
+    was = obs.is_enabled()
+    obs.enable()
+    obs.reset()
+    apply.clear_cache()                     # the counter ticks at trace time
+    _ragged_paged_attention_jit.clear_cache()
+    try:
+        eng, _, _ = _serve(model, params, _prompts(5, 70, 300), 6)
+        spans = [e["fields"] for e in obs.events()
+                 if e["name"] == "engine.step.dispatch"]
+        heads = {s["labels"]["heads"]
+                 for s in obs.counter("ops.ragged.lowered").series()}
+    finally:
+        obs.reset()
+        (obs.enable if was else obs.disable)()
+    busy = [m for m in eng.metrics.steps if m.decode_tokens
+            or m.prefill_tokens]
+    assert any(m.decode_tokens and m.prefill_tokens > 1 for m in busy)
+    assert [m.ragged_grid_steps for m in busy] == grids
+    assert [s["ragged_grid_steps"] for s in spans] == grids
+    # one full layer and four window layers, every head in one block
+    assert all(m.ragged_grid_steps == m.kv_pages + 3 * m.kv_pages_window
+               for m in busy)
+    assert heads == {"2/2"}
 
 
 def test_the_slide_has_a_span_inside_the_schedule_phase(served):
