@@ -202,10 +202,9 @@ class FaultInjector:
                      and engine.pool.refcount(p) == 1), None)
         if page is None:
             return False
-        # a latent-attention model keeps no V pools
-        for pools in (engine._k_pools, engine._v_pools):
-            for layer, pool in enumerate(pools):
-                pools[layer] = pool.at[page].set(jnp.nan)
+        # whatever the layers keep under the request's page ids
+        engine.set_page_pools([pool.at[page].set(jnp.nan)
+                               for pool in engine.page_pools()])
         return True
 
 

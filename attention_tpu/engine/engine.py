@@ -46,12 +46,7 @@ from attention_tpu import obs
 from attention_tpu.obs import compiles as _compiles
 from attention_tpu.obs import trace as _trace
 from attention_tpu.engine.allocator import BlockAllocator
-from attention_tpu.engine.errors import (
-    DeadlineExceededError,
-    LatentCacheUnsupportedError,
-    PageSpacesUnsupportedError,
-    RecurrentStateUnsupportedError,
-)
+from attention_tpu.engine.errors import DeadlineExceededError
 from attention_tpu.engine.metrics import (
     EngineMetrics,
     RequestMetrics,
@@ -63,15 +58,13 @@ from attention_tpu.engine.scheduler import (
     Scheduler,
     split_step_buffer,
 )
-from attention_tpu.models.moe import PackedTokens
-from attention_tpu.ops.gated_delta import RaggedStateStep
+from attention_tpu.models import cache_layout as spaces
 from attention_tpu.ops.paged import (
     OutOfPagesError,
     PageAccountingError,
     PagePool,
 )
 from attention_tpu.ops.ragged_paged import (
-    RaggedPagedStep,
     head_block,
     live_pages,
     packed_bucket,
@@ -138,36 +131,14 @@ class StepLimitExceededError(RuntimeError):
 
 
 def require_pages_only(model, feature: str) -> None:
-    """Refuse ``feature`` for a model with recurrent layers: it carries
-    KV pages only, and pages alone do not restore such a request.  And
-    for a model with latent-attention layers: it carries K / V pool
-    pairs, one a layer, and such a model keeps one pool a sublayer, or
-    a latent pool and its selector's index pool.  And for a model of
-    two page spaces: it carries one list of page ids a request."""
-    layers = tuple(getattr(model, "window_layers", ()))
-    if layers:
-        raise PageSpacesUnsupportedError(
-            f"{feature} carries ONE list of page ids a request, and "
-            f"{type(model).__name__} keeps the pages of its "
-            f"sliding-window layers {list(layers)} in a page space of "
-            "their own")
-    layers = tuple(getattr(model, "recurrent_layers", ()))
-    if layers:
-        raise RecurrentStateUnsupportedError(
-            f"{feature} knows only KV pages, and {type(model).__name__} "
-            f"keeps a recurrent state per request in layers {list(layers)}")
-    layers = tuple(getattr(model, "latent_layers", ()))
-    if layers:
-        raise LatentCacheUnsupportedError(
-            f"{feature} carries a K and a V pool a layer, and "
-            f"{type(model).__name__} keeps ONE latent pool for each "
-            f"attention sublayer of layers {list(layers)}")
-    layers = tuple(getattr(model, "indexed_layers", ()))
-    if layers:
-        raise LatentCacheUnsupportedError(
-            f"{feature} carries a K and a V pool a layer, and "
-            f"{type(model).__name__} keeps a latent pool and a "
-            f"selector's index pool in layers {list(layers)}")
+    """Refuse ``feature``, which carries the K and V pages of ONE page
+    space and nothing else, for a model that keeps anything more: the
+    typed error its cache layout names (a recurrent state, one latent
+    pool a sublayer or an index pool beside it, a second page space)."""
+    refusal = model.cache_layout().pages_only_refusal
+    if refusal is not None:
+        error, text = refusal
+        raise error(f"{feature} {text}")
 
 
 class RaggedStepIndex(NamedTuple):
@@ -192,192 +163,15 @@ class RaggedStepIndex(NamedTuple):
     window_table: jax.Array | None = None
 
 
-def _layer_steps(model, pools, index: RaggedStepIndex) -> tuple:
-    """Each layer's cache for a packed step: its pool pair (K and V,
-    or recurrent state and convolution tail) with the shared index; a
-    layer that keeps nothing (``pools`` holds None for it) is told
-    which tokens are pads; a double layer (``pools`` holds the latent
-    pool of each of its attention sublayers) gets a step a sublayer,
-    each of ONE pool; a layer whose attention chooses its keys
-    (``pools`` holds its latent pool and its index pool) one step of
-    both; a window layer of a model with two page spaces reads the
-    window table."""
-    recurrent = set(getattr(model, "recurrent_layers", ()))
-    latent = set(getattr(model, "latent_layers", ()))
-    indexed = set(getattr(model, "indexed_layers", ()))
-    window = set(getattr(model, "window_layers", ()))
-    # the fields of `RaggedPagedStep` after its pools
-    shared = index[:7]
-
-    def cache(layer, pair):
-        if pair is None:
-            return PackedTokens(index.token_slot)
-        if layer in recurrent:
-            return RaggedStateStep(*pair, index.state_rows, index.kv_lens,
-                                   index.cu_q_lens, index.token_slot,
-                                   index.q_span)
-        if layer in latent:
-            return tuple(RaggedPagedStep(pool, None, *shared)
-                         for pool in pair)
-        if layer in indexed:
-            return RaggedPagedStep(pair[0], None, *shared,
-                                   index_pool=pair[1])
-        if layer in window:
-            return RaggedPagedStep(*pair, index.window_table, *shared[1:])
-        return RaggedPagedStep(*pair, *shared)
-
-    return tuple(cache(layer, pair) for layer, pair in enumerate(pools))
-
-
-def _step_pools(model, pools, steps) -> tuple:
-    """What `_ragged_apply` hands back of the layers' ``steps``: the
-    arrays ``pools`` held, in their places."""
-    latent = set(getattr(model, "latent_layers", ()))
-    indexed = set(getattr(model, "indexed_layers", ()))
-
-    def kept(layer, pair, step):
-        if pair is None:
-            return None
-        if layer in latent:
-            return tuple(sub.k_pool for sub in step)
-        if layer in indexed:
-            return step.k_pool, step.index_pool
-        return step[:2]
-
-    return tuple(kept(layer, pair, step)
-                 for layer, (pair, step) in enumerate(zip(pools, steps)))
-
-
 class StepLayout(NamedTuple):
     """What places a packed step in its buffer, and its query tile:
     Python ints, static under the jit.  The first two are an engine's
-    constants; whether the buffer ends with ``state_rows`` is read
-    from the model."""
+    constants; whether the buffer ends with ``state_rows`` or a
+    second table is read from the model's cache layout."""
 
     slots: int
     table_width: int
     q_tile: int
-
-
-def _step_inputs(model, buffer, layout: StepLayout):
-    """``(tokens, index)`` of a packed step's ``buffer``
-    (`PackedBatch.buffer`, on the host or on the device): the token
-    axis ``(1, width)`` and the `RaggedStepIndex` every layer reads,
-    the width read from the buffer's length.  ``q_span`` is made here,
-    ``layout.q_tile`` long: its shape is all anyone reads of it."""
-    seg = split_step_buffer(
-        buffer, slots=layout.slots, table_width=layout.table_width,
-        recurrent=bool(getattr(model, "recurrent_layers", ())),
-        window_tables=bool(getattr(model, "window_layers", ())))
-    return seg.tokens, RaggedStepIndex(
-        seg.tables, seg.kv_lens, seg.cu_q_lens, seg.distribution,
-        seg.token_pos, seg.token_slot,
-        jnp.zeros((layout.q_tile,), jnp.int32), seg.state_rows,
-        seg.window_tables)
-
-
-@functools.partial(jax.jit, static_argnames=("model", "layout"),
-                   donate_argnames=("pools",))
-def _ragged_apply(model, params, buffer, pools, layout):
-    """One PACKED model step: the whole mixed decode/prefill batch as a
-    single ``(1, width)`` token axis over per-layer `RaggedPagedStep`
-    caches — exactly one attention launch per layer per engine step.
-    ``buffer`` is the step's ONE upload (`PackedBatch.buffer`), split
-    here by static slices into the tokens and the index every layer
-    shares (`_step_inputs`).  Its length, a function of the width
-    alone in one engine, and ``layout.q_tile`` are pow2-bucketed by
-    the caller, so distinct compiled signatures stay
-    O(log max_tokens): one a ``(width, q_tile)``.
-
-    ``pools`` holds one pair of arrays a layer, in layer order (for a
-    double layer the two latent pools of its attention sublayers), and
-    is DONATED: the step writes its rows into them in place
-    (`ragged_paged_append`, the recurrent layers' kernel) and hands
-    the same buffers back, so the caller's arrays are gone after the
-    call and it rebinds from the result.  ``buffer`` stays the
-    caller's.
-
-    Returns ``(logits, pools, counts)``, the logits of the rows
-    a step can sample, not of every packed position, and for a model
-    with expert layers the sum over them of what each sowed
-    (`models.moe.LatentExperts`: pairs per held expert, pairs of
-    experts held elsewhere, held experts that received a pair), with,
-    LAST, for a model whose attention chooses its keys the pairs its
-    masks let through, summed over the sublayers
-    (`models.latent_attention`); None for a model with neither.  When
-    the packed axis is
-    wider than the slot count, each slot's last row
-    (`_slot_last_rows`) is gathered before the final norm and the
-    float32 head, and the result is ``(1, slots, vocab)`` with slot
-    ``s`` at row ``s``; a width within the slot count already returns
-    no more rows than that and stays ``(1, width, vocab)``
-    (`_sampled_logit_rows` is the host's half of this rule).  Both
-    sizes are input shapes, and the indices come from the
-    ``cu_q_lens`` already on the device: no signature and no upload is
-    added."""
-    tokens, index = _step_inputs(model, buffer, layout)
-    cu = index.cu_q_lens
-    rows = None
-    if tokens.shape[1] > cu.shape[0] - 1:
-        rows = _slot_last_rows(cu)
-    counted = [name for name, layers in (
-        ("expert_stats", "expert_layers"),
-        ("attention_stats", "indexed_layers")) if getattr(model, layers, ())]
-    out = model.apply({"params": params}, tokens,
-                      _layer_steps(model, pools, index), logit_rows=rows,
-                      mutable=counted or False)
-    counts = None
-    if counted:
-        out, sown = out
-        counts = jnp.concatenate([
-            jnp.atleast_1d(sum(jax.tree_util.tree_leaves(sown[name])))
-            for name in counted])
-    logits, steps = out
-    return logits, _step_pools(model, pools, steps), counts
-
-
-def _qk_pairs(kv_before: np.ndarray, q_lens: np.ndarray,
-              window: int | None) -> int:
-    """(query token, key) pairs an attention sublayer attends in a
-    step: slot by slot, query token ``t`` of ``q`` new ones on ``kv``
-    cached reaches ``kv + t + 1`` keys, ``window`` at most."""
-    kv, q = kv_before.astype(np.int64), q_lens.astype(np.int64)
-    # the first ``free`` of a slot's query tokens reach every key
-    free = q if window is None else np.clip(window - kv, 0, q)
-    pairs = free * kv + free * (free + 1) // 2
-    if window is not None:
-        pairs = pairs + (q - free) * window
-    return int(pairs.sum())
-
-
-def _band_pages(kv_before: np.ndarray, q_lens: np.ndarray,
-                window: int | None, page: int) -> int:
-    """Pages of an attention sublayer's cache that hold a key SOME
-    query row of the step attends: slot by slot, from the page of the
-    first row's oldest key (``window`` back from it; key 0 without
-    one) to the page of the last row's own.  What any kernel has to
-    read, whatever tile it walks in."""
-    kv, q = kv_before.astype(np.int64), q_lens.astype(np.int64)
-    first = 0 if window is None else np.maximum(kv - window + 1, 0)
-    pages = (kv + q - 1) // page - first // page + 1
-    return int(pages[q > 0].sum())
-
-
-def _slot_last_rows(cu_q_lens):
-    """The packed row each slot samples: the last of its span
-    ``[cu[s], cu[s + 1])``.  Empty slots repeat the last offset, so
-    the row is clipped at 0.  Takes the host's array or the device's."""
-    return (cu_q_lens[1:] - 1).clip(0)
-
-
-def _sampled_logit_rows(cu_q_lens: np.ndarray, width: int) -> np.ndarray:
-    """Row of `_ragged_apply`'s logits that slot ``s`` samples, for
-    every slot: ``s`` itself where the step gathered (``width`` over
-    the slot count), else the slot's last packed row."""
-    slots = len(cu_q_lens) - 1
-    if width > slots:
-        return np.arange(slots)
-    return _slot_last_rows(cu_q_lens)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -403,10 +197,6 @@ class EngineConfig:
     # every tier (fewer step shapes, more padding in those steps)
     min_prefill_tile: int = 0
     cache_dtype: Any = None        # None -> model dtype
-    # one value left, and it selects nothing: the benchmark's config
-    # files pass "step_mode": "ragged" into this constructor, so the
-    # field stays until they drop the key (PERF.md §7)
-    step_mode: str = "ragged"
     # 0 = single-device (default).  N >= 1 serves every per-step jitted
     # launch through the KV-head-sharded kernels on a 1D "tp" mesh of
     # the first N devices: one pool slice per head shard, page tables
@@ -420,12 +210,6 @@ class EngineConfig:
             raise ValueError(
                 f"page_size {self.page_size} must be a 128-multiple "
                 "(paged kernel granule)"
-            )
-        if self.step_mode != "ragged":
-            raise ValueError(
-                f"step_mode {self.step_mode!r}: the packed step "
-                "(\"ragged\") is the only lowering, the two-call pair "
-                "is gone"
             )
         if min(self.num_pages, self.max_seq_len, self.max_decode_batch,
                self.max_prefill_rows, self.prefill_chunk,
@@ -456,23 +240,146 @@ class EngineConfig:
                  // self.page_size)
 
 
+def _slot_last_rows(cu_q_lens):
+    """The packed row each slot samples: the last of its span
+    ``[cu[s], cu[s + 1])``.  Empty slots repeat the last offset, so
+    the row is clipped at 0.  Takes the host's array or the device's."""
+    return (cu_q_lens[1:] - 1).clip(0)
+
+
+def _sampled_logit_rows(cu_q_lens: np.ndarray, width: int) -> np.ndarray:
+    """Row of `_ragged_apply`'s logits that slot ``s`` samples, for
+    every slot: ``s`` itself where the step gathered (``width`` over
+    the slot count), else the slot's last packed row."""
+    slots = len(cu_q_lens) - 1
+    if width > slots:
+        return np.arange(slots)
+    return _slot_last_rows(cu_q_lens)
+
+
+def _step_inputs(model, buffer, layout: StepLayout):
+    """``(tokens, index)`` of a packed step's ``buffer``
+    (`PackedBatch.buffer`, on the host or on the device): the token
+    axis ``(1, width)`` and the `RaggedStepIndex` every layer reads,
+    the width read from the buffer's length.  ``q_span`` is made here,
+    ``layout.q_tile`` long: its shape is all anyone reads of it."""
+    kept = model.cache_layout()
+    seg = split_step_buffer(
+        buffer, slots=layout.slots, table_width=layout.table_width,
+        recurrent=kept.state_rows, window_tables=kept.window_table)
+    return seg.tokens, RaggedStepIndex(
+        seg.tables, seg.kv_lens, seg.cu_q_lens, seg.distribution,
+        seg.token_pos, seg.token_slot,
+        jnp.zeros((layout.q_tile,), jnp.int32), seg.state_rows,
+        seg.window_tables)
+
+
+@functools.partial(jax.jit, static_argnames=("model", "layout"),
+                   donate_argnames=("pools",))
+def _ragged_apply(model, params, buffer, pools, layout):
+    """One PACKED model step: the whole mixed decode/prefill batch as a
+    single ``(1, width)`` token axis over the layers' step caches
+    (`CacheLayout.steps`) — exactly one attention launch per layer per
+    engine step.
+    ``buffer`` is the step's ONE upload (`PackedBatch.buffer`), split
+    here by static slices into the tokens and the index every layer
+    shares (`_step_inputs`).  Its length, a function of the width
+    alone in one engine, and ``layout.q_tile`` are pow2-bucketed by
+    the caller, so distinct compiled signatures stay
+    O(log max_tokens): one a ``(width, q_tile)``.
+
+    ``pools`` holds a layer's arrays, in layer order, as the model's
+    cache layout lists them (`models.cache_layout`; None for a layer
+    that keeps nothing), and is DONATED: the step writes its rows into
+    them in place (`ragged_paged_append`, the recurrent layers' kernel)
+    and hands the same buffers back, so the caller's arrays are gone
+    after the call and it rebinds from the result.  ``buffer`` stays
+    the caller's.
+
+    Returns ``(logits, pools, counts)``, the logits of the rows
+    a step can sample, not of every packed position, and for a model
+    with expert layers the sum over them of what each sowed
+    (`models.moe.LatentExperts`: pairs per held expert, pairs of
+    experts held elsewhere, held experts that received a pair), with,
+    LAST, for a model whose attention chooses its keys the pairs its
+    masks let through, summed over the sublayers
+    (`models.latent_attention`); None for a model with neither.  When
+    the packed axis is
+    wider than the slot count, each slot's last row
+    (`_slot_last_rows`) is gathered before the final norm and the
+    float32 head, and the result is ``(1, slots, vocab)`` with slot
+    ``s`` at row ``s``; a width within the slot count already returns
+    no more rows than that and stays ``(1, width, vocab)``
+    (`_sampled_logit_rows` is the host's half of this rule).  Both
+    sizes are input shapes, and the indices come from the
+    ``cu_q_lens`` already on the device: no signature and no upload is
+    added."""
+    tokens, index = _step_inputs(model, buffer, layout)
+    cu = index.cu_q_lens
+    rows = None
+    if tokens.shape[1] > cu.shape[0] - 1:
+        rows = _slot_last_rows(cu)
+    counted = [name for name, layers in (
+        ("expert_stats", "expert_layers"),
+        ("attention_stats", "indexed_layers")) if getattr(model, layers, ())]
+    kept = model.cache_layout()
+    out = model.apply({"params": params}, tokens,
+                      kept.steps(pools, index), logit_rows=rows,
+                      mutable=counted or False)
+    counts = None
+    if counted:
+        out, sown = out
+        counts = jnp.concatenate([
+            jnp.atleast_1d(sum(jax.tree_util.tree_leaves(sown[name])))
+            for name in counted])
+    logits, steps = out
+    return logits, kept.pools(steps), counts
+
+
+def _qk_pairs(kv_before: np.ndarray, q_lens: np.ndarray,
+              window: int | None) -> int:
+    """(query token, key) pairs an attention sublayer attends in a
+    step: slot by slot, query token ``t`` of ``q`` new ones on ``kv``
+    cached reaches ``kv + t + 1`` keys, ``window`` at most."""
+    kv, q = kv_before.astype(np.int64), q_lens.astype(np.int64)
+    # the first ``free`` of a slot's query tokens reach every key
+    free = q if window is None else np.clip(window - kv, 0, q)
+    pairs = free * kv + free * (free + 1) // 2
+    if window is not None:
+        pairs = pairs + (q - free) * window
+    return int(pairs.sum())
+
+
+def _band_pages(kv_before: np.ndarray, q_lens: np.ndarray,
+                window: int | None, page: int) -> int:
+    """Pages of an attention sublayer's cache that hold a key SOME
+    query row of the step attends: slot by slot, from the page of the
+    first row's oldest key (``window`` back from it; key 0 without
+    one) to the page of the last row's own.  What any kernel has to
+    read, whatever tile it walks in."""
+    kv, q = kv_before.astype(np.int64), q_lens.astype(np.int64)
+    first = 0 if window is None else np.maximum(kv - window + 1, 0)
+    pages = (kv + q - 1) // page - first // page + 1
+    return int(pages[q > 0].sum())
+
+
 class ServingEngine:
     """Deterministic continuous-batching engine over a TinyDecoder-
     family model (any ``impl='flash'`` model whose ``apply`` threads
     per-layer caches, the `generate_paged` contract).
 
-    What a request holds is read from the model: K and V pools exist
-    for its attention layers, all on the one page-id space; for its
-    recurrent layers (``model.recurrent_layers``) each running request
-    also holds one STATE SLOT, a row of every such layer's state pool
-    ``(slots + 1, H, dk, dv)`` float32 and convolution-tail pool, taken
-    at admission and given back at finish, cancel, time-out and
-    preemption.  There are ``max_decode_batch + max_prefill_rows``
-    slots, as many as a step has rows; a request that (re)starts at
-    token 0 starts from a zero state inside the kernel, so a
-    preempted request recomputes correctly and no row is ever cleared
-    by hand.  Features that know only pages refuse such a model
-    (`require_pages_only`)."""
+    What a request holds is the model's word (`model.cache_layout()`,
+    `models.cache_layout`): the engine allocates each layer's arrays as
+    the layout lists them, hands them to the step and takes them back,
+    and names no kind of layer doing so.  Where a layer keeps a
+    recurrent state each running request also holds one STATE SLOT, a
+    row of that layer's arrays, taken at admission and given back at
+    finish, cancel, time-out and preemption.  There are
+    ``max_decode_batch + max_prefill_rows`` slots, as many as a step
+    has rows; a request that (re)starts at token 0 starts from a zero
+    state inside the kernel, so a preempted request recomputes
+    correctly and no row is ever cleared by hand.  Features that know
+    only pages refuse such a model (`require_pages_only`)."""
 
     def __init__(self, model, params, config: EngineConfig, *,
                  on_token: Callable[[Request, int], None] | None = None,
@@ -489,8 +396,10 @@ class ServingEngine:
         self.on_token = on_token
         self.on_finish = on_finish
         self.on_timeout = on_timeout
-        # the layer of every attention SUBLAYER (a double layer has
-        # two), and the layers whose sublayers keep one latent pool
+        self._layout = layout = model.cache_layout()
+        # what the step's COUNTS read of the model's kinds (ROADMAP D23;
+        # the pools name none): the layer of every attention SUBLAYER (a
+        # double layer has two), those of one latent pool, of a selector
         self._kv_layers = tuple(getattr(
             model, "attention_sublayers", range(model.depth)))
         self._latent_layers = tuple(getattr(model, "latent_layers", ()))
@@ -505,23 +414,13 @@ class ServingEngine:
         self._window_layers = tuple(getattr(model, "window_layers", ()))
         self._windows = ((None, model.window) if self._window_layers
                          else (model.window,))
-        if bool(self._window_layers) != bool(config.num_window_pages):
+        if layout.window_table != bool(config.num_window_pages):
             raise ValueError(
                 f"num_window_pages {config.num_window_pages}: the pages "
                 "of a second page space, which a model has where "
                 "sliding-window layers stand beside full-attention "
                 f"layers (this one's window layers: "
                 f"{list(self._window_layers)})")
-        if config.mesh_shards:
-            if self._latent_layers or self._indexed_layers:
-                from attention_tpu.parallel.serving import MeshConfigError
-
-                raise MeshConfigError(
-                    f"mesh_shards {config.mesh_shards}: the mesh engine "
-                    "shards KV HEADS, and a latent-attention sublayer has "
-                    "one; its four-chip layout shards pages, not heads")
-            self.require_pages_only("mesh_shards > 0")
-
         # mesh mode: a 1D "tp" mesh of the first mesh_shards devices;
         # the step launches run the model's head-sharded cached paths
         # (a clone with tp_axis set — same params, same math per head)
@@ -532,6 +431,12 @@ class ServingEngine:
         if config.mesh_shards:
             from attention_tpu.parallel.serving import MeshConfigError
 
+            if not layout.shard_kv_heads:
+                raise MeshConfigError(
+                    f"mesh_shards {config.mesh_shards}: the mesh engine "
+                    "shards KV HEADS, and a latent-attention sublayer has "
+                    "one; its four-chip layout shards pages, not heads")
+            self.require_pages_only("mesh_shards > 0")
             devices = jax.devices()
             if config.mesh_shards > len(devices):
                 raise MeshConfigError(
@@ -569,46 +474,28 @@ class ServingEngine:
             self._pool_sharding = None
             self._replicated = None   # the default device
 
+        # each layer's arrays (None: it keeps nothing), in layer order: the
+        # ONE list the step is handed and hands back.  State rows: one a
+        # slot, and one more, nobody's, where a step's empty slots land
+        state_slots = (config.max_decode_batch + config.max_prefill_rows
+                       if layout.state_rows else 0)
+        rows = {spaces.PAGES: config.num_pages,
+                spaces.WINDOW_PAGES: config.num_window_pages,
+                spaces.STATE_ROWS: state_slots + 1}
         dtype = config.cache_dtype or model.dtype
-        # what an attention sublayer keeps, by the model's own word: K
-        # and V pools of the head size, or ONE latent pool and no V,
-        # or a latent pool and its selector's index pool
-        kv_heads, widths = model.kv_pool_widths()
-
-        def pools(width):
-            def shape(layer):
-                pages = (config.num_window_pages
-                         if layer in self._window_layers
-                         else config.num_pages)
-                return (pages, kv_heads, config.page_size, width)
-
-            return [self._place_pool(jnp.zeros(shape(layer), dtype))
-                    for layer in self._kv_layers]
-
-        # one pool (pair) per attention SUBLAYER, in layer order
-        self._k_pools = pools(widths[0])
-        second = pools(widths[1]) if len(widths) > 1 else []
-        self._v_pools, self._index_pools = (
-            ([], second) if self._indexed_layers else (second, []))
-        # one state and one convolution-tail pool per RECURRENT layer;
-        # the last row is nobody's (empty slots of a step land there)
-        state_slots = 0
-        self._state_pools: list[jax.Array] = []
-        self._conv_pools: list[jax.Array] = []
-        if self._state_layers:
-            state_slots = config.max_decode_batch + config.max_prefill_rows
-            state_shape, conv_shape = model.recurrent_state_shapes()
-            for _ in self._state_layers:
-                self._state_pools.append(jnp.zeros(
-                    (state_slots + 1, *state_shape), jnp.float32))
-                self._conv_pools.append(jnp.zeros(
-                    (state_slots + 1, *conv_shape), model.dtype))
+        self._pools = [c and tuple(
+            self._place_pool(jnp.zeros(
+                (rows[c.space], *(config.page_size if n == spaces.PAGE else n
+                                  for n in row)), dtype if d is None else d))
+            for row, d in c.arrays) for c in layout.layers]
+        self._paged = [i for i, c in enumerate(layout.layers)
+                       if c and c.space == spaces.PAGES]
         if obs.is_enabled():
             _MESH_SHARDS.set(float(config.mesh_shards or 1))
 
         self.pool = PagePool(config.num_pages)
         self.window_pool = (PagePool(config.num_window_pages)
-                            if self._window_layers else None)
+                            if layout.window_table else None)
         # the widest query tile a step is dispatched with: what a
         # window layer's kernel reaches back beyond the window
         self._widest_tile = max(self._q_tile(1),
@@ -619,7 +506,7 @@ class ServingEngine:
             state_slots=state_slots,
             window_pool=self.window_pool,
             band=(model.window + self._widest_tile - 1
-                  if self._window_layers else 0),
+                  if layout.window_table else 0),
         )
         self.scheduler = Scheduler(
             self.allocator,
@@ -691,11 +578,26 @@ class ServingEngine:
         self.trace_start_tick: int = 0
         self.trace_owner: str = "engine"
 
-    # -- recurrent state --------------------------------------------------
+    # -- the layers' pools -----------------------------------------------
 
     def require_pages_only(self, feature: str) -> None:
         """`require_pages_only` for this engine's model."""
         require_pages_only(self.model, feature)
+
+    def page_pools(self) -> list:
+        """The arrays kept under a request's page ids, each such layer's
+        first, then each one's second: every K then every V pool in a
+        model that passed `require_pages_only`, the page wire formats'
+        order.  The next step consumes them."""
+        return [a for nth in zip(*(self._pools[i] for i in self._paged))
+                for a in nth]
+
+    def set_page_pools(self, pools) -> None:
+        """Put ``pools``, placed as the engine places its own, in the
+        places of `page_pools`' arrays."""
+        for j, i in enumerate(self._paged):
+            self._pools[i] = tuple(self._place_pool(a)
+                                   for a in pools[j::len(self._paged)])
 
     # -- request tracing --------------------------------------------------
 
@@ -764,7 +666,7 @@ class ServingEngine:
         into the allocator so the lookup then hits.  A no-op without
         an attached store; never raises (corruption is counted and
         the request simply cold-prefills)."""
-        if self.prefix_store is None or self._state_layers:
+        if self.prefix_store is None or self._layout.state_rows:
             return 0
         from attention_tpu.prefixstore.adapter import import_chain
 
@@ -1143,33 +1045,6 @@ class ServingEngine:
         the parameters are."""
         return jax.device_put(buffer, self._replicated)
 
-    def _layer_pools(self) -> tuple:
-        """The pools as `_ragged_apply` takes them: a pair a layer, in
-        layer order.  The call consumes these arrays."""
-        # None stays for a layer that keeps nothing (sparse experts)
-        pairs: list[Any] = [None] * self.model.depth
-        for i, layer in enumerate(self._kv_layers):
-            # K and V, or a latent sublayer's ONE pool; a double
-            # layer's two sublayers follow each other into its tuple
-            pairs[layer] = (pairs[layer] or ()) + tuple(
-                pools[i] for pools in (self._k_pools, self._v_pools,
-                                       self._index_pools)
-                if pools)
-        for i, layer in enumerate(self._state_layers):
-            pairs[layer] = (self._state_pools[i], self._conv_pools[i])
-        return tuple(pairs)
-
-    def _rebind_pools(self, pairs) -> None:
-        kept = (pool for layer in dict.fromkeys(self._kv_layers)
-                for pool in pairs[layer])
-        for i in range(len(self._kv_layers)):     # `_layer_pools`' order
-            self._k_pools[i] = next(kept)
-            for second in (self._v_pools, self._index_pools):
-                if second:
-                    second[i] = next(kept)
-        for i, layer in enumerate(self._state_layers):
-            self._state_pools[i], self._conv_pools[i] = pairs[layer]
-
     def _fetch_logits(self, logits_dev, used: int,
                       pairs_dev=None) -> np.ndarray:
         """The step loop's ONLY device sync: materialize on host the
@@ -1243,7 +1118,7 @@ class ServingEngine:
             slots = cfg.max_decode_batch + cfg.max_prefill_rows
             max_q = max((n for _, n in sched.prefill), default=1)
             q_tile = self._q_tile(max_q)
-            if q_tile > self._widest_tile and self._window_layers:
+            if q_tile > self._widest_tile and self.window_pool is not None:
                 # the kernel would reach below the band, into pages
                 # given back and handed to another request
                 raise PageAccountingError(
@@ -1254,8 +1129,8 @@ class ServingEngine:
             width = packed_bucket(max(total, q_tile))
             batch = sched.pack(width=width, slots=slots,
                                table_width=cfg.table_width,
-                               recurrent=bool(self._state_layers),
-                               window_tables=bool(self._window_layers))
+                               recurrent=self._layout.state_rows,
+                               window_tables=self._layout.window_table)
             q_lens = np.diff(batch.cu_q_lens)
             # the kernel's grid bound for this step, counted here by
             # the rule the device builds it from (a slot the append
@@ -1336,11 +1211,10 @@ class ServingEngine:
                       kv_pages=kv_pages, own_tile_spans=own_tile,
                       ragged_grid_steps=grid_steps, attn_rows=rows_read,
                       **fields):
-            logits_dev, new_pools, pairs_dev = _ragged_apply(
-                self._step_model, self.params, buffer,
-                self._layer_pools(),
+            logits_dev, pools, pairs_dev = _ragged_apply(
+                self._step_model, self.params, buffer, tuple(self._pools),
                 StepLayout(slots, cfg.table_width, q_tile))
-            self._rebind_pools(new_pools)
+            self._pools = list(pools)
         logits = self._fetch_logits(logits_dev, sampled, pairs_dev)
         with obs.span("engine.step.sample", rows=sampled):
             row_of = _sampled_logit_rows(batch.cu_q_lens, width)
@@ -1383,9 +1257,7 @@ class ServingEngine:
     def quiesce(self) -> None:
         """Block until the device pools are final.  A snapshot cut
         runs this before it reads them."""
-        for a in (*self._k_pools, *self._v_pools, *self._index_pools,
-                  *self._state_pools, *self._conv_pools):
-            jax.block_until_ready(a)
+        jax.block_until_ready(self._pools)
 
     def _post_decode(self, req: Request, logits_row: np.ndarray) -> None:
         """Consume one decode request's logits row: guard it, sample
@@ -1418,7 +1290,7 @@ class ServingEngine:
         chunk and would take it a second time.  Nobody kept the state
         before the step, so the request goes back to the queue and is
         recomputed from token 0, as after a preemption."""
-        if self._state_layers:
+        if self._layout.state_rows:
             self.scheduler.requeue_for_recompute(req)
 
     def _post_prefill(self, req: Request, real: int,
@@ -1453,7 +1325,7 @@ class ServingEngine:
 
     def _commit_prefix(self, req: Request) -> None:
         full = req.num_prompt_tokens // self.config.page_size
-        if full and not self._state_layers:
+        if full and not self._layout.state_rows:
             self.allocator.commit_prefix(
                 req.prompt, req.pages[:full], now=self._step,
                 window_pages=(req.window_pages[:full]

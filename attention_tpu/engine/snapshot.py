@@ -242,16 +242,16 @@ def _serialize_sections(engine: ServingEngine) -> list[tuple[str, bytes]]:
     cfg = dataclasses.asdict(engine.config)
     if cfg["cache_dtype"] is not None:
         cfg["cache_dtype"] = _dtype_name(cfg["cache_dtype"])
+    hosted = [np.asarray(a) for a in engine.page_pools()]
     meta = {
         "config": cfg,
         "model": model_fingerprint(engine.model),
         "step": engine.current_step,
         "next_seq": engine._next_seq,
-        "pool_dtype": _dtype_name(engine._k_pools[0].dtype),
-        "pool_shape": list(engine._k_pools[0].shape),
+        "pool_dtype": _dtype_name(hosted[0].dtype),
+        "pool_shape": list(hosted[0].shape),
     }
     shards = getattr(engine.config, "mesh_shards", 0) or 1
-    hosted = [np.asarray(a) for a in (*engine._k_pools, *engine._v_pools)]
     if shards == 1:
         pool_sections = [("pools", b"".join(a.tobytes() for a in hosted))]
     else:
@@ -556,13 +556,9 @@ def restore(path: str, model, params, *,
                 parts[i].append(np.frombuffer(
                     payload[i * slice_nb:(i + 1) * slice_nb],
                     dtype=dtype).reshape(slice_shape))
-        arrays = [
-            engine._place_pool(
-                p[0] if shards == 1 else np.concatenate(p, axis=1))
-            for p in parts
-        ]
-        engine._k_pools = arrays[:model.depth]
-        engine._v_pools = arrays[model.depth:]
+        engine.set_page_pools(
+            [p[0] if shards == 1 else np.concatenate(p, axis=1)
+             for p in parts])
 
         engine.pool._free = [int(p) for p in state["free"]]
         engine.pool._refs = [int(r) for r in state["refs"]]

@@ -303,7 +303,7 @@ def export_handoff(engine, req, request_record: dict) -> bytes | None:
     pages = [int(p) for p in list(req.pages)[:full]]
     arrays = tuple(
         np.stack([np.asarray(pool[p]) for p in pages])
-        for pool in (*engine._k_pools, *engine._v_pools)
+        for pool in engine.page_pools()
     )
     return encode_handoff(
         request=request_record,
@@ -340,16 +340,10 @@ def import_handoff(engine, blob: bytes, *, now: int) -> int:
         pages = engine.allocator.allocate(n - local, for_decode=False)
     except OutOfPagesError:
         return 0
-    depth = len(engine._k_pools)
     idx = jnp.asarray(pages, jnp.int32)
-    dtype = engine._k_pools[0].dtype
-    for layer in range(depth):
-        k_stack = jnp.asarray(rec.arrays[layer][local:], dtype)
-        v_stack = jnp.asarray(rec.arrays[depth + layer][local:], dtype)
-        engine._k_pools[layer] = engine._place_pool(
-            engine._k_pools[layer].at[idx].set(k_stack))
-        engine._v_pools[layer] = engine._place_pool(
-            engine._v_pools[layer].at[idx].set(v_stack))
+    engine.set_page_pools([
+        pool.at[idx].set(jnp.asarray(stack[local:], pool.dtype))
+        for pool, stack in zip(engine.page_pools(), rec.arrays)])
     chain = engine.allocator.cached_chain(toks)
     engine.allocator.commit_prefix(toks, chain + pages, now=now)
     # drop the importer's reference: the prefix cache's own incref is

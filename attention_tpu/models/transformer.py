@@ -591,26 +591,26 @@ class TinyDecoder(nn.Module):
         return tuple(sorted(self.attention_layers + 2 * self.latent_layers
                             + self.indexed_layers))
 
-    def kv_pool_widths(self) -> tuple[int, tuple[int, ...]]:
-        """What an attention sublayer keeps a token: the KV heads, and
-        the row width of each pool: K and V of the head size; or the
-        ONE latent pool's ``[c | k_r]`` of ONE head
-        (`latent_attention.latent_row_width`); or that latent pool and
-        a selector's index pool beside it
-        (`latent_attention.index_row_width`)."""
-        kinds = [k for k in (self.attention_layers, self.latent_layers,
-                             self.indexed_layers) if k]
-        if len(kinds) > 1:
-            raise ValueError(
-                "K / V pools, one latent pool, and a latent pool with an "
-                "index pool are three pool layouts; an engine's "
-                "sublayers share one")
+    def kv_pool_widths(self, layer: int | None = None
+                       ) -> tuple[int, tuple[int, ...]]:
+        """What attention sublayer ``layer`` (None: the model's first)
+        keeps a token: the KV heads, and the row width of each pool: K
+        and V of the head size; or the ONE latent pool's ``[c | k_r]``
+        of ONE head (`latent_attention.latent_row_width`); or that
+        latent pool and a selector's index pool beside it
+        (`latent_attention.index_row_width`).  The step's counts take
+        the first's for every sublayer; the pools are laid out a layer
+        at a time (`cache_layout`)."""
+        if layer is None:
+            layer = self.attention_sublayers[0]
+        kind = self.kinds[layer]
         f = dict(self.sublayer)
-        if self.latent_layers:
+        if kind == SHORTCUT_EXPERTS:
             return 1, (latent_row_width(f["kv_lora_rank"], f["rope_dim"]),)
-        if self.indexed_layers:
+        if kind in LATENT_KINDS:
             return 1, (latent_row_width(f["kv_lora_rank"], f["rope_dim"]),
                        index_row_width(f["index_dim"]))
+        # K and V of every KV head
         return self.num_kv_heads, (self.head_size,) * 2
 
     @property
@@ -638,16 +638,16 @@ class TinyDecoder(nn.Module):
         routes over); 0 for a model without expert layers."""
         return dict(self.sublayer).get("experts_held", 0)
 
-    def recurrent_state_shapes(self) -> tuple[tuple, tuple]:
-        """Per request and recurrent layer: the float32 state (Gated
-        DeltaNet: ``(heads, key_dim, value_dim)``; state-space:
-        ``(heads, head_dim, state)``) and the convolution's tail
-        ``(conv - 1, channels)`` in the model's dtype.  One pool shape
-        serves every recurrent layer, so a model has one kind of them."""
-        if self._layers_of(STATE_SPACE):
-            if self._layers_of(LINEAR_ATTENTION):
-                raise ValueError("state-space and linear-attention layers "
-                                 "in one model would need two pool shapes")
+    def recurrent_state_shapes(self, layer: int | None = None
+                               ) -> tuple[tuple, tuple]:
+        """Per request, of recurrent layer ``layer`` (None: the first):
+        the float32 state (Gated DeltaNet: ``(heads, key_dim,
+        value_dim)``; state-space: ``(heads, head_dim, state)``) and
+        the convolution's tail ``(conv - 1, channels)`` in the model's
+        dtype."""
+        if layer is None:
+            layer = self.recurrent_layers[0]
+        if self.kinds[layer] == STATE_SPACE:
             f = dict(self.sublayer)
             h, p, n = f["ssm_heads"], f["ssm_head_dim"], f["ssm_state"]
             return (h, p, n), (f.get("ssm_conv", 4) - 1,
@@ -785,3 +785,51 @@ class TinyDecoder(nn.Module):
                            cache_dtype or self.dtype)
             for _ in range(self.depth)
         )
+
+    def cache_layout(self):
+        """What each layer keeps between the engine's steps: the one
+        place that turns a layer's kind into its arrays, and the kinds
+        into the typed refusal of features that carry K / V pages alone."""
+        # here, not at the top: the engine imports this package
+        from attention_tpu.engine import errors
+        from attention_tpu.models import cache_layout as kept
+
+        def layer_cache(layer, kind):
+            if kind in (LINEAR_ATTENTION, STATE_SPACE):
+                state, conv = self.recurrent_state_shapes(layer)
+                return kept.LayerCache(kept.STATE_ROWS, (
+                    (state, jnp.float32), (conv, self.dtype)), kept.state_step)
+            if kind == SPARSE_EXPERTS:
+                return None
+            heads, widths = self.kv_pool_widths(layer)
+            pools = tuple(((heads, kept.PAGE, w), None) for w in widths)
+            if kind == SHORTCUT_EXPERTS:    # a pool a sublayer, two of them
+                return kept.LayerCache(kept.PAGES, pools * 2,
+                                       kept.latent_steps, kept.latents_kept)
+            if kind in LATENT_KINDS:
+                return kept.LayerCache(kept.PAGES, pools, kept.indexed_step,
+                                       kept.indexed_kept)
+            return kept.LayerCache(
+                kept.WINDOW_PAGES if layer in self.window_layers
+                else kept.PAGES, pools, kept.kv_step)
+
+        name = type(self).__name__
+        pair = f"carries a K and a V pool a layer, and {name} keeps "
+        refusals = (
+            (self.window_layers, errors.PageSpacesUnsupportedError,
+             f"carries ONE list of page ids a request, and {name} keeps the "
+             "pages of its sliding-window layers {} in a page space of "
+             "their own"),
+            (self.recurrent_layers, errors.RecurrentStateUnsupportedError,
+             f"knows only KV pages, and {name} keeps a recurrent state per "
+             "request in layers {}"),
+            (self.latent_layers, errors.LatentCacheUnsupportedError, pair
+             + "ONE latent pool for each attention sublayer of layers {}"),
+            (self.indexed_layers, errors.LatentCacheUnsupportedError, pair
+             + "a latent pool and a selector's index pool in layers {}"))
+        return kept.CacheLayout(
+            tuple(layer_cache(i, kind) for i, kind in enumerate(self.kinds)),
+            shard_kv_heads=not (self.latent_layers or self.indexed_layers),
+            pages_only_refusal=next(
+                ((error, text.format(list(layers)))
+                 for layers, error, text in refusals if layers), None))

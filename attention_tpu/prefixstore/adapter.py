@@ -70,20 +70,19 @@ def fleet_fingerprint(engine) -> dict:
 
 def engine_geometry(engine) -> dict:
     """The page geometry this engine exports under / imports against."""
-    pool = engine._k_pools[0]
+    pool = engine.page_pools()[0]
     return page_geometry(
         num_kv_heads=pool.shape[1],
         page_size=engine.config.page_size,
         head_dim=pool.shape[3],
-        layers=len(engine._k_pools),
+        layers=engine.model.depth,
         dtype=_dtype_name(pool.dtype),
     )
 
 
 def _page_arrays(engine, page: int) -> list[np.ndarray]:
     """Host copies of one page's K then V arrays across layers."""
-    return [np.asarray(pool[page])
-            for pool in (*engine._k_pools, *engine._v_pools)]
+    return [np.asarray(pool[page]) for pool in engine.page_pools()]
 
 
 def export_chain(engine, tokens, pages, *, now: int) -> int:
@@ -171,18 +170,11 @@ def import_chain(engine, tokens, *, now: int) -> int:
         pages = engine.allocator.allocate(len(recs), for_decode=False)
     except OutOfPagesError:
         return 0
-    depth = len(engine._k_pools)
     idx = jnp.asarray(pages, jnp.int32)
-    dtype = engine._k_pools[0].dtype
-    for layer in range(depth):
-        k_stack = jnp.asarray(
-            np.stack([r.arrays[layer] for r in recs]), dtype)
-        v_stack = jnp.asarray(
-            np.stack([r.arrays[depth + layer] for r in recs]), dtype)
-        engine._k_pools[layer] = engine._place_pool(
-            engine._k_pools[layer].at[idx].set(k_stack))
-        engine._v_pools[layer] = engine._place_pool(
-            engine._v_pools[layer].at[idx].set(v_stack))
+    engine.set_page_pools([
+        pool.at[idx].set(jnp.asarray(np.stack(stack), pool.dtype))
+        for pool, stack in zip(engine.page_pools(),
+                               zip(*(r.arrays for r in recs)))])
     chain = engine.allocator.cached_chain(toks)
     covered = local + len(recs)
     engine.allocator.commit_prefix(

@@ -351,15 +351,12 @@ def test_engine_rejects_oversized_and_bad_requests(tiny_model):
 
 
 def test_engine_config_has_one_step_lowering_and_no_switch_for_it():
-    """The packed step is the only lowering: the one other value
-    `step_mode` ever took is refused, and the loop has no second
-    switch."""
-    assert EngineConfig().step_mode == "ragged"
-    EngineConfig(step_mode="ragged").validate()
-    with pytest.raises(ValueError, match="two-call pair is gone"):
-        EngineConfig(step_mode="two_call").validate()
-    with pytest.raises(TypeError, match="async_steps"):
-        EngineConfig(async_steps=True)
+    """The packed step is the only lowering, and nothing names it: the
+    two fields that once chose another are no fields, refused as any
+    unknown one is."""
+    for gone in ("step_mode", "async_steps"):
+        with pytest.raises(TypeError, match=gone):
+            EngineConfig(**{gone: "ragged"})
 
 
 def test_scheduler_respects_token_budget_and_fcfs():

@@ -179,8 +179,8 @@ def test_replay_gives_the_parents_recorded_tokens(hybrid):
     step = eng.step
 
     def watched():
-        held = [*eng._k_pools, *eng._v_pools, *eng._state_pools,
-                *eng._conv_pools]
+        held = jax.tree.leaves(eng._pools)
+        assert len(held) == 2 * model.depth
         out = step()
         pools.append((held, out))
         return out
@@ -214,9 +214,13 @@ def test_state_slots_follow_the_request_life_cycle(hybrid):
     eng = ServingEngine(model, params, EngineConfig(**dict(
         ENGINE, max_decode_batch=2)))
     assert eng.allocator.state_slots == 3
-    assert eng._state_pools[0].shape == (4, 2, 16, 32)
-    assert eng._conv_pools[0].shape == (4, 3, 2 * (16 + 16 + 32))
-    assert len(eng._k_pools) == 1 and len(eng._state_pools) == 3
+    state, tail = eng._pools[0]
+    assert state.shape == (4, 2, 16, 32)
+    assert tail.shape == (4, 3, 2 * (16 + 16 + 32))
+    # three layers keep a state a request, one keeps K and V pages
+    assert [p[0].shape == state.shape for p in eng._pools] == [
+        True, True, True, False]
+    assert [p.shape for p in eng.page_pools()] == [(32, 2, 128, 32)] * 2
     prompts = _prompts(5, 20, 20, 20, 20)
     reqs = [eng.add_request(p, SamplingParams(max_tokens=40),
                             request_id=f"r{i}", arrival=0,

@@ -221,10 +221,10 @@ def test_a_choice_wider_than_the_context_is_dense_latent_attention(
 def test_the_engine_builds_a_latent_and_an_index_pool_a_sublayer(
         float32_run):
     _, eng, _, _ = float32_run
-    assert len(eng._k_pools) == 3 and len(eng._index_pools) == 3
-    assert eng._v_pools == []
-    assert {p.shape for p in eng._k_pools} == {(24, 1, 128, 128)}
-    assert {p.shape for p in eng._index_pools} == {(24, 1, 128, 128)}
+    # a latent pool and an index pool a layer, no V
+    assert [len(pools) for pools in eng._pools] == [2, 2, 2]
+    assert {p.shape for p, _ in eng._pools} == {(24, 1, 128, 128)}
+    assert {p.shape for _, p in eng._pools} == {(24, 1, 128, 128)}
     assert eng.model.kv_pool_widths() == (1, (128, 128))
     assert eng.model.attention_sublayers == (0, 1, 2)
     assert eng.model.indexed_layers == (0, 1, 2)

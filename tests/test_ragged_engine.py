@@ -272,9 +272,10 @@ def test_a_step_consumes_the_pools_it_was_given(tiny_model):
     model, params = tiny_model
     eng = ServingEngine(model, params, _cfg())
     eng.add_request(list(range(1, 20)), SamplingParams(max_tokens=4))
-    before = [*eng._k_pools, *eng._v_pools]
+    before = eng.page_pools()
     eng.step()
-    after = [*eng._k_pools, *eng._v_pools]
+    after = eng.page_pools()
+    assert len(before) == len(after) == 2
     assert all(a.is_deleted() for a in before)
     assert not any(a.is_deleted() for a in after)
     assert float(jnp.abs(after[0]).sum()) > 0.0     # rows were written
@@ -328,7 +329,7 @@ def _page_poison(model, params, trace, tmp_path):
     eng = _stepped_engine(model, params, trace, 4)
     target = next(r.request_id for r in eng.scheduler.running if r.pages)
     assert FaultInjector(eng, FaultPlan(0, ()))._corrupt(target)
-    assert bool(jnp.isnan(eng._k_pools[0]).any())
+    assert bool(jnp.isnan(eng.page_pools()[0]).any())
     got = _drain(eng)
     clean = _drain(_stepped_engine(model, params, trace, 4))
     got.pop(target, None), clean.pop(target, None)
@@ -595,7 +596,7 @@ def ragged_calls(monkeypatch):
         before = jax.tree.map(jnp.copy, pools)
         out = _ragged_apply(model, params, buffer, pools, layout)
         tokens, index = engine_mod._step_inputs(model, buffer, layout)
-        calls.append((tokens, engine_mod._layer_steps(model, before, index),
+        calls.append((tokens, model.cache_layout().steps(before, index),
                       out[0]))
         return out
 

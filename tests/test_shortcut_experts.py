@@ -131,12 +131,13 @@ def test_the_engine_builds_one_latent_pool_a_sublayer(float32_run):
     """(e): 2 x depth pools of (pages, 1, page, [c | k_r] padded to
     whole registers), none for V; a token costs one row a sublayer."""
     _, eng, _, _ = float32_run
-    assert len(eng._k_pools) == 2 * 2 and eng._v_pools == []
-    assert {p.shape for p in eng._k_pools} == {(24, 1, 128, 128)}
+    latents = [p for pools in eng._pools for p in pools]
+    assert [len(pools) for pools in eng._pools] == [2, 2]   # and no V
+    assert {p.shape for p in latents} == {(24, 1, 128, 128)}
     assert eng.model.kv_pool_widths() == (1, (128,))
     assert eng.model.attention_sublayers == (0, 0, 1, 1)
     per_token = sum(p.shape[1] * p.shape[3] * p.dtype.itemsize
-                    for p in eng._k_pools)
+                    for p in latents)
     assert per_token == 4 * 128 * 4
     # at the published widths: 576 values in 640 lanes, 2 bytes each
     full = decoder_from_config(harness.load_json(
@@ -144,7 +145,8 @@ def test_the_engine_builds_one_latent_pool_a_sublayer(float32_run):
     assert full.kv_pool_widths() == (1, (640,))
     assert len(full.attention_sublayers) == 8
     assert full.num_kv_heads == 1 and full.num_q_heads == 64
-    assert eng._state_pools == [] and eng.allocator.state_slots_in_use == 0
+    assert not eng._layout.state_rows
+    assert eng.allocator.state_slots_in_use == 0
 
 
 def test_the_steps_counters_add_up(float32_run):

@@ -30,7 +30,6 @@ from attention_tpu.engine import engine as engine_mod
 from attention_tpu.engine.engine import (
     RaggedStepIndex,
     StepLayout,
-    _layer_steps,
     _ragged_apply,
     _slot_last_rows,
     _step_inputs,
@@ -43,6 +42,12 @@ from attention_tpu.engine.scheduler import (
 )
 from attention_tpu.engine.sim import replay
 from attention_tpu.models import TinyDecoder, decoder_from_config
+from attention_tpu.models.cache_layout import (
+    STATE_ROWS,
+    CacheLayout,
+    LayerCache,
+    state_step,
+)
 from attention_tpu.models.decode import generate_paged
 from attention_tpu.obs import compiles
 
@@ -155,7 +160,9 @@ def test_the_buffers_segments_are_the_batchs_fields(case):
     np.testing.assert_array_equal(
         buf, np.concatenate([want[name].ravel() for name in order]))
 
-    model = types.SimpleNamespace(recurrent_layers=(0,) * recurrent)
+    kept = CacheLayout((LayerCache(
+        STATE_ROWS, (((2,), jnp.float32),) * 2, state_step),) * recurrent)
+    model = types.SimpleNamespace(cache_layout=lambda: kept)
     layout = StepLayout(_SLOTS, _TABLE, q_tile=16)
     tokens, index = jax.jit(
         functools.partial(_step_inputs, model, layout=layout))(
@@ -343,7 +350,7 @@ def _step_from_seven_arrays(model, params, tokens, pools, index):
     if tokens.shape[1] > index.cu_q_lens.shape[0] - 1:
         rows = _slot_last_rows(index.cu_q_lens)
     logits, _ = model.apply({"params": params}, tokens,
-                            _layer_steps(model, pools, index),
+                            model.cache_layout().steps(pools, index),
                             logit_rows=rows)
     return logits
 

@@ -144,8 +144,12 @@ def test_prefill_over_chunks_then_decode_matches_the_reference(served):
     assert len(reqs[0].output_tokens) == 6
     _check(reference, params, prompts, reqs, logits)
     assert eng.allocator.state_slots_in_use == 0 == eng.pool.used_pages
-    assert len(eng._k_pools) == 1 and len(eng._state_pools) == 3
-    assert eng._state_pools[0].shape == (5, 8, 16, 16)
+    # one K / V pair, three state pairs, nothing for the experts
+    assert [p and len(p) for p in eng._pools] == [2, None, 2, 2, None, 2,
+                                                  None]
+    assert [p is q for p, q in zip(eng.page_pools(), eng._pools[3])] == [
+        True, True]
+    assert eng._pools[0][0].shape == (5, 8, 16, 16)
 
 
 def test_requests_of_free_lengths_in_one_batch(served):

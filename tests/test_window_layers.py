@@ -238,8 +238,7 @@ def test_a_freed_window_page_poisoned_with_nan_changes_no_logit(
     read, so the logits are the clean run's to the bit."""
     model, params = served
     _, prompts, _, clean, _ = session
-    window = [i for i, layer in enumerate(model.attention_sublayers)
-              if layer in model.window_layers]
+    window = model.window_layers
     assert len(window) == 4
     eng = ServingEngine(model, params, EngineConfig(**dict(
         ENGINE, num_window_pages=40)))
@@ -257,15 +256,15 @@ def test_a_freed_window_page_poisoned_with_nan_changes_no_logit(
         if hostages:
             at = jnp.asarray(hostages)
             for i in window:
-                eng._k_pools[i] = eng._k_pools[i].at[at].set(jnp.nan)
-                eng._v_pools[i] = eng._v_pools[i].at[at].set(jnp.nan)
+                eng._pools[i] = tuple(pool.at[at].set(jnp.nan)
+                                      for pool in eng._pools[i])
 
     eng.allocator.release_window = hold
     _serve(model, params, [prompts[0][:640]], 1, eng=eng, after_step=poison)
     _, _, logits = _serve(model, params, prompts, 5, eng=eng,
                           after_step=poison)
     assert len(hostages) >= 3
-    assert all(bool(jnp.isnan(eng._k_pools[window[0]][p]).all())
+    assert all(bool(jnp.isnan(eng._pools[window[0]][0][p]).all())
                for p in hostages)
     for got, want in zip(logits, clean):
         np.testing.assert_array_equal(got, want)
@@ -483,8 +482,8 @@ def test_the_grid_steps_are_each_layers_own_grid(served, monkeypatch):
     def spy(model, params, buffer, pools, layout):
         _, index = engine_mod._step_inputs(model, buffer, layout)
         total = 0
-        for layer, c in enumerate(engine_mod._layer_steps(model, pools,
-                                                          index)):
+        for layer, c in enumerate(model.cache_layout().steps(pools,
+                                                             index)):
             hkv = c.k_pool.shape[1]
             group = model.num_q_heads // hkv
             width = c.token_slot.shape[0]
